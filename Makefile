@@ -10,7 +10,7 @@ test-fast:         ## <5-min single-core gate: kernel/math numerics + model smok
 test-heavy:        ## the compile-heavy model-level integration tier
 	python -m pytest tests/ -q -m "heavy"
 
-bench:             ## one-line JSON benchmark (TPU if available, CPU fallback)
+bench:             ## one-line JSON flagship benchmark on the chip (fails without a TPU)
 	python bench.py
 
 denoise:           ## denoise training example
@@ -143,8 +143,5 @@ perf-gate:         ## committed budgets vs the evidence streams (docs/PERFORMANC
 tpu-checks:        ## on-chip equivariance + kernel numerics/speed gate
 	python scripts/tpu_checks.py
 
-tpu-session:       ## full on-chip suite, retried until the chip is free
-	bash scripts/tpu_session_loop.sh
-
-clean-cache:       ## wipe the Q_J and jit caches
-	rm -rf ~/.cache/se3_transformer_tpu
+clean-cache:       ## wipe the Q_J, kernel-table and jit caches
+	rm -rf .jax_cache

@@ -8,7 +8,7 @@ per-molecule invariant target from a pooled type-0 readout. Demonstrates:
   * discrete edge tokens + adjacency-ring embeddings,
   * the full train loop with the background input pipeline.
 
-Run: python examples/molecular_property.py [--steps N]
+Run: python examples/molecular_property.py [--steps N] [--cpu]
 """
 import argparse
 import os
@@ -18,7 +18,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
 
-if os.environ.get('SE3_EXAMPLES_TPU') != '1':
+# runs on the default backend; --cpu forces the CPU (a sandbox)
+if '--cpu' in sys.argv:
     jax.config.update('jax_platforms', 'cpu')
 
 import jax.numpy as jnp
@@ -55,6 +56,8 @@ def build_batch(i: int) -> dict:
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument('--steps', type=int, default=30)
+    ap.add_argument('--cpu', action='store_true',
+                    help='force the CPU backend (read before jax starts)')
     args = ap.parse_args()
 
     adj = jnp.asarray(chain_adjacency(NUM_ATOMS))

@@ -1,10 +1,11 @@
-"""Shared flagship train-step builder for the diagnostic scripts.
+"""Shared flagship train-step builder for chip_smoke.py and the
+diagnostic scripts.
 
 bench.py is the source of truth for the officially-timed program; this
 module mirrors its setup (seeds, denoise objective, adam(1e-4), donated
-make_sharded_train_step) so bench_diag.py and profile_flagship.py
-measure the same program without three hand-copied replicas drifting
-apart. Any change to bench.py's program must land here too — the
+make_sharded_train_step) so chip_smoke.py, bench_diag.py and
+profile_flagship.py run the same program without hand-copied replicas
+drifting apart. Any change to bench.py's program must land here too — the
 bench_diag loss-sequence cross-check (same seeds => identical losses)
 catches a silent divergence.
 """
@@ -15,18 +16,30 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def build_flagship_step(fast=True, remat=None, chunks=None, nodes=1024,
-                        dim=64, batch=1):
+                        dim=64, batch=1, mesh=None, **recipe_kwargs):
     """Returns (step, params, opt_state, data, key, module): the
     bench-identical donated train step and its initial state.
 
     remat: remat_policy override ('none' forces the policy off);
-    chunks: edge_chunks override (0 = unchunked)."""
+    chunks: edge_chunks override (0 = unchunked); recipe_kwargs: the
+    recipe's own arguments (depth, num_neighbors — a cut-depth run).
+
+    mesh: a parallel.mesh.make_mesh mesh. The same program then runs
+    SPMD: params and adam's state tensor-parallel over 'tp'
+    (parallel.sharding.composed_state_shardings under the 'tp' rules),
+    the batch over 'dp' (parallel.mesh.shard_batch), and the step built
+    with make_sharded_train_step(mesh=..., state_shardings=<those
+    placements>). Same seeds as the one-device build, so the two are
+    comparable loss for loss."""
     import jax
     import jax.numpy as jnp
     import numpy as np
     import optax
 
-    from se3_transformer_tpu.parallel.sharding import make_sharded_train_step
+    from se3_transformer_tpu.parallel.mesh import shard_batch
+    from se3_transformer_tpu.parallel.sharding import (
+        composed_state_shardings, make_sharded_train_step,
+    )
     from se3_transformer_tpu.training import recipes
     from se3_transformer_tpu.utils.compilation_cache import (
         enable_compilation_cache,
@@ -34,7 +47,7 @@ def build_flagship_step(fast=True, remat=None, chunks=None, nodes=1024,
     enable_compilation_cache()
 
     name = 'flagship_fast' if fast else 'flagship'
-    overrides = dict(output_degrees=2, reduce_dim_out=True)
+    overrides = dict(output_degrees=2, reduce_dim_out=True, **recipe_kwargs)
     if remat:
         overrides['remat_policy'] = None if remat == 'none' else remat
     if chunks is not None:
@@ -61,24 +74,22 @@ def build_flagship_step(fast=True, remat=None, chunks=None, nodes=1024,
     params = init_fn(jax.random.PRNGKey(0), seqs, coords, mask=masks,
                      return_type=1)['params']
     optimizer = optax.adam(1e-4)
-    opt_state = optimizer.init(params)
-    step = make_sharded_train_step(loss_fn, optimizer)
     data = dict(seqs=seqs, coords=coords, masks=masks)
+    if mesh is None:
+        opt_state = optimizer.init(params)
+        step = make_sharded_train_step(loss_fn, optimizer)
+    else:
+        # tp placement for params AND adam's state (scalars like `count`
+        # replicated ON the mesh — an eager optimizer.init leaves them on
+        # the first device and the jitted step rejects the device mix),
+        # and the step's in AND out state shardings pinned to it: left
+        # to GSPMD (tensor_parallel=True alone) some leaves come back
+        # from the first step under another spec, which a jitted step
+        # answers with a silent recompile and an AOT executable with a
+        # refusal
+        params, opt_state, placed = composed_state_shardings(
+            params, optimizer.init(params), mesh, rules='tp')
+        data = shard_batch(data, mesh)
+        step = make_sharded_train_step(loss_fn, optimizer, mesh=mesh,
+                                       state_shardings=placed)
     return step, params, opt_state, data, jax.random.PRNGKey(1), module
-
-
-def validate_bench_record(rec: dict) -> dict:
-    """Schema gate for banked flagship records (VERDICT r4 next #5): an
-    on-chip record without a non-null equivariance_l2 must NOT be banked
-    — two round-4 rows (the b=2/edge_chunks variants) regressed to null
-    and the judge flagged it two rounds running. Raises ValueError; the
-    session's crash-isolated stage runner logs the record (it is printed
-    before the save) so the timing survives in the log for forensics
-    without entering the record stream."""
-    metric = str(rec.get('metric', ''))
-    on_chip = 'backend=cpu' not in metric
-    if on_chip and rec.get('equivariance_l2') is None:
-        raise ValueError(
-            f'refusing to bank an on-chip record without equivariance_l2 '
-            f'(schema gate): {metric}')
-    return rec

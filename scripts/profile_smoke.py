@@ -109,7 +109,9 @@ def main(argv=None):
     capture_step_profile(run, log_dir=args.trace_dir, steps=args.steps)
     profile = profile_payload(
         args.trace_dir, label=label, hlo_text=hlo_text,
-        flops_per_step=cost['flops'], steps=args.steps)
+        flops_per_step=cost['flops'], steps=args.steps,
+        device_kind=(None if jax.default_backend() == 'cpu'
+                     else jax.devices()[0].device_kind))
 
     print(json.dumps(dict(label=label,
                           coverage=profile['coverage'],

@@ -22,8 +22,10 @@ This doubles as the `make serve-smoke` gate, exiting non-zero when
 
 `--replicas N` (N > 1) switches to the multi-replica continuous-
 batching router (se3_transformer_tpu.serving): N replica workers, each
-owning its own AOT engine, least-outstanding dispatch, requests
-admitted into in-flight bucket slots (deadline only as a fallback),
+owning its own AOT engine placed on its own device (round-robin over
+the devices JAX sees — `replica_mesh`), least-outstanding dispatch,
+requests admitted into in-flight bucket slots (deadline only as a
+fallback),
 and — with `--swap-at K` — one rolling weight swap after the K-th
 request (fresh seeded params; zero recompiles, zero dropped requests).
 This is the `make serve-multi-smoke` gate; on top of the single-replica
@@ -51,7 +53,10 @@ subsequent dispatch fails deterministically until a swap restores a
 different step — the fault-injected canary of `make serve-fleet-smoke`.
 
 `--fleet N` (N > 1) runs the CROSS-HOST front-end: spawn N `--host`
-worker processes, route the request stream through a
+worker processes — always on the CPU backend: a chip belongs to one
+process at a time, so workers on chips need one chip each and are
+started one per chip by whoever owns the chips, never by this script —
+and route the request stream through a
 `serving.fleet.FleetRouter` (host-level breakers, cross-host
 redispatch, deadline propagation), bank the schema'd `fleet` record,
 and SIGTERM the workers on the way out (each must exit 0). Exits
@@ -221,6 +226,19 @@ def build_module_and_params(args, buckets, seed=None):
             return_type=1)['params']
         print(f'no --checkpoint: initialized fresh params (seed {seed})')
     return cfg, module, params
+
+
+def replica_mesh(i, devices=None):
+    """A one-device mesh pinning replica `i` to its own device
+    (round-robin over `devices`, default every device JAX sees). The
+    engine's `mesh` argument is its placement mechanism; without one
+    every replica's params and executables land on the first device and
+    N replicas on a multi-chip host share one chip."""
+    import jax
+
+    from se3_transformer_tpu.parallel.mesh import make_mesh
+    devices = jax.devices() if devices is None else devices
+    return make_mesh([devices[i % len(devices)]], dp=1, sp=1, tp=1)
 
 
 def request_lengths(args, buckets, max_len, rng):
@@ -402,7 +420,8 @@ def serve_multi(args):
     engines = [InferenceEngine(
         module, params, buckets=buckets, batch_size=args.batch_size,
         return_type=1, timer=timer, precision=mixes[i],
-        activation_dtype=jnp.bfloat16 if args.bf16 else None)
+        activation_dtype=jnp.bfloat16 if args.bf16 else None,
+        mesh=replica_mesh(i), partition_rules='replicated')
         for i in range(args.replicas)]
     print(f'warmup: {args.replicas} replicas x '
           f'{len(engines[0].executables)} bucket executables in '
@@ -592,7 +611,8 @@ def serve_host(args):
     engines = [InferenceEngine(
         module, params, buckets=buckets, batch_size=args.batch_size,
         return_type=1, timer=timer, precision=mixes[i],
-        activation_dtype=jnp.bfloat16 if args.bf16 else None)
+        activation_dtype=jnp.bfloat16 if args.bf16 else None,
+        mesh=replica_mesh(i), partition_rules='replicated')
         for i in range(max(args.replicas, 1))]
     print(f'host {args.host_id}: warmup {len(engines)} replicas x '
           f'{len(engines[0].executables)} bucket executables in '
@@ -826,7 +846,7 @@ def serve_fleet(args):
             max_queue_depth=args.max_queue_depth,
             checkpoint=args.checkpoint,
             checkpoint_step=args.checkpoint_step, bf16=args.bf16,
-            async_dispatch=args.async_dispatch, cpu=args.cpu,
+            async_dispatch=args.async_dispatch, cpu=True,
             transport=args.transport))
     try:
         for p in procs:

@@ -15,7 +15,7 @@ The numbers are XLA:CPU SPMD estimates — layouts/fusion differ from TPU
 (measured on-chip: dim=64 needs the remat recipe to fit 16 GB, which
 matches this harness's estimate within ~20%) — so the table is stated
 as the scaling story, with the dim=64 single-chip point anchored by the
-real-HBM measurements in docs/STATUS.md.
+real-HBM measurements in BENCH_SESSION.jsonl.
 
 Usage (fresh process per device count — the virtual device count is
 fixed at backend init):

@@ -36,6 +36,7 @@ import numpy as np
 
 from .so3.spherical_harmonics import real_spherical_harmonics_all
 from .so3.wigner import wigner_d_from_rotation, rot
+from .utils.compilation_cache import CHECKOUT_CACHE_DIR
 
 # fixed, well-conditioned random rotations for the Sylvester system
 # (role of reference basis.py:20-26 RANDOM_ANGLES; values are our own)
@@ -47,8 +48,7 @@ _RANDOM_ANGLES = np.array([
     [0.14730622, 4.18146178, 0.78533526],
 ])
 
-CACHE_PATH = os.environ.get(
-    'SE3_TPU_CACHE_PATH', os.path.expanduser('~/.cache/se3_transformer_tpu'))
+CACHE_PATH = os.environ.get('SE3_TPU_CACHE_PATH', CHECKOUT_CACHE_DIR)
 CLEAR_CACHE = 'SE3_TPU_CLEAR_CACHE' in os.environ
 _CACHE_VERSION = 1
 

@@ -454,11 +454,10 @@ def _att_partitioned(heads, scale, interpret, has_mask, bwd):
         rule = f'a n d, b n j d, b n j d{mask_term} -> a n d'
     # special-factor indices must be sorted by first appearance in the
     # rule: d (q's last dim) precedes the slot axis j
-    from .pallas_pairwise import _def_partition_compat
-    _def_partition_compat(f, partition=partition,
-                          infer_sharding_from_operands=infer,
-                          sharding_rule=rule,
-                          need_replication_factors=('d', 'j'))
+    f.def_partition(partition=partition,
+                    infer_sharding_from_operands=infer,
+                    sharding_rule=rule,
+                    need_replication_factors=('d', 'j'))
     return f
 
 

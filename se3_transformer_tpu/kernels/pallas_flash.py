@@ -37,8 +37,9 @@ tensor folded into the state at slot-block 0; neighbor masks keep the
 unfused semantics exactly (finite NEG_INF fill, so a fully-masked row
 degrades to the same uniform average the XLA softmax produces).
 
-Dispatch: the Pallas kernel runs on TPU (or under `interpret=True` for
-the CPU tests); everywhere else `_flash_stream` computes the identical
+Dispatch: the Pallas kernel runs under `interpret=True` (the CPU tests);
+its body does not compile for the TPU yet (MOSAIC_REFUSES below), so on
+the chip — and everywhere else — `_flash_stream` computes the identical
 function by streaming REMAT'D NODE CHUNKS through XLA (lax.map +
 jax.checkpoint), which is also what the `custom_vjp` backward replays —
 recompute-in-backward, so the only saved residuals are the kernel's
@@ -442,7 +443,12 @@ def _flash_vmem_bytes(bn: int, bj: int, S0: int, heads: int, kv_h: int,
 def flash_admissible_blocks(shape) -> list:
     """Tile-legal, VMEM-admissible (block_n, block_j) candidates for a
     'flash' shape tuple (n, K, S0, heads, kv_h, Dh, mid, IF, P, xres)
-    — what scripts/tune_kernels.py may measure. In kNN mode (K > 0)
+    — what scripts/tune_kernels.py may measure. Both block sizes are
+    multiples of 8: block_j is a second-minor block dimension of the
+    [.., block_j, mid] payload operands, and the operands whose MINOR
+    axis is the slot axis (idx / nmask / nodemask) are laid out
+    slot-block-major in _flash_fwd_impl so that any such block_j spans
+    their whole minor axis. In kNN mode (K > 0)
     the node-feature residency is n-scaled and block-independent: a
     shape whose resident set alone busts the budget admits NOTHING
     (the caller must fall back to the XLA stream), rather than
@@ -726,7 +732,7 @@ def _flash_kernel_body(cfg: FlashConfig, spec, dims, *refs):
     # per degree instead of Q -> 128 per channel row); unflatten after
     # the gather
     if cfg.mode == 'knn':
-        idxb = named['idx'][0]                     # [bn, bj] int32
+        idxb = named['idx'][0, 0]                  # [bn, bj] int32
         xg = tuple(
             jnp.take(named[f'x{i}'][0], idxb,
                      axis=0).reshape(bn, bj, c, 2 * d + 1)
@@ -735,7 +741,7 @@ def _flash_kernel_body(cfg: FlashConfig, spec, dims, *refs):
         h_k = named['h_k'][0] if 'h_k' in named else h_v
         sh = named['sh'][0] if 'sh' in named else None
         fr = unpack_frames(named['fr'][0]) if 'fr' in named else None
-        maskb = named['nmask'][0] if cfg.has_mask else None
+        maskb = named['nmask'][0, 0] if cfg.has_mask else None
     else:
         ci = named['coords_i'][0]                  # [bn, 3]
         cj = named['coords_j'][0]                  # [bj, 3]
@@ -751,8 +757,7 @@ def _flash_kernel_body(cfg: FlashConfig, spec, dims, *refs):
             for i, (d, c) in enumerate(cfg.pairs))
         maskb = None
         if cfg.has_mask:
-            maskb = jnp.broadcast_to(named['nodemask'][0][None, :],
-                                     (bn, bj))
+            maskb = jnp.broadcast_to(named['nodemask'][0, 0], (bn, bj))
         if cfg.exclude_self:
             rows = pl.program_id(1) * bn + \
                 jax.lax.broadcasted_iota(jnp.int32, (bn, bj), 0)
@@ -845,13 +850,24 @@ def _flash_fwd_impl(cfg: FlashConfig, ops: dict) -> jnp.ndarray:
             pad[2] = (0, K_p - a.shape[2])
             return jnp.pad(a, pad, constant_values=fill)
 
+        def slot_major(a):
+            """[B, n_p, K_p] -> [B, jcount, n_p, bj]: Mosaic wants a
+            block's last two dims (8, 128)-divisible or the array's
+            full extent, and a bj-wide slot block of a [.., n_p, K_p]
+            operand is neither on its minor axis. With the slot-block
+            index as its own axis the (bn, bj) block spans the whole
+            minor axis."""
+            return jnp.swapaxes(
+                a.reshape(a.shape[0], n_p, jcount, bj), 1, 2)
+
         # padded slots are hard-zeroed by the `inbounds` vector in the
         # kernel body, so no mask is needed for them
-        add('idx', pad_slots(pad_nodes(ops['idx'])), (1, bn, bj),
-            lambda b, i, j: (b, i, j))
+        add('idx', slot_major(pad_slots(pad_nodes(ops['idx']))),
+            (1, 1, bn, bj), lambda b, i, j: (b, j, i, 0))
         if cfg.has_mask:
-            add('nmask', pad_slots(pad_nodes(ops['nmask'], False), False),
-                (1, bn, bj), lambda b, i, j: (b, i, j))
+            add('nmask', slot_major(pad_slots(
+                pad_nodes(ops['nmask'], False), False)),
+                (1, 1, bn, bj), lambda b, i, j: (b, j, i, 0))
         mid = ops['h_v'].shape[-1]
         add('h_v', pad_slots(pad_nodes(ops['h_v'])), (1, bn, bj, mid),
             lambda b, i, j: (b, i, j, 0))
@@ -889,8 +905,11 @@ def _flash_fwd_impl(cfg: FlashConfig, ops: dict) -> jnp.ndarray:
         add('coords_j', pad_cols(ops['coords'], 1), (1, bj, 3),
             lambda b, i, j: (b, j, 0))
         if cfg.has_mask:
-            add('nodemask', pad_cols(ops['nodemask'], 1, False), (1, bj),
-                lambda b, i, j: (b, j))
+            # [B, n_pj] -> [B, jcount, 1, bj]: same minor-axis rule as
+            # the kNN arm's idx/nmask (slot_major above)
+            add('nodemask', pad_cols(ops['nodemask'], 1, False).reshape(
+                B, jcount, 1, bj), (1, 1, 1, bj),
+                lambda b, i, j: (b, j, 0, 0))
         for i, x in enumerate(ops['xs']):
             xp = pad_cols(x.reshape(x.shape[0], x.shape[1], -1), 1)
             add(f'x{i}', xp, (1, bj, xp.shape[-1]),
@@ -1002,13 +1021,37 @@ def _flash_core_bwd(cfg, ops, g):
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
 
 
-def _resolve_pallas(pallas: Optional[bool], interpret: bool) -> bool:
+# What the TPU compiler says to each mode's kernel body once the block
+# specs are legal (deviceless compile for a described v5e, jax 0.9.0 /
+# libtpu 0.0.34; tests/test_tpu_compile.py holds each as a strict xfail,
+# so the entry goes the day the arm compiles). The body is the XLA
+# stream's jnp code run on a tile, and Mosaic lowers neither a general
+# gather nor a dot with two batch dimensions.
+MOSAIC_REFUSES = {
+    'knn': "ValueError: Shape mismatch in input, indices and output — "
+           "the in-tile neighbour gather, jnp.take of the [n, C*Q] "
+           "features with a [block_n, block_j] index block (dense and "
+           "so2 arms alike)",
+    'global': "'tpu.matmul' op Not implemented: Up to 1 batch dim "
+              "supported — the per-edge einsums carry (block_n, block_j) "
+              "as two batch dimensions",
+}
+
+
+def _resolve_pallas(pallas: Optional[bool], interpret: bool,
+                    mode: str) -> bool:
+    """The selector. Interpret mode (the CPU tests) runs the kernel. On
+    the chip no arm compiles yet (MOSAIC_REFUSES), so the default is the
+    XLA stream by rule — never by catching the compiler — and asking
+    for the kernel outright says why it cannot be had."""
     if interpret:
         return True
-    if pallas is None:
-        from ..utils.helpers import is_tpu_backend
-        return is_tpu_backend()
-    return pallas
+    if pallas:
+        raise NotImplementedError(
+            f'pallas=True: the flash kernel\'s {mode!r} arm does not '
+            f'compile for the TPU ({MOSAIC_REFUSES[mode]}). Leave pallas '
+            f'unset for the XLA streaming path, or use interpret mode.')
+    return False
 
 
 # --------------------------------------------------------------------- #
@@ -1042,7 +1085,7 @@ def flash_attention(q, xs, idx, nmask, h_v, wv, bv, *,
         scale=float(scale), arm_v=arm_v, arm_k=arm_k, tie=tie,
         prefix=int(prefix_k.shape[2]) if prefix_k is not None else 0,
         has_mask=nmask is not None, mode='knn',
-        use_pallas=_resolve_pallas(pallas, interpret),
+        use_pallas=_resolve_pallas(pallas, interpret, 'knn'),
         interpret=interpret)
     ops = dict(q=q, xs=tuple(xs), idx=idx, h_v=h_v, wv=wv, bv=bv)
     if wv_scale is not None:
@@ -1099,7 +1142,7 @@ def flash_global_attention(q, xs, coords, rp_v, wv, bv, *,
         has_mask=node_mask is not None, mode='global',
         exclude_self=bool(exclude_self),
         use_pallas=(False if materialize
-                    else _resolve_pallas(pallas, interpret)),
+                    else _resolve_pallas(pallas, interpret, 'global')),
         interpret=interpret)
     rp_v = tuple(p.reshape(1, -1) if p.ndim == 1 else p for p in rp_v)
     ops = dict(q=q, xs=tuple(xs), coords=coords, rp_v=rp_v, wv=wv, bv=bv)
@@ -1141,7 +1184,7 @@ def flash_global_attention_sharded(q, xs, coords, rp_v, wv, bv, *,
     bit-compatible results (the fold is `_attend_block`, the same
     online softmax the kernel and the stream run)."""
     from jax.sharding import PartitionSpec as P
-    from ..parallel.ring import pcast_varying, ring_scan, shard_map
+    from ..parallel.ring import pcast_varying, ring_scan
     tie = wk is None
     cfg = FlashConfig(
         pairs=tuple((int(d), int(c)) for d, c in pairs),
@@ -1196,8 +1239,8 @@ def flash_global_attention_sharded(q, xs, coords, rp_v, wv, bv, *,
             wk_l, bk_l, axis_name=axis_name, overlap=overlap,
             pcast=pcast_varying, ring=ring_scan)
 
-    fn = shard_map(local, mesh=mesh, in_specs=tuple(in_specs),
-                   out_specs=row(4))
+    fn = jax.shard_map(local, mesh=mesh, in_specs=tuple(in_specs),
+                       out_specs=row(4))
     with jax.named_scope('flash_global_attention_sharded'):
         return fn(*sharded, *repl)
 

@@ -1,9 +1,11 @@
 """ctypes loader for the native host-side graph/data pipeline.
 
-Compiles graph_builder.cpp on first use (cached as a shared library next to
-the source; rebuilt when the source is newer). Every entry point has a
-NumPy fallback, so the framework works even without a toolchain — the
-native path just keeps the TPU from waiting on host-side batch prep.
+Compiles graph_builder.cpp on first use (the shared library next to the
+source is a build output, never committed: a fresh checkout builds it,
+and an edited source rebuilds it). Every entry point has a NumPy
+fallback, so the framework works even without a toolchain — the native
+path just keeps the TPU from waiting on host-side batch prep;
+`native_available()` says which of the two is in effect.
 """
 from __future__ import annotations
 

@@ -284,11 +284,15 @@ def profile_payload(trace_dir: str, *, label: str,
                     hlo_text: Optional[str] = None,
                     scopes: Sequence[str] = MODEL_SCOPES,
                     flops_per_step: Optional[float] = None,
-                    steps: int = 1, top_unattributed: int = 8) -> dict:
+                    steps: int = 1, top_unattributed: int = 8,
+                    device_kind: Optional[str] = None) -> dict:
     """The schema'd `profile` record body (kind='profile', minus
     run_id): per-scope device-time shares + attribution coverage for
     one captured trace, and the roofline figure when the caller
-    supplies the program's per-step flops (observability.costs)."""
+    supplies the program's per-step flops (observability.costs).
+    `device_kind` names the accelerator the trace was captured on
+    (`jax.devices()[0].device_kind`); utilization is priced against
+    that device's published peak and omitted without it."""
     events = load_trace_events(trace_dir)
     dev, info = device_events(events)
     op_map = op_scope_map(hlo_text, scopes) if hlo_text else {}
@@ -312,11 +316,14 @@ def profile_payload(trace_dir: str, *, label: str,
             for op, us in att['unattributed'][:top_unattributed]],
     )
     if flops_per_step and total_us:
-        from ..utils.flops import PEAK_BF16
         flops_per_sec = flops_per_step * steps / (total_us / 1e6)
         body['roofline'] = dict(
             flops_per_step=flops_per_step,
-            device_flops_per_sec=round(flops_per_sec, 1),
-            # v5e bf16 MXU peak; decorative on CPU hosts (documented)
-            utilization_vs_bf16_peak=round(flops_per_sec / PEAK_BF16, 6))
+            device_flops_per_sec=round(flops_per_sec, 1))
+        if device_kind is not None:
+            # against the peak of the device the trace was taken on; a
+            # CPU trace (device_kind=None) carries no utilization
+            from ..utils.flops import device_peaks
+            body['roofline']['utilization_vs_bf16_peak'] = round(
+                flops_per_sec / device_peaks(device_kind)['bf16_flops'], 6)
     return body

@@ -39,21 +39,20 @@ def _install_compile_listener():
     if _LISTENER_INSTALLED[0]:
         return
     _LISTENER_INSTALLED[0] = True
-    try:
-        from jax import monitoring
+    # no guard: a listener that failed to install would make every
+    # "zero post-warmup compiles" gate pass without counting anything
+    from jax import monitoring
 
-        def _on_event(event: str, **kwargs):
-            if 'compil' in event:
-                _COMPILE_EVENTS[0] += 1
+    def _on_event(event: str, **kwargs):
+        if 'compil' in event:
+            _COMPILE_EVENTS[0] += 1
 
-        def _on_duration(event: str, duration: float, **kwargs):
-            if 'compil' in event:
-                _COMPILE_EVENTS[0] += 1
+    def _on_duration(event: str, duration: float, **kwargs):
+        if 'compil' in event:
+            _COMPILE_EVENTS[0] += 1
 
-        monitoring.register_event_listener(_on_event)
-        monitoring.register_event_duration_secs_listener(_on_duration)
-    except Exception:  # noqa: BLE001 - monitoring API is advisory
-        pass
+    monitoring.register_event_listener(_on_event)
+    monitoring.register_event_duration_secs_listener(_on_duration)
 
 
 def device_memory_stats() -> Optional[dict]:

@@ -52,7 +52,7 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..utils.helpers import batched_index_select
-from .ring import pcast_varying, ring_scan, shard_map
+from .ring import pcast_varying, ring_scan
 
 
 # ------------------------------------------------------------------------- #
@@ -106,7 +106,7 @@ def neighbor_gather(values: jnp.ndarray, idx: jnp.ndarray, mesh: Mesh,
     vspec = P(None, axis_name, *([None] * (values.ndim - 2)))
     ispec = P(None, axis_name, None)
     ospec = P(None, axis_name, *([None] * (values.ndim - 1)))
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_gather_local, axis_name=axis_name, overlap=overlap),
         mesh=mesh, in_specs=(vspec, ispec), out_specs=ospec)
     # 'exchange' scopes the rotation+select for xprof attribution
@@ -132,8 +132,8 @@ def rowwise_gather(values: jnp.ndarray, idx: jnp.ndarray, mesh: Mesh,
     vspec = P(None, axis_name, *([None] * (values.ndim - 2)))
     ispec = P(None, axis_name, None)
     ospec = P(None, axis_name, *([None] * (values.ndim - 2)))
-    fn = shard_map(lambda v, i: batched_index_select(v, i, axis=2),
-                   mesh=mesh, in_specs=(vspec, ispec), out_specs=ospec)
+    fn = jax.shard_map(lambda v, i: batched_index_select(v, i, axis=2),
+                       mesh=mesh, in_specs=(vspec, ispec), out_specs=ospec)
     with jax.named_scope('exchange'):
         return fn(values, idx)
 
@@ -182,7 +182,7 @@ def bonded_priority_mask(adj_mat: jnp.ndarray, noise_n1: jnp.ndarray,
     sp = mesh.shape[axis_name]
     assert n % sp == 0, f'n={n} must divide over {axis_name}={sp}'
     row = P(None, axis_name, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_bonded_local, num_sparse=num_sparse, n=n,
                 axis_name=axis_name),
         mesh=mesh, in_specs=(row, row), out_specs=row)

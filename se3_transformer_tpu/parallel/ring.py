@@ -42,42 +42,11 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.neighbors import FINF, _top_k_smallest
 
-# --- jax version compat (this container ships jax 0.4.37) ----------------- #
-# shard_map graduated from jax.experimental to jax.shard_map, and the vma
-# (varying-manual-axes) tracking it enforces grew the jax.lax.pcast
-# entry point, only on newer jax. Resolve both once here; exchange.py
-# shares these shims.
-try:
-    _shard_map = jax.shard_map
-    _SHARD_MAP_KW: dict = {}
-except AttributeError:  # jax < 0.6
-    from jax.experimental.shard_map import (  # type: ignore
-        shard_map as _shard_map,
-    )
-    # the legacy rep-tracker mis-infers scan-carry TANGENT replication
-    # when a shard_map is differentiated under a custom_vjp's jvp (the
-    # reversible trunk): instantiated-zero tangents enter the carry with
-    # rep None and the check rejects the (correct) program. jax's own
-    # guidance for this false positive is check_rep=False — a static
-    # checker toggle only, numerics unchanged. New-jax vma tracking
-    # (pcast_varying below) stays fully checked.
-    _SHARD_MAP_KW = dict(check_rep=False)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs):
-    """Version-portable shard_map (see _SHARD_MAP_KW above); the ring and
-    parallel.exchange build every collective region through this."""
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **_SHARD_MAP_KW)
-
 
 def pcast_varying(x: jnp.ndarray, axis_name: str) -> jnp.ndarray:
     """Mark a per-shard constant as device-varying for shard_map's vma
-    tracking; identity on jax versions that predate vma."""
-    pcast = getattr(jax.lax, 'pcast', None)
-    if pcast is None:
-        return x
-    return pcast(x, (axis_name,), to='varying')
+    (varying-manual-axes) tracking."""
+    return jax.lax.pcast(x, (axis_name,), to='varying')
 
 
 def ring_scan(body, carry, blocks, axis_name: str, overlap: bool = True):
@@ -296,8 +265,8 @@ def ring_knn(coors: jnp.ndarray, k: int, mesh: Mesh,
             ops[sp_pos] if sp_pos is not None else None,
             k=k, axis_name=axis_name, causal=causal, overlap=overlap)
 
-    fn = shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                   out_specs=(spec, spec))
+    fn = jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
+                       out_specs=(spec, spec))
     # scope the ring (scan of score/merge/ppermute) for xprof attribution
     # (observability.timing.MODEL_SCOPES)
     with jax.named_scope('ring_knn'):

@@ -63,7 +63,7 @@ def flagship_fast(dim: int = 64, num_neighbors: int = 32,
     (edge_chunks=None): with fuse_basis the V2 edge tensor never touches
     HBM in the forward, and after the MXU one-hot gather fix the whole
     dim=64/n=1024 reversible training step fits one 16 GB v5e outright.
-    Measured on chip (PROBE_TPU.jsonl, round 4): edge_chunks=8 ->
+    Measured on chip (round 4; record deleted with PR 21): edge_chunks=8 ->
     309.3, =2 -> 322.3, unchunked -> 394.28 nodes*steps/s — the chunk
     streaming's lax.map tax costs 27%.
 
@@ -81,11 +81,14 @@ def flagship_fast(dim: int = 64, num_neighbors: int = 32,
     if overrides['reversible']:  # the policy is meaningless (and raises)
         # without reversible remat — e.g. the probe's --nonrev arm
         overrides.setdefault('remat_policy', 'save_conv_outputs')
+    # a parity check that must differ in nothing but its sharding turns
+    # the bf16 radial casts off (chip_smoke.py --chips 4)
+    overrides.setdefault('radial_bf16', True)
     return SE3TransformerModule(
         dim=dim, depth=depth, num_degrees=4, heads=8, dim_head=max(8, dim // 8),
         attend_self=True, num_neighbors=num_neighbors,
         valid_radius=valid_radius, shared_radial_hidden=True,
-        fuse_basis=True, radial_bf16=True, **overrides)
+        fuse_basis=True, **overrides)
 
 
 def af2_refinement(dim: int = 32) -> SE3TransformerModule:

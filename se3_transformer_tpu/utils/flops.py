@@ -32,10 +32,28 @@ from .helpers import to_order
 # nowhere because it is <1% of the apply term it rides on.
 MID = 128
 
-# v5e per-chip peaks used for MFU reporting: ~197 TFLOP/s bf16 MXU;
-# f32 runs as 3-pass bf16 (~1/4 rate)
-PEAK_BF16 = 197e12
-PEAK_F32 = PEAK_BF16 / 4
+# Published per-chip peaks, keyed by jax's `device_kind`. Source: Google
+# Cloud documentation, "TPU v5e" system architecture table (197 TFLOP/s
+# bf16, 819 GB/s HBM bandwidth, 16 GB HBM). f32 matmuls run as multi-pass
+# bf16 on the MXU; the repo's utilization figures have always priced
+# them at a quarter of the bf16 rate.
+DEVICE_PEAKS = {
+    'TPU v5 lite': dict(bf16_flops=197e12, f32_flops=197e12 / 4,
+                        hbm_bytes_per_sec=819e9),
+}
+
+
+def device_peaks(device_kind: str) -> dict:
+    """Peaks of the device a number was measured on. A kind that is not
+    in the table raises: a utilization against another chip's peak (or
+    a CPU's wall clock against any) is a fabricated figure."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f'no published peaks for device_kind {device_kind!r} '
+            f'(known: {sorted(DEVICE_PEAKS)}); add it to '
+            f'utils.flops.DEVICE_PEAKS with its source') from None
 
 
 def conv_flops(fiber_in, fiber_out, E: int, shared_trunk: bool = True

@@ -12,7 +12,7 @@ Load-bearing contracts (ISSUE 4 acceptance):
     rejected with a warning, never handed to Mosaic;
   * candidate enumeration is bwd-aware and excludes the configs the
     round-4 standalone sweep measured as Mosaic VMEM compile failures
-    (KERNEL_TUNE.jsonl: bx (256,16)/(512,16), bxf (512,16)).
+    (bx (256,16)/(512,16), bxf (512,16)).
 
 Everything runs on CPU; the end-to-end check uses interpreter-mode
 kernels at tiny shapes.
@@ -29,7 +29,7 @@ from se3_transformer_tpu.kernels.pallas_pairwise import (
     _pick_blocks, _pick_blocks_bx,
 )
 
-# the flagship shape tuples (BASELINE.md / KERNEL_TUNE.jsonl)
+# the flagship shape tuples (BASELINE.md)
 PLAIN_FLAGSHIP = (32768, 1024, 64, 7, 128)
 PLAIN_CHUNKED = (4096, 1024, 64, 7, 128)
 BX_FLAGSHIP = (32768, 64, 64, 7, 7, 7, 128)
@@ -220,8 +220,8 @@ def test_shape_pinned_force_does_not_leak_to_other_shapes():
 
 
 def test_admissible_candidates_exclude_measured_mosaic_failures():
-    # the round-4 sweep's Mosaic VMEM compile failures
-    # (KERNEL_TUNE.jsonl) must be excluded up front
+    # the round-4 sweep's Mosaic VMEM compile failures must be
+    # excluded up front
     bx = tuning.admissible_candidates('bx', BX_FLAGSHIP)
     assert (256, 16) not in bx and (512, 16) not in bx
     assert (128, 8) in bx  # the production-validated default

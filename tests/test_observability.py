@@ -305,7 +305,7 @@ def test_obs_report_reproduces_round5_best_of_two():
 def test_report_flags_one_sided_outliers():
     recs = [dict(metric='m(x)', value=300.0, unit='u', vs_baseline=1.0),
             dict(metric='m(x)', value=297.0, unit='u', vs_baseline=1.0),
-            # a tunnel-latency-poisoned window: far below best
+            # a host-latency-poisoned window: far below best
             dict(metric='m(x)', value=199.0, unit='u', vs_baseline=0.66),
             # an impossible rate: flagged regardless of magnitude
             dict(metric='m(x)', value=2487.0, unit='u', vs_baseline=9.4,

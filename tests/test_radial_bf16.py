@@ -3,9 +3,8 @@
 The radial MLP's inputs are rotation-invariant scalars, so quantizing it
 to bf16 adds noise that (nearly) cancels between the rotated and
 unrotated forward — unlike a global bf16 matmul policy, which quantizes
-the equivariant contractions and costs ~1e-3 equivariance error on chip
-(docs/STATUS.md). These tests pin that property and the numeric
-agreement of the XLA and Pallas (interpret) bf16 paths.
+the equivariant contractions and costs ~1e-3 equivariance error on chip.
+These tests pin that property and the numeric agreement of the XLA and Pallas (interpret) bf16 paths.
 """
 import jax
 import jax.numpy as jnp

@@ -1,0 +1,344 @@
+"""The main path's kernels, asked of the TPU's own compiler without a TPU.
+
+libtpu compiles for a chip that is DESCRIBED, not attached
+(`jax.experimental.topologies`), so each case here raises what the chip's
+compiler would raise — tile-illegal block specs, scoped-VMEM overflows,
+ops Mosaic does not lower — at real widths, in a second or a few, at no
+chip time. Interpret mode accepts all of that silently.
+
+Nothing runs: these cases say a kernel COMPILES, never that it is right
+or fast (the interpret-mode parity tests hold the numerics; a chip run
+holds the rest). Code that asks `jax.default_backend()` still sees the
+CPU here, so every case calls the kernel entry point itself.
+
+Two processes cannot hold the TPU compiler plug-in at once (libtpu's
+multi-process lockfile: "ABORTED: Internal error when accessing libtpu
+multi-process lockfile") unless ALLOW_MULTIPLE_LIBTPU_LOAD is set, which
+this file does before libtpu loads: a parallel test run (pytest-xdist)
+spreads these cases over its workers, and a compile-only use never
+touches a chip. The persistent compilation cache is off around the
+cases — a deviceless executable can be written to it but not read back
+without a chip.
+"""
+import os
+
+os.environ.setdefault('TPU_LOG_DIR', 'disabled')  # else libtpu logs to /tmp
+os.environ.setdefault('ALLOW_MULTIPLE_LIBTPU_LOAD', '1')
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import pytest  # noqa: E402
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+from se3_transformer_tpu.kernels import pallas_flash as pf  # noqa: E402
+from se3_transformer_tpu.kernels.pallas_attention import (  # noqa: E402
+    fused_attention,
+)
+from se3_transformer_tpu.kernels.pallas_pairwise import (  # noqa: E402
+    fused_pairwise_conv, fused_pairwise_conv_bwd, fused_pairwise_conv_bx,
+    fused_pairwise_conv_bxf,
+)
+
+# the flagship shape tuples (tests/test_kernel_tuning.py pins the block
+# picks at the same ones): dim=64, n=1024, k=32, degree 4
+E, MID, O, P, Q, F, C = 32768, 128, 64, 7, 7, 7, 64
+IF = 1024
+ATT_N, ATT_J, ATT_D, ATT_HEADS = 1024, 33, 56, 8
+
+
+@pytest.fixture(scope='module')
+def v5e_2x2():
+    """The four devices of a described v5e 2x2; skipped where libtpu
+    cannot describe it."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform='tpu',
+                                            topology_name='v5e:2x2')
+    except Exception as e:  # noqa: BLE001 - no TPU compiler installed
+        pytest.skip(f'cannot describe a v5e topology here: {e}')
+    jax.config.update('jax_enable_compilation_cache', False)
+    compilation_cache.reset_cache()
+    yield topo.devices
+    jax.config.update('jax_enable_compilation_cache', True)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope='module')
+def v5e(v5e_2x2):
+    """One described chip."""
+    return SingleDeviceSharding(v5e_2x2[0])
+
+
+def compile_for(device, fn, *shapes):
+    """Lower `fn` at (shape, dtype) pairs placed on the described device
+    and compile; returns the number of Mosaic calls in the program."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=device) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    return text.count('tpu_custom_call')
+
+
+f32, bf16, i32, b1 = jnp.float32, jnp.bfloat16, jnp.int32, jnp.bool_
+
+# (h, w3, bias) dtypes: f32, and the bf16 radial operands radial_bf16
+# hands the kernels (ops/conv.py: bias and equivariant operands stay f32)
+RADIAL = pytest.mark.parametrize('rdt', [f32, bf16],
+                                 ids=['f32', 'radial_bf16'])
+
+
+@RADIAL
+def test_pairwise_forward_compiles(v5e, rdt):
+    calls = compile_for(
+        v5e, lambda h, w3, v2, b3: fused_pairwise_conv(h, w3, v2, b3),
+        ((E, MID), rdt), ((MID, IF, O), rdt), ((E, P, IF), f32),
+        ((IF, O), f32))
+    assert calls > 0
+
+
+@RADIAL
+def test_pairwise_backward_compiles(v5e, rdt):
+    calls = compile_for(
+        v5e,
+        lambda h, w3, v2, g, b3: fused_pairwise_conv_bwd(h, w3, v2, g, b3),
+        ((E, MID), rdt), ((MID, IF, O), rdt), ((E, P, IF), f32),
+        ((E, P, O), f32), ((IF, O), f32))
+    assert calls > 0
+
+
+@RADIAL
+def test_pairwise_bx_compiles(v5e, rdt):
+    calls = compile_for(
+        v5e,
+        lambda h, w3, basis, x, b3: fused_pairwise_conv_bx(
+            h, w3, basis, x, b3),
+        ((E, MID), rdt), ((MID, C * F, O), rdt), ((E, P, Q, F), f32),
+        ((E, C, Q), f32), ((C * F, O), f32))
+    assert calls > 0
+
+
+@RADIAL
+def test_pairwise_bxf_compiles(v5e, rdt):
+    calls = compile_for(
+        v5e,
+        lambda h, w3, basis, x, b3: fused_pairwise_conv_bxf(
+            h, w3, basis, x, (P, Q, F), b3),
+        ((E, MID), rdt), ((MID, C * F, O), rdt), ((E, P * F * Q), f32),
+        ((E, C, Q), f32), ((C * F, O), f32))
+    assert calls > 0
+
+
+ATT_SHAPES = (((ATT_HEADS, ATT_N, ATT_D), f32),
+              ((ATT_HEADS, ATT_N, ATT_J, ATT_D), f32),
+              ((ATT_HEADS, ATT_N, ATT_J, ATT_D), f32),
+              ((1, ATT_N, ATT_J), b1))
+
+
+def test_fused_attention_forward_compiles(v5e):
+    calls = compile_for(
+        v5e, lambda q, k, v, m: fused_attention(
+            q, k, v, m, ATT_HEADS, ATT_D ** -0.5), *ATT_SHAPES)
+    assert calls > 0
+
+
+def test_fused_attention_backward_compiles(v5e):
+    def grads(q, k, v, m):
+        return jax.grad(lambda q, k, v: fused_attention(
+            q, k, v, m, ATT_HEADS, ATT_D ** -0.5).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+    # the dq/dk/dv kernel alone: nothing reads the forward's output, so
+    # XLA drops the forward call
+    assert compile_for(v5e, grads, *ATT_SHAPES) > 0
+
+
+def test_so2_contraction_compiles(v5e):
+    """conv backend 'so2' for one degree-3 pair at flagship n, k, width:
+    rotate-in, banded z, the radial apply through the plain kernel,
+    rotate-out."""
+    from se3_transformer_tpu.so2.contract import so2_pair_contract
+    n, k, d = 1024, 32, 3
+    edge = (1, n, k)
+
+    def fn(h, w3, b3, x, ca, sa, cb, sb):
+        frames = dict(cos_a=ca, sin_a=sa, cos_b=cb, sin_b=sb)
+        return so2_pair_contract(h, w3, b3, frames, x, d_in=d, d_out=d,
+                                 pallas=True, pallas_interpret=False,
+                                 edge_chunks=None)
+    calls = compile_for(
+        v5e, fn, ((*edge, MID), f32), ((MID, C * F, O), f32),
+        ((C * F, O), f32), ((*edge, C, Q), f32),
+        *[((*edge, d + 1), f32)] * 4)
+    assert calls > 0
+
+
+@pytest.mark.xfail(strict=True, reason='INVALID_ARGUMENT: Custom emitter '
+                   'for CustomSPMDPartitioning not found')
+def test_pairwise_kernel_partitions_over_a_mesh(v5e_2x2):
+    """A Pallas kernel inside a program jitted over the described 2x2
+    (dp=2 x tp=2 operands). The kernels partition through
+    jax.experimental.custom_partitioning, and this installation's TPU
+    compiler (jax 0.9.0, libtpu 0.0.34) neither resolves that custom
+    call nor can emit it — the chip answered `chip_smoke.py --chips 4`
+    with the same words (PR 21), so it is the installation's limit, not
+    the deviceless compile's. The same call partitions correctly on
+    virtual CPU devices (tests/test_sharding.py). Strict: when this
+    compiles, chip_smoke.py's mesh phase can have its kernels back."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as Ps
+    mesh = Mesh(np.asarray(v5e_2x2).reshape(2, 2), ('dp', 'tp'))
+    e, i = 4096, 256
+
+    def on(shape, *spec):
+        return jax.ShapeDtypeStruct(shape, f32,
+                                    sharding=NamedSharding(mesh, Ps(*spec)))
+    text = jax.jit(
+        lambda h, w3, v2, b3: fused_pairwise_conv(h, w3, v2, b3)).lower(
+        on((e, MID), 'dp', None), on((MID, i, O), None, None, 'tp'),
+        on((e, P, i), 'dp', None, None), on((i, O), None, 'tp'),
+    ).compile().as_text()
+    assert 'tpu_custom_call' in text and 'all-gather(' not in text
+
+
+# --------------------------------------------------------------------- #
+# the flash kernel: block specs are tile-legal; the body does not lower
+# --------------------------------------------------------------------- #
+FL_N, FL_K, FL_HEADS, FL_KVH, FL_DIM_HEAD, FL_S0 = 256, 16, 2, 1, 8, 2
+FL_PAIRS, FL_DOUT, FL_L = ((0, 16), (1, 16)), 1, 2
+FL_DH = FL_DIM_HEAD * (2 * FL_DOUT + 1)
+FL_IF = sum(c * (2 * min(d, FL_DOUT) + 1) for d, c in FL_PAIRS)
+FL_O = FL_KVH * FL_DIM_HEAD
+
+
+def _flash_cfg(arm, mode):
+    return pf.FlashConfig(
+        pairs=FL_PAIRS, d_out=FL_DOUT, heads=FL_HEADS, kv_heads=FL_KVH,
+        scale=FL_DIM_HEAD ** -0.5, arm_v=arm, arm_k=arm,
+        prefix=FL_S0 if mode == 'knn' else 0, has_mask=True, mode=mode,
+        exclude_self=mode == 'global', use_pallas=True)
+
+
+def _compile_flash_knn(v5e, arm):
+    n, k = FL_N, FL_K
+    names = ['q', 'x0', 'x1', 'idx', 'nmask', 'h_v', 'h_k', 'wv', 'bv',
+             'wk', 'bk', 'prefix_k', 'prefix_v']
+    shapes = [((1, n, FL_HEADS, FL_DH), f32), ((1, n, 16, 1), f32),
+              ((1, n, 16, 3), f32), ((1, n, k), i32), ((1, n, k), b1),
+              ((1, n, k, MID), f32), ((1, n, k, MID), f32),
+              ((MID, FL_IF, FL_O), f32), ((FL_IF, FL_O), f32),
+              ((MID, FL_IF, FL_O), f32), ((FL_IF, FL_O), f32),
+              ((1, n, FL_S0, FL_KVH * FL_DH), f32),
+              ((1, n, FL_S0, FL_KVH * FL_DH), f32)]
+    if arm == 'dense':
+        names.append('sh')
+        shapes.append(((1, n, k, (2 * FL_L + 1) ** 2), f32))
+    else:
+        names.append('fr')
+        shapes.append(((1, n, k, 4 * (FL_L + 1)), f32))
+
+    def fn(*arrays):
+        ops = dict(zip(names, arrays))
+        ops['xs'] = (ops.pop('x0'), ops.pop('x1'))
+        return pf._flash_fwd_impl(_flash_cfg(arm, 'knn'), ops)
+    return compile_for(v5e, fn, *shapes)
+
+
+def _compile_flash_global(v5e):
+    n = 512
+    rp = [((1, MID), f32)] + [((1, MID), f32)] * 3 \
+        + [((MID, MID), f32)] + [((1, MID), f32)] * 3
+
+    def fn(q, x0, x1, coords, nodemask, wv, bv, *rp_v):
+        ops = dict(q=q, xs=(x0, x1), coords=coords, nodemask=nodemask,
+                   wv=wv, bv=bv, rp_v=tuple(rp_v))
+        return pf._flash_fwd_impl(
+            _flash_cfg('dense', 'global')._replace(tie=True), ops)
+    return compile_for(
+        v5e, fn, ((1, n, FL_HEADS, FL_DH), f32), ((1, n, 16, 1), f32),
+        ((1, n, 16, 3), f32), ((1, n, 3), f32), ((1, n), b1),
+        ((MID, FL_IF, FL_O), f32), ((FL_IF, FL_O), f32), *rp)
+
+
+# strict: the day an arm compiles, its xfail fails, and the arm's entry
+# in pallas_flash.MOSAIC_REFUSES (the selector's rule) goes with it
+@pytest.mark.xfail(strict=True, reason=pf.MOSAIC_REFUSES['knn'])
+@pytest.mark.parametrize('arm', ['dense', 'so2'])
+def test_flash_knn_arm_compiles(v5e, arm):
+    assert _compile_flash_knn(v5e, arm) > 0
+
+
+@pytest.mark.xfail(strict=True, reason=pf.MOSAIC_REFUSES['global'])
+def test_flash_global_arm_compiles(v5e):
+    assert _compile_flash_global(v5e) > 0
+
+
+@pytest.mark.parametrize('which', ['dense', 'so2', 'global'])
+def test_flash_block_specs_are_tile_legal(v5e, which):
+    """The repair this file was written for: the lowering used to stop
+    at the idx / nmask / nodemask block specs ("the last two dimensions
+    of your block shape are divisible by 8 and 128 ..."). Whatever the
+    compiler refuses now, it is past the block specs."""
+    with pytest.raises(Exception) as err:
+        if which == 'global':
+            _compile_flash_global(v5e)
+        else:
+            _compile_flash_knn(v5e, which)
+    assert 'block shape' not in str(err.value)
+
+
+@pytest.mark.parametrize('mode', ['knn', 'global'])
+def test_flash_selector_is_an_explicit_rule(mode):
+    """Unset, the selector picks the XLA stream (no compiler error is
+    caught to get there); asked for outright, the kernel is refused with
+    the compiler's message; interpret mode still runs it."""
+    assert pf._resolve_pallas(None, False, mode) is False
+    assert pf._resolve_pallas(None, True, mode) is True
+    with pytest.raises(NotImplementedError) as err:
+        pf._resolve_pallas(True, False, mode)
+    assert pf.MOSAIC_REFUSES[mode] in str(err.value)
+
+
+@pytest.mark.slow
+def test_flagship_train_step_compiles_and_fits(v5e, monkeypatch):
+    """chip_smoke.py's train program — value_and_grad + adam of
+    recipes.flagship_fast(dim=64) at n=1024, k=32, degree 4, depth 6 —
+    compiled for the chip (minutes): the Pallas kernels are in it and
+    arguments + temporaries fit the v5e's 16 GiB."""
+    import optax
+    from se3_transformer_tpu.parallel.sharding import make_sharded_train_step
+    from se3_transformer_tpu.training import recipes
+    from se3_transformer_tpu.utils import helpers
+
+    # the auto-dispatch asks the default backend, which is the CPU here
+    monkeypatch.setattr(helpers, 'is_tpu_backend', lambda: True)
+    n, dim = 1024, 64
+    module = recipes.flagship_fast(dim=dim, output_degrees=2,
+                                   reduce_dim_out=True)
+
+    def loss_fn(params, data, key):
+        noise = jax.random.normal(key, data['coords'].shape)
+        noised = data['coords'] + noise
+        out = module.apply({'params': params}, data['seqs'], noised,
+                           mask=data['masks'], return_type=1)
+        return (((noised + out) - data['coords']) ** 2).sum(-1).mean(), {}
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+            tree)
+
+    data = dict(seqs=jnp.zeros((1, n, dim)), coords=jnp.zeros((1, n, 3)),
+                masks=jnp.ones((1, n), bool))
+    optimizer = optax.adam(1e-4)
+    params = jax.eval_shape(
+        lambda: module.init(jax.random.PRNGKey(0), data['seqs'],
+                            data['coords'], mask=data['masks'],
+                            return_type=1)['params'])
+    opt_state = jax.eval_shape(optimizer.init, params)
+    compiled = make_sharded_train_step(loss_fn, optimizer).lower(
+        on_chip(params), on_chip(opt_state), on_chip(data),
+        on_chip(jax.random.PRNGKey(1))).compile()
+    assert compiled.as_text().count('tpu_custom_call') > 0
+    mem = compiled.memory_analysis()
+    # donated state aliases its outputs
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < 16 * 2 ** 30, mem

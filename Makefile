@@ -1,5 +1,5 @@
 # Common entry points (see README.md for details)
-.PHONY: test test-fast bench denoise cookbook molecular profile tpu-checks obs-smoke serve-smoke serve-multi-smoke serve-fleet-smoke slo-smoke transport-smoke pipeline-smoke tune-smoke ring-smoke profile-smoke so2-smoke v2-smoke flash-smoke assembly-smoke mesh-smoke chaos-smoke train-chaos-smoke quant-smoke perf-gate clean-cache
+.PHONY: test test-fast bench denoise cookbook molecular tpu-checks obs-smoke serve-smoke serve-multi-smoke serve-fleet-smoke slo-smoke transport-smoke pipeline-smoke tune-smoke ring-smoke profile-smoke so2-smoke v2-smoke flash-smoke assembly-smoke mesh-smoke chaos-smoke train-chaos-smoke quant-smoke perf-gate clean-cache
 
 test:              ## full suite on the simulated 8-device CPU mesh
 	python -m pytest tests/ -q
@@ -21,9 +21,6 @@ cookbook:          ## every reference README usage pattern
 
 molecular:         ## edge-conditioned molecular training example
 	python examples/molecular_property.py
-
-profile:           ## capture an xprof trace of a training step
-	python scripts/profile_model.py --cpu
 
 obs-smoke:         ## 3-step CPU denoise with telemetry: schema-gates the JSONL, renders the report (docs/OBSERVABILITY.md)
 	python denoise.py --steps 3 --nodes 48 --accum 2 --cpu --telemetry --flush-every 2 --metrics /tmp/obs_smoke.jsonl
@@ -55,7 +52,7 @@ ring-smoke:        ## virtual-8-device sequence-parallel comm gate (docs/PERFORM
 	python scripts/ring_smoke.py --metrics /tmp/ring_smoke.jsonl
 	python scripts/obs_report.py /tmp/ring_smoke.jsonl --validate --require-comm --out /tmp/ring_smoke_summary.json
 
-profile-smoke:     ## toy trace -> per-scope device-time attribution (docs/PERFORMANCE.md "Reading rooflines"): exits non-zero unless MODEL_SCOPES cover >=80% of device time AND the cost/profile records are schema-valid
+profile-smoke:     ## toy trace (CPU) -> device time by the leaves of MODEL_SCOPES, read from the xplane (docs/PERFORMANCE.md "Reading rooflines"): exits non-zero unless they cover >=80% of device time AND the cost/profile records are schema-valid
 	rm -f /tmp/profile_smoke.jsonl
 	python scripts/profile_smoke.py --metrics /tmp/profile_smoke.jsonl --min-coverage 0.8
 	python scripts/obs_report.py /tmp/profile_smoke.jsonl --validate --require cost,profile --out /tmp/profile_smoke_summary.json

@@ -1,0 +1,23 @@
+"""Seconds of set-up inside the trace, lowering, compile or cache load of every
+other function that began before `train_step`'s load ended (the program's
+compile log; nested spans counted once, and not where they lie inside
+`train_step`'s own)."""
+import os
+import sys
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+if HERE not in sys.path:
+    sys.path.insert(0, HERE)
+try:
+    import _program_profile as prog  # noqa: E402
+except ImportError:   # a checkout that lacks the helper reads nothing
+    prog = None
+
+
+def _read(ctx):
+    secs = prog.compile_seconds(ctx)
+    return None if secs is None else secs['other_s']
+
+
+def read(ctx):
+    return prog and prog.or_nothing(_read, ctx)

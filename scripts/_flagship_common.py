@@ -3,9 +3,8 @@ diagnostic scripts.
 
 bench.py is the source of truth for the officially-timed program; this
 module mirrors its setup (seeds, denoise objective, adam(1e-4), donated
-make_sharded_train_step) so chip_smoke.py, bench_diag.py and
-profile_flagship.py run the same program without hand-copied replicas
-drifting apart. Any change to bench.py's program must land here too — the
+make_sharded_train_step) so chip_smoke.py and bench_diag.py run the
+same program without hand-copied replicas drifting apart. Any change to bench.py's program must land here too — the
 bench_diag loss-sequence cross-check (same seeds => identical losses)
 catches a silent divergence.
 """

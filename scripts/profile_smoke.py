@@ -1,14 +1,15 @@
 """Profile-attribution smoke gate (`make profile-smoke`).
 
-Toy run -> jax.profiler trace -> per-scope device-time attribution
-(observability.profiling) -> schema-valid `cost` + `profile` records.
+Toy run -> jax.profiler trace (`.xplane.pb`) -> device time by the leaves
+of MODEL_SCOPES and by phase (observability.profiling, the program's one
+trace reducer; on the CPU the AOT executable's HLO text gives each
+instruction's op_name) -> schema-valid `cost` + `profile` records.
 Exits non-zero unless:
 
   * the trace parsed into nonzero device time,
-  * the MODEL_SCOPES attribution covers >= --min-coverage of it (the
-    proof that the named_scope labels still blanket the hot paths — a
-    new unscoped subsystem shows up here as falling coverage, with the
-    offending ops named in the record), and
+  * the leaves cover >= --min-coverage of it (the proof that the labels
+    still blanket the hot paths — a new unscoped subsystem shows up here
+    as falling coverage, with the offending ops named in the record), and
   * the emitted records validate against observability.schema
     (`scripts/obs_report.py --validate --require cost,profile` re-gates
     the stream from the file alone).
@@ -18,9 +19,11 @@ Usage:
         [--min-coverage 0.8] [--nodes 64] [--steps 3]
         [--trace-dir DIR] [--train]
 
-Default is the toy model FORWARD (fully under the model scopes);
---train profiles the full train step instead (optimizer/loss ops are
-unscoped by design, so expect lower coverage — reported, not gated).
+Default is the toy model FORWARD; --train profiles the full train step
+instead (`loss` and `optimizer` are leaves too, and forward, backward and
+replay are told apart; its coverage is reported, not gated: XLA:CPU
+rewrites more instructions without metadata than the chip's compiler).
+The traced flagship step on the chip is `benchmark/run.py --trace 1`.
 """
 import argparse
 import json
@@ -41,8 +44,7 @@ def main(argv=None):
     ap.add_argument('--trace-dir', default='/tmp/profile_smoke_trace')
     ap.add_argument('--train', action='store_true',
                     help='profile the train step instead of the forward '
-                         '(coverage reported, not gated: loss/optimizer '
-                         'ops are deliberately outside MODEL_SCOPES)')
+                         '(coverage reported, not gated)')
     args = ap.parse_args(argv)
 
     import shutil
@@ -118,6 +120,8 @@ def main(argv=None):
                           device_time_ms=profile['device_time_ms'],
                           scopes={s: st['share']
                                   for s, st in profile['scopes'].items()},
+                          phases={s: st['share']
+                                  for s, st in profile['phases'].items()},
                           unattributed_top=profile['unattributed_top'][:5],
                           peak_bytes=cost['peak_bytes'],
                           flops=cost['flops'],

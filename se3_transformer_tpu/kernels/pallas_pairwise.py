@@ -55,6 +55,16 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+# The launches are named by role (`name=` on pallas_call): the name is the
+# innermost component of the op's name stack, which XLA takes as the custom
+# call's instruction name, so the device trace holds `fused_pairwise_conv`,
+# `_bx`, `_bxf` (forward), `fused_pairwise_conv_bwd_a` (dV2, dW3, dB3) and
+# `fused_pairwise_conv_bwd_b` (dH). The pads, transposes and reshapes the
+# wrappers issue on either side of a launch sit under the leaf scope
+# `pairwise_layout` (observability.timing.MODEL_SCOPES), never the launch
+# itself.
+
+
 def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
@@ -352,14 +362,15 @@ def _fused_pairwise_conv_impl(h, w3, b3, v2, interpret, precision,
     block_e, block_if = _pick_blocks(E, IF, O, P, mid, dtype=key_dtype)
     Ep, IFp = _round_up(E, block_e), _round_up(IF, block_if)
 
-    ht, w3t, v2t, _ = _to_lanes(h, w3, v2)
-    b3t = _bias_column(b3, IF, O, IFp)
-    if Ep != E:
-        ht = jnp.pad(ht, ((0, 0), (0, Ep - E)))
-        v2t = jnp.pad(v2t, ((0, 0), (0, 0), (0, Ep - E)))
-    if IFp != IF:
-        w3t = jnp.pad(w3t, ((0, (IFp - IF) * O), (0, 0)))
-        v2t = jnp.pad(v2t, ((0, 0), (0, IFp - IF), (0, 0)))
+    with jax.named_scope('pairwise_layout'):
+        ht, w3t, v2t, _ = _to_lanes(h, w3, v2)
+        b3t = _bias_column(b3, IF, O, IFp)
+        if Ep != E:
+            ht = jnp.pad(ht, ((0, 0), (0, Ep - E)))
+            v2t = jnp.pad(v2t, ((0, 0), (0, 0), (0, Ep - E)))
+        if IFp != IF:
+            w3t = jnp.pad(w3t, ((0, (IFp - IF) * O), (0, 0)))
+            v2t = jnp.pad(v2t, ((0, 0), (0, IFp - IF), (0, 0)))
 
     n_e, n_if = Ep // block_e, IFp // block_if
 
@@ -376,8 +387,9 @@ def _fused_pairwise_conv_impl(h, w3, b3, v2, interpret, precision,
     if scaled:
         # per-(if,o)-channel dequant scales in the w3T row order — the
         # same [S, 1] column layout (and zero-row padding) as the bias
-        st = _bias_column(jnp.asarray(w3_scale, jnp.float32).reshape(
-            IF, O), IF, O, IFp)
+        with jax.named_scope('pairwise_layout'):
+            st = _bias_column(jnp.asarray(w3_scale, jnp.float32).reshape(
+                IF, O), IF, O, IFp)
         in_specs.append(pl.BlockSpec((block_if * O, 1),
                                      lambda e, f: (f, 0),
                                      memory_space=pltpu.VMEM))
@@ -396,9 +408,11 @@ def _fused_pairwise_conv_impl(h, w3, b3, v2, interpret, precision,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((P * O, Ep), jnp.float32),
         interpret=interpret,
+        name='fused_pairwise_conv',
     )(*args)
 
-    return outt.reshape(P, O, Ep).transpose(2, 0, 1)[:E]
+    with jax.named_scope('pairwise_layout'):
+        return outt.reshape(P, O, Ep).transpose(2, 0, 1)[:E]
 
 
 # --------------------------------------------------------------------- #
@@ -717,19 +731,20 @@ def _fused_pairwise_conv_bx_impl(h, w3, b3, basis, x, interpret, precision,
     Cp = _round_up(C, cb)
     Ep = _round_up(E, block_e)
 
-    ht = h.T                                          # [mid, E]
-    bt = basis.T if pqf is not None \
-        else basis.transpose(1, 3, 2, 0).reshape(P * F * Q, E)
-    xt = x.transpose(1, 2, 0).reshape(C * Q, E)
-    w3t = w3.reshape(mid, C * F * O).T                # [(c,f,o), mid]
-    b3t = _bias_column(b3, C * F, O, Cp * F)
-    if Cp != C:
-        xt = jnp.pad(xt, ((0, (Cp - C) * Q), (0, 0)))
-        w3t = jnp.pad(w3t, ((0, (Cp - C) * F * O), (0, 0)))
-    if Ep != E:
-        ht = jnp.pad(ht, ((0, 0), (0, Ep - E)))
-        bt = jnp.pad(bt, ((0, 0), (0, Ep - E)))
-        xt = jnp.pad(xt, ((0, 0), (0, Ep - E)))
+    with jax.named_scope('pairwise_layout'):
+        ht = h.T                                          # [mid, E]
+        bt = basis.T if pqf is not None \
+            else basis.transpose(1, 3, 2, 0).reshape(P * F * Q, E)
+        xt = x.transpose(1, 2, 0).reshape(C * Q, E)
+        w3t = w3.reshape(mid, C * F * O).T                # [(c,f,o), mid]
+        b3t = _bias_column(b3, C * F, O, Cp * F)
+        if Cp != C:
+            xt = jnp.pad(xt, ((0, (Cp - C) * Q), (0, 0)))
+            w3t = jnp.pad(w3t, ((0, (Cp - C) * F * O), (0, 0)))
+        if Ep != E:
+            ht = jnp.pad(ht, ((0, 0), (0, Ep - E)))
+            bt = jnp.pad(bt, ((0, 0), (0, Ep - E)))
+            xt = jnp.pad(xt, ((0, 0), (0, Ep - E)))
 
     n_e, n_c = Ep // block_e, Cp // cb
 
@@ -753,9 +768,12 @@ def _fused_pairwise_conv_bx_impl(h, w3, b3, basis, x, interpret, precision,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((P * O, Ep), jnp.float32),
         interpret=interpret,
+        name='fused_pairwise_conv_bxf' if pqf is not None
+        else 'fused_pairwise_conv_bx',
     )(ht, w3t, b3t, bt, xt)
 
-    return outt.reshape(P, O, Ep).transpose(2, 0, 1)[:E]
+    with jax.named_scope('pairwise_layout'):
+        return outt.reshape(P, O, Ep).transpose(2, 0, 1)[:E]
 
 
 @functools.lru_cache(maxsize=None)
@@ -923,33 +941,37 @@ def _fused_pairwise_conv_bwd_impl(h, w3, b3, v2, g, interpret, precision):
     # backward kernels upcast rows in VMEM like the forward does, so the
     # half-width saving on the dominant stream holds for the backward
     # too (upcasting here would write a full f32 copy back to HBM first)
-    h, w3 = h.astype(jnp.float32), w3.astype(jnp.float32)
-    g = g.astype(jnp.float32)
-    if v2.dtype == jnp.bfloat16 and interpret:
-        # interpret can't mix dtypes the way Mosaic lowers them; the
-        # pre-upcast is bit-identical to the kernels' row upcasts
-        v2 = v2.astype(jnp.float32)
     E, mid = h.shape
     _, IF, O = w3.shape
     P = v2.shape[1]
 
     block_e, block_if = _pick_blocks(E, IF, O, P, mid, bwd=True)
     Ep, IFp = _round_up(E, block_e), _round_up(IF, block_if)
-
-    ht, w3t, v2t, gt = _to_lanes(h, w3, v2, g)
-    b3t = _bias_column(b3, IF, O, IFp)
-    h_p, w3f = h, w3.reshape(mid, IF * O)
-    if Ep != E:
-        ht = jnp.pad(ht, ((0, 0), (0, Ep - E)))
-        h_p = jnp.pad(h_p, ((0, Ep - E), (0, 0)))
-        v2t = jnp.pad(v2t, ((0, 0), (0, 0), (0, Ep - E)))
-        gt = jnp.pad(gt, ((0, 0), (0, Ep - E)))
-    if IFp != IF:
-        w3t = jnp.pad(w3t, ((0, (IFp - IF) * O), (0, 0)))
-        w3f = jnp.pad(w3f, ((0, 0), (0, (IFp - IF) * O)))
-        v2t = jnp.pad(v2t, ((0, 0), (0, IFp - IF), (0, 0)))
-
     n_e, n_if = Ep // block_e, IFp // block_if
+
+    with jax.named_scope('pairwise_layout'):
+        h, w3 = h.astype(jnp.float32), w3.astype(jnp.float32)
+        g = g.astype(jnp.float32)
+        if v2.dtype == jnp.bfloat16 and interpret:
+            # interpret can't mix dtypes the way Mosaic lowers them; the
+            # pre-upcast is bit-identical to the kernels' row upcasts
+            v2 = v2.astype(jnp.float32)
+        ht, w3t, v2t, gt = _to_lanes(h, w3, v2, g)
+        b3t = _bias_column(b3, IF, O, IFp)
+        h_p, w3f = h, w3.reshape(mid, IF * O)
+        if Ep != E:
+            ht = jnp.pad(ht, ((0, 0), (0, Ep - E)))
+            h_p = jnp.pad(h_p, ((0, Ep - E), (0, 0)))
+            v2t = jnp.pad(v2t, ((0, 0), (0, 0), (0, Ep - E)))
+            gt = jnp.pad(gt, ((0, 0), (0, Ep - E)))
+        if IFp != IF:
+            w3t = jnp.pad(w3t, ((0, (IFp - IF) * O), (0, 0)))
+            w3f = jnp.pad(w3f, ((0, 0), (0, (IFp - IF) * O)))
+            v2t = jnp.pad(v2t, ((0, 0), (0, IFp - IF), (0, 0)))
+        # kernel B's w3: the if-chunk axis rides a leading block-1 dim so
+        # the (mid, bif*O) tail covers its full array dims (Mosaic
+        # block-shape rule)
+        w3f3 = w3f.reshape(mid, n_if, block_if * O).transpose(1, 0, 2)
 
     # kernel A: dV2 + dW3 + dB3 (accumulate over inner e axis)
     dv2t, dw3t, db3t = pl.pallas_call(
@@ -984,13 +1006,11 @@ def _fused_pairwise_conv_bwd_impl(h, w3, b3, v2, g, interpret, precision):
             jax.ShapeDtypeStruct((IFp * O, 1), jnp.float32),
         ],
         interpret=interpret,
+        name='fused_pairwise_conv_bwd_a',
     )(ht, h_p, w3t, b3t, v2t, gt)
 
     # kernel B: dH (accumulate over inner if axis; no matmul with w3T
-    # needed — dR comes straight from v2/g). The if-chunk axis of w3 rides
-    # a leading block-1 dim so the (mid, bif*O) tail covers its full array
-    # dims (Mosaic block-shape rule).
-    w3f3 = w3f.reshape(mid, n_if, block_if * O).transpose(1, 0, 2)
+    # needed — dR comes straight from v2/g)
     dht = pl.pallas_call(
         functools.partial(_bwd_b_kernel, P=P, O=O, bif=block_if,
                           precision=precision),
@@ -1007,12 +1027,14 @@ def _fused_pairwise_conv_bwd_impl(h, w3, b3, v2, g, interpret, precision):
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((mid, Ep), jnp.float32),
         interpret=interpret,
+        name='fused_pairwise_conv_bwd_b',
     )(w3f3, v2t, gt)
 
-    dh = dht.T[:E]
-    dw3 = dw3t.reshape(IFp, O, mid).transpose(2, 0, 1)[:, :IF]
-    dv2 = dv2t.transpose(2, 0, 1)[:E, :, :IF]
-    db3 = db3t.reshape(IFp, O)[:IF]
+    with jax.named_scope('pairwise_layout'):
+        dh = dht.T[:E]
+        dw3 = dw3t.reshape(IFp, O, mid).transpose(2, 0, 1)[:, :IF]
+        dv2 = dv2t.transpose(2, 0, 1)[:E, :, :IF]
+        db3 = db3t.reshape(IFp, O)[:IF]
     return dh, dw3, dv2, db3
 
 

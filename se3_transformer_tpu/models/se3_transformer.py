@@ -504,15 +504,17 @@ class SE3TransformerModule(nn.Module):
         sparse_mask = remove_self(sp_full, self_excl) \
             if sp_full is not None else None
 
-        # pairwise geometry, self-excluded by construction (reference :1221-1229)
-        rel_pos_full = coors[:, :, None, :] - coors[:, None, :, :]
-        rel_pos = remove_self(rel_pos_full, self_excl)
-        indices = jnp.broadcast_to(self_excl[None], (b, n, n - 1))
+        # pairwise geometry, self-excluded by construction (reference
+        # :1221-1229); the O(n^2) tensors the selection below consumes
+        with named_scope('neighbors'):
+            rel_pos_full = coors[:, :, None, :] - coors[:, None, :, :]
+            rel_pos = remove_self(rel_pos_full, self_excl)
+            indices = jnp.broadcast_to(self_excl[None], (b, n, n - 1))
 
-        pair_mask = None
-        if mask is not None:
-            pm = mask[:, :, None] & mask[:, None, :]
-            pair_mask = remove_self(pm, self_excl)
+            pair_mask = None
+            if mask is not None:
+                pm = mask[:, :, None] & mask[:, None, :]
+                pair_mask = remove_self(pm, self_excl)
 
         # edges (reference :1231-1239)
         if edges is not None:
@@ -875,18 +877,19 @@ class SE3TransformerModule(nn.Module):
                         nonlin=lambda t: t, name='norm_out')(x)
 
         final_fiber = fiber_out if fiber_out is not None else fiber_hidden
-        if self.reduce_dim_out:
-            x = LinearSE3(final_fiber, final_fiber.to(1),
-                          name='linear_out')(x)
-            x = {k: v[..., 0, :] for k, v in x.items()}
+        with named_scope('readout'):
+            if self.reduce_dim_out:
+                x = LinearSE3(final_fiber, final_fiber.to(1),
+                              name='linear_out')(x)
+                x = {k: v[..., 0, :] for k, v in x.items()}
 
-        x = _permute_degree1(x, _IRREP_TO_CART)
+            x = _permute_degree1(x, _IRREP_TO_CART)
 
-        # output conventions (reference :1365-1375)
-        if return_pooled:
-            pool = (lambda t: masked_mean(t, mask, axis=1)) if mask is not None \
-                else (lambda t: t.mean(axis=1))
-            x = {k: pool(v) for k, v in x.items()}
+            # output conventions (reference :1365-1375)
+            if return_pooled:
+                pool = (lambda t: masked_mean(t, mask, axis=1)) \
+                    if mask is not None else (lambda t: t.mean(axis=1))
+                x = {k: pool(v) for k, v in x.items()}
         if '0' in x:
             x = {**x, '0': x['0'][..., 0]}
         if return_type is not None:

@@ -10,7 +10,9 @@ a re-export shim). Four pillars:
     backend, host metadata).
   * `runtime`  — `RetraceWatchdog`: jit-cache-size / compile-event /
     device-memory snapshots per flush, with a loud structured warning
-    when a step function retraces after warmup.
+    when a step function retraces after warmup; and the compile log
+    (`compile_log`, `compile_seconds`): what JAX reports of every
+    trace, lowering, compile and cache load, by function.
   * `timing`   — `PhaseTimer`: host-side wall-clock reservoirs with
     windowed p50/p95/max per phase, plus `named_scope` / `profile_trace`
     for device-side (xprof) attribution of the model phases.
@@ -27,11 +29,12 @@ Two attribution pillars joined in PR 6:
     collective bytes). Consumed by bench.py, the training step
     factories, `InferenceEngine.warmup` (one record per shape bucket),
     and scripts/width_table.py; enforced by scripts/perf_gate.py.
-  * `profiling` — per-scope device-time attribution: jax.profiler
-    traces parsed (no tensorboard) onto the `MODEL_SCOPES` labels via
-    the compiled HLO's op_name metadata -> schema'd `profile` record
-    with coverage + roofline utilization. Supersedes the ad-hoc
-    trace_summary/stage_timings script pair.
+  * `profiling` — the one trace reducer: the profiler's `.xplane.pb`
+    (parsed as an `XSpace` through the few message types the module
+    declares itself: the op_name is a stat of the event's metadata, which
+    `jax.profiler.ProfileData` does not show; no tensorboard) -> device
+    seconds per leaf of `MODEL_SCOPES`, per phase, per kernel role and
+    degree pair, with coverage -> schema'd `profile` record.
 
 Two fleet pillars joined in PR 16:
 
@@ -53,7 +56,8 @@ from .metrics import (  # noqa: F401
     MetricAccumulator, MetricLogger, collect_run_meta, merge_windows,
 )
 from .runtime import (  # noqa: F401
-    RetraceWarning, RetraceWatchdog, device_memory_stats,
+    RetraceWarning, RetraceWatchdog, compile_log, compile_seconds,
+    device_memory_stats,
 )
 from .timing import (  # noqa: F401
     MODEL_SCOPES, PhaseTimer, named_scope, profile_trace,

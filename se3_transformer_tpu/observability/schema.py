@@ -67,11 +67,13 @@ A stream is JSONL; every record carries `kind` and `run_id`. Kinds:
                    from parallel.exchange.analyze_hlo_comm.
   profile          per-scope device-time attribution of one captured
                    trace (observability.profiling.profile_payload):
-                   label, scopes (per-MODEL_SCOPES-label {time_ms,
+                   label, scopes (per-MODEL_SCOPES-leaf {time_ms,
                    share}), device_time_ms, and the load-bearing
-                   coverage field (share of device time attributed to
-                   known scopes — `make profile-smoke` gates on it);
-                   optional roofline utilization vs the bf16 MXU peak.
+                   coverage field (share of device time under a leaf —
+                   `make profile-smoke` gates on it); optional phases
+                   (forward / backward / replay, same shape as scopes),
+                   kernels (per launch role) and roofline utilization
+                   vs the bf16 MXU peak.
   flash            fused-vs-XLA streaming-attention A/B
                    (bench.flash_main via scripts/flash_smoke.py):
                    label, fused_step_ms / unfused_step_ms and the
@@ -775,6 +777,16 @@ def validate_record(rec: dict, index=None) -> dict:
                 _fail(index, f'profile.scopes[{scope!r}] missing '
                              f'{missing} (per-scope time+share are the '
                              f'whole attribution)')
+        phases = rec.get('phases', {})
+        if not isinstance(phases, dict):
+            _fail(index, 'profile.phases must be an object')
+        for phase, st in phases.items():
+            missing = [k for k in _PROFILE_SCOPE_REQUIRED
+                       if not isinstance(st, dict) or k not in st]
+            if phase not in ('forward', 'backward', 'replay') or missing:
+                _fail(index, f'profile.phases[{phase!r}] must be one of '
+                             f'forward/backward/replay with time_ms and '
+                             f'share (missing {missing})')
         cov = rec['coverage']
         if not isinstance(cov, (int, float)) or not 0 <= cov <= 1:
             _fail(index, f'profile.coverage must be a number in [0, 1], '

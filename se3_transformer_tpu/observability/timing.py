@@ -8,44 +8,78 @@ Two complementary views of where time goes:
     backpressure, so windowed p50/p95/max of the 'step' phase tracks
     real step time without forcing a per-step sync.
   * `named_scope` / `profile_trace` — DEVICE attribution: scopes label
-    the HLO so xprof/perfetto traces name every hot region. The model
-    scopes in `MODEL_SCOPES` are kept in sync with the code
-    (models/se3_transformer.py, ops/attention.py,
-    kernels/pallas_attention.py, parallel/ring.py).
+    the HLO, and `MODEL_SCOPES` is the closed list of leaves the trace
+    reducer (observability.profiling) files device time under.
 """
 from __future__ import annotations
 
 import contextlib
 import os
+import re
 import threading
 import time
 from typing import Dict, Optional
 
 import jax
 
-# every named_scope label the model emits, for trace readers
-# (scripts/profile_model.py docstring mirrors this list)
+# The closed list of leaves. Every `named_scope` the package writes is one of
+# these (tests/test_scopes.py scans the sources), and the trace reducer
+# (observability.profiling) files each device operation under the INNERMOST
+# component of its `op_name` path that is on the list. Flax writes module
+# names into the same path (`.../attn_block2/attention/attn/to_k/...`), so a
+# scope is written by hand only where no module boundary exists. Forward,
+# backward and replay are read from the path (`transpose(jvp(...))`,
+# `rematted_computation`), never labelled.
 MODEL_SCOPES = (
-    'neighbors',          # models/se3_transformer.py — kNN selection
-    'adjacency',          # models/se3_transformer.py — adjacency
-    #                       expansion + jittered bonded top-k (the
-    #                       scatter whiles; dominant on toy CPU traces)
-    'basis',              # models/se3_transformer.py — SH basis
+    'neighbors',          # models/se3_transformer.py: pairwise geometry +
+    #                       kNN selection
+    'adjacency',          # models/se3_transformer.py: adjacency expansion
+    #                       + jittered bonded top-k
+    'basis',              # models/se3_transformer.py: SH basis
     'conv_in',            # models/se3_transformer.py
     'trunk',              # models/se3_transformer.py
     'conv_out',           # models/se3_transformer.py
-    'attention',          # ops/attention.py — whole attention call
-    'attn_qkv',           # ops/attention.py — q/k/v projections+convs
-    'attn_core',          # ops/attention.py — sim/softmax/weighted sum
-    'pallas_attention',   # kernels/pallas_attention.py — fused kernel
-    'ring_knn',           # parallel/ring.py — sequence-parallel kNN
-    'ici_wait',           # parallel/ring.py ring_scan — the ppermute hop;
+    'readout',            # models/se3_transformer.py: linear_out, degree-1
+    #                       permutation, pooling
+    'frames',             # v2/model.py: edge frames
+    'gather',             # ops/conv.py ConvSE3: neighbour gather
+    'radial',             # ops/conv.py: the radial trunk that makes h
+    'pair',               # ops/conv.py: written `pair_<d_in>_<d_out>`
+    #                       (`pair_all_<d_out>` for a grouped launch)
+    #                       around each pairwise contraction; the kernels'
+    #                       launches carry the degree pair here, not in
+    #                       their names
+    'basis_contract',     # ops/conv.py: V2 = basis . x and its cotangents
+    #                       (the basis-fused kernels' backward materializes
+    #                       V2 once)
+    'pairwise_layout',    # kernels/pallas_pairwise.py: the pads, transposes
+    #                       and reshapes on either side of each launch
+    'norm',               # ops/core.py NormSE3
+    'ff',                 # ops/core.py FeedForwardBlockSE3
+    'attention',          # ops/attention.py: whole attention call
+    'attn_qkv',           # ops/attention.py: q/k/v projections + convs
+    'attn_core',          # ops/attention.py: sim/softmax/weighted sum
+    'pallas_attention',   # kernels/pallas_attention.py: fused kernel
+    'pallas_attention_bwd',  # ... and its backward
+    'flash_attention',    # kernels/pallas_flash.py: streaming kernel
+    'flash_global_attention',          # ... kNN-free mode
+    'flash_global_attention_sharded',  # ... under sequence parallelism
+    'global_attention_materialized',   # ... its XLA oracle
+    'ring_knn',           # parallel/ring.py: sequence-parallel kNN
+    'ici_wait',           # parallel/ring.py ring_scan: the ppermute hop;
     #                       in an overlapped trace its exclusive time is
     #                       the NON-hidden remainder of the transfer
-    'exchange',           # parallel/exchange.py — neighbor-sparse value
+    'exchange',           # parallel/exchange.py: neighbor-sparse value
     #                       rotation + select (and the zero-comm rowwise
     #                       column select)
+    'loss',               # parallel/sharding.py train_step: what the
+    #                       model's scopes do not claim inside the
+    #                       differentiated loss
+    'optimizer',          # parallel/sharding.py train_step: the update
 )
+
+# `pair_<d_in>_<d_out>` / `pair_all_<d_out>` -> the leaf `pair`
+PAIR_SCOPE = re.compile(r'^pair_(\d+|all)_(\d+)$')
 
 
 def named_scope(name: str):
@@ -107,9 +141,14 @@ class PhaseTimer:
 
     @contextlib.contextmanager
     def phase(self, name: str):
+        # also a TraceAnnotation of the phase's name: while a profiler
+        # trace is being taken the span lies on the profiler's clock
+        # (`/host:CPU`) beside the device operations; otherwise it costs
+        # a fraction of a microsecond
         t0 = time.perf_counter()
         try:
-            yield
+            with jax.profiler.TraceAnnotation(name):
+                yield
         finally:
             self.record(name, time.perf_counter() - t0)
 

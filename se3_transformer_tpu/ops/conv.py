@@ -31,6 +31,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..observability import named_scope
 from ..parallel.exchange import exchange_index_select
 from ..quant.qtensor import QuantTensor, concat_weights
 from ..utils.helpers import fourier_encode, masked_mean, to_order
@@ -321,15 +322,18 @@ def _pc_bx_bwd(interpret, precision, res, g):
     C = x.shape[1]
     # conv_bf16 residuals arrive bf16 (that's the remat/HBM saving);
     # gradient math runs f32 on the exactly-upcast quantized values
-    b32, x32 = basis.astype(jnp.float32), x.astype(jnp.float32)
-    v2 = jnp.einsum('epqf,ecq->epcf', b32, x32,
-                    precision=precision).reshape(E, P, C * F)
+    with named_scope('basis_contract'):
+        b32, x32 = basis.astype(jnp.float32), x.astype(jnp.float32)
+        v2 = jnp.einsum('epqf,ecq->epcf', b32, x32,
+                        precision=precision).reshape(E, P, C * F)
     dh, dw3, dv2, db3 = fused_pairwise_conv_bwd(h, w3, v2, g, b3=b3,
                                                 interpret=interpret,
                                                 precision=precision)
-    dv2 = dv2.reshape(E, P, C, F)
-    dx = jnp.einsum('epqf,epcf->ecq', b32, dv2, precision=precision)
-    dbasis = jnp.einsum('ecq,epcf->epqf', x32, dv2, precision=precision)
+    with named_scope('basis_contract'):
+        dv2 = dv2.reshape(E, P, C, F)
+        dx = jnp.einsum('epqf,epcf->ecq', b32, dv2, precision=precision)
+        dbasis = jnp.einsum('ecq,epcf->epqf', x32, dv2,
+                            precision=precision)
     return (dh.astype(h.dtype), dw3.astype(w3.dtype), db3.astype(b3.dtype),
             dbasis.astype(basis.dtype), dx.astype(x.dtype))
 
@@ -363,17 +367,19 @@ def _pc_bxf_bwd(pqf, interpret, precision, res, g):
     E = basis_flat.shape[0]
     C = x.shape[1]
     # conv_bf16 residuals arrive bf16 (see _pc_bx_bwd)
-    b4 = basis_flat.astype(jnp.float32).reshape(E, P, F, Q)
-    x32 = x.astype(jnp.float32)
-    v2 = jnp.einsum('epfq,ecq->epcf', b4, x32,
-                    precision=precision).reshape(E, P, C * F)
+    with named_scope('basis_contract'):
+        b4 = basis_flat.astype(jnp.float32).reshape(E, P, F, Q)
+        x32 = x.astype(jnp.float32)
+        v2 = jnp.einsum('epfq,ecq->epcf', b4, x32,
+                        precision=precision).reshape(E, P, C * F)
     dh, dw3, dv2, db3 = fused_pairwise_conv_bwd(h, w3, v2, g, b3=b3,
                                                 interpret=interpret,
                                                 precision=precision)
-    dv2 = dv2.reshape(E, P, C, F)
-    dx = jnp.einsum('epfq,epcf->ecq', b4, dv2, precision=precision)
-    dbasis = jnp.einsum('ecq,epcf->epfq', x32, dv2,
-                        precision=precision).reshape(E, P * F * Q)
+    with named_scope('basis_contract'):
+        dv2 = dv2.reshape(E, P, C, F)
+        dx = jnp.einsum('epfq,epcf->ecq', b4, dv2, precision=precision)
+        dbasis = jnp.einsum('ecq,epcf->epfq', x32, dv2,
+                            precision=precision).reshape(E, P * F * Q)
     return (dh.astype(h.dtype), dw3.astype(w3.dtype), db3.astype(b3.dtype),
             dbasis.astype(basis_flat.dtype), dx.astype(x.dtype))
 
@@ -463,9 +469,10 @@ class PairwiseConvSE3(nn.Module):
             assert self.fused, \
                 f'backend {self.backend!r} requires the fused ' \
                 f'parameterization (fused=False is the dense-path oracle)'
-            h = radial_hidden(
-                edge_feats, self.mid_dim,
-                dtype=jnp.bfloat16 if self.radial_bf16 else None)
+            with named_scope('radial'):
+                h = radial_hidden(
+                    edge_feats, self.mid_dim,
+                    dtype=jnp.bfloat16 if self.radial_bf16 else None)
             w3 = self.param(
                 'w3',
                 nn.initializers.variance_scaling(
@@ -492,14 +499,17 @@ class PairwiseConvSE3(nn.Module):
             basis_slice = unflatten_basis(basis_slice, P, Q, F)
 
         if not self.fused:
-            R = RadialFunc(num_freq=F, in_dim=self.nc_in,
-                           out_dim=self.nc_out, mid_dim=self.mid_dim,
-                           name='radial')(edge_feats)
+            with named_scope('radial'):
+                R = RadialFunc(num_freq=F, in_dim=self.nc_in,
+                               out_dim=self.nc_out, mid_dim=self.mid_dim,
+                               name='radial')(edge_feats)
             return pairwise_conv_contract(R, basis_slice, x)
 
-        h = radial_hidden(
-            edge_feats, self.mid_dim,
-            dtype=jnp.bfloat16 if self.radial_bf16 else None)  # [b,n,k,mid]
+        with named_scope('radial'):
+            h = radial_hidden(
+                edge_feats, self.mid_dim,
+                dtype=jnp.bfloat16
+                if self.radial_bf16 else None)  # [b,n,k,mid]
 
         w3 = self.param(
             'w3',
@@ -518,8 +528,9 @@ class PairwiseConvSE3(nn.Module):
             return jnp.swapaxes(out, -1, -2)  # [..., c_out, P]
 
         # V2[..., P, (i, f)] = sum_Q B[..., P, Q, f] x[..., i, Q]
-        v2 = jnp.einsum('...pqf,...cq->...pcf', basis_slice, x)
-        v2 = v2.reshape(*v2.shape[:-2], IF)  # [..., P, c_in*F]
+        with named_scope('basis_contract'):
+            v2 = jnp.einsum('...pqf,...cq->...pcf', basis_slice, x)
+            v2 = v2.reshape(*v2.shape[:-2], IF)  # [..., P, c_in*F]
 
         out = _radial_contract(h, w3, b3, v2, pallas=self.pallas,
                                pallas_interpret=self.pallas_interpret,
@@ -823,9 +834,10 @@ class ConvSE3(nn.Module):
             assert self.backend in ('dense', 'so2'), \
                 f'fuse_pairwise supports the dense/so2 arms, not ' \
                 f'{self.backend!r}'
-            hidden = radial_hidden(
-                edge_features, DEFAULT_MID_DIM,
-                dtype=jnp.bfloat16 if self.radial_bf16 else None)
+            with named_scope('radial'):
+                hidden = radial_hidden(
+                    edge_features, DEFAULT_MID_DIM,
+                    dtype=jnp.bfloat16 if self.radial_bf16 else None)
             w3s: Dict[str, jnp.ndarray] = {}
             b3s: Dict[str, jnp.ndarray] = {}
             for degree_out, m_out in self.fiber_out:
@@ -849,15 +861,19 @@ class ConvSE3(nn.Module):
         # this is the neighbor-sparse ring rotation; a plain dense gather
         # everywhere else — parallel/exchange.py)
         gathered = {}
-        for degree_in, _ in self.fiber_in:
-            key = str(degree_in)
-            gathered[key] = exchange_index_select(
-                inp[key], neighbor_indices, axis=1)  # [b, n, k, c_in, 2di+1]
+        with named_scope('gather'):
+            for degree_in, _ in self.fiber_in:
+                key = str(degree_in)
+                gathered[key] = exchange_index_select(
+                    inp[key], neighbor_indices,
+                    axis=1)  # [b, n, k, c_in, 2di+1]
 
-        hidden = radial_hidden(
-            edge_features, DEFAULT_MID_DIM,
-            dtype=jnp.bfloat16 if self.radial_bf16 else None) \
-            if self.shared_radial_hidden else None
+        hidden = None
+        if self.shared_radial_hidden:
+            with named_scope('radial'):
+                hidden = radial_hidden(
+                    edge_features, DEFAULT_MID_DIM,
+                    dtype=jnp.bfloat16 if self.radial_bf16 else None)
 
         fuse_bx = self.fuse_basis and _use_pallas(self.pallas,
                                                   self.pallas_interpret)
@@ -896,16 +912,18 @@ class ConvSE3(nn.Module):
                         m_out)
                     w3s.append(w3)
                     b3s.append(b3)
-                    z_segs.append(banded_z(rotated[str(degree_in)],
-                                           degree_in, degree_out))
-                acc = _radial_contract(
-                    hidden, concat_weights(w3s, axis=1),
-                    jnp.concatenate(b3s, axis=0),
-                    jnp.concatenate(z_segs, axis=-1),
-                    pallas=self.pallas,
-                    pallas_interpret=self.pallas_interpret,
-                    edge_chunks=self.edge_chunks,
-                    conv_bf16=self.conv_bf16)            # [..., P, O]
+                    with named_scope(f'pair_{degree_in}_{degree_out}'):
+                        z_segs.append(banded_z(rotated[str(degree_in)],
+                                               degree_in, degree_out))
+                with named_scope(f'pair_all_{degree_out}'):
+                    acc = _radial_contract(
+                        hidden, concat_weights(w3s, axis=1),
+                        jnp.concatenate(b3s, axis=0),
+                        jnp.concatenate(z_segs, axis=-1),
+                        pallas=self.pallas,
+                        pallas_interpret=self.pallas_interpret,
+                        edge_chunks=self.edge_chunks,
+                        conv_bf16=self.conv_bf16)        # [..., P, O]
                 acc = rotate_out(jnp.swapaxes(acc, -1, -2), so2_frames,
                                  degree_out)             # [..., O, P]
             elif so2_hoist:
@@ -945,32 +963,39 @@ class ConvSE3(nn.Module):
                         degree_in, degree_out, hidden.shape[-1], m_in,
                         m_out)
                     basis_pair = basis[f'{degree_in},{degree_out}']
-                    if fuse_bx:
-                        y = _radial_contract_bx(
-                            hidden, w3, b3, basis_pair,
-                            gathered[str(degree_in)],
-                            pallas_interpret=self.pallas_interpret,
-                            edge_chunks=self.edge_chunks, pqf=(P, Q, F),
-                            conv_bf16=self.conv_bf16)
-                        acc = y if acc is None else acc + y
-                        continue
-                    if _basis_is_flat(basis_pair, gathered[str(degree_in)]):
-                        basis_pair = unflatten_basis(basis_pair, P, Q, F)
-                    v2 = jnp.einsum('...pqf,...cq->...pcf',
-                                    basis_pair,
-                                    gathered[str(degree_in)])
-                    v2s.append(v2.reshape(*v2.shape[:-2], IF))
+                    # the degree pair is a component of the op's path,
+                    # not of the kernel's name (MODEL_SCOPES: `pair`)
+                    with named_scope(f'pair_{degree_in}_{degree_out}'):
+                        if fuse_bx:
+                            y = _radial_contract_bx(
+                                hidden, w3, b3, basis_pair,
+                                gathered[str(degree_in)],
+                                pallas_interpret=self.pallas_interpret,
+                                edge_chunks=self.edge_chunks,
+                                pqf=(P, Q, F), conv_bf16=self.conv_bf16)
+                            acc = y if acc is None else acc + y
+                            continue
+                        if _basis_is_flat(basis_pair,
+                                          gathered[str(degree_in)]):
+                            basis_pair = unflatten_basis(basis_pair, P, Q,
+                                                         F)
+                        with named_scope('basis_contract'):
+                            v2 = jnp.einsum('...pqf,...cq->...pcf',
+                                            basis_pair,
+                                            gathered[str(degree_in)])
+                            v2s.append(v2.reshape(*v2.shape[:-2], IF))
                     w3s.append(w3)
                     b3s.append(b3)
                 if not fuse_bx:
-                    acc = _radial_contract(
-                        hidden, concat_weights(w3s, axis=1),
-                        jnp.concatenate(b3s, axis=0),
-                        jnp.concatenate(v2s, axis=-1),
-                        pallas=self.pallas,
-                        pallas_interpret=self.pallas_interpret,
-                        edge_chunks=self.edge_chunks,
-                        conv_bf16=self.conv_bf16)
+                    with named_scope(f'pair_all_{degree_out}'):
+                        acc = _radial_contract(
+                            hidden, concat_weights(w3s, axis=1),
+                            jnp.concatenate(b3s, axis=0),
+                            jnp.concatenate(v2s, axis=-1),
+                            pallas=self.pallas,
+                            pallas_interpret=self.pallas_interpret,
+                            edge_chunks=self.edge_chunks,
+                            conv_bf16=self.conv_bf16)
                 acc = jnp.swapaxes(acc, -1, -2)  # [..., c_out, P]
             else:
                 acc = None

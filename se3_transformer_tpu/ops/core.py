@@ -16,6 +16,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..observability import named_scope
 from ..quant.qtensor import QuantTensor
 from ..utils.helpers import safe_norm
 from .fiber import Fiber
@@ -85,29 +86,31 @@ class NormSE3(nn.Module):
 
     @nn.compact
     def __call__(self, features: Features) -> Features:
-        output = {}
-        for degree, t in features.items():
-            chan = t.shape[-2]
-            norm = jnp.clip(safe_norm(t, axis=-1, keepdims=True),
-                            self.eps, None)
-            phase = t / norm
+        with named_scope('norm'):
+            output = {}
+            for degree, t in features.items():
+                chan = t.shape[-2]
+                norm = jnp.clip(safe_norm(t, axis=-1, keepdims=True),
+                                self.eps, None)
+                phase = t / norm
 
-            scalars = norm[..., 0]  # [..., c]
-            if self.gated_scale:
-                w_gate = self.param(
-                    f'w_gate{degree}',
-                    lambda key, shape, dtype: jax.random.uniform(
-                        key, shape, dtype, -1e-3, 1e-3),
-                    (chan, chan), t.dtype)
-                scaled = jnp.einsum('...c,ce->...e', scalars, w_gate)
-            else:
-                scale = self.param(
-                    f'scale{degree}', nn.initializers.ones, (1, 1, chan),
-                    t.dtype)
-                scaled = scalars * scale.reshape((1,) * (scalars.ndim - 1) + (chan,))
-            transformed = self.nonlin(scaled)
-            output[degree] = transformed[..., None] * phase
-        return output
+                scalars = norm[..., 0]  # [..., c]
+                if self.gated_scale:
+                    w_gate = self.param(
+                        f'w_gate{degree}',
+                        lambda key, shape, dtype: jax.random.uniform(
+                            key, shape, dtype, -1e-3, 1e-3),
+                        (chan, chan), t.dtype)
+                    scaled = jnp.einsum('...c,ce->...e', scalars, w_gate)
+                else:
+                    scale = self.param(
+                        f'scale{degree}', nn.initializers.ones, (1, 1, chan),
+                        t.dtype)
+                    scaled = scalars * scale.reshape(
+                        (1,) * (scalars.ndim - 1) + (chan,))
+                transformed = self.nonlin(scaled)
+                output[degree] = transformed[..., None] * phase
+            return output
 
 
 class FeedForwardSE3(nn.Module):
@@ -133,7 +136,8 @@ class FeedForwardBlockSE3(nn.Module):
     @nn.compact
     def __call__(self, features: Features) -> Features:
         res = features
-        out = NormSE3(self.fiber, gated_scale=self.norm_gated_scale,
-                      name='prenorm')(features)
-        out = FeedForwardSE3(self.fiber, name='feedforward')(out)
-        return residual_se3(out, res)
+        with named_scope('ff'):
+            out = NormSE3(self.fiber, gated_scale=self.norm_gated_scale,
+                          name='prenorm')(features)
+            out = FeedForwardSE3(self.fiber, name='feedforward')(out)
+            return residual_se3(out, res)

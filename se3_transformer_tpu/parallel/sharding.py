@@ -177,22 +177,30 @@ def make_sharded_train_step(loss_fn: Callable, optimizer,
     steps must leave it off, or the second step reads deleted buffers.
     """
 
-    def step(params, opt_state, batch, rng):
-        (loss, aux), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(params, batch, rng)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
-        return params, opt_state, loss, aux
+    # `loss` and `optimizer` are leaves of MODEL_SCOPES: whatever the
+    # model's own scopes do not claim inside the differentiated loss (the
+    # objective, its noise) reads as `loss`, the update as `optimizer`.
+    # Both variants are called `train_step`, the `fun_name` under which
+    # the compile log (observability.runtime) files their seconds.
+    def _grads_and_update(params, opt_state, batch, rng):
+        with jax.named_scope('loss'):
+            (loss, aux), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(params, batch, rng)
+        with jax.named_scope('optimizer'):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
+        return params, opt_state, loss, aux, grads
 
-    def step_telemetry(params, opt_state, batch, rng, acc):
-        (loss, aux), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(params, batch, rng)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
-        acc = acc.update(loss=loss, grad_norm=optax.global_norm(grads))
-        return params, opt_state, loss, aux, acc
+    if telemetry:
+        def train_step(params, opt_state, batch, rng, acc):
+            params, opt_state, loss, aux, grads = _grads_and_update(
+                params, opt_state, batch, rng)
+            acc = acc.update(loss=loss, grad_norm=optax.global_norm(grads))
+            return params, opt_state, loss, aux, acc
+    else:
+        def train_step(params, opt_state, batch, rng):
+            return _grads_and_update(params, opt_state, batch, rng)[:4]
 
-    fn = step_telemetry if telemetry else step
     # the accumulator is replaced every step — donate it like the state;
     # the batch (argnum 2) only on request (see the donation audit above)
     donate_argnums = ((0, 1, 4) if telemetry else (0, 1)) if donate else ()
@@ -200,25 +208,27 @@ def make_sharded_train_step(loss_fn: Callable, optimizer,
         donate_argnums = tuple(sorted(donate_argnums + (2,)))
         _expect_unusable_batch_donation()
     if mesh is None:
-        return jax.jit(fn, donate_argnums=donate_argnums)
+        return jax.jit(train_step, donate_argnums=donate_argnums)
 
     repl = replicated(mesh)
     acc_in = (repl,) if telemetry else ()
     acc_out = (repl,) if telemetry else ()
     if state_shardings is not None:
         ps, os_ = state_shardings
-        return jax.jit(fn, in_shardings=(ps, os_, None, repl) + acc_in,
+        return jax.jit(train_step,
+                       in_shardings=(ps, os_, None, repl) + acc_in,
                        out_shardings=(ps, os_, repl, repl) + acc_out,
                        donate_argnums=donate_argnums)
     if tensor_parallel or sharded_state:
         # None = follow the argument/result placement (params arrive
         # pre-sharded by shard_params, opt state — under sharded_state —
         # by shard_opt_state; donation keeps buffers in place)
-        return jax.jit(fn, in_shardings=(None, None, None, repl) + acc_in,
+        return jax.jit(train_step,
+                       in_shardings=(None, None, None, repl) + acc_in,
                        out_shardings=(None, None, repl, repl) + acc_out,
                        donate_argnums=donate_argnums)
     return jax.jit(
-        fn,
+        train_step,
         in_shardings=(repl, repl, None, repl) + acc_in,
         out_shardings=(repl, repl, repl, repl) + acc_out,
         donate_argnums=donate_argnums)
@@ -262,23 +272,34 @@ def make_accumulating_train_step(loss_fn: Callable, optimizer,
         return jax.tree_util.tree_map(lambda g: g / accum_steps,
                                       grads), losses
 
-    def step(params, opt_state, batch, rng):
-        grads, losses = _grads_and_losses(params, batch, rng)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
-        # per-micro-step losses ride along (the reference prints every
-        # outer step's loss, denoise.py:91 — the mean alone hides a
-        # diverging micro-batch); same 4-arity as make_sharded_train_step
-        return params, opt_state, losses.mean(), losses
+    # named and scoped like make_sharded_train_step's: `train_step` in the
+    # compile log, the leaves `loss` and `optimizer` in a trace
+    def _grads_and_update(params, opt_state, batch, rng):
+        with jax.named_scope('loss'):
+            grads, losses = _grads_and_losses(params, batch, rng)
+        with jax.named_scope('optimizer'):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
+        return params, opt_state, losses, grads
 
-    def step_telemetry(params, opt_state, batch, rng, acc):
-        grads, losses = _grads_and_losses(params, batch, rng)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
-        acc = acc.update(loss=losses, grad_norm=optax.global_norm(grads))
-        return params, opt_state, losses.mean(), losses, acc
+    if telemetry:
+        def train_step(params, opt_state, batch, rng, acc):
+            params, opt_state, losses, grads = _grads_and_update(
+                params, opt_state, batch, rng)
+            acc = acc.update(loss=losses,
+                             grad_norm=optax.global_norm(grads))
+            return params, opt_state, losses.mean(), losses, acc
+    else:
+        def train_step(params, opt_state, batch, rng):
+            # per-micro-step losses ride along (the reference prints every
+            # outer step's loss, denoise.py:91: the mean alone hides a
+            # diverging micro-batch); same 4-arity as
+            # make_sharded_train_step
+            params, opt_state, losses, _ = _grads_and_update(
+                params, opt_state, batch, rng)
+            return params, opt_state, losses.mean(), losses
 
-    fn = step_telemetry if telemetry else step
+    fn = train_step
     donate_argnums = (0, 1, 4) if telemetry else (0, 1)
     if donate_batch:
         donate_argnums = tuple(sorted(donate_argnums + (2,)))

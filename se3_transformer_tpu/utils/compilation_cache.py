@@ -29,6 +29,10 @@ def enable_compilation_cache(path: str | None = None) -> str:
     `JAX_COMPILATION_CACHE_DIR` is not set."""
     import jax
 
+    # the compile log starts with the cache: a process that turns the
+    # cache on knows, per function, what it traced, compiled and loaded
+    from ..observability.runtime import install_compile_listener
+    install_compile_listener()
     jax.config.update('jax_persistent_cache_min_compile_time_secs', 1.0)
     env = os.environ.get('JAX_COMPILATION_CACHE_DIR')
     if env:

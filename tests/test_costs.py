@@ -1,11 +1,10 @@
 """Cost/profile attribution layer (PR 6): schema negative cases for the
 new `cost`/`profile` record kinds, the cost ledger on a real compiled
 CPU program plus the fallback path when `cost_analysis()` returns None,
-trace parsing + per-scope attribution on a synthetic Chrome trace (no
-profiler dependency — the parser's contract is the trace FORMAT), the
+trace reading + per-leaf attribution on a synthetic `.xplane.pb` (written
+with the reader's own message classes, tests/xplane_fixture.py), the
 unified `obs_report --require` flag, and the perf gate's pass /
 breach / injected-regression behavior on synthetic budgets."""
-import gzip
 import json
 import os
 import sys
@@ -205,25 +204,16 @@ def test_cost_payload_refuses_zero_memory_fabrication(tiny_compiled):
 
 
 # --------------------------------------------------------------------- #
-# trace parsing + attribution on a synthetic Chrome trace
+# trace reading + attribution on a synthetic CPU trace (.xplane.pb)
 # --------------------------------------------------------------------- #
-def _write_trace(d, events):
-    os.makedirs(d, exist_ok=True)
-    path = os.path.join(d, 'host.trace.json.gz')
-    with gzip.open(path, 'wt') as f:
-        json.dump(dict(traceEvents=events), f)
-    return path
+def _x(name, ts, dur, pid=7, tid=1):
+    return dict(ph='X', pid=pid, tid=tid, ts=ts, dur=dur, name=name)
 
 
-def _x(name, ts, dur, pid=7, tid=1, hlo=True):
-    args = {'hlo_op': name, 'hlo_module': 'jit_f'} if hlo else {}
-    return dict(ph='X', pid=pid, tid=tid, ts=ts, dur=dur, name=name,
-                args=args)
-
-
-_SYNTH_HLO = '''
+_SYNTH_HLO = '''HloModule jit_f, entry_computation_layout={()->f32[]}
 %dot.3 = f32[4,4]{1,0} dot(...), metadata={op_name="jit(f)/jit(main)/trunk/matmul"}
-%exp_fusion.clone = f32[4]{0} fusion(...), metadata={op_name="jit(f)/jit(main)/transpose(jvp(attention))/exp"}
+%exp_fusion.clone = f32[4]{0} fusion(...), metadata={op_name="jit(f)/jit(main)/transpose(jvp(attention))/trunk/attention/exp"}
+%rsqrt.7 = f32[4]{0} rsqrt(...), metadata={op_name="jit(f)/transpose(jvp(M))/trunk/checkpoint/rematted_computation/ff_block0/ff/prenorm/norm/rsqrt"}
 %call.2 = f32[4]{0} call(...), metadata={op_name="jit(f)/jit(main)"}
 '''
 
@@ -240,49 +230,79 @@ def test_exclusive_durations_subtract_nested_children():
 
 
 def test_scope_attribution_and_payload(tmp_path):
-    events = [
-        dict(ph='M', pid=7, name='process_name',
-             args=dict(name='/host:CPU')),
-        _x('call.2', 0, 100),
-        _x('exp_fusion.clone', 10, 60),   # attention (via transpose(jvp))
-        _x('dot.3', 200, 50),             # trunk
-        _x('mystery.9', 300, 30),         # unattributed
-    ]
+    """A CPU trace: the device events are those of `/host:CPU` that carry
+    an `hlo_op` stat, nested per thread, and the caller's HLO text gives
+    each instruction's op_name."""
+    from xplane_fixture import write_xplane
+    worker = '/host:CPU/tf_XLAEigen'
+    events = {'device': {worker: [
+        ['call.2', 0.0, 100e3, None, 'jit_f'],
+        ['exp_fusion.clone', 10e3, 60e3, None, 'jit_f'],  # attention, bwd
+        ['dot.3', 200e3, 50e3, None, 'jit_f'],             # trunk
+        ['rsqrt.7', 260e3, 20e3, None, 'jit_f'],           # norm, replay
+        ['mystery.9', 300e3, 30e3, None, 'jit_f'],         # unlabelled
+        ['dot.3', 400e3, 999e3, None, 'jit_other'],        # another program
+    ]}, 'host': [['python', 'step', 0.0, 500e3]]}
     d = str(tmp_path / 'trace')
-    _write_trace(d, events)
+    write_xplane(os.path.join(d, 'plugins', 'profile', 'run',
+                              'host.xplane.pb'), events)
 
-    dev, info = profiling.device_events(profiling.load_trace_events(d))
-    assert info['selector'] == 'hlo_op' and len(dev) == 4
+    read = profiling.read_xplane(profiling.newest_xplane(d), ['step'])
+    assert read['selector'] == 'hlo_op'
+    assert sum(len(v) for v in read['device'].values()) == 6
+    assert read['host'] == [['python', 'step', 0.0, 500e3]]
 
-    op_map = profiling.op_scope_map(_SYNTH_HLO)
-    assert op_map['dot.3'] == 'trunk'
-    assert op_map['exp_fusion.clone'] == 'attention'
-    assert 'call.2' not in op_map    # no scope component on its path
+    names = profiling.hlo_op_names(_SYNTH_HLO)
+    assert profiling.scope_leaf(names['dot.3']) == 'trunk'
+    assert profiling.scope_leaf(names['exp_fusion.clone']) == 'attention'
+    assert profiling.scope_leaf(names['call.2']) is None
 
     body = profiling.profile_payload(d, label='synthetic',
                                      hlo_text=_SYNTH_HLO,
                                      flops_per_step=1e6, steps=2)
     validate_record(dict(kind='profile', run_id='r', **body))
-    # exclusive device time: 40 (call) + 60 + 50 + 30 = 180 us;
-    # attributed: 60 (attention) + 50 (trunk)
-    assert body['device_time_ms'] == pytest.approx(0.18)
-    assert body['coverage'] == pytest.approx(110 / 180, abs=1e-3)
+    # exclusive device time of jit_f's events: 40 (call) + 60 + 50 + 20
+    # + 30 = 200 us; labelled: 60 (attention) + 50 (trunk) + 20 (norm)
+    assert body['device_time_ms'] == pytest.approx(0.2)
+    assert body['coverage'] == pytest.approx(130 / 200, abs=1e-3)
     assert body['scopes']['attention']['time_ms'] == pytest.approx(0.06)
-    assert body['scopes']['trunk']['share'] == pytest.approx(50 / 180,
+    assert body['scopes']['trunk']['share'] == pytest.approx(50 / 200,
                                                              abs=1e-3)
+    assert body['phases']['backward']['time_ms'] == pytest.approx(0.06)
+    assert body['phases']['replay']['time_ms'] == pytest.approx(0.02)
+    assert body['phases']['forward']['time_ms'] == pytest.approx(0.05)
     assert body['unattributed_top'][0]['op'] in ('call', 'mystery')
+    assert body['tracks']['op_name_source'] == 'hlo_text'
     assert body['roofline']['device_flops_per_sec'] == pytest.approx(
-        2e6 / 180e-6)
+        2e6 / 200e-6)
 
 
-def test_innermost_scope_wins_and_pallas_not_swallowed():
-    by_len = sorted(profiling.MODEL_SCOPES, key=len, reverse=True)
-    assert profiling._scope_of_path(
-        'jit(f)/trunk/attention/mul', profiling.MODEL_SCOPES,
-        by_len) == 'attention'
-    assert profiling._scope_of_path(
-        'jit(f)/trunk/pallas_attention/kernel', profiling.MODEL_SCOPES,
-        by_len) == 'pallas_attention'
+def test_innermost_leaf_wins_and_the_path_gives_phase_and_pair():
+    leaf, phase, pair = (profiling.scope_leaf, profiling.scope_phase,
+                         profiling.scope_pair)
+    assert leaf('jit(f)/trunk/attention/mul') == 'attention'
+    assert leaf('jit(f)/trunk/pallas_attention/kernel') \
+        == 'pallas_attention'
+    fwd = ('jit(train_step)/loss/jvp(M)/M._body/trunk/attn_block1/attention/'
+           'attn/attn_qkv/to_k/pair_1_2/jit(fused_pairwise_conv_bxf)/')
+    assert leaf(fwd + 'fused_pairwise_conv_bxf/pallas_call') == 'pair'
+    assert leaf(fwd + 'pairwise_layout/transpose') == 'pairwise_layout'
+    assert pair(fwd + 'pairwise_layout/transpose') == '1,2'
+    assert pair('jit(f)/conv_in/pair_all_3/dot_general') == 'all,3'
+    assert phase(fwd + 'pairwise_layout/transpose') == 'forward'
+    # a module that merely starts like a leaf is not one; the step's own
+    # scopes catch what the model's do not claim
+    assert leaf('jit(train_step)/loss/jvp(M)/pairing/mul') == 'loss'
+    assert leaf('jit(train_step)/optimizer/mul') == 'optimizer'
+    assert leaf('jit(other)/mul') is None and leaf(None) is None
+    bwd = 'jit(train_step)/loss/transpose(jvp(M))/M._body/trunk/checkpoint/'
+    assert phase(bwd + 'attn_block1/attention/attn/to_out/dot') == 'backward'
+    assert phase(bwd + 'rematted_computation/ff_block0/ff/add') == 'replay'
+    # a fusion's metadata may join paths with ';': the first counts
+    assert leaf('jit(f)/basis/mul;jit(f)/trunk/add') == 'basis'
+    assert profiling.kernel_role('fused_pairwise_conv_bwd_a.17') \
+        == 'fused_pairwise_conv_bwd_a'
+    assert profiling.kernel_role('fusion.12.clone') is None
 
 
 # --------------------------------------------------------------------- #

@@ -65,18 +65,12 @@ def test_profile_roofline_needs_a_known_device_kind(tmp_path):
     """observability.profiling prices utilization against the peak of
     the device the trace names; a CPU trace names none and carries
     none, an unknown device raises."""
-    import gzip
-    import json
+    from xplane_fixture import write_xplane
 
     from se3_transformer_tpu.observability import profiling
-    d = tmp_path / 'plugins' / 'profile' / 'run'
-    d.mkdir(parents=True)
-    events = [dict(ph='M', pid=7, name='process_name',
-                   args=dict(name='/host:CPU')),
-              dict(ph='X', pid=7, tid=1, name='dot.1', ts=0, dur=100,
-                   args=dict(hlo_op='dot.1'))]
-    with gzip.open(d / 'host.trace.json.gz', 'wt') as f:
-        json.dump(dict(traceEvents=events), f)
+    write_xplane(
+        str(tmp_path / 'plugins' / 'profile' / 'run' / 'host.xplane.pb'),
+        {'device': {'/device:TPU:0': [['dot.1', 0.0, 100e3, None, None]]}})
     kw = dict(label='x', flops_per_step=1e9, steps=1)
     body = profiling.profile_payload(str(tmp_path), **kw)
     assert 'utilization_vs_bf16_peak' not in body['roofline']

@@ -1,0 +1,221 @@
+"""The program labels its own time: every `named_scope` the package writes is
+a leaf of the closed list, a tiny train step lowered on the CPU (kernels in
+interpret mode) has every labelled instruction under a leaf with forward,
+backward and replay told apart, and the pairwise launches carry three
+distinct role names with the old prefixes."""
+import ast
+import os
+import re
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from se3_transformer_tpu.observability import profiling
+from se3_transformer_tpu.observability.timing import MODEL_SCOPES, PAIR_SCOPE
+
+PACKAGE = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), 'se3_transformer_tpu')
+
+
+def _scope_literals():
+    """(file, line, text) of the first argument of every `named_scope(...)`
+    call in the package; an f-string gives its literal parts with `{}`
+    holes."""
+    out = []
+    for dirpath, _, files in os.walk(PACKAGE):
+        for f in files:
+            if not f.endswith('.py'):
+                continue
+            path = os.path.join(dirpath, f)
+            for node in ast.walk(ast.parse(open(path).read())):
+                if not (isinstance(node, ast.Call) and node.args):
+                    continue
+                fn = node.func
+                name = fn.attr if isinstance(fn, ast.Attribute) else \
+                    getattr(fn, 'id', None)
+                if name != 'named_scope':
+                    continue
+                arg = node.args[0]
+                if isinstance(arg, ast.Constant):
+                    text = arg.value
+                elif isinstance(arg, ast.JoinedStr):
+                    text = ''.join(v.value if isinstance(v, ast.Constant)
+                                   else '{}' for v in arg.values)
+                else:
+                    text = None      # timing.named_scope's own forwarder
+                out.append((os.path.relpath(path, PACKAGE), node.lineno,
+                            text))
+    return out
+
+
+def test_every_named_scope_in_the_package_is_a_leaf():
+    sites = _scope_literals()
+    assert len(sites) > 30
+    forwarders = [s for s in sites if s[2] is None]
+    assert [s[0] for s in forwarders] == ['observability/timing.py']
+    for path, line, text in sites:
+        if text is None:
+            continue
+        if '{}' in text:
+            # `pair_<d_in>_<d_out>` / `pair_all_<d_out>`, the one pattern
+            assert PAIR_SCOPE.match(text.replace('{}', '1')), (path, line)
+            continue
+        assert text in MODEL_SCOPES, \
+            f'{path}:{line}: named_scope({text!r}) is not in MODEL_SCOPES'
+    assert len(set(MODEL_SCOPES)) == len(MODEL_SCOPES)
+
+
+# --------------------------------------------------------------------- #
+# a tiny train step, lowered and compiled on the CPU
+# --------------------------------------------------------------------- #
+_INSTR = re.compile(r'^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*?\s([\w\-]+)\(')
+_PLUMBING = {'parameter', 'constant', 'tuple', 'get-tuple-element',
+             'bitcast'}
+
+
+@pytest.fixture(scope='module')
+def step_hlo():
+    """HLO text of make_sharded_train_step around a degree-2, depth-1
+    reversible model with the flagship's execution knobs, the pairwise
+    kernels in interpret mode."""
+    import optax
+
+    from se3_transformer_tpu import SE3TransformerModule
+    from se3_transformer_tpu.parallel.sharding import make_sharded_train_step
+    n = 8
+    module = SE3TransformerModule(
+        dim=4, depth=1, num_degrees=2, heads=2, dim_head=2,
+        one_headed_key_values=True, attend_self=True, num_neighbors=3,
+        valid_radius=1e5, input_degrees=1, output_degrees=2,
+        reduce_dim_out=True, shared_radial_hidden=True, fuse_basis=True,
+        reversible=True, remat_policy='save_conv_outputs',
+        pallas_interpret=True)
+    rng = np.random.default_rng(0)
+    data = dict(seqs=jnp.asarray(rng.normal(size=(1, n, 4)), jnp.float32),
+                coords=jnp.asarray(np.cumsum(rng.normal(size=(1, n, 3)), 1),
+                                   jnp.float32),
+                masks=jnp.ones((1, n), bool))
+    params = jax.eval_shape(
+        partial(module.init, return_type=1), jax.random.PRNGKey(0),
+        data['seqs'], data['coords'], mask=data['masks'])['params']
+
+    def loss_fn(params, batch, key):
+        noised = batch['coords'] + jax.random.normal(
+            key, batch['coords'].shape)
+        out = module.apply({'params': params}, batch['seqs'], noised,
+                           mask=batch['masks'], return_type=1)
+        return (((noised + out) - batch['coords']) ** 2).sum(-1).mean(), {}
+
+    optimizer = optax.adam(1e-4)
+    step = make_sharded_train_step(loss_fn, optimizer)
+    assert step.__name__ == 'train_step'
+    lowered = step.lower(params, jax.eval_shape(optimizer.init, params),
+                         data, jax.ShapeDtypeStruct((2,), jnp.uint32))
+    return lowered.compile().as_text()
+
+
+@pytest.fixture(scope='module')
+def labelled(step_hlo):
+    """[(instruction, opcode, op_name)] for every instruction that carries
+    an op_name and is not plumbing."""
+    rows = []
+    for line in step_hlo.splitlines():
+        m = _INSTR.match(line)
+        op = re.search(r'op_name="([^"]*)"', line)
+        if m and op and m.group(2) not in _PLUMBING:
+            rows.append((m.group(1), m.group(2), op.group(1)))
+    assert len(rows) > 1000
+    return rows
+
+
+def test_every_instruction_of_the_step_is_under_a_leaf(labelled):
+    """Every instruction that does arithmetic or moves data, and that the
+    compiler left its op_name, resolves to a leaf of MODEL_SCOPES."""
+    lost = [(n, p) for n, _, p in labelled
+            if p.startswith('jit(train_step)')
+            and profiling.scope_leaf(p) is None]
+    assert not lost, lost[:5]
+    # the rest are bodies of interpret-mode kernel loops, which the
+    # interpreter traces under the launch's name alone (no such
+    # instructions exist on the chip, where a launch is one custom call)
+    rest = {p.split('/')[0] for _, _, p in labelled
+            if not p.startswith('jit(train_step)') and '/' in p}
+    assert all(profiling.kernel_role(r) for r in rest), rest
+
+
+def test_step_leaves_and_phases_are_all_present(labelled):
+    cells = {(profiling.scope_leaf(p), profiling.scope_phase(p))
+             for _, _, p in labelled}
+    leaves = {leaf for leaf, _ in cells}
+    for leaf in ('loss', 'optimizer', 'pairwise_layout', 'pair', 'radial',
+                 'gather', 'norm', 'ff', 'basis', 'basis_contract',
+                 'attn_core', 'attn_qkv', 'neighbors', 'readout'):
+        assert leaf in leaves, leaf
+    # the optimizer runs once, after the gradient; the layout traffic of
+    # the kernels' wrappers exists forward and backward; the reversible
+    # trunk replays its cheap glue (never a kernel's wrapper: the policy
+    # saves the convolutions' outputs)
+    assert ('optimizer', 'forward') in cells
+    assert {('pairwise_layout', 'forward'),
+            ('pairwise_layout', 'backward')} <= cells
+    assert ('pairwise_layout', 'replay') not in cells
+    for leaf in ('radial', 'norm', 'attn_core'):
+        assert {(leaf, 'forward'), (leaf, 'backward'),
+                (leaf, 'replay')} <= cells, leaf
+    assert {phase for _, phase in cells} == set(profiling.PHASES)
+
+
+def test_pairwise_launches_lower_to_three_role_names(labelled):
+    """In interpret mode a launch is a loop, whose instructions carry the
+    launch's name as a path component: the component XLA takes as the
+    custom call's instruction name on the chip (checked there by the
+    deviceless compile, tests/test_tpu_compile.py)."""
+    roles = set()
+    for _, _, p in labelled:
+        comps = p.split(';')[0].split('/')
+        roles.update(c for c in comps if c.startswith('fused_'))
+    assert roles == {'fused_pairwise_conv_bxf', 'fused_pairwise_conv_bwd_a',
+                     'fused_pairwise_conv_bwd_b'}
+    # the old prefixes: what the benchmark's metrics select on
+    assert sum(r.startswith('fused_pairwise_conv_bwd') for r in roles) == 2
+    assert sum(r.startswith('fused_pairwise_conv_bxf') for r in roles) == 1
+    # each launch sits under its degree pair, and its phase is readable
+    launches = {(profiling.scope_pair(p), profiling.scope_phase(p))
+                for _, _, p in labelled if 'fused_pairwise_conv_bwd_a' in p
+                and p.startswith('jit(train_step)')}
+    assert {pair for pair, _ in launches} == {'0,0', '0,1', '1,0', '1,1'}
+    assert {phase for _, phase in launches} == {'backward'}
+
+
+@pytest.mark.parametrize('telemetry', [False, True])
+def test_the_accumulating_step_is_named_and_scoped_like_the_plain_one(
+        telemetry):
+    """`train_step` in the compile log, `loss` around the scanned
+    micro-batches and `optimizer` around the update."""
+    import optax
+
+    from se3_transformer_tpu.parallel.sharding import (
+        make_accumulating_train_step,
+    )
+
+    def loss_fn(params, batch, key):
+        return ((batch @ params['w']) ** 2).mean(), {}
+
+    optimizer = optax.adam(1e-3)
+    step = make_accumulating_train_step(loss_fn, optimizer, accum_steps=2,
+                                        telemetry=telemetry)
+    assert step.__name__ == 'train_step'
+    params = dict(w=jnp.ones((4, 4)))
+    args = [params, optimizer.init(params), jnp.ones((2, 3, 4)),
+            jax.random.PRNGKey(0)]
+    if telemetry:
+        from se3_transformer_tpu.observability import MetricAccumulator
+        args.append(MetricAccumulator.zero(('loss', 'grad_norm')))
+    text = step.lower(*args).as_text(debug_info=True)
+    paths = set(re.findall(r'"(jit\(train_step\)/[^"]*)"', text))
+    leaves = {profiling.scope_leaf(p) for p in paths}
+    assert {'loss', 'optimizer'} <= leaves, sorted(paths)[:5]
+    assert any('/loss/' in p and 'while' in p for p in paths)

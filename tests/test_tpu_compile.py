@@ -127,6 +127,38 @@ def test_pairwise_bxf_compiles(v5e, rdt):
     assert calls > 0
 
 
+def test_launches_are_named_by_role(v5e):
+    """`name=` on pl.pallas_call is the innermost component of the op's
+    name stack, and the chip's compiler takes that as the custom call's
+    instruction name: the name a device trace shows. Forward, backward A
+    (dV2, dW3, dB3) and backward B (dH) are three names, the old prefixes
+    kept, and the degree pair rides in the op_name, not in the name."""
+    import re
+    e, c, o, p, q, f = 1024, 8, 8, 3, 3, 3
+
+    def fn(h, w3, basis, x, v2, g, b3):
+        with jax.named_scope('pair_1_1'):
+            out = fused_pairwise_conv_bxf(h, w3, basis, x, (p, q, f), b3)
+            return out, fused_pairwise_conv_bwd(h, w3, v2, g, b3)
+
+    args = [jax.ShapeDtypeStruct(s, f32, sharding=v5e) for s in (
+        (e, MID), (MID, c * f, o), (e, p * f * q), (e, c, q),
+        (e, p, c * f), (e, p, o), (c * f, o))]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    calls = re.findall(r'%([\w.]+) = [^\n]*custom_call_target='
+                       r'"tpu_custom_call"[^\n]*op_name="([^"]*)"', text)
+    names = sorted(re.sub(r'\.\d+$', '', n) for n, _ in calls)
+    assert names == ['fused_pairwise_conv_bwd_a', 'fused_pairwise_conv_bwd_b',
+                     'fused_pairwise_conv_bxf']
+    for name, op_name in calls:
+        comps = op_name.split('/')
+        assert comps[-1] == 'pallas_call' and name.startswith(comps[-2])
+        assert 'pair_1_1' in comps and 'pairwise_layout' not in comps
+    # the wrappers' relayouts are under their own leaf
+    assert 'pairwise_layout/transpose' in text \
+        or 'pairwise_layout/reshape' in text
+
+
 ATT_SHAPES = (((ATT_HEADS, ATT_N, ATT_D), f32),
               ((ATT_HEADS, ATT_N, ATT_J, ATT_D), f32),
               ((ATT_HEADS, ATT_N, ATT_J, ATT_D), f32),

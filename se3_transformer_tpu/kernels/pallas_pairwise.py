@@ -42,7 +42,10 @@ The backward runs as TWO kernels because its two accumulated cotangents
 want different inner grid axes: dW3 accumulates over edges (grid
 (n_if, n_e), e inner) while dH accumulates over if-chunks (grid
 (n_e, n_if), f inner). dV2 falls out of kernel A for free. dR exists only
-as per-(i) VMEM blocks in both.
+in VMEM in both: each program stacks its if-chunk's rows, [bif*O, E_b],
+in a scratch and feeds them to ONE dot (dW3 in A, dH in B), so the MXU
+sees bif*O rows (or a bif*O-wide contraction), never O. The three dots of
+the backward take h and w3 in the dtype they arrive in, like the forward.
 """
 from __future__ import annotations
 
@@ -136,7 +139,9 @@ def _vmem_plain(be: int, bif: int, IF: int, O: int, P: int, mid: int,
     if bwd:
         # kernel A additionally holds h_p (be*mid), the gT block
         # (= out-sized), the dv2 block (= v2-sized), the dw3 block
-        # (= w3-sized) and the db3 block (= b3-sized)
+        # (= w3-sized) and the db3 block (= b3-sized). The stacked-dR
+        # scratch (bif*O*be) adds no term: it is the second of the two
+        # [bif*O, be] tiles above, of which the backward holds R alone
         total += 4 * (be * mid + P * O * be + P * bif * be
                       + bif * O * mid + bif * O * 128)
     return total
@@ -852,14 +857,21 @@ def fused_pairwise_conv_bxf(h: jnp.ndarray, w3: jnp.ndarray,
 #   dW3[m,if,o] = sum_e  H[e,m] dR[e,if,o]
 #   dB3[if,o]   = sum_e  dR[e,if,o]
 # Kernel A (grid (n_if, n_e), e inner): rT matmul (+bias) -> dV2 rows
-# (sublane reduce), dR blocks -> dW3 (matmul) and dB3 (lane reduce),
-# both accumulated over the inner edge axis.
-# Kernel B (grid (n_e, n_if), f inner): dR blocks (no matmul needed)
-# -> dH accumulated over the inner if axis.
+# (sublane reduce); dR rows stacked i-major in a VMEM scratch -> dW3 (one
+# matmul over the stack) and dB3 (one lane reduce), both accumulated over
+# the inner edge axis.
+# Kernel B (grid (n_e, n_if), f inner): the same stacked dR (no rT matmul
+# needed) -> dH (one matmul, contraction bif*O wide) accumulated over the
+# inner if axis.
+# A per-i form (bif dots, each with an O-wide side) feeds a 128 x 128 MXU
+# O/128 of its rate: at O = 24 it took 1.6-2x the stacked form's time on
+# the v5e, where the VPU side (the FMAs that build dR, kernel A's dV2
+# reductions and one-row stores) now sets the pace (PERF.md, PR 25).
 
 
 def _bwd_a_kernel(ht_ref, h_ref, w3t_ref, b3t_ref, v2t_ref, gt_ref,
-                  dv2_ref, dw3_ref, db3_ref, *, P, O, bif, precision):
+                  dv2_ref, dw3_ref, db3_ref, dr_ref, *, P, O, bif, precision,
+                  mxu_dtype):
     e = pl.program_id(1)
     # R must include the bias here: dV2 = g . R
     rt = jax.lax.dot_general(
@@ -881,35 +893,38 @@ def _bwd_a_kernel(ht_ref, h_ref, w3t_ref, b3t_ref, v2t_ref, gt_ref,
                 vrow = vrow.astype(jnp.float32)      # conv_bf16 storage
             term = vrow * gp                         # [O, E_b]
             dr_i = term if dr_i is None else dr_i + term
-        # dW3 rows for this i: [O, E_b] @ [E_b, mid], accumulated over the
-        # inner edge grid axis (consecutive revisits)
-        upd = jax.lax.dot_general(
-            dr_i, h_ref[:],
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            precision=precision,
-            preferred_element_type=jnp.float32)      # [O, mid]
-        # dB3 rows: sum dR over edges (lane reduction), same revisit
-        # accumulation. Padded edge lanes contribute zeros (v2/g padded).
-        db3_upd = jnp.sum(dr_i, axis=1, keepdims=True)   # [O, 1]
-        sl = slice(i * O, (i + 1) * O)
+        dr_ref[i * O:(i + 1) * O, :] = dr_i
+    dr = dr_ref[:]                                   # [bif*O, E_b], f32
+    hp = h_ref[:]
+    # dW3 rows of the whole if-chunk in ONE dot, [bif*O, E_b] @ [E_b, mid]
+    # (per-i dots would hand the MXU O rows at a time), accumulated over
+    # the inner edge grid axis (consecutive revisits). dR is rounded to
+    # the dtype h arrived in (mxu_dtype; the second cast is the exact
+    # upcast of interpret mode, nothing under Mosaic)
+    upd = jax.lax.dot_general(
+        dr.astype(mxu_dtype).astype(hp.dtype), hp,
+        dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=precision,
+        preferred_element_type=jnp.float32)          # [bif*O, mid]
+    # dB3 rows: sum the f32 dR over edges (lane reduction), same revisit
+    # accumulation. Padded edge lanes contribute zeros (v2/g padded).
+    db3_upd = jnp.sum(dr, axis=1, keepdims=True)     # [bif*O, 1]
 
-        @pl.when(e == 0)
-        def _(upd=upd, db3_upd=db3_upd, sl=sl):
-            dw3_ref[sl, :] = upd.astype(dw3_ref.dtype)
-            db3_ref[sl, :] = db3_upd.astype(db3_ref.dtype)
+    @pl.when(e == 0)
+    def _():
+        dw3_ref[:] = upd.astype(dw3_ref.dtype)
+        db3_ref[:] = db3_upd.astype(db3_ref.dtype)
 
-        @pl.when(e > 0)
-        def _(upd=upd, db3_upd=db3_upd, sl=sl):
-            dw3_ref[sl, :] = dw3_ref[sl, :] + upd.astype(dw3_ref.dtype)
-            db3_ref[sl, :] = db3_ref[sl, :] + db3_upd.astype(db3_ref.dtype)
+    @pl.when(e > 0)
+    def _():
+        dw3_ref[:] = dw3_ref[:] + upd.astype(dw3_ref.dtype)
+        db3_ref[:] = db3_ref[:] + db3_upd.astype(db3_ref.dtype)
 
 
-def _bwd_b_kernel(w3f_ref, v2t_ref, gt_ref, dh_ref, *, P, O, bif,
-                  precision):
+def _bwd_b_kernel(w3f_ref, v2t_ref, gt_ref, dh_ref, dr_ref, *, P, O, bif,
+                  precision, mxu_dtype):
     f = pl.program_id(1)
     g = gt_ref[:]                                    # [P*O, E_b]
-    w3f = w3f_ref[0]                                 # [mid, bif*O]
-    acc = None
     for i in range(bif):
         dr_i = None
         for p in range(P):
@@ -918,13 +933,15 @@ def _bwd_b_kernel(w3f_ref, v2t_ref, gt_ref, dh_ref, *, P, O, bif,
                 vrow = vrow.astype(jnp.float32)      # conv_bf16 storage
             term = vrow * g[p * O:(p + 1) * O, :]
             dr_i = term if dr_i is None else dr_i + term
-        # dH partial: [mid, O] @ [O, E_b]
-        upd = jax.lax.dot_general(
-            w3f[:, i * O:(i + 1) * O], dr_i,
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            precision=precision,
-            preferred_element_type=jnp.float32)      # [mid, E_b]
-        acc = upd if acc is None else acc + upd
+        dr_ref[i * O:(i + 1) * O, :] = dr_i
+    w3f = w3f_ref[0]                                 # [mid, bif*O]
+    # dH partial of the whole if-chunk in ONE dot, [mid, bif*O] @
+    # [bif*O, E_b]: the contraction is bif*O wide, not O
+    acc = jax.lax.dot_general(
+        w3f, dr_ref[:].astype(mxu_dtype).astype(w3f.dtype),
+        dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=precision,
+        preferred_element_type=jnp.float32)          # [mid, E_b]
 
     @pl.when(f == 0)
     def _():
@@ -936,11 +953,15 @@ def _bwd_b_kernel(w3f_ref, v2t_ref, gt_ref, dh_ref, *, P, O, bif,
 
 
 def _fused_pairwise_conv_bwd_impl(h, w3, b3, v2, g, interpret, precision):
-    # f32 gradient math: bf16 radial operands (radial_bf16) upcast
-    # exactly. A bf16 V2 (conv_bf16) STAYS bf16 through HBM — the
-    # backward kernels upcast rows in VMEM like the forward does, so the
-    # half-width saving on the dominant stream holds for the backward
-    # too (upcasting here would write a full f32 copy back to HBM first)
+    # The MXU operands keep the dtype h arrives in, as in the forward:
+    # bf16 radial operands (radial_bf16) feed the three dots as they are,
+    # with dR rounded to bf16 in the tile; f32 h leaves everything f32 at
+    # the caller's precision. g, the reductions (dV2, dB3) and every
+    # accumulator are f32 either way. A bf16 V2 (conv_bf16) STAYS bf16
+    # through HBM — the backward kernels upcast rows in VMEM like the
+    # forward does, so the half-width saving on the dominant stream holds
+    # for the backward too (upcasting here would write a full f32 copy
+    # back to HBM first)
     E, mid = h.shape
     _, IF, O = w3.shape
     P = v2.shape[1]
@@ -949,8 +970,17 @@ def _fused_pairwise_conv_bwd_impl(h, w3, b3, v2, g, interpret, precision):
     Ep, IFp = _round_up(E, block_e), _round_up(IF, block_if)
     n_e, n_if = Ep // block_e, IFp // block_if
 
+    mxu_dtype = jnp.bfloat16 if h.dtype == jnp.bfloat16 else jnp.float32
+    if mxu_dtype == jnp.bfloat16:
+        # explicit DEFAULT, see fused_pairwise_conv: None would inherit a
+        # possibly-fp32 context precision, which Mosaic rejects on bf16
+        precision = jax.lax.Precision.DEFAULT
+    # CPU interpret can't dispatch BF16xBF16=F32 dots: h and w3 go up
+    # (exactly) there, and the kernels still round dR to mxu_dtype
+    rdt = jnp.float32 if interpret else mxu_dtype
+
     with jax.named_scope('pairwise_layout'):
-        h, w3 = h.astype(jnp.float32), w3.astype(jnp.float32)
+        h, w3 = h.astype(rdt), w3.astype(rdt)
         g = g.astype(jnp.float32)
         if v2.dtype == jnp.bfloat16 and interpret:
             # interpret can't mix dtypes the way Mosaic lowers them; the
@@ -973,10 +1003,14 @@ def _fused_pairwise_conv_bwd_impl(h, w3, b3, v2, g, interpret, precision):
         # block-shape rule)
         w3f3 = w3f.reshape(mid, n_if, block_if * O).transpose(1, 0, 2)
 
+    # the if-chunk's dR, stacked i-major: the one operand of each kernel's
+    # large dot, never in HBM
+    dr_scratch = pltpu.VMEM((block_if * O, block_e), jnp.float32)
+
     # kernel A: dV2 + dW3 + dB3 (accumulate over inner e axis)
     dv2t, dw3t, db3t = pl.pallas_call(
         functools.partial(_bwd_a_kernel, P=P, O=O, bif=block_if,
-                          precision=precision),
+                          precision=precision, mxu_dtype=mxu_dtype),
         grid=(n_if, n_e),
         in_specs=[
             pl.BlockSpec((mid, block_e), lambda f, e: (0, e),
@@ -1005,6 +1039,7 @@ def _fused_pairwise_conv_bwd_impl(h, w3, b3, v2, g, interpret, precision):
             jax.ShapeDtypeStruct((IFp * O, mid), jnp.float32),
             jax.ShapeDtypeStruct((IFp * O, 1), jnp.float32),
         ],
+        scratch_shapes=[dr_scratch],
         interpret=interpret,
         name='fused_pairwise_conv_bwd_a',
     )(ht, h_p, w3t, b3t, v2t, gt)
@@ -1013,7 +1048,7 @@ def _fused_pairwise_conv_bwd_impl(h, w3, b3, v2, g, interpret, precision):
     # needed — dR comes straight from v2/g)
     dht = pl.pallas_call(
         functools.partial(_bwd_b_kernel, P=P, O=O, bif=block_if,
-                          precision=precision),
+                          precision=precision, mxu_dtype=mxu_dtype),
         grid=(n_e, n_if),
         in_specs=[
             pl.BlockSpec((1, mid, block_if * O), lambda e, f: (f, 0, 0),
@@ -1026,6 +1061,7 @@ def _fused_pairwise_conv_bwd_impl(h, w3, b3, v2, g, interpret, precision):
         out_specs=pl.BlockSpec((mid, block_e), lambda e, f: (0, e),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((mid, Ep), jnp.float32),
+        scratch_shapes=[dr_scratch],
         interpret=interpret,
         name='fused_pairwise_conv_bwd_b',
     )(w3f3, v2t, gt)
@@ -1077,8 +1113,11 @@ def fused_pairwise_conv_bwd(h: jnp.ndarray, w3: jnp.ndarray,
     h [E, mid], w3 [mid, IF, O], v2 [E, P, IF], g [E, P, O], b3 [IF, O]
     (optional, zeros when None — b3 feeds dV2 = g . R with R including
     the bias; db3 itself is bias-independent: sum_e dR).
-    bf16 radial operands are upcast (exactly) and the backward runs in
-    f32 — gradients stay at the policy precision under radial_bf16.
+    The dtype of h decides the MXU operands, as in the forward: bf16 h
+    and w3 (radial_bf16) enter the three dots as they are, with dR
+    rounded to bf16 in the tile, one pass, f32 accumulation; f32 h keeps
+    every operand f32 at `precision`. g, dV2, dB3 and every accumulator
+    are f32 either way.
     Partitions over sharded edge/output-channel axes with the dW3/dB3
     (and, under tp, dH/dV2) partial sums reduced in the partition body.
     """

@@ -96,13 +96,20 @@ def test_pairwise_forward_compiles(v5e, rdt):
 
 
 @RADIAL
-def test_pairwise_backward_compiles(v5e, rdt):
+@pytest.mark.parametrize('p,n_if,o', [
+    (P, IF, O),      # the flagship's trunk convolution
+    # d4_onehead_train's keys / values (one head of 24) at the degree
+    # pairs (3,3) and (0,3): the stacked-dR scratch is 384 rows there
+    (7, 448, 24),
+    (7, 64, 24),
+])
+def test_pairwise_backward_compiles(v5e, rdt, p, n_if, o):
     calls = compile_for(
         v5e,
         lambda h, w3, v2, g, b3: fused_pairwise_conv_bwd(h, w3, v2, g, b3),
-        ((E, MID), rdt), ((MID, IF, O), rdt), ((E, P, IF), f32),
-        ((E, P, O), f32), ((IF, O), f32))
-    assert calls > 0
+        ((E, MID), rdt), ((MID, n_if, o), rdt), ((E, p, n_if), f32),
+        ((E, p, o), f32), ((n_if, o), f32))
+    assert calls == 2  # kernel A (dV2, dW3, dB3) and kernel B (dH)
 
 
 @RADIAL

@@ -1,1 +1,2 @@
 from .se3_transformer import SE3Transformer, SE3TransformerModule
+from .token_decoder import TokenDecoder

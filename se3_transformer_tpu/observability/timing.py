@@ -72,6 +72,28 @@ MODEL_SCOPES = (
     'exchange',           # parallel/exchange.py: neighbor-sparse value
     #                       rotation + select (and the zero-comm rowwise
     #                       column select)
+    # the token decoder (models/token_decoder.py); its block norms and final
+    # norms are filed under `norm`
+    'embed',              # models/token_decoder.py: token embedding rows
+    'latent_qkv',         # ops/latent_attention.py: down- and
+    #                       up-projections, their norms, the rotation
+    'latent_core',        # ops/latent_attention.py: scores, softmax,
+    #                       weighted sum (the streaming kernel on a TPU)
+    'latent_out',         # ops/latent_attention.py: output projection
+    'moe_router',         # ops/expert_layer.py: logits, sigmoid, top-k,
+    #                       weights
+    'moe_dispatch',       # ops/expert_layer.py: sort / gather into expert
+    #                       order
+    'moe_experts',        # ops/expert_layer.py: the grouped products and
+    #                       the activation
+    'moe_combine',        # ops/expert_layer.py: back to token order, the
+    #                       weighted sum over a token's slots
+    'shared_expert',      # ops/expert_layer.py
+    'dense_ff',           # models/token_decoder.py: a dense block's SwiGLU
+    'mtp_merge',          # models/token_decoder.py: the prediction block's
+    #                       two norms, concatenation and projection
+    'lm_head',            # training/lm_loss.py: both heads' logits and
+    #                       cross-entropies, chunk by chunk
     'loss',               # parallel/sharding.py train_step: what the
     #                       model's scopes do not claim inside the
     #                       differentiated loss

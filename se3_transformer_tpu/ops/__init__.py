@@ -9,5 +9,8 @@ from .neighbors import (
     exclude_self_indices, remove_self, expand_adjacency,
     sparse_neighbor_mask, select_neighbors, Neighborhood,
 )
-from .rotary import sinusoidal_embeddings, apply_rotary_pos_emb
+from .rotary import (
+    sinusoidal_embeddings, apply_rotary_pos_emb, rotary_angles,
+    apply_rotary_halves,
+)
 from .trunk import SequentialTrunk

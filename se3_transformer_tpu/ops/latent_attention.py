@@ -1,0 +1,130 @@
+"""Latent attention for a causal token decoder.
+
+Queries go through a low-rank bottleneck; keys and values are expanded from
+one compressed latent a token; one rotary key a token is shared by every
+head; value heads may be wider than the keys' un-rotated part:
+
+    cq = RMSNorm(x Wqa)                 q = cq Wqb -> [qn ; qr] per head
+    [ckv ; kr] = x Wkva                 ckv <- RMSNorm(ckv)
+    [kn ; v] = ckv Wkvb per head        qr, kr <- rotation(positions)
+    scores = (qn . kn + qr . kr) / sqrt(d_nope + d_rope), causal,
+    softmax in float32; out = (softmax . v) Wo
+
+Training materializes kn and v (no absorbed form). The core never holds the
+[heads, T, T] scores: on a TPU it is JAX's streaming Pallas kernel
+(`jax.experimental.pallas.ops.tpu.flash_attention`: bfloat16 operands,
+float32 softmax and accumulation), elsewhere blocks of queries against the
+keys at or before them, each block recomputed in the backward pass.
+"""
+from __future__ import annotations
+
+from functools import partial
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from ..observability import named_scope
+from ..utils.helpers import is_tpu_backend
+from .rotary import apply_rotary_halves, rotary_angles
+
+
+class RMSNorm(nn.Module):
+    """x / sqrt(mean(x^2) + eps) * scale, in float32."""
+    eps: float = 1e-5
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param('scale', nn.initializers.ones, (x.shape[-1],))
+        x = x.astype(jnp.float32)
+        var = jnp.mean(x * x, axis=-1, keepdims=True)
+        return x * jax.lax.rsqrt(var + self.eps) * scale
+
+
+def causal_attention_blocked(q, k, v, scale: float, block_q: int = 512):
+    """q, k [B, H, T, Dk], v [B, H, T, Dv] -> [B, H, T, Dv]. Query block i
+    meets keys 0 .. (i + 1) block_q only (static extents, so the masked half
+    is never computed) and is recomputed in the backward pass."""
+    t = q.shape[2]
+    bq = min(block_q, t)
+    assert t % bq == 0, (t, bq)
+
+    @jax.checkpoint
+    def block(qi, kj, vj, q0):
+        s = jnp.einsum('bhqd,bhkd->bhqk', qi, kj,
+                       preferred_element_type=jnp.float32) * scale
+        qpos = q0 + jnp.arange(qi.shape[2])[:, None]
+        kpos = jnp.arange(kj.shape[2])[None, :]
+        s = jnp.where(kpos <= qpos, s, jnp.finfo(jnp.float32).min)
+        p = jax.nn.softmax(s, axis=-1)
+        return jnp.einsum('bhqk,bhkd->bhqd', p, vj,
+                          preferred_element_type=jnp.float32)
+
+    outs = [block(q[:, :, i:i + bq], k[:, :, :i + bq], v[:, :, :i + bq], i)
+            for i in range(0, t, bq)]
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=2)
+
+
+def causal_attention_flash(q, k, v, scale: float, block: int = 512):
+    """The same on the TPU's streaming kernel; operands rounded to bfloat16
+    here, where the XLA form leaves it to the default matmul precision."""
+    from jax.experimental.pallas.ops.tpu import flash_attention as fa
+    b = min(block, q.shape[2])
+    sizes = fa.BlockSizes(
+        block_q=b, block_k_major=b, block_k=b, block_b=1,
+        block_q_major_dkv=b, block_k_major_dkv=b, block_k_dkv=b,
+        block_q_dkv=b, block_k_major_dq=b, block_k_dq=b, block_q_dq=b)
+    q, k, v = (a.astype(jnp.bfloat16) for a in (q, k, v))
+    out = fa.flash_attention(q, k, v, causal=True, sm_scale=scale,
+                             block_sizes=sizes)
+    return out.astype(jnp.float32)
+
+
+class LatentAttention(nn.Module):
+    dim: int
+    heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope_theta: float = 10000.0
+    eps: float = 1e-5
+    block: int = 512      # of queries (and of keys, in the kernel)
+
+    @nn.compact
+    def __call__(self, x):
+        """x [B, T, dim] -> [B, T, dim]; positions are 0 .. T - 1."""
+        b, t, _ = x.shape
+        h, dn, dr, dv = (self.heads, self.qk_nope_head_dim,
+                         self.qk_rope_head_dim, self.v_head_dim)
+        dense = partial(nn.Dense, use_bias=False)
+        with named_scope('latent_qkv'):
+            cq = RMSNorm(self.eps, name='q_a_norm')(
+                dense(self.q_lora_rank, name='q_a')(x))
+            q = dense(h * (dn + dr), name='q_b')(cq).reshape(b, t, h, dn + dr)
+            ckv_kr = dense(self.kv_lora_rank + dr, name='kv_a')(x)
+            ckv = RMSNorm(self.eps, name='kv_a_norm')(
+                ckv_kr[..., :self.kv_lora_rank])
+            kr = ckv_kr[..., self.kv_lora_rank:]               # [B, T, dr]
+            kv = dense(h * (dn + dv), name='kv_b')(ckv).reshape(
+                b, t, h, dn + dv)
+            angles = rotary_angles(jnp.arange(t), dr, self.rope_theta)
+            qr = apply_rotary_halves(q[..., dn:], angles[None, :, None, :])
+            kr = apply_rotary_halves(kr, angles[None])
+            q = jnp.concatenate((q[..., :dn], qr), axis=-1)
+            k = jnp.concatenate(
+                (kv[..., :dn],
+                 jnp.broadcast_to(kr[:, :, None, :], (b, t, h, dr))), axis=-1)
+            q, k, v = (a.transpose(0, 2, 1, 3) for a in (q, k, kv[..., dn:]))
+        scale = (dn + dr) ** -0.5
+        with named_scope('latent_core'):
+            # the streaming kernel where it can run: on a TPU, at a length
+            # its tiles divide
+            core = causal_attention_flash \
+                if is_tpu_backend() and t % 128 == 0 \
+                else causal_attention_blocked
+            o = core(q, k, v, scale, self.block)
+        with named_scope('latent_out'):
+            o = o.transpose(0, 2, 1, 3).reshape(b, t, h * dv)
+            return dense(self.dim, name='out')(o)

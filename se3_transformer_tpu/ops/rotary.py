@@ -3,6 +3,9 @@
 Functional JAX analogue of reference rotary.py (SinusoidalEmbeddings /
 apply_rotary_pos_emb). Rotary features are applied only to the invariant
 (degree-0) q/k/v channels, so they do not interact with equivariance.
+
+`rotary_angles` / `apply_rotary_halves` are the same rotation for a plain
+`[..., d]` layout at a given base (the token decoder's shared rotary key).
 """
 from __future__ import annotations
 
@@ -33,3 +36,20 @@ def apply_rotary_pos_emb(t: jnp.ndarray, freqs: jnp.ndarray) -> jnp.ndarray:
     t_rot, t_pass = t[..., :rot_dim, :], t[..., rot_dim:, :]
     t_rot = (t_rot * jnp.cos(freqs)) + (_rotate_half(t_rot) * jnp.sin(freqs))
     return jnp.concatenate((t_rot, t_pass), axis=-2)
+
+
+def rotary_angles(positions: jnp.ndarray, dim: int,
+                  base: float = 10000.0) -> jnp.ndarray:
+    """positions [...] -> [..., dim // 2] angles, one per rotated pair."""
+    inv_freq = 1.0 / (base ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    return positions[..., None].astype(jnp.float32) * inv_freq
+
+
+def apply_rotary_halves(x: jnp.ndarray, angles: jnp.ndarray) -> jnp.ndarray:
+    """Rotate all of x [..., d] by angles (broadcast against [..., d // 2]);
+    the pairs are (x_i, x_{i + d/2}): the half-split pairing, no trailing
+    irrep axis."""
+    x1, x2 = jnp.split(x, 2, axis=-1)
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    return jnp.concatenate((x1 * cos - x2 * sin, x2 * cos + x1 * sin),
+                           axis=-1)

@@ -16,10 +16,17 @@ the driver tracks:
                        num_edge_tokens=4, attend_sparse_neighbors, adj mat)
   * egnn_stress      — reversible depth-12 EGNN-hybrid large-graph
                        memory stress
+
+and one builder of the token decoder (models/token_decoder.py):
+
+  * token_decoder    — latent attention + held experts + prediction block,
+                       at tiny widths for tests; a benchmark configuration
+                       passes the published widths as overrides
 """
 from __future__ import annotations
 
 from ..models.se3_transformer import SE3TransformerModule
+from ..models.token_decoder import TokenDecoder
 
 
 def toy_denoise() -> SE3TransformerModule:
@@ -114,6 +121,20 @@ def egnn_stress(dim: int = 16, depth: int = 12) -> SE3TransformerModule:
         num_neighbors=16, reversible=True)
 
 
+def token_decoder(**overrides) -> TokenDecoder:
+    """Tiny widths by default (CPU tests): one dense block and one expert
+    block, 2 of 8 experts a token, 4 of them held here. Train it with
+    `training.lm_loss.make_lm_loss(module)`."""
+    sizes = dict(
+        vocab_rows=48, hidden_size=32, intermediate_size=48,
+        moe_intermediate_size=16, num_hidden_layers=2, num_attention_heads=2,
+        q_lora_rank=12, kv_lora_rank=8, qk_nope_head_dim=8,
+        qk_rope_head_dim=4, v_head_dim=12, n_routed_experts=8,
+        num_experts_per_tok=2, experts_held=4, routed_scaling_factor=1.8)
+    sizes.update(overrides)
+    return TokenDecoder(**sizes)
+
+
 RECIPES = {
     'toy_denoise': toy_denoise,
     'flagship': flagship,
@@ -121,4 +142,5 @@ RECIPES = {
     'af2_refinement': af2_refinement,
     'molecular_edges': molecular_edges,
     'egnn_stress': egnn_stress,
+    'token_decoder': token_decoder,
 }

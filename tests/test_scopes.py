@@ -219,3 +219,61 @@ def test_the_accumulating_step_is_named_and_scoped_like_the_plain_one(
     leaves = {profiling.scope_leaf(p) for p in paths}
     assert {'loss', 'optimizer'} <= leaves, sorted(paths)[:5]
     assert any('/loss/' in p and 'while' in p for p in paths)
+
+
+# --------------------------------------------------------------------- #
+# the token decoder's leaves, and that the SE(3) step has none of them
+# --------------------------------------------------------------------- #
+DECODER_LEAVES = ('embed', 'latent_qkv', 'latent_core', 'latent_out',
+                  'moe_router', 'moe_dispatch', 'moe_experts', 'moe_combine',
+                  'shared_expert', 'dense_ff', 'mtp_merge', 'lm_head')
+
+
+def test_the_token_decoders_leaves_are_on_the_closed_list():
+    assert set(DECODER_LEAVES) <= set(MODEL_SCOPES)
+
+
+def test_no_decoder_leaf_occurs_in_a_path_of_the_se3_step(labelled):
+    """Flax writes module names into the same paths: a new leaf that is also
+    a component of an SE(3) path would take that operation's time."""
+    comps = {c for _, _, p in labelled for c in p.split(';')[0].split('/')}
+    assert not comps & set(DECODER_LEAVES)
+    leaves = {profiling.scope_leaf(p) for _, _, p in labelled}
+    assert not leaves & set(DECODER_LEAVES)
+
+
+def test_a_tiny_decoder_step_has_every_decoder_leaf_and_the_three_phases():
+    import optax
+
+    from se3_transformer_tpu.parallel.sharding import make_sharded_train_step
+    from se3_transformer_tpu.training.lm_loss import make_lm_loss
+    from se3_transformer_tpu.training.recipes import RECIPES
+    module = RECIPES['token_decoder'](attention_block=8)
+    tokens = jax.ShapeDtypeStruct((1, 16), jnp.int32)
+    params = jax.eval_shape(module.init, jax.random.PRNGKey(0),
+                            tokens)['params']
+    optimizer = optax.adam(1e-4)
+    step = make_sharded_train_step(make_lm_loss(module, chunk=8), optimizer)
+    text = step.lower(params, jax.eval_shape(optimizer.init, params),
+                      dict(tokens=tokens),
+                      jax.ShapeDtypeStruct((2,), jnp.uint32)
+                      ).as_text(debug_info=True)
+    # a path's last component is the primitive's name, and `gather`, the
+    # primitive behind every row lookup here, is also a leaf of the SE(3)
+    # model: the decoder's readers (benchmark/layer_metrics/_lm_leaves.py)
+    # read the scopes alone, and so does this test
+    paths = {p.rsplit('/', 1)[0] for p in re.findall(
+        r'"(jit\(train_step\)/[^"]*)"', text)}
+    cells = {(profiling.scope_leaf(p), profiling.scope_phase(p))
+             for p in paths}
+    leaves = {leaf for leaf, _ in cells}
+    assert set(DECODER_LEAVES) | {'norm', 'loss', 'optimizer'} <= leaves
+    assert None not in leaves
+    # every block is recomputed in the backward pass: its leaves have the
+    # three phases; the heads are recomputed chunk by chunk
+    for leaf in ('latent_qkv', 'latent_core', 'latent_out', 'moe_router',
+                 'moe_dispatch', 'moe_experts', 'moe_combine',
+                 'shared_expert', 'dense_ff', 'lm_head'):
+        assert {(leaf, ph) for ph in profiling.PHASES} <= cells, leaf
+    # no SE(3) leaf but the shared `norm`, `loss` and `optimizer`
+    assert leaves <= set(DECODER_LEAVES) | {'norm', 'loss', 'optimizer'}

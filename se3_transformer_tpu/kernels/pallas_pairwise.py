@@ -46,6 +46,13 @@ in VMEM in both: each program stacks its if-chunk's rows, [bif*O, E_b],
 in a scratch and feeds them to ONE dot (dW3 in A, dH in B), so the MXU
 sees bif*O rows (or a bif*O-wide contraction), never O. The three dots of
 the backward take h and w3 in the dtype they arrive in, like the forward.
+
+Each comes in two forms, chosen by what the call site holds: a V2 (the
+plain forward and backward), or the flat basis and the gathered features
+it is the product of (`fused_pairwise_conv_bxf`,
+`fused_pairwise_conv_bwd_bxf`). The second builds V2 in VMEM, forward and
+backward, and its kernel A also emits dx: no V2, dV2 or dx passes through
+HBM as an XLA operand.
 """
 from __future__ import annotations
 
@@ -847,7 +854,9 @@ def fused_pairwise_conv_bxf(h: jnp.ndarray, w3: jnp.ndarray,
 
 
 # --------------------------------------------------------------------- #
-# fused backward
+# fused backward, V2 given (the plain forward's, and the structured-basis
+# forward's, whose call site builds V2 by an einsum first; the flat-basis
+# forward's own backward follows further down)
 # --------------------------------------------------------------------- #
 # Cotangents of out[e,P,o] = sum_{if} V2[e,P,if] R[e,if,o],
 # R = H W3 + B3:
@@ -1124,3 +1133,395 @@ def fused_pairwise_conv_bwd(h: jnp.ndarray, w3: jnp.ndarray,
     if b3 is None:
         b3 = jnp.zeros(w3.shape[1:], jnp.float32)
     return _bwd_partitioned(interpret, precision)(h, w3, b3, v2, g)
+
+
+# --------------------------------------------------------------------- #
+# basis-fused backward (V2, dV2 -> dx never touch HBM or XLA)
+# --------------------------------------------------------------------- #
+# The backward of fused_pairwise_conv_bxf: kernels A and B above with the
+# V2 block they load replaced by one they build in a VMEM scratch from B
+# and x, and with A also folding the dV2 block it writes into dx,
+#   V2[(p, f), c, :]  = sum_q B[(p, f), q, :] x[q, c, :]
+#   dx[q, c, :]       = sum_(p, f) B[(p, f), q, :] dV2[(p, f), c, :].
+# Both put the chunk's CHANNELS on sublanes and broadcast a row of B over
+# them, so a tile of 8 channels costs 2Q - 1 (2 P F) VPU operations and
+# no sublane reduction: the forward's form, one [Q, E_b] product and a
+# reduction per (p, c, f), measured 1.8 ns a row in these kernels where
+# their whole dR body takes 2.4 (PERF.md, PR 28). The if-chunk is whole
+# channels (cb of them, cb*F i's), so a dx block is finished inside one
+# program. dbasis sums over channels, A's OUTER grid axis, so it stays
+# outside: A writes dV2 as before and the wrapper reduces it against x in
+# XLA, which drops both when nothing asks for the basis' cotangent.
+#
+# Every loop is ROLLED but the P x F (or Q) body inside it, so the traced
+# kernel does not grow with cb. What a loop indexes is a leading, untiled
+# axis, one sublane row, or a tile-aligned row offset:
+#   bt  [P*F, Q, E]    B, one [Q, E_b] slab per (p, f)
+#   xt  [Q, C, E]      gathered features; dxt the same
+#   dv2 [P*F, C, E]    A's output; the V2 scratch is one block of this
+#   R, dR scratches [cb*F*O, E_b], rows of i = (c, f) at i*O, with O
+#                      padded to a multiple of 8 by the wrapper (zero
+#                      rows of w3, b3 and g)
+
+
+def _f32(v):
+    """conv_bf16 stores B and x bf16; the math is f32 on those values."""
+    return v if v.dtype == jnp.float32 else v.astype(jnp.float32)
+
+
+def _contract_over_q(bt_ref, xt_ref, v2_ref, Q):
+    """v2_ref[(p, f)] = sum_q B[(p, f), q] x[q], all cb channels at once."""
+    def one(pf, carry):
+        b = _f32(bt_ref[pf])                         # [Q, E_b]
+        acc = None
+        for q in range(Q):
+            term = b[q:q + 1, :] * _f32(xt_ref[q])   # [cb, E_b]
+            acc = term if acc is None else acc + term
+        v2_ref[pf] = acc
+        return carry
+
+    jax.lax.fori_loop(0, v2_ref.shape[0], one, 0)
+
+
+def _row_chunks(S, rows=512):
+    """The R / dR stack in slices of at most `rows` rows: each of the
+    kernels' dots runs slice by slice, still full tiles for the MXU, so
+    what the compiler holds beside the blocks and scratches (a dot's
+    result, dR rounded for the MXU, the lane-padded dB3 column) is
+    `rows` tall, not cb*F*O."""
+    return [slice(s0, min(S, s0 + rows)) for s0 in range(0, S, rows)]
+
+
+def _dr_rows(c, f, F, O):
+    return pl.ds(pl.multiple_of((c * F + f) * O, 8), O)
+
+
+def _bwd_bxf_a_kernel(ht_ref, h_ref, w3t_ref, b3t_ref, bt_ref, xt_ref,
+                      gt_ref, dv2_ref, dw3_ref, db3_ref, dx_ref, r_ref,
+                      dr_ref, v2_ref, *, P, O, Q, F, cb, precision,
+                      mxu_dtype):
+    e = pl.program_id(1)
+    hb = ht_ref[:]
+    # R of the whole c-chunk, bias included (dV2 = g . R): [cb*F*O, E_b]
+    for rows in _row_chunks(r_ref.shape[0]):
+        r_ref[rows, :] = jax.lax.dot_general(
+            w3t_ref[rows, :], hb,
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            precision=precision,
+            preferred_element_type=jnp.float32) + b3t_ref[rows, :]
+    _contract_over_q(bt_ref, xt_ref, v2_ref, Q)
+
+    def channel(c, carry):
+        row = pl.ds(c, 1)
+        for f in range(F):
+            rows = _dr_rows(c, f, F, O)
+            r_i = r_ref[rows, :]                     # [O, E_b]
+            dr_i = None
+            for p in range(P):
+                pf = p * F + f
+                gp = gt_ref[p * O:(p + 1) * O, :]    # [O, E_b]
+                # dV2[(p, f), c] = sum_o g[p, o] R[(c, f), o]
+                dv2_ref[pf, row, :] = jnp.sum(gp * r_i, axis=0,
+                                              keepdims=True)
+                term = v2_ref[pf, row, :] * gp
+                dr_i = term if dr_i is None else dr_i + term
+            dr_ref[rows, :] = dr_i
+        return carry
+
+    jax.lax.fori_loop(0, cb, channel, 0)
+
+    for q in range(Q):
+        def add(pf, acc, q=q):
+            return acc + _f32(bt_ref[pf])[q:q + 1, :] * dv2_ref[pf]
+
+        dx_ref[q] = jax.lax.fori_loop(
+            0, P * F, add, jnp.zeros(dx_ref.shape[1:], jnp.float32))
+
+    hp = h_ref[:]
+    # as _bwd_a_kernel: dW3 rows by full-tile dots over the stacked dR,
+    # dB3 by lane reductions, both accumulated over the inner edge axis
+    for rows in _row_chunks(dr_ref.shape[0]):
+        dr = dr_ref[rows, :]
+        upd = jax.lax.dot_general(
+            dr.astype(mxu_dtype).astype(hp.dtype), hp,
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            precision=precision,
+            preferred_element_type=jnp.float32)      # [rows, mid]
+        db3_upd = jnp.sum(dr, axis=1, keepdims=True)  # [rows, 1]
+
+        @pl.when(e == 0)
+        def _(rows=rows, upd=upd, db3_upd=db3_upd):
+            dw3_ref[rows, :] = upd
+            db3_ref[rows, :] = db3_upd
+
+        @pl.when(e > 0)
+        def _(rows=rows, upd=upd, db3_upd=db3_upd):
+            dw3_ref[rows, :] = dw3_ref[rows, :] + upd
+            db3_ref[rows, :] = db3_ref[rows, :] + db3_upd
+
+
+def _bwd_bxf_b_kernel(w3f_ref, bt_ref, xt_ref, gt_ref, dh_ref, dr_ref,
+                      v2_ref, *, P, O, Q, F, cb, precision, mxu_dtype):
+    c0 = pl.program_id(1)
+    _contract_over_q(bt_ref, xt_ref, v2_ref, Q)
+
+    def channel(c, carry):
+        for f in range(F):
+            dr_i = None
+            for p in range(P):
+                term = v2_ref[p * F + f, pl.ds(c, 1), :] \
+                    * gt_ref[p * O:(p + 1) * O, :]
+                dr_i = term if dr_i is None else dr_i + term
+            dr_ref[_dr_rows(c, f, F, O), :] = dr_i
+        return carry
+
+    jax.lax.fori_loop(0, cb, channel, 0)
+    acc = None
+    for rows in _row_chunks(dr_ref.shape[0]):
+        w3f = w3f_ref[0, :, rows]                    # [mid, rows]
+        part = jax.lax.dot_general(
+            w3f, dr_ref[rows, :].astype(mxu_dtype).astype(w3f.dtype),
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            precision=precision,
+            preferred_element_type=jnp.float32)      # [mid, E_b]
+        acc = part if acc is None else acc + part
+
+    @pl.when(c0 == 0)
+    def _():
+        dh_ref[:] = acc
+
+    @pl.when(c0 > 0)
+    def _():
+        dh_ref[:] = dh_ref[:] + acc
+
+
+def _vmem_bxf_bwd(be: int, cb: int, O: int, P: int, Q: int, F: int,
+                  mid: int) -> int:
+    """Bytes kernel A of the basis-fused backward holds at (be, cb), the
+    larger of the two, at float32: the blocks that move with the edge
+    axis twice (double buffering), the weight-shaped ones (w3t, b3t, dw3,
+    db3: one buffer each, they change with the outer axis only) and the
+    R, dR and V2 scratches once, and what one slice of _row_chunks leaves
+    beside them. O as padded, cb a multiple of 8; Q pads to the sublane
+    tile in B's slabs; the [S, 1] columns pad lanes to 128."""
+    S = cb * F * O
+    moving = (2 * mid * be                           # ht, h
+              + P * F * _round_up(Q, 8) * be         # bt
+              + 2 * Q * cb * be                      # xt, dxt
+              + P * O * be + P * F * cb * be)        # gt; dv2
+    held = 2 * S * mid + 2 * S * 128 + 2 * S * be + P * F * cb * be
+    return 4 * (2 * moving + held + 4 * min(S, 512) * max(be, mid))
+
+
+def _pick_blocks_bxf_bwd(E: int, C: int, O: int, P: int, Q: int, F: int,
+                         mid: int, dtype: str = 'float32',
+                         vmem_budget: int = 18 * 2 ** 20):
+    """(block_e, cb) of the basis-fused backward: the widest edge block
+    at which the model holds 8 channels, then as many as fit. Channels
+    ride sublanes, so cb is a multiple of the sublane tile of the dtype x
+    is stored in, and C counts up to one. The heuristic alone
+    decides, as for the plain backward; the pick is recorded under a kind
+    of its own, so a step's consult log counts the launches that took
+    this form.
+
+    The budget is in the model's bytes, which are not the compiler's:
+    with float32 operands and every output kept, the v5e compiler took
+    each (block_e, cb) tried at the cell's pairs (C 64; O 24 and 64) that
+    the model puts at 19.2 MiB or less, refused some from 19.5 on and all
+    from 21.5 (deviceless compile, PR 28); the picks at O = 24 are the
+    ones a whole step ran with on the chip."""
+    sub = 16 if dtype == 'bfloat16' else 8
+    C = _round_up(C, sub)
+
+    def _heuristic():
+        for block_e in (512, 256, 128):
+            if block_e > _round_up(E, 128):
+                continue
+            cb = C
+            while cb > sub and _vmem_bxf_bwd(block_e, cb, O, P, Q, F,
+                                             mid) > vmem_budget:
+                cb = _round_up(cb // 2, sub)
+            if _vmem_bxf_bwd(block_e, cb, O, P, Q, F, mid) <= vmem_budget:
+                return block_e, cb
+        return 128, sub
+
+    from . import tuning
+    blocks = _heuristic()
+    tuning.record_consult('bxf_bwd', (E, C, O, P, Q, F, mid), dtype,
+                          'heuristic', blocks)
+    return blocks
+
+
+def _fused_pairwise_conv_bwd_bxf_impl(h, w3, b3, basis, x, g, pqf,
+                                      interpret, precision):
+    # dtypes as _fused_pairwise_conv_bwd_impl: h decides the MXU operands
+    # of the three dots; the basis contraction, dV2, dx, dB3 and every
+    # accumulator are f32 on the VPU, bf16-stored basis / x (conv_bf16)
+    # upcast in VMEM
+    P, Q, F = pqf
+    E, mid = h.shape
+    C = x.shape[1]
+    O = w3.shape[-1]
+    assert basis.shape == (E, P * F * Q), (basis.shape, pqf)
+    assert w3.shape[1] == C * F, (w3.shape, C, F)
+    Op = _round_up(O, 8)
+
+    mxu_dtype = jnp.bfloat16 if h.dtype == jnp.bfloat16 else jnp.float32
+    if mxu_dtype == jnp.bfloat16:
+        precision = jax.lax.Precision.DEFAULT  # see fused_pairwise_conv
+    rdt = jnp.float32 if interpret else mxu_dtype
+    key_dtype = jnp.dtype(x.dtype).name
+    if interpret:
+        # bit-identical to the kernels' upcasts at use
+        basis, x = basis.astype(jnp.float32), x.astype(jnp.float32)
+
+    block_e, cb = _pick_blocks_bxf_bwd(E, C, Op, P, Q, F, mid,
+                                       dtype=key_dtype)
+    Ep, Cp = _round_up(E, block_e), _round_up(C, cb)
+    n_e, n_c = Ep // block_e, Cp // cb
+    S = cb * F * Op
+
+    with jax.named_scope('pairwise_layout'):
+        h, w3 = h.astype(rdt), w3.astype(rdt)
+        g = g.astype(jnp.float32)
+        # zero output channels up to the sublane tile, zero input
+        # channels up to the chunk, zero edges up to the block: each
+        # contributes nothing to any sum and its own rows are cut below
+        w3 = jnp.pad(w3, ((0, 0), (0, (Cp - C) * F), (0, Op - O)))
+        b3 = jnp.pad(b3.astype(jnp.float32),
+                     ((0, (Cp - C) * F), (0, Op - O)))
+        pad_e = (0, Ep - E)
+        ht = jnp.pad(h.T, ((0, 0), pad_e))                    # [mid, E]
+        h_p = jnp.pad(h, (pad_e, (0, 0)))
+        gt = jnp.pad(g, ((0, 0), (0, 0), (0, Op - O))).transpose(
+            1, 2, 0).reshape(P * Op, E)
+        gt = jnp.pad(gt, ((0, 0), pad_e))
+        bt = jnp.pad(basis.T.reshape(P * F, Q, E),
+                     ((0, 0), (0, 0), pad_e))
+        xt = jnp.pad(x.transpose(2, 1, 0),
+                     ((0, 0), (0, Cp - C), pad_e))            # [Q, Cp, Ep]
+        w3f = w3.reshape(mid, Cp * F * Op)
+        w3t = w3f.T                                           # [(c,f,o), mid]
+        b3t = b3.reshape(Cp * F * Op, 1)
+        w3f3 = w3f.reshape(mid, n_c, S).transpose(1, 0, 2)
+
+    def vmem(shape, index_map, **kw):
+        return pl.BlockSpec(shape, index_map, memory_space=pltpu.VMEM, **kw)
+
+    once = dict(pipeline_mode=pl.Buffered(1))
+    s_e = jax.ShapeDtypeStruct
+    rows = pltpu.VMEM((S, block_e), jnp.float32)
+    v2 = pltpu.VMEM((P * F, cb, block_e), jnp.float32)
+    statics = dict(P=P, O=Op, Q=Q, F=F, cb=cb, precision=precision,
+                   mxu_dtype=mxu_dtype)
+
+    dv2t, dw3t, db3t, dxt = pl.pallas_call(
+        functools.partial(_bwd_bxf_a_kernel, **statics),
+        grid=(n_c, n_e),
+        in_specs=[
+            vmem((mid, block_e), lambda c, e: (0, e)),
+            vmem((block_e, mid), lambda c, e: (e, 0)),
+            vmem((S, mid), lambda c, e: (c, 0), **once),
+            vmem((S, 1), lambda c, e: (c, 0), **once),
+            vmem((P * F, Q, block_e), lambda c, e: (0, 0, e)),
+            vmem((Q, cb, block_e), lambda c, e: (0, c, e)),
+            vmem((P * Op, block_e), lambda c, e: (0, e)),
+        ],
+        out_specs=[
+            vmem((P * F, cb, block_e), lambda c, e: (0, c, e)),
+            vmem((S, mid), lambda c, e: (c, 0), **once),
+            vmem((S, 1), lambda c, e: (c, 0), **once),
+            vmem((Q, cb, block_e), lambda c, e: (0, c, e)),
+        ],
+        out_shape=[
+            s_e((P * F, Cp, Ep), jnp.float32),
+            s_e((Cp * F * Op, mid), jnp.float32),
+            s_e((Cp * F * Op, 1), jnp.float32),
+            s_e((Q, Cp, Ep), jnp.float32),
+        ],
+        scratch_shapes=[rows, rows, v2],
+        interpret=interpret,
+        name='fused_pairwise_conv_bwd_a',
+    )(ht, h_p, w3t, b3t, bt, xt, gt)
+
+    dht = pl.pallas_call(
+        functools.partial(_bwd_bxf_b_kernel, **statics),
+        grid=(n_e, n_c),
+        in_specs=[
+            vmem((1, mid, S), lambda e, c: (c, 0, 0)),
+            vmem((P * F, Q, block_e), lambda e, c: (0, 0, e)),
+            vmem((Q, cb, block_e), lambda e, c: (0, c, e)),
+            vmem((P * Op, block_e), lambda e, c: (0, e)),
+        ],
+        out_specs=vmem((mid, block_e), lambda e, c: (0, e)),
+        out_shape=s_e((mid, Ep), jnp.float32),
+        scratch_shapes=[rows, v2],
+        interpret=interpret,
+        name='fused_pairwise_conv_bwd_b',
+    )(w3f3, bt, xt, gt)
+
+    with jax.named_scope('pairwise_layout'):
+        dh = dht.T[:E]
+        dw3 = dw3t.reshape(Cp * F, Op, mid).transpose(2, 0, 1)[
+            :, :C * F, :O]
+        db3 = db3t.reshape(Cp * F, Op)[:C * F, :O]
+        dx = dxt.transpose(2, 1, 0)[:E, :C]
+    with jax.named_scope('basis_contract'):
+        # dbasis[(p,f), q, e] = sum_c dV2[(p,f), c, e] x[q, c, e], on the
+        # kernels' own layouts: one multiply-reduce with E on lanes
+        dbt = jnp.sum(dv2t[:, None] * xt.astype(jnp.float32)[None],
+                      axis=2)
+        dbasis = dbt.reshape(P * F * Q, Ep).T[:E]
+    return dh, dw3, db3, dbasis, dx
+
+
+def _bwd_bxf_psums(outs, e, o):
+    dh, dw3, db3, dbasis, dx = outs
+    # as _bwd_psums; dbasis and dx are linear in dV2
+    if _axis_tuple(e):
+        dw3 = jax.lax.psum(dw3, _axis_tuple(e))
+        db3 = jax.lax.psum(db3, _axis_tuple(e))
+    if _axis_tuple(o):
+        dh, dbasis, dx = (jax.lax.psum(t, _axis_tuple(o))
+                          for t in (dh, dbasis, dx))
+    return dh, dw3, db3, dbasis, dx
+
+
+@functools.lru_cache(maxsize=None)
+def _bwd_bxf_partitioned(pqf, interpret, precision):
+    return _make_partitioned(
+        lambda h, w3, b3, basis, x, g: _fused_pairwise_conv_bwd_bxf_impl(
+            h, w3, b3, basis, x, g, pqf, interpret, precision),
+        rule='e m, m i o, i o, e z, e c q, e p o '
+             '-> e m, m i o, i o, e z, e c q',
+        need_repl=('m', 'i', 'z', 'c', 'q'),
+        arg_specs=lambda P_, e, o: (P_(e, None), P_(None, None, o),
+                                    P_(None, o), P_(e, None),
+                                    P_(e, None, None), P_(e, None, o)),
+        result_specs=lambda P_, e, o: (P_(e, None), P_(None, None, o),
+                                       P_(None, o), P_(e, None),
+                                       P_(e, None, None)),
+        psum_fn=_bwd_bxf_psums)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=('pqf', 'interpret', 'precision'))
+def fused_pairwise_conv_bwd_bxf(h: jnp.ndarray, w3: jnp.ndarray,
+                                basis_flat: jnp.ndarray, x: jnp.ndarray,
+                                g: jnp.ndarray, pqf: tuple,
+                                b3: jnp.ndarray = None,
+                                interpret: bool = False, precision=None):
+    """Backward of fused_pairwise_conv_bxf: (dh, dw3, db3, dbasis, dx),
+    all f32, in the shapes of h [E, mid], w3 [mid, C*F, O], b3 [C*F, O],
+    basis_flat [E, P*F*Q] and x [E, C, Q]; g [E, P, O].
+
+    Two launches under the plain backward's names (the roles are the
+    same: A gives dW3, dB3, dV2 and here dx too, B gives dH); each builds
+    its V2 block in a VMEM scratch. MXU operands as
+    fused_pairwise_conv_bwd; everything else f32. dbasis is reduced from
+    A's dV2 in XLA and costs nothing where its result is unused.
+    Partitions like the forward, partial sums reduced in the body."""
+    if b3 is None:
+        b3 = jnp.zeros(w3.shape[1:], jnp.float32)
+    return _bwd_bxf_partitioned(tuple(pqf), interpret, precision)(
+        h, w3, b3, basis_flat, x, g)

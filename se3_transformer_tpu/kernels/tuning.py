@@ -52,7 +52,9 @@ CACHE_VERSION = 1
 
 # kernel kinds with tunable picks. 'plain'/'bx'/'bxf' are the pairwise
 # forward kernels (the backward ALWAYS runs its own bwd-model heuristic
-# — overrides and table entries never reach it, see _pick_blocks);
+# — overrides and table entries never reach it, see _pick_blocks; the
+# basis-fused backward logs its picks under 'bxf_bwd', a kind of the
+# consult log only, so a step's log counts the launches of that form);
 # 'attention' is the fused attention forward block_n and
 # 'attention_bwd' the fused attention BACKWARD block_n (its working set
 # is ~2x the forward's, so it keys its own measured entries —
@@ -290,7 +292,8 @@ def clear_kernel_caches() -> int:
         pallas_pairwise as pp
     for mod, names in (
             (pp, ('fused_pairwise_conv', 'fused_pairwise_conv_bx',
-                  'fused_pairwise_conv_bxf', 'fused_pairwise_conv_bwd')),
+                  'fused_pairwise_conv_bxf', 'fused_pairwise_conv_bwd',
+                  'fused_pairwise_conv_bwd_bxf')),
             (pa, ('_fused_attention_fwd_impl',
                   '_fused_attention_bwd_impl')),
             (pf, ('_flash_fwd_impl',))):
@@ -301,7 +304,8 @@ def clear_kernel_caches() -> int:
                 cleared += 1
     for mod, names in (
             (pp, ('_fwd_partitioned', '_bx_partitioned',
-                  '_bxf_partitioned', '_bwd_partitioned')),
+                  '_bxf_partitioned', '_bwd_partitioned',
+                  '_bwd_bxf_partitioned')),
             (pa, ('_att_partitioned',))):
         for nm in names:
             f = getattr(mod, nm, None)

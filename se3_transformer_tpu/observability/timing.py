@@ -50,8 +50,10 @@ MODEL_SCOPES = (
     #                       launches carry the degree pair here, not in
     #                       their names
     'basis_contract',     # ops/conv.py: V2 = basis . x and its cotangents
-    #                       (the basis-fused kernels' backward materializes
-    #                       V2 once)
+    #                       where XLA computes them: every path but the
+    #                       flat-basis fused pair, which keeps only the
+    #                       reduction to dbasis here (and that only under
+    #                       differentiable_coors)
     'pairwise_layout',    # kernels/pallas_pairwise.py: the pads, transposes
     #                       and reshapes on either side of each launch
     'norm',               # ops/core.py NormSE3

@@ -357,29 +357,17 @@ def _pc_bxf_fwd(h, w3, b3, basis_flat, x, pqf, interpret=False,
 
 
 def _pc_bxf_bwd(pqf, interpret, precision, res, g):
-    # flat twin of _pc_bx_bwd: the (p, f, q)-ordered flat basis reshapes
-    # straight to [E, P, F, Q] — no transpose — and every einsum reads
-    # that form, so the ~60x tile-padded [E, P, Q, F] buffer never
-    # materializes in the backward either.
-    from ..kernels.pallas_pairwise import fused_pairwise_conv_bwd
+    # the call site holds a flat basis and x, not a V2, so it takes the
+    # basis-fused backward: each launch rebuilds its V2 block in VMEM, as
+    # the forward builds its rows, and dx comes out of kernel A. No V2 or
+    # dV2 passes through XLA; dbasis (coordinate gradients under
+    # differentiable_coors) is one reduction over A's dV2 that XLA drops
+    # when its cotangent is.
+    from ..kernels.pallas_pairwise import fused_pairwise_conv_bwd_bxf
     h, w3, b3, basis_flat, x = res
-    P, Q, F = pqf
-    E = basis_flat.shape[0]
-    C = x.shape[1]
-    # conv_bf16 residuals arrive bf16 (see _pc_bx_bwd)
-    with named_scope('basis_contract'):
-        b4 = basis_flat.astype(jnp.float32).reshape(E, P, F, Q)
-        x32 = x.astype(jnp.float32)
-        v2 = jnp.einsum('epfq,ecq->epcf', b4, x32,
-                        precision=precision).reshape(E, P, C * F)
-    dh, dw3, dv2, db3 = fused_pairwise_conv_bwd(h, w3, v2, g, b3=b3,
-                                                interpret=interpret,
-                                                precision=precision)
-    with named_scope('basis_contract'):
-        dv2 = dv2.reshape(E, P, C, F)
-        dx = jnp.einsum('epfq,epcf->ecq', b4, dv2, precision=precision)
-        dbasis = jnp.einsum('ecq,epcf->epfq', x32, dv2,
-                            precision=precision).reshape(E, P * F * Q)
+    dh, dw3, db3, dbasis, dx = fused_pairwise_conv_bwd_bxf(
+        h, w3, basis_flat, x, g, pqf, b3=b3, interpret=interpret,
+        precision=precision)
     return (dh.astype(h.dtype), dw3.astype(w3.dtype), db3.astype(b3.dtype),
             dbasis.astype(basis_flat.dtype), dx.astype(x.dtype))
 
@@ -422,9 +410,10 @@ class PairwiseConvSE3(nn.Module):
     # (lax.map + remat): bounds peak memory to O(E/edge_chunks * c_in *
     # c_out * F) for huge configs (e.g. dim-512 flagship). None = off.
     edge_chunks: Optional[int] = None
-    # contract the angular basis inside the Pallas kernel so the V2
-    # intermediate never touches HBM (forward only; the backward
-    # materializes it once). Requires the Pallas path; ignored otherwise.
+    # contract the angular basis inside the Pallas kernels so the V2
+    # intermediate never touches HBM: forward, and with a flat basis
+    # backward too (a structured basis' backward materializes it once).
+    # Requires the Pallas path; ignored otherwise.
     fuse_basis: bool = False
     # run the radial trunk + radial matmul in bf16 (MXU-native): its
     # inputs are rotation-invariant, so this preserves equivariance to

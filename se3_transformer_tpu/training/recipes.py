@@ -68,7 +68,9 @@ def flagship_fast(dim: int = 64, num_neighbors: int = 32,
 
     Unlike the conservative flagship this recipe runs UNCHUNKED
     (edge_chunks=None): with fuse_basis the V2 edge tensor never touches
-    HBM in the forward, and after the MXU one-hot gather fix the whole
+    HBM, forward or backward (the kernels rebuild its rows in VMEM from
+    the flat basis; dx leaves backward A), and after the MXU one-hot
+    gather fix the whole
     dim=64/n=1024 reversible training step fits one 16 GB v5e outright.
     Measured on chip (round 4; record deleted with PR 21): edge_chunks=8 ->
     309.3, =2 -> 322.3, unchunked -> 394.28 nodes*steps/s — the chunk

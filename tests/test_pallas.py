@@ -555,6 +555,150 @@ def test_convse3_fuse_basis_group_path():
         assert jnp.abs(a - b2).max() / s < 1e-4
 
 
+# ------------------------------------------------------------------ #
+# basis-fused backward (V2 and dx in VMEM only)
+# ------------------------------------------------------------------ #
+
+@RDT
+@pytest.mark.parametrize('d_in,d_out', [(i, o) for i in range(4)
+                                        for o in range(4)])
+def test_fused_bwd_bxf_kernel_matches_einsum(d_in, d_out, rdt, monkeypatch):
+    """Both launches of the basis-fused backward against the einsum VJP,
+    at every (P, Q, F) of degrees 0..3: three e-blocks of 128 for 300
+    edges and two c-chunks of 8 for 13 channels (the pick is pinned, so
+    both accumulations revisit and both axes are padded), O = 6 padded to
+    the sublane tile. dx and dbasis are float32 reductions of dV2 and
+    hold the float32 tolerance under bfloat16 h as well."""
+    import functools
+    from se3_transformer_tpu.kernels import pallas_pairwise as pp
+    P, Q, F = 2 * d_out + 1, 2 * d_in + 1, 2 * min(d_in, d_out) + 1
+    E, mid, C, O = 300, 16, 13, 6
+    h, w3, b3, _, g = _bwd_case(5 + 4 * d_in + d_out, E, mid, C * F, O, P,
+                                rdt)
+    rng = np.random.RandomState(7)
+    basis = jnp.asarray(rng.normal(size=(E, P * F * Q)), jnp.float32)
+    x = jnp.asarray(rng.normal(size=(E, C, Q)), jnp.float32)
+
+    monkeypatch.setattr(pp, '_pick_blocks_bxf_bwd',
+                        lambda *a, **k: (128, 8))
+    got = jax.jit(functools.partial(
+        pp._fused_pairwise_conv_bwd_bxf_impl, pqf=(P, Q, F),
+        interpret=True, precision=None))(h, w3, b3, basis, x, g)
+    assert all(t.dtype == jnp.float32 for t in got)
+
+    b4 = basis.reshape(E, P, F, Q)
+
+    def exact(round_dr):
+        with jax.default_matmul_precision('highest'):
+            v2 = jnp.einsum('epfq,ecq->epcf', b4, x).reshape(E, P, C * F)
+            dh, dw3, dv2, db3 = _bwd_einsum(h, w3, b3, v2, g, round_dr)
+            dv2 = dv2.reshape(E, P, C, F)
+            return (dh, dw3, db3,
+                    jnp.einsum('ecq,epcf->epfq', x, dv2).reshape(E, -1),
+                    jnp.einsum('epfq,epcf->ecq', b4, dv2))
+
+    names = ('dh', 'dw3', 'db3', 'dbasis', 'dx')
+    for n, a, q, b in zip(names, got, exact(rdt != 'f32'), exact(False)):
+        assert a.shape == b.shape, (n, a.shape, b.shape)
+        if rdt == 'f32' or n in ('db3', 'dbasis', 'dx'):
+            assert _rel(a, b) < 2e-5, (n, _rel(a, b))
+        else:  # see _assert_bwd_matches
+            assert _rel(a, q) < 5e-4, (n, _rel(a, q))
+            assert _rel(a, b) < 1e-2, (n, _rel(a, b))
+
+
+def test_bxf_backward_pick_fits_and_is_recorded():
+    """The block pick of the basis-fused backward: within its own VMEM
+    model at the cell's sixteen pairs (C 64, O 24) and at conv_in /
+    conv_out's O 64; whole tiles of 8 channels; written to the consult
+    log under a kind of its own, once per launch pair."""
+    from se3_transformer_tpu.kernels import pallas_pairwise as pp, tuning
+    snap = tuning.snapshot()
+    for O in (24, 64):
+        for d_in in range(4):
+            for d_out in range(4):
+                pqf = (2 * d_out + 1, 2 * d_in + 1,
+                       2 * min(d_in, d_out) + 1)
+                be, cb = pp._pick_blocks_bxf_bwd(32768, 64, O, *pqf, 128)
+                assert be in (128, 256, 512) and 64 % cb == 0 \
+                    and cb % 8 == 0
+                assert pp._vmem_bxf_bwd(be, cb, O, *pqf, 128) \
+                    <= 18 * 2 ** 20
+    new = tuning.consults_since(snap)
+    assert {c['kernel'] for c in new} == {'bxf_bwd'}
+    assert sum(c['count'] for c in new) == 32
+    assert pp._pick_blocks_bxf_bwd(32768, 64, 24, 7, 7, 7, 128) == (512, 8)
+    assert pp._pick_blocks_bxf_bwd(32768, 64, 24, 7, 1, 1, 128) == (512, 64)
+    assert pp._pick_blocks_bxf_bwd(32768, 64, 64, 7, 7, 7, 128) == (128, 8)
+    # bfloat16-stored features tile 16 channels to a sublane block; a
+    # budget nothing fits gives the smallest legal blocks, not a loop
+    assert pp._pick_blocks_bxf_bwd(300, 16, 8, 7, 7, 7, 16, 'bfloat16',
+                                   vmem_budget=1) == (128, 16)
+
+
+@pytest.mark.parametrize('differentiable_coors', [False, True])
+def test_convse3_bxf_gradients_match_xla(differentiable_coors):
+    """ConvSE3 on a flat basis with fuse_basis (one bxf launch per pair,
+    the basis-fused backward behind each) against the XLA group path on
+    the same parameter tree: values, parameter and feature gradients, and
+    with a differentiable basis the gradient of the coordinates too."""
+    from se3_transformer_tpu.ops import ConvSE3, Fiber
+    from se3_transformer_tpu.utils import batched_index_select
+
+    rng = np.random.RandomState(17)
+    n, k, dim, degrees = 10, 4, 5, 3
+    fiber = Fiber.create(degrees, dim)
+    feats = {str(d): jnp.asarray(rng.normal(size=(1, n, dim, 2 * d + 1)),
+                                 jnp.float32) for d in range(degrees)}
+    coors = jnp.asarray(rng.normal(size=(1, n, 3)) * 2, jnp.float32)
+    # no node is its own neighbour: the zero vector has no direction
+    idx = jnp.asarray((np.arange(n)[:, None] + 1 + rng.randint(
+        0, n - 1, (n, k))) % n, jnp.int32)[None]
+    mask = jnp.ones((1, n, k), bool)
+
+    def call(mod, layout, params, feats, coors):
+        rel = coors[:, :, None, :] - batched_index_select(coors, idx, axis=1)
+        basis = get_basis(rel, degrees - 1,
+                          differentiable=differentiable_coors, layout=layout)
+        args = (feats, (idx, mask, None), jnp.linalg.norm(rel, axis=-1),
+                basis)
+        if params is None:
+            return mod.init(jax.random.PRNGKey(0), *args)
+        out = mod.apply(params, *args)
+        return sum((out[d] ** 2).sum() for d in out)
+
+    kw = dict(shared_radial_hidden=True, pool=False, self_interaction=False)
+    group = ConvSE3(fiber, fiber, pallas=False, **kw)
+    bxf = ConvSE3(fiber, fiber, pallas=False, pallas_interpret=True,
+                  fuse_basis=True, **kw)
+    params = call(group, 'pqf', None, feats, coors)
+
+    from se3_transformer_tpu.kernels import tuning
+    argnums = (0, 1, 2) if differentiable_coors else (0, 1)
+    v1, g1 = jax.value_and_grad(
+        lambda *a: call(group, 'pqf', *a), argnums)(params, feats, coors)
+    tuning.clear_kernel_caches()  # picks are logged when a launch traces
+    snap = tuning.snapshot()
+    v2, g2 = jax.value_and_grad(
+        lambda *a: call(bxf, 'pfq_flat', *a), argnums)(params, feats, coors)
+    # the counter: every pair's forward is the bxf launch, and every one
+    # of them took the basis-fused backward
+    picks = {kind: sum(c['count'] for c in tuning.consults_since(snap)
+                       if c['kernel'] == kind)
+             for kind in ('bxf', 'bxf_bwd', 'plain')}
+    assert picks == {'bxf': degrees ** 2, 'bxf_bwd': degrees ** 2,
+                     'plain': 0}
+    assert abs(float(v1) - float(v2)) < 1e-4 * abs(float(v1))
+    leaves1, tree1 = jax.tree_util.tree_flatten(g1)
+    leaves2, tree2 = jax.tree_util.tree_flatten(g2)
+    assert tree1 == tree2
+    for a, b2 in zip(leaves1, leaves2):
+        s = float(jnp.abs(a).max()) + 1e-9
+        assert float(jnp.abs(a - b2).max()) / s < 1e-4
+    if differentiable_coors:
+        assert float(jnp.abs(g2[2]).max()) > 0
+
+
 def test_flat_basis_layout_equivalence():
     """get_basis(layout='pfq_flat') holds exactly the structured values,
     (p, f, q)-ordered; unflatten_basis round-trips to the reference
@@ -608,14 +752,14 @@ def test_bxf_kernel_matches_bx():
     loss_bxf = lambda h, bb, b, x: (_pairwise_contract_pallas_bxf(  # noqa: E731,E501
         h, w3, bb, b, x, (P, Q, F), True, None) ** 2).sum()
     g_bx = jax.grad(loss_bx, argnums=(0, 1, 2, 3))(h, b3, basis, x)
-    g_bxf = jax.grad(loss_bxf, argnums=(0, 1, 2, 3))(h, b3, flat, x)
-    assert np.abs(np.asarray(g_bx[0]) - np.asarray(g_bxf[0])).max() < 1e-4
-    assert np.abs(np.asarray(g_bx[1]) - np.asarray(g_bxf[1])).max() < 1e-4
-    g_basis_back = jnp.swapaxes(
+    g_bxf = list(jax.grad(loss_bxf, argnums=(0, 1, 2, 3))(h, b3, flat, x))
+    g_bxf[2] = jnp.swapaxes(
         g_bxf[2].reshape(E, P, F, Q), -1, -2)  # (p,f,q) -> (p,q,f)
-    assert np.abs(np.asarray(g_bx[2]) - np.asarray(g_basis_back)).max() \
-        < 1e-4
-    assert np.abs(np.asarray(g_bx[3]) - np.asarray(g_bxf[3])).max() < 1e-4
+    # the two backwards sum in different orders (bx: einsums around the
+    # plain kernels; bxf: the basis-fused kernels), so to float32
+    # rounding of gradients that reach 5e2, not to an absolute 1e-4
+    for a, b in zip(g_bx, g_bxf):
+        assert _rel(b, a) < 1e-5
 
 
 def test_model_flat_basis_matches_structured():

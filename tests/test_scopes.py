@@ -151,9 +151,13 @@ def test_step_leaves_and_phases_are_all_present(labelled):
              for _, _, p in labelled}
     leaves = {leaf for leaf, _ in cells}
     for leaf in ('loss', 'optimizer', 'pairwise_layout', 'pair', 'radial',
-                 'gather', 'norm', 'ff', 'basis', 'basis_contract',
+                 'gather', 'norm', 'ff', 'basis',
                  'attn_core', 'attn_qkv', 'neighbors', 'readout'):
         assert leaf in leaves, leaf
+    # every convolution of this step is a basis-fused pair, forward and
+    # backward, and no gradient reaches the basis: nothing is left for the
+    # leaf that holds the basis contraction outside the kernels
+    assert 'basis_contract' not in leaves
     # the optimizer runs once, after the gradient; the layout traffic of
     # the kernels' wrappers exists forward and backward; the reversible
     # trunk replays its cheap glue (never a kernel's wrapper: the policy

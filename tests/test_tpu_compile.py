@@ -35,8 +35,8 @@ from se3_transformer_tpu.kernels.pallas_attention import (  # noqa: E402
     fused_attention,
 )
 from se3_transformer_tpu.kernels.pallas_pairwise import (  # noqa: E402
-    fused_pairwise_conv, fused_pairwise_conv_bwd, fused_pairwise_conv_bx,
-    fused_pairwise_conv_bxf,
+    fused_pairwise_conv, fused_pairwise_conv_bwd, fused_pairwise_conv_bwd_bxf,
+    fused_pairwise_conv_bx, fused_pairwise_conv_bxf,
 )
 
 # the flagship shape tuples (tests/test_kernel_tuning.py pins the block
@@ -134,6 +134,30 @@ def test_pairwise_bxf_compiles(v5e, rdt):
     assert calls > 0
 
 
+@RADIAL
+@pytest.mark.parametrize('d_in,d_out,o', [
+    # d4_onehead_train's extremes, C = 64: keys / values (one head of 24)
+    # at the widest pair, where R and dR are 1344 rows of 512 edges each,
+    # and conv_out's 64 channels from degree 3, where Q is 7 and P*F 9
+    (3, 3, 24),
+    (3, 1, 64),
+    # degree 0 in: Q = 1, and all 64 channels in one program
+    (0, 3, 24),
+    # the flagship recipe's widest pair (8 heads of 8): R and dR are 3584
+    # rows, and only the smallest blocks fit
+    (3, 3, 64),
+])
+def test_pairwise_bxf_backward_compiles(v5e, rdt, d_in, d_out, o):
+    p, q, f = 2 * d_out + 1, 2 * d_in + 1, 2 * min(d_in, d_out) + 1
+    calls = compile_for(
+        v5e,
+        lambda h, w3, basis, x, g, b3: fused_pairwise_conv_bwd_bxf(
+            h, w3, basis, x, g, (p, q, f), b3),
+        ((E, MID), rdt), ((MID, C * f, o), rdt), ((E, p * f * q), f32),
+        ((E, C, q), f32), ((E, p, o), f32), ((C * f, o), f32))
+    assert calls == 2  # A (dW3, dB3, dV2, dx) and B (dH), V2 built in both
+
+
 def test_launches_are_named_by_role(v5e):
     """`name=` on pl.pallas_call is the innermost component of the op's
     name stack, and the chip's compiler takes that as the custom call's
@@ -146,7 +170,12 @@ def test_launches_are_named_by_role(v5e):
     def fn(h, w3, basis, x, v2, g, b3):
         with jax.named_scope('pair_1_1'):
             out = fused_pairwise_conv_bxf(h, w3, basis, x, (p, q, f), b3)
-            return out, fused_pairwise_conv_bwd(h, w3, v2, g, b3)
+            # the basis-fused backward as a step without coordinate
+            # gradients leaves it: the basis' cotangent dropped
+            dh, dw3, db3, _, dx = fused_pairwise_conv_bwd_bxf(
+                h, w3, basis, x, g, (p, q, f), b3)
+            return (out, fused_pairwise_conv_bwd(h, w3, v2, g, b3),
+                    dh, dw3, db3, dx)
 
     args = [jax.ShapeDtypeStruct(s, f32, sharding=v5e) for s in (
         (e, MID), (MID, c * f, o), (e, p * f * q), (e, c, q),
@@ -155,8 +184,9 @@ def test_launches_are_named_by_role(v5e):
     calls = re.findall(r'%([\w.]+) = [^\n]*custom_call_target='
                        r'"tpu_custom_call"[^\n]*op_name="([^"]*)"', text)
     names = sorted(re.sub(r'\.\d+$', '', n) for n, _ in calls)
-    assert names == ['fused_pairwise_conv_bwd_a', 'fused_pairwise_conv_bwd_b',
-                     'fused_pairwise_conv_bxf']
+    # the plain and the basis-fused backward share the two role names
+    assert names == ['fused_pairwise_conv_bwd_a'] * 2 \
+        + ['fused_pairwise_conv_bwd_b'] * 2 + ['fused_pairwise_conv_bxf']
     for name, op_name in calls:
         comps = op_name.split('/')
         assert comps[-1] == 'pallas_call' and name.startswith(comps[-2])
@@ -164,6 +194,9 @@ def test_launches_are_named_by_role(v5e):
     # the wrappers' relayouts are under their own leaf
     assert 'pairwise_layout/transpose' in text \
         or 'pairwise_layout/reshape' in text
+    # V2, dV2 and dx never pass through XLA: with no gradient into the
+    # basis nothing at all is left under the contraction's own leaf
+    assert 'basis_contract' not in text
 
 
 ATT_SHAPES = (((ATT_HEADS, ATT_N, ATT_D), f32),

@@ -144,15 +144,6 @@ def main(backend: str, fast=None, fallback_reason=None, pipelined=False):
         if remat_env:
             overrides['remat_policy'] = (
                 None if remat_env.lower() == 'none' else remat_env)
-        # SE3_TPU_BENCH_CB16=1 turns on conv_bf16 (bf16 STORAGE of the
-        # equivariant kernel operands — ops/conv.py): the round-5 A/B
-        # knob for the bandwidth-bound contraction. Labelled cb16 so the
-        # record never masquerades as the recipe default; the equivariance
-        # cost (~1e-3 expected) is the tradeoff being measured.
-        cb16 = os.environ.get('SE3_TPU_BENCH_CB16', '').lower() \
-            in ('1', 'true', 'yes', 'on')
-        if cb16:
-            overrides['conv_bf16'] = True
         # vector head for the denoise objective: the recipe default
         # output_degrees=1 is scalar-out (return_type coerced to 0)
         module = recipes.RECIPES[recipe_name](dim=dim, **overrides)
@@ -160,8 +151,7 @@ def main(backend: str, fast=None, fallback_reason=None, pipelined=False):
         label = f'{recipe_name},dim={dim},depth={module.depth}' + (
             f',b={batch}' if batch != 1 else '') + (
             f',ec={int(chunk_env)}' if chunk_env != '' else '') + (
-            f',rp={remat_env}' if remat_env else '') + (
-            ',cb16' if cb16 else '')
+            f',rp={remat_env}' if remat_env else '')
     else:
         # the CPU toy (explicit callers only): tiny config, honestly
         # labelled backend=cpu. FROZEN DEFINITION (VERDICT r3 weak #5):
@@ -385,13 +375,9 @@ def main(backend: str, fast=None, fallback_reason=None, pipelined=False):
                 and eq_env not in ('0', 'false', 'no', 'off')):
         eq_err = equivariance_l2(module, params, seqs, coords, masks)
     elif on_chip and eq_env not in ('0', 'false', 'no', 'off'):
-        # the twin must run the SAME precision knobs as the recorded
-        # program: a cb16 record with an f32 twin would hide the
-        # ~1e-3 equivariance cost the A/B arm exists to measure
         twin = recipes.RECIPES[recipe_name](
             dim=16, depth=2, num_neighbors=8, output_degrees=2,
-            reduce_dim_out=True,
-            **({'conv_bf16': True} if cb16 else {}))
+            reduce_dim_out=True)
         t_n = 128
         t_feats = jnp.asarray(rng.normal(size=(1, t_n, 16)), jnp.float32)
         t_coors = jnp.asarray(rng.normal(size=(1, t_n, 3)) * 2,
@@ -402,8 +388,7 @@ def main(backend: str, fast=None, fallback_reason=None, pipelined=False):
             return_type=1)['params']
         eq_err = equivariance_l2(twin, t_params, t_feats, t_coors, t_mask)
         eq_scope = f'reduced_twin({recipe_name},dim=16,depth=2,' \
-                   f'deg={twin.num_degrees},n={t_n},k=8' \
-                   f'{",cb16" if cb16 else ""})'
+                   f'deg={twin.num_degrees},n={t_n},k=8)'
 
     actual = jax.default_backend()
     # the chip branch verified at entry that the device is a TPU;

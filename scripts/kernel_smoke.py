@@ -86,10 +86,10 @@ def main():
                                   precision='float32')
     ok &= check('pairwise fwd bf16-radial @ f32 ctx', out, ref, tol=3e-2)
 
-    # --- basis-fused pairwise kernel (forward; bwd shares the kernels
-    # gated above via the reconstruct-VJP) ---
+    # --- basis-fused pairwise kernel, forward: the flat [E, P*F*Q]
+    # basis the fast path feeds (Mosaic must lower the 2D-transposed bt) ---
     from se3_transformer_tpu.kernels.pallas_pairwise import (
-        fused_pairwise_conv_bx,
+        fused_pairwise_conv_bxf,
     )
     for (E, mid, C, Q, F, O, P) in [(300, 128, 8, 3, 3, 8, 5),
                                     (64, 128, 9, 5, 3, 4, 5),
@@ -103,49 +103,10 @@ def main():
             v2 = jnp.einsum('epqf,ecq->epcf', bas, x).reshape(E, P, C * F)
             ref = jnp.einsum('epk,eko->epo', v2,
                              jnp.einsum('em,mko->eko', h, w3) + b3)
-        out = fused_pairwise_conv_bx(h, w3, bas, x, b3=b3,
-                                     precision='highest')
-        ok &= check(f'pairwise bx fwd E={E} C={C} Q={Q} F={F}', out, ref)
-
-        # flat-basis twin (bxf): the layout the flagship fast path now
-        # feeds — same math through a [E, P*F*Q] operand (Mosaic must
-        # lower the 2D-transposed bt identically)
-        from se3_transformer_tpu.kernels.pallas_pairwise import (
-            fused_pairwise_conv_bxf,
-        )
         flat = jnp.swapaxes(bas, -1, -2).reshape(E, P * F * Q)
         outf = fused_pairwise_conv_bxf(h, w3, flat, x, (P, Q, F), b3=b3,
                                        precision='highest')
         ok &= check(f'pairwise bxf fwd E={E} C={C} Q={Q} F={F}', outf, ref)
-
-    # --- conv_bf16 operands (bf16 STORAGE of V2 / basis / x; kernel
-    # upcasts rows after the VMEM load): Mosaic must lower the bf16
-    # sublane slices + converts, and the result must equal the f32
-    # kernel run on quantize-then-upcast operands (same math) ---
-    E, mid, IF, O, P = 300, 128, 24, 8, 5
-    h = jnp.asarray(rng.normal(size=(E, mid)), jnp.float32)
-    w3 = jnp.asarray(rng.normal(size=(mid, IF, O)), jnp.float32)
-    b3 = jnp.asarray(rng.normal(size=(IF, O)), jnp.float32)
-    v2 = jnp.asarray(rng.normal(size=(E, P, IF)), jnp.float32)
-    v2q = v2.astype(jnp.bfloat16)
-    out = fused_pairwise_conv(h, w3, v2q, b3=b3, precision='highest')
-    ref = fused_pairwise_conv(h, w3, v2q.astype(jnp.float32), b3=b3,
-                              precision='highest')
-    ok &= check('pairwise fwd conv_bf16(v2) vs quantized oracle', out, ref,
-                tol=1e-6)
-    C, Q, F = 8, 7, 7
-    w3x = jnp.asarray(rng.normal(size=(mid, C * F, O)), jnp.float32)
-    b3x = jnp.asarray(rng.normal(size=(C * F, O)), jnp.float32)
-    basf = jnp.asarray(rng.normal(size=(E, P * F * Q)), jnp.float32)
-    x = jnp.asarray(rng.normal(size=(E, C, Q)), jnp.float32)
-    bq, xq = basf.astype(jnp.bfloat16), x.astype(jnp.bfloat16)
-    out = fused_pairwise_conv_bxf(h, w3x, bq, xq, (P, Q, F), b3=b3x,
-                                  precision='highest')
-    ref = fused_pairwise_conv_bxf(h, w3x, bq.astype(jnp.float32),
-                                  xq.astype(jnp.float32), (P, Q, F),
-                                  b3=b3x, precision='highest')
-    ok &= check('pairwise bxf fwd conv_bf16(basis,x) vs quantized oracle',
-                out, ref, tol=1e-6)
 
     # --- MXU one-hot gather vs jnp.take at a flagship-shaped gather:
     # the auto heuristic only fires on TPU, so CPU tests never see the

@@ -24,14 +24,13 @@ import numpy as np
 from se3_transformer_tpu.models.se3_transformer import SE3TransformerModule
 
 
-def check_equivariance(precision: str, radial_bf16: bool = False,
-                       conv_bf16: bool = False):
+def check_equivariance(precision: str, radial_bf16: bool = False):
     from se3_transformer_tpu.utils.validation import equivariance_l2
 
     module = SE3TransformerModule(
         dim=16, depth=1, attend_self=True, num_neighbors=8, num_degrees=3,
         output_degrees=2, fourier_encode_dist=True,
-        radial_bf16=radial_bf16, conv_bf16=conv_bf16)
+        radial_bf16=radial_bf16)
     rng = np.random.RandomState(0)
     feats = jnp.asarray(rng.normal(size=(1, 32, 16)), jnp.float32)
     coors = jnp.asarray(rng.normal(size=(1, 32, 3)), jnp.float32)
@@ -77,7 +76,7 @@ def check_equivariance_sparse_only(precision: str = 'float32'):
 
 
 def bench_conv(pallas: bool, n=512, k=24, dim=32, degrees=3, iters=10,
-               fuse_basis=False, radial_bf16=False, conv_bf16=False):
+               fuse_basis=False, radial_bf16=False):
     from se3_transformer_tpu.basis import get_basis
     from se3_transformer_tpu.ops import ConvSE3, Fiber
     from se3_transformer_tpu.utils import batched_index_select
@@ -91,12 +90,12 @@ def bench_conv(pallas: bool, n=512, k=24, dim=32, degrees=3, iters=10,
     mask = jnp.ones((1, n, k), bool)
 
     conv = ConvSE3(fiber, fiber, pallas=pallas, fuse_basis=fuse_basis,
-                   radial_bf16=radial_bf16, conv_bf16=conv_bf16)
+                   radial_bf16=radial_bf16)
 
     # jit the input prep: eager gathers/basis would dispatch thousands of
     # tiny ops one by one (minutes). fuse_basis
     # measures the FLAT basis layout — what the model actually feeds the
-    # bx kernel since round 4 (docs/DESIGN.md §2a)
+    # bxf kernel since round 4 (docs/DESIGN.md §2a)
     layout = 'pfq_flat' if fuse_basis else 'pqf'
 
     @jax.jit
@@ -225,14 +224,6 @@ def main():
     print(f'equivariance @ f32 + radial_bf16: abs={err_rb:.2e} '
           f'rel={rel_rb:.2e} [{"PASS" if err_rb < 1e-4 else "FAIL"}]')
 
-    # conv_bf16 quantizes EQUIVARIANT operands: expected ~1e-3-class
-    # error (the documented tradeoff, ops/conv.py) — info + sanity bound,
-    # not the 1e-4 gate
-    err_cb, rel_cb = check_equivariance('float32', conv_bf16=True)
-    print(f'equivariance @ f32 + conv_bf16: abs={err_cb:.2e} '
-          f'rel={rel_cb:.2e} '
-          f'[{"PASS" if err_cb < 5e-2 else "FAIL"} (5e-2 sanity bound)]')
-
     err_sp = check_equivariance_sparse_only()
     print(f'equivariance sparse-only @ f32: abs={err_sp:.2e} '
           f'[{"PASS" if err_sp < 1e-4 else "FAIL"}]')
@@ -258,22 +249,12 @@ def main():
 
     t_rb, out_rb = bench_conv(pallas=True, fuse_basis=True,
                               radial_bf16=True)
-    # one normalization scale for BOTH bf16 rel-diff gates below — they
-    # must stay comparable
     scale = max(float(jnp.abs(out_xla[d]).max()) for d in out_xla)
     diff = max(float(jnp.abs(out_xla[d] - out_rb[d]).max())
                for d in out_xla) / scale
     print(f'ConvSE3 fwd fuse_basis+radial_bf16: {t_rb*1e3:.1f} ms '
           f'({t_xla/t_rb:.2f}x vs xla), rel diff={diff:.2e} '
           f'[{"PASS" if diff < 3e-2 else "FAIL"}]')
-
-    t_cb, out_cb = bench_conv(pallas=True, fuse_basis=True,
-                              radial_bf16=True, conv_bf16=True)
-    diff = max(float(jnp.abs(out_xla[d] - out_cb[d]).max())
-               for d in out_xla) / scale
-    print(f'ConvSE3 fwd fuse_basis+radial_bf16+conv_bf16: '
-          f'{t_cb*1e3:.1f} ms ({t_xla/t_cb:.2f}x vs xla), '
-          f'rel diff={diff:.2e} [{"PASS" if diff < 3e-2 else "FAIL"}]')
 
     # attention numerics + wall-clock at every flagship per-degree
     # shape. Layout DECIDED round 4 (retirement table in

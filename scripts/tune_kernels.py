@@ -13,8 +13,8 @@ This tuner therefore never times a kernel in isolation:
      (kind, shape, dtype) they resolved — those are the tuning targets;
   2. per target, enumerate only tile-legal, VMEM-model-admissible
      candidates (kernels.tuning.admissible_candidates — the bwd-aware
-     admission that excludes up front the bx/bxf (512, 16) / bx
-     (256, 16) Mosaic VMEM compile failures the old sweep paid for);
+     admission that excludes up front the bxf (512, 16) / (256, 16)
+     Mosaic VMEM compile failures the old sweep paid for);
   3. measure each candidate through the full train step in ALTERNATING
      A/B pairs against the incumbent (host-side dispatch noise is
      one-sided and time-correlated; alternation cancels the drift), via
@@ -36,7 +36,7 @@ subprocess-per-candidate design would fail against its own parent).
 Usage:
     python scripts/tune_kernels.py [--dry-run] [--smoke]
         [--out TUNE.jsonl] [--steps 10] [--pairs 3] [--margin 0.03]
-        [--recipe flagship_fast] [--kinds plain bx bxf attention]
+        [--recipe flagship_fast] [--kinds plain bxf attention]
         [--max-candidates 0] [--fuse-basis]
 
 --margin is the fractional end-to-end win a candidate must clear; the
@@ -238,7 +238,7 @@ def main(argv=None):
     ap.add_argument('--dim', type=int, default=64)
     ap.add_argument('--nodes', type=int, default=0)
     ap.add_argument('--kinds', nargs='+',
-                    default=['plain', 'bx', 'bxf', 'attention',
+                    default=['plain', 'bxf', 'attention',
                              'attention_bwd', 'so2', 'flash',
                              'flash_stream', 'flash_global'])
     ap.add_argument('--conv-backend', default='dense',
@@ -252,7 +252,7 @@ def main(argv=None):
                          '0 = all (the smoke gate bounds its runtime '
                          'with this — interpret-mode compiles are slow)')
     ap.add_argument('--fuse-basis', action='store_true',
-                    help='smoke: exercise the bx/bxf kinds instead of '
+                    help='smoke: exercise the bxf kind instead of '
                          'plain')
     ap.add_argument('--fuse-pairwise', action='store_true',
                     help='route attention through the streaming flash '

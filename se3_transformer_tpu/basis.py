@@ -192,7 +192,7 @@ def get_basis(rel_pos: jnp.ndarray, max_degree: int,
     minor positions, inflating the materialized HBM buffers up to ~60x
     at num_degrees=4 ((Q,F)=(7,7) pads to (8,128)); one flat minor axis
     pads only to the next 128 multiple (~1.1x), and (p,f,q) is exactly
-    the order the fused bx kernel's [P*F*Q, E] operand wants, so the
+    the order the fused bxf kernel's [P*F*Q, E] operand wants, so the
     relayout into the kernel is a plain 2D transpose.
     """
     rhat, _ = safe_normalize(rel_pos)

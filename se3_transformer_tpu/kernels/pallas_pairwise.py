@@ -68,7 +68,7 @@ from jax.experimental.pallas import tpu as pltpu
 # The launches are named by role (`name=` on pallas_call): the name is the
 # innermost component of the op's name stack, which XLA takes as the custom
 # call's instruction name, so the device trace holds `fused_pairwise_conv`,
-# `_bx`, `_bxf` (forward), `fused_pairwise_conv_bwd_a` (dV2, dW3, dB3) and
+# `_bxf` (forward), `fused_pairwise_conv_bwd_a` (dV2, dW3, dB3) and
 # `fused_pairwise_conv_bwd_b` (dH). The pads, transposes and reshapes the
 # wrappers issue on either side of a launch sit under the leaf scope
 # `pairwise_layout` (observability.timing.MODEL_SCOPES), never the launch
@@ -77,62 +77,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
-
-
-def _block_overrides(*names):
-    """Forward block-size env overrides — the highest-priority escape
-    hatch, above the measured table (kernels.tuning) and the heuristic:
-    SE3_TPU_BLOCK_E paired with SE3_TPU_BLOCK_IF (plain) /
-    SE3_TPU_BLOCK_CB (bx). BOTH variables of a pair must be set — a
-    lone one warns and is ignored. Read per call (the jit cache keys on
-    shapes/statics, not env — clear the entry-point caches after
-    flipping them, see tuning.clear_kernel_caches). Backward kernels
-    never use overrides (their working set is ~2x the forward's)."""
-    import os
-    vals = [os.environ.get(n, '') for n in names]
-    if all(vals):
-        try:
-            return tuple(int(v) for v in vals)
-        except ValueError:
-            import warnings
-            warnings.warn(f'block override ignored: {names} must be '
-                          f'integers (got {vals})', stacklevel=2)
-            return None
-    if any(vals):
-        import warnings
-        warnings.warn(f'block override ignored: {names} must ALL be set '
-                      f'(got {vals})', stacklevel=2)
-    return None
-
-
-def _validate_override(block_e, second, second_name, full_second,
-                       vmem_estimate, vmem_budget):
-    """Check an env override against the Mosaic tile-quantum rules
-    (block_e multiple of 128; the pair's second member a multiple of 8 or
-    the full axis) and the VMEM model. Quantum violations warn AND are
-    ignored (a bad value would otherwise surface as an opaque Mosaic
-    compile error, ADVICE r3 #4); an over-budget but tile-legal override
-    warns and is HONORED — sweeps probe the budget edge on purpose."""
-    import warnings
-    if block_e <= 0 or block_e % 128 != 0:
-        warnings.warn(
-            f'block override ignored: SE3_TPU_BLOCK_E={block_e} must be a '
-            f'positive multiple of 128 (Mosaic lane tiling)', stacklevel=3)
-        return False
-    if second <= 0 or (second % 8 != 0 and second < full_second):
-        warnings.warn(
-            f'block override ignored: {second_name}={second} must be a '
-            f'positive multiple of 8 or cover the full axis '
-            f'({full_second}) — Mosaic sublane tiling', stacklevel=3)
-        return False
-    est = vmem_estimate(block_e, min(second, full_second))
-    if est > vmem_budget:
-        warnings.warn(
-            f'block override working set ~{est / 2**20:.1f} MiB exceeds '
-            f'the {vmem_budget / 2**20:.0f} MiB VMEM model (honored '
-            f'anyway — expect a Mosaic VMEM error if the model is right)',
-            stacklevel=3)
-    return True
 
 
 def _vmem_plain(be: int, bif: int, IF: int, O: int, P: int, mid: int,
@@ -162,14 +106,16 @@ def _vmem_bx(be: int, cb: int, O: int, P: int, Q: int, F: int,
                 + P * F * Q * be + cb * Q * be + P * O * be)
 
 
-def _consult_table(kind, shape, dtype, heuristic_fn):
-    """Measured-config table consult (kernels.tuning), between the env
-    override and the heuristic: forced tuner candidates and promoted
-    cache entries steer the pick; a cache entry failing the tile-quantum
-    / VMEM admission model degrades to the heuristic with a warning.
+def _consult_table(kind, shape, heuristic_fn):
+    """Measured-config table consult (kernels.tuning), ahead of the
+    heuristic: forced tuner candidates and promoted cache entries steer
+    the pick; a cache entry failing the tile-quantum / VMEM admission
+    model degrades to the heuristic with a warning. The table's dtype
+    key is float32: V2, the basis and x reach these kernels in no other.
     Every resolution is recorded for telemetry (bench record / serving
     warmup / run report)."""
     from . import tuning
+    dtype = 'float32'
     hit = tuning.lookup(kind, shape, dtype=dtype)
     if hit is not None:
         blocks, source = hit
@@ -186,18 +132,16 @@ def _consult_table(kind, shape, dtype, heuristic_fn):
 
 def _pick_blocks(E: int, IF: int, O: int, P: int, mid: int,
                  vmem_budget: Optional[int] = None,
-                 max_unroll: int = 256, bwd: bool = False,
-                 dtype: str = 'float32'):
+                 max_unroll: int = 256, bwd: bool = False):
     """Choose (block_e, block_if) so the working set fits in VMEM (with
     headroom for double buffering) and the in-kernel unrolled loop count
     P*block_if stays bounded (Mosaic compile time).
 
     Resolution order (forward only — the backward always runs this
-    heuristic against its own 6 MiB model): SE3_TPU_BLOCK_E/IF env
-    overrides, then the measured shape-keyed table (kernels.tuning:
-    tuner-forced candidates, then promoted cache entries), then the
-    VMEM-model heuristic below. With no overrides and an empty table the
-    pick is bit-identical to the heuristic (regression-pinned in
+    heuristic against its own 6 MiB model): the measured shape-keyed
+    table (kernels.tuning: tuner-forced candidates, then promoted cache
+    entries), then the VMEM-model heuristic below. With an empty table
+    the pick is bit-identical to the heuristic (regression-pinned in
     tests/test_kernel_tuning.py).
 
     Budget: 7 MiB forward / 6 MiB backward. The forward bump is an
@@ -231,14 +175,10 @@ def _pick_blocks(E: int, IF: int, O: int, P: int, mid: int,
     w3/R tiles of a wide block_if evict the lax.map body's working set
     and the e-grid shortens 8x, while standalone the tiny block_if=8
     tiles are DMA-bound. The picker therefore keeps the
-    production-validated preference (block_e first); use the
-    SE3_TPU_BLOCK_E/IF overrides to experiment, and only re-rank from
+    production-validated preference (block_e first); only re-rank from
     END-TO-END bench numbers, never from standalone kernel timings."""
     if vmem_budget is None:
         vmem_budget = (6 if bwd else 7) * 2 ** 20  # see docstring
-
-    def _vmem(be, bif):
-        return _vmem_plain(be, bif, IF, O, P, mid)
 
     def _heuristic():
         e_cap = _round_up(E, 128)
@@ -258,18 +198,10 @@ def _pick_blocks(E: int, IF: int, O: int, P: int, mid: int,
         return 128, min(IF, 8)
 
     if bwd:
-        # the backward never takes overrides or table entries (its ~2x
-        # working set was only ever validated under this model's picks)
+        # the backward never takes table entries (its ~2x working set
+        # was only ever validated under this model's picks)
         return _heuristic()
-    ov = _block_overrides('SE3_TPU_BLOCK_E', 'SE3_TPU_BLOCK_IF')
-    if ov and _validate_override(ov[0], ov[1], 'SE3_TPU_BLOCK_IF', IF,
-                                 _vmem, vmem_budget):
-        from . import tuning
-        blocks = ov[0], min(IF, ov[1])
-        tuning.record_consult('plain', (E, IF, O, P, mid), dtype, 'env',
-                              blocks)
-        return blocks
-    return _consult_table('plain', (E, IF, O, P, mid), dtype, _heuristic)
+    return _consult_table('plain', (E, IF, O, P, mid), _heuristic)
 
 
 def _fwd_kernel(ht_ref, w3t_ref, b3t_ref, *rest, P, O, bif,
@@ -304,10 +236,6 @@ def _fwd_kernel(ht_ref, w3t_ref, b3t_ref, *rest, P, O, bif,
         acc = None
         for i in range(bif):
             vrow = v2t_ref[p, i:i + 1, :]            # [1, E_b]
-            if vrow.dtype != jnp.float32:
-                # conv_bf16: V2 is STORED bf16 (half the dominant HBM/VMEM
-                # stream) but the apply math stays f32-on-quantized-values
-                vrow = vrow.astype(jnp.float32)
             term = vrow * rt[i * O:(i + 1) * O, :]   # [O, E_b]
             acc = term if acc is None else acc + term
         sl = slice(p * O, (p + 1) * O)
@@ -347,10 +275,6 @@ def _fused_pairwise_conv_impl(h, w3, b3, v2, interpret, precision,
     E, mid = h.shape
     _, IF, O = w3.shape
     P = v2.shape[1]
-    # table key dtype: the dominant-stream storage dtype (conv_bf16
-    # halves the V2 traffic, so its measured winner may differ from the
-    # f32 one) — captured BEFORE the interpret-mode upcasts below
-    key_dtype = jnp.dtype(v2.dtype).name
 
     # bf16 radial operands (radial_bf16): run the rt dot MXU-native with
     # f32 accumulation. Must be an EXPLICIT DEFAULT: None inherits the
@@ -365,13 +289,8 @@ def _fused_pairwise_conv_impl(h, w3, b3, v2, interpret, precision,
                 w3 = w3.astype(jnp.float32)
             # quantized w3 keeps its storage dtype — the kernel body's
             # dtype-mismatch upcast is the dequant-in-tile
-    if v2.dtype == jnp.bfloat16 and interpret:
-        # conv_bf16 under interpret: the kernel body upcasts bf16 rows to
-        # f32 right after the (Mosaic-only) VMEM load, so pre-upcasting
-        # here is bit-identical — quantize-then-f32 either way
-        v2 = v2.astype(jnp.float32)
 
-    block_e, block_if = _pick_blocks(E, IF, O, P, mid, dtype=key_dtype)
+    block_e, block_if = _pick_blocks(E, IF, O, P, mid)
     Ep, IFp = _round_up(E, block_e), _round_up(IF, block_if)
 
     with jax.named_scope('pairwise_layout'):
@@ -611,16 +530,9 @@ def _fwd_bx_kernel(ht_ref, w3t_ref, b3t_ref, bt_ref, xt_ref, o_ref, *,
         for il in range(cb * F):
             c_l, f_l = divmod(il, F)
             b_sl = (p * F + f_l) * Q
-            # V2 row for (p, i=(c, f)): one [Q, E] product + reduction.
-            # conv_bf16 stores B/x bf16 in HBM/VMEM (halving the biggest
-            # streams); rows upcast at use so the math stays f32
-            brows = bt_ref[b_sl:b_sl + Q, :]
-            xrows = xt_ref[c_l * Q:(c_l + 1) * Q, :]
-            if brows.dtype != jnp.float32:
-                brows = brows.astype(jnp.float32)
-            if xrows.dtype != jnp.float32:
-                xrows = xrows.astype(jnp.float32)
-            v2row = jnp.sum(brows * xrows,
+            # V2 row for (p, i=(c, f)): one [Q, E] product + reduction
+            v2row = jnp.sum(bt_ref[b_sl:b_sl + Q, :]
+                            * xt_ref[c_l * Q:(c_l + 1) * Q, :],
                             axis=0, keepdims=True)   # [1, E_b]
             term = v2row * rt[il * O:(il + 1) * O, :]
             acc = term if acc is None else acc + term
@@ -637,28 +549,21 @@ def _fwd_bx_kernel(ht_ref, w3t_ref, b3t_ref, bt_ref, xt_ref, o_ref, *,
 
 def _pick_blocks_bx(E: int, C: int, O: int, P: int, Q: int, F: int,
                     mid: int, vmem_budget: int = 6 * 2 ** 20,
-                    max_unroll: int = 512, kind: str = 'bx',
-                    dtype: str = 'float32'):
+                    max_unroll: int = 512):
     """(block_e, cb) for the basis-fused kernel. cb is the c-chunk: a
     multiple of 8 (so the xt row-block cb*Q and w3t row-block cb*F*O are
     tile-aligned for any odd Q/F) or the full (padded) C.
 
-    Resolution order mirrors _pick_blocks: SE3_TPU_BLOCK_E/CB env
-    overrides, then the measured shape-keyed table (kernels.tuning —
-    'bx' and 'bxf' are distinct kinds: same contraction, different HBM
-    basis operand), then the heuristic below.
+    Resolution order mirrors _pick_blocks: the measured shape-keyed
+    table (kernels.tuning, kind 'bxf'), then the heuristic below.
 
     The round-4 KERNEL_TUNE standalone sweep at the flagship bxf shape
-    measured the default (128, 8) within 2% of the best override
+    measured the default (128, 8) within 2% of the best other pick
     (7.896 vs 7.723 ms at (512, 8)) — and the plain picker's cautionary
     tale applies (see _pick_blocks: a standalone-sweep-derived
     "improvement" cost the production conservative path 2.7x), so the
-    budget and ordering stay as production-validated; the overrides and
-    the end-to-end tuner (scripts/tune_kernels.py) are the
-    experimentation paths."""
-    def _vmem(be, cb):
-        return _vmem_bx(be, cb, O, P, Q, F, mid)
-
+    budget and ordering stay as production-validated; the end-to-end
+    tuner (scripts/tune_kernels.py) is the experimentation path."""
     def _heuristic():
         for block_e in (512, 256, 128):
             if block_e > _round_up(E, 128):
@@ -680,12 +585,12 @@ def _pick_blocks_bx(E: int, C: int, O: int, P: int, Q: int, F: int,
         # (ADVICE r4 #3: a warning that fires on every healthy flagship
         # run trains users to ignore it). Only genuinely larger shapes
         # get the heads-up that pre-explains a real Mosaic VMEM failure.
-        total = _vmem(128, 8)
+        total = _vmem_bx(128, 8, O, P, Q, F, mid)
         validated_silence = 9 * 2 ** 20  # flagship 7.5 MiB + margin
         if total > validated_silence:
             import warnings
             warnings.warn(
-                f'fused bx kernel working-set model ~{total / 2**20:.1f} '
+                f'fused bxf kernel working-set model ~{total / 2**20:.1f} '
                 f'MiB exceeds the {vmem_budget / 2**20:.0f} MiB budget '
                 f'even at the smallest block (P={P}, Q={Q}, F={F}, O={O}, '
                 f'mid={mid}) and is beyond the production-validated '
@@ -694,59 +599,39 @@ def _pick_blocks_bx(E: int, C: int, O: int, P: int, Q: int, F: int,
                 stacklevel=4)
         return 128, 8
 
-    shape = (E, C, O, P, Q, F, mid)
-    ov = _block_overrides('SE3_TPU_BLOCK_E', 'SE3_TPU_BLOCK_CB')
-    if ov and _validate_override(ov[0], ov[1], 'SE3_TPU_BLOCK_CB',
-                                 _round_up(C, 8), _vmem, vmem_budget):
-        from . import tuning
-        tuning.record_consult(kind, shape, dtype, 'env', ov)
-        return ov
-    return _consult_table(kind, shape, dtype, _heuristic)
+    return _consult_table('bxf', (E, C, O, P, Q, F, mid), _heuristic)
 
 
 def _fused_pairwise_conv_bx_impl(h, w3, b3, basis, x, interpret, precision,
-                                 pqf=None):
-    """basis is [E, P, Q, F] (structured), or — when `pqf`=(P, Q, F) is
-    given — [E, P*F*Q] pre-flattened in (p, f, q) order (the layout
-    get_basis(layout='pfq_flat') produces): the kernel operand
-    bt [P*F*Q, E] is then a plain 2D transpose instead of a 6D
-    relayout reading a ~60x tile-padded HBM buffer."""
+                                 pqf):
+    """basis is [E, P*F*Q], flattened per edge in (p, f, q) order (the
+    layout get_basis(layout='pfq_flat') produces, pqf = (P, Q, F)): the
+    kernel operand bt [P*F*Q, E] is a plain 2D transpose, where the
+    structured [E, P, Q, F] form would be a 6D relayout reading a ~60x
+    tile-padded HBM buffer."""
     E, mid = h.shape
-    if pqf is None:
-        _, P, Q, F = basis.shape
-    else:
-        P, Q, F = pqf
-        assert basis.shape == (E, P * F * Q), (basis.shape, pqf)
+    P, Q, F = pqf
+    assert basis.shape == (E, P * F * Q), (basis.shape, pqf)
     C = x.shape[1]
     O = w3.shape[-1]
     assert w3.shape[1] == C * F, (w3.shape, C, F)
-    # table key dtype: basis/x storage width (conv_bf16), captured
-    # before the interpret-mode upcasts below
-    key_dtype = jnp.dtype(basis.dtype).name
+    # the kernels hold one storage width: a basis built from bf16
+    # coordinates (the serving engine's activation_dtype) goes up here
+    basis, x = basis.astype(jnp.float32), x.astype(jnp.float32)
     if h.dtype == jnp.bfloat16:  # see fused_pairwise_conv (explicit
         # DEFAULT — None would inherit a possibly-fp32 context precision,
         # which Mosaic rejects on bf16 operands)
         precision = jax.lax.Precision.DEFAULT
         if interpret:
             h, w3 = h.astype(jnp.float32), w3.astype(jnp.float32)
-    if interpret:
-        # conv_bf16 under interpret: bit-identical to the kernel's
-        # load-then-upcast (quantize-then-f32 either way)
-        if basis.dtype == jnp.bfloat16:
-            basis = basis.astype(jnp.float32)
-        if x.dtype == jnp.bfloat16:
-            x = x.astype(jnp.float32)
 
-    block_e, cb = _pick_blocks_bx(E, C, O, P, Q, F, mid,
-                                  kind='bxf' if pqf is not None else 'bx',
-                                  dtype=key_dtype)
+    block_e, cb = _pick_blocks_bx(E, C, O, P, Q, F, mid)
     Cp = _round_up(C, cb)
     Ep = _round_up(E, block_e)
 
     with jax.named_scope('pairwise_layout'):
         ht = h.T                                          # [mid, E]
-        bt = basis.T if pqf is not None \
-            else basis.transpose(1, 3, 2, 0).reshape(P * F * Q, E)
+        bt = basis.T                                      # [(p,f,q), E]
         xt = x.transpose(1, 2, 0).reshape(C * Q, E)
         w3t = w3.reshape(mid, C * F * O).T                # [(c,f,o), mid]
         b3t = _bias_column(b3, C * F, O, Cp * F)
@@ -780,45 +665,11 @@ def _fused_pairwise_conv_bx_impl(h, w3, b3, basis, x, interpret, precision,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((P * O, Ep), jnp.float32),
         interpret=interpret,
-        name='fused_pairwise_conv_bxf' if pqf is not None
-        else 'fused_pairwise_conv_bx',
+        name='fused_pairwise_conv_bxf',
     )(ht, w3t, b3t, bt, xt)
 
     with jax.named_scope('pairwise_layout'):
         return outt.reshape(P, O, Ep).transpose(2, 0, 1)[:E]
-
-
-@functools.lru_cache(maxsize=None)
-def _bx_partitioned(interpret, precision):
-    return _make_partitioned(
-        lambda h, w3, b3, basis, x: _fused_pairwise_conv_bx_impl(
-            h, w3, b3, basis, x, interpret, precision),
-        rule='e m, m i o, i o, e p q f, e c q -> e p o',
-        need_repl=('m', 'i', 'q', 'f', 'c'),
-        arg_specs=lambda P_, e, o: (P_(e, None), P_(None, None, o),
-                                    P_(None, o),
-                                    P_(e, None, None, None),
-                                    P_(e, None, None)),
-        result_specs=lambda P_, e, o: (P_(e, None, o),))
-
-
-@functools.partial(jax.jit, static_argnames=('interpret', 'precision'))
-def fused_pairwise_conv_bx(h: jnp.ndarray, w3: jnp.ndarray,
-                           basis: jnp.ndarray, x: jnp.ndarray,
-                           b3: jnp.ndarray = None,
-                           interpret: bool = False,
-                           precision=None) -> jnp.ndarray:
-    """Basis-fused forward: h [E, mid], w3 [mid, C*F, O] (i=(c,f)
-    c-major), basis [E, P, Q, F], x [E, C, Q], b3 [C*F, O] (optional,
-    zeros when None) -> out [E, P, O] (f32).
-
-    Equals fused_pairwise_conv(h, w3, einsum('epqf,ecq->e p (c f)', ...),
-    b3) without ever materializing that V2 tensor in HBM. Partitions over
-    sharded edge/output-channel axes (see the SPMD rules above).
-    """
-    if b3 is None:
-        b3 = jnp.zeros(w3.shape[1:], jnp.float32)
-    return _bx_partitioned(interpret, precision)(h, w3, b3, basis, x)
 
 
 @functools.lru_cache(maxsize=None)
@@ -841,12 +692,18 @@ def fused_pairwise_conv_bxf(h: jnp.ndarray, w3: jnp.ndarray,
                             pqf: tuple, b3: jnp.ndarray = None,
                             interpret: bool = False,
                             precision=None) -> jnp.ndarray:
-    """fused_pairwise_conv_bx with the basis pre-flattened per edge to
-    [E, P*F*Q] in (p, f, q) order (get_basis layout='pfq_flat'). Same
-    math, but the HBM basis buffer is ~60x smaller at num_degrees=4: the
-    structured [.., P, Q, F] form tile-pads its two small odd minor axes
-    to (8, 128), the flat form pads one axis to the next 128 multiple.
-    pqf = (P, Q, F) static ints."""
+    """Basis-fused forward: h [E, mid], w3 [mid, C*F, O] (i=(c,f)
+    c-major), basis_flat [E, P*F*Q] in (p, f, q) order (get_basis
+    layout='pfq_flat'), x [E, C, Q], pqf = (P, Q, F) static ints, b3
+    [C*F, O] (optional, zeros when None) -> out [E, P, O] (f32).
+
+    Equals fused_pairwise_conv(h, w3, einsum('epqf,ecq->e p (c f)', ...),
+    b3) without ever materializing that V2 tensor in HBM. The flat form
+    keeps the HBM basis buffer ~60x smaller at num_degrees=4 than the
+    structured [.., P, Q, F] one, which tile-pads its two small odd minor
+    axes to (8, 128); the flat form pads one axis to the next 128
+    multiple. Partitions over sharded edge/output-channel axes (see the
+    SPMD rules above)."""
     if b3 is None:
         b3 = jnp.zeros(w3.shape[1:], jnp.float32)
     return _bxf_partitioned(tuple(pqf), interpret, precision)(
@@ -854,8 +711,7 @@ def fused_pairwise_conv_bxf(h: jnp.ndarray, w3: jnp.ndarray,
 
 
 # --------------------------------------------------------------------- #
-# fused backward, V2 given (the plain forward's, and the structured-basis
-# forward's, whose call site builds V2 by an einsum first; the flat-basis
+# fused backward, V2 given (the plain forward's; the basis-fused
 # forward's own backward follows further down)
 # --------------------------------------------------------------------- #
 # Cotangents of out[e,P,o] = sum_{if} V2[e,P,if] R[e,if,o],
@@ -897,10 +753,7 @@ def _bwd_a_kernel(ht_ref, h_ref, w3t_ref, b3t_ref, v2t_ref, gt_ref,
             # dV2[(p, i)] = sum_o g[p,o,:] * r[i,o,:]
             dv2_ref[p, i:i + 1, :] = jnp.sum(
                 gp * r_i, axis=0, keepdims=True).astype(dv2_ref.dtype)
-            vrow = v2t_ref[p, i:i + 1, :]            # [1, E_b]
-            if vrow.dtype != jnp.float32:
-                vrow = vrow.astype(jnp.float32)      # conv_bf16 storage
-            term = vrow * gp                         # [O, E_b]
+            term = v2t_ref[p, i:i + 1, :] * gp       # [O, E_b]
             dr_i = term if dr_i is None else dr_i + term
         dr_ref[i * O:(i + 1) * O, :] = dr_i
     dr = dr_ref[:]                                   # [bif*O, E_b], f32
@@ -937,10 +790,7 @@ def _bwd_b_kernel(w3f_ref, v2t_ref, gt_ref, dh_ref, dr_ref, *, P, O, bif,
     for i in range(bif):
         dr_i = None
         for p in range(P):
-            vrow = v2t_ref[p, i:i + 1, :]
-            if vrow.dtype != jnp.float32:
-                vrow = vrow.astype(jnp.float32)      # conv_bf16 storage
-            term = vrow * g[p * O:(p + 1) * O, :]
+            term = v2t_ref[p, i:i + 1, :] * g[p * O:(p + 1) * O, :]
             dr_i = term if dr_i is None else dr_i + term
         dr_ref[i * O:(i + 1) * O, :] = dr_i
     w3f = w3f_ref[0]                                 # [mid, bif*O]
@@ -966,11 +816,7 @@ def _fused_pairwise_conv_bwd_impl(h, w3, b3, v2, g, interpret, precision):
     # bf16 radial operands (radial_bf16) feed the three dots as they are,
     # with dR rounded to bf16 in the tile; f32 h leaves everything f32 at
     # the caller's precision. g, the reductions (dV2, dB3) and every
-    # accumulator are f32 either way. A bf16 V2 (conv_bf16) STAYS bf16
-    # through HBM — the backward kernels upcast rows in VMEM like the
-    # forward does, so the half-width saving on the dominant stream holds
-    # for the backward too (upcasting here would write a full f32 copy
-    # back to HBM first)
+    # accumulator are f32 either way
     E, mid = h.shape
     _, IF, O = w3.shape
     P = v2.shape[1]
@@ -991,10 +837,6 @@ def _fused_pairwise_conv_bwd_impl(h, w3, b3, v2, g, interpret, precision):
     with jax.named_scope('pairwise_layout'):
         h, w3 = h.astype(rdt), w3.astype(rdt)
         g = g.astype(jnp.float32)
-        if v2.dtype == jnp.bfloat16 and interpret:
-            # interpret can't mix dtypes the way Mosaic lowers them; the
-            # pre-upcast is bit-identical to the kernels' row upcasts
-            v2 = v2.astype(jnp.float32)
         ht, w3t, v2t, gt = _to_lanes(h, w3, v2, g)
         b3t = _bias_column(b3, IF, O, IFp)
         h_p, w3f = h, w3.reshape(mid, IF * O)
@@ -1164,18 +1006,13 @@ def fused_pairwise_conv_bwd(h: jnp.ndarray, w3: jnp.ndarray,
 #                      rows of w3, b3 and g)
 
 
-def _f32(v):
-    """conv_bf16 stores B and x bf16; the math is f32 on those values."""
-    return v if v.dtype == jnp.float32 else v.astype(jnp.float32)
-
-
 def _contract_over_q(bt_ref, xt_ref, v2_ref, Q):
     """v2_ref[(p, f)] = sum_q B[(p, f), q] x[q], all cb channels at once."""
     def one(pf, carry):
-        b = _f32(bt_ref[pf])                         # [Q, E_b]
+        b = bt_ref[pf]                               # [Q, E_b]
         acc = None
         for q in range(Q):
-            term = b[q:q + 1, :] * _f32(xt_ref[q])   # [cb, E_b]
+            term = b[q:q + 1, :] * xt_ref[q]         # [cb, E_b]
             acc = term if acc is None else acc + term
         v2_ref[pf] = acc
         return carry
@@ -1232,7 +1069,7 @@ def _bwd_bxf_a_kernel(ht_ref, h_ref, w3t_ref, b3t_ref, bt_ref, xt_ref,
 
     for q in range(Q):
         def add(pf, acc, q=q):
-            return acc + _f32(bt_ref[pf])[q:q + 1, :] * dv2_ref[pf]
+            return acc + bt_ref[pf][q:q + 1, :] * dv2_ref[pf]
 
         dx_ref[q] = jax.lax.fori_loop(
             0, P * F, add, jnp.zeros(dx_ref.shape[1:], jnp.float32))
@@ -1314,15 +1151,13 @@ def _vmem_bxf_bwd(be: int, cb: int, O: int, P: int, Q: int, F: int,
 
 
 def _pick_blocks_bxf_bwd(E: int, C: int, O: int, P: int, Q: int, F: int,
-                         mid: int, dtype: str = 'float32',
-                         vmem_budget: int = 18 * 2 ** 20):
+                         mid: int, vmem_budget: int = 18 * 2 ** 20):
     """(block_e, cb) of the basis-fused backward: the widest edge block
     at which the model holds 8 channels, then as many as fit. Channels
-    ride sublanes, so cb is a multiple of the sublane tile of the dtype x
-    is stored in, and C counts up to one. The heuristic alone
-    decides, as for the plain backward; the pick is recorded under a kind
-    of its own, so a step's consult log counts the launches that took
-    this form.
+    ride sublanes, so cb is a multiple of the sublane tile, 8, and C
+    counts up to one. The heuristic alone decides, as for the plain
+    backward; the pick is recorded under a kind of its own, so a step's
+    consult log counts the launches that took this form.
 
     The budget is in the model's bytes, which are not the compiler's:
     with float32 operands and every output kept, the v5e compiler took
@@ -1330,24 +1165,23 @@ def _pick_blocks_bxf_bwd(E: int, C: int, O: int, P: int, Q: int, F: int,
     the model puts at 19.2 MiB or less, refused some from 19.5 on and all
     from 21.5 (deviceless compile, PR 28); the picks at O = 24 are the
     ones a whole step ran with on the chip."""
-    sub = 16 if dtype == 'bfloat16' else 8
-    C = _round_up(C, sub)
+    C = _round_up(C, 8)
 
     def _heuristic():
         for block_e in (512, 256, 128):
             if block_e > _round_up(E, 128):
                 continue
             cb = C
-            while cb > sub and _vmem_bxf_bwd(block_e, cb, O, P, Q, F,
-                                             mid) > vmem_budget:
-                cb = _round_up(cb // 2, sub)
+            while cb > 8 and _vmem_bxf_bwd(block_e, cb, O, P, Q, F,
+                                           mid) > vmem_budget:
+                cb = _round_up(cb // 2, 8)
             if _vmem_bxf_bwd(block_e, cb, O, P, Q, F, mid) <= vmem_budget:
                 return block_e, cb
-        return 128, sub
+        return 128, 8
 
     from . import tuning
     blocks = _heuristic()
-    tuning.record_consult('bxf_bwd', (E, C, O, P, Q, F, mid), dtype,
+    tuning.record_consult('bxf_bwd', (E, C, O, P, Q, F, mid), 'float32',
                           'heuristic', blocks)
     return blocks
 
@@ -1356,8 +1190,7 @@ def _fused_pairwise_conv_bwd_bxf_impl(h, w3, b3, basis, x, g, pqf,
                                       interpret, precision):
     # dtypes as _fused_pairwise_conv_bwd_impl: h decides the MXU operands
     # of the three dots; the basis contraction, dV2, dx, dB3 and every
-    # accumulator are f32 on the VPU, bf16-stored basis / x (conv_bf16)
-    # upcast in VMEM
+    # accumulator are f32 on the VPU
     P, Q, F = pqf
     E, mid = h.shape
     C = x.shape[1]
@@ -1370,13 +1203,10 @@ def _fused_pairwise_conv_bwd_bxf_impl(h, w3, b3, basis, x, g, pqf,
     if mxu_dtype == jnp.bfloat16:
         precision = jax.lax.Precision.DEFAULT  # see fused_pairwise_conv
     rdt = jnp.float32 if interpret else mxu_dtype
-    key_dtype = jnp.dtype(x.dtype).name
-    if interpret:
-        # bit-identical to the kernels' upcasts at use
-        basis, x = basis.astype(jnp.float32), x.astype(jnp.float32)
+    # one storage width, as the forward
+    basis, x = basis.astype(jnp.float32), x.astype(jnp.float32)
 
-    block_e, cb = _pick_blocks_bxf_bwd(E, C, Op, P, Q, F, mid,
-                                       dtype=key_dtype)
+    block_e, cb = _pick_blocks_bxf_bwd(E, C, Op, P, Q, F, mid)
     Ep, Cp = _round_up(E, block_e), _round_up(C, cb)
     n_e, n_c = Ep // block_e, Cp // cb
     S = cb * F * Op
@@ -1469,8 +1299,7 @@ def _fused_pairwise_conv_bwd_bxf_impl(h, w3, b3, basis, x, g, pqf,
     with jax.named_scope('basis_contract'):
         # dbasis[(p,f), q, e] = sum_c dV2[(p,f), c, e] x[q, c, e], on the
         # kernels' own layouts: one multiply-reduce with E on lanes
-        dbt = jnp.sum(dv2t[:, None] * xt.astype(jnp.float32)[None],
-                      axis=2)
+        dbt = jnp.sum(dv2t[:, None] * xt[None], axis=2)
         dbasis = dbt.reshape(P * F * Q, Ep).T[:E]
     return dh, dw3, db3, dbasis, dx
 

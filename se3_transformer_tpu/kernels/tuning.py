@@ -9,27 +9,27 @@ measured OPPOSITE to end-to-end rankings (the d0cd10d regression:
 294.97 -> 107.51 nodes*steps/s). This module gives every pick function a
 measured-config table consulted BEFORE the heuristic:
 
-    precedence:  env override  >  forced candidate  >  cache  >  heuristic
+    precedence:  forced candidate  >  cache  >  heuristic
 
-  * env overrides (SE3_TPU_BLOCK_E/IF/CB) stay the highest-priority
-    escape hatch — checked by the pick functions before this module is
-    consulted at all;
+(the so2 and flash pick functions alone still read an environment
+variable of their own first, and log it as source 'env')
+
   * `force(kind, blocks)` is the tuner's in-process candidate mechanism
     (scripts/tune_kernels.py): a pending table entry under measurement,
-    without env-string round-trips or a subprocess per setting;
+    in the tuner's own process;
   * the cache is a versioned on-disk JSON table (same durability pattern
     as the Q_J `.npz` cache in basis.py: atomic rename, corrupt file =
     miss, version bump = invalidation) keyed on
     (kernel kind, shape tuple, dtype, device_kind, cache version), with
     per-entry provenance (code_rev, benched nodes*steps/s, timestamp);
-  * with an empty cache and no overrides every pick is bit-identical to
-    the heuristic (regression-pinned in tests/test_kernel_tuning.py).
+  * with an empty cache every pick is bit-identical to the heuristic
+    (regression-pinned in tests/test_kernel_tuning.py).
 
 Entries enter the cache ONLY through `promote()`, and the supported
 promoter (scripts/tune_kernels.py) measures candidates END-TO-END
 through the real bench step — never the standalone kernel — and
 requires a win over the incumbent across alternating A/B pairs. Every
-consult (cache hit, env/forced override, or heuristic fallback) is
+consult (cache hit, forced candidate, 'env', or heuristic fallback) is
 recorded in an in-process log that bench.py, the serving engine's AOT
 warmup, and the run report surface, so an adopted pick is always
 distinguishable from a heuristic one in telemetry.
@@ -50,9 +50,9 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 CACHE_VERSION = 1
 
-# kernel kinds with tunable picks. 'plain'/'bx'/'bxf' are the pairwise
+# kernel kinds with tunable picks. 'plain'/'bxf' are the pairwise
 # forward kernels (the backward ALWAYS runs its own bwd-model heuristic
-# — overrides and table entries never reach it, see _pick_blocks; the
+# — table entries never reach it, see _pick_blocks; the
 # basis-fused backward logs its picks under 'bxf_bwd', a kind of the
 # consult log only, so a step's log counts the launches of that form);
 # 'attention' is the fused attention forward block_n and
@@ -71,8 +71,8 @@ CACHE_VERSION = 1
 # so a small-n kNN-calibrated entry must never steer an assembly-n
 # global step (the Pallas block pick stays kind 'flash': global
 # shapes key K=0 there).
-KINDS = ('plain', 'bx', 'bxf', 'attention', 'attention_bwd', 'so2',
-         'flash', 'flash_stream', 'flash_global')
+KINDS = ('plain', 'bxf', 'attention', 'attention_bwd', 'so2', 'flash',
+         'flash_stream', 'flash_global')
 
 # Mosaic's scoped-vmem stack limit is ~16 MiB; 12 MiB leaves slack for
 # compiler temporaries (same constant, same hard-won reason, as
@@ -255,8 +255,8 @@ def force(kind: str, blocks: Sequence[int], *,
           shape: Optional[Sequence[int]] = None,
           dtype: Optional[str] = None):
     """Pin a candidate for one kind — the tuner's in-process measurement
-    path (precedence: below env overrides, above the cache). Pass the
-    target `shape` (and `dtype`) so ONLY that pick takes the candidate:
+    path (precedence: above the cache). Pass the target `shape` (and
+    `dtype`) so ONLY that pick takes the candidate:
     a same-kind pick at another shape was never admitted for these
     blocks and must keep resolving cache/heuristic, or the measured A/B
     would not be the program that deploys. shape=None applies to every
@@ -285,14 +285,13 @@ def force(kind: str, blocks: Sequence[int], *,
 def clear_kernel_caches() -> int:
     """Drop every kernel jit/trace cache whose pick this table steers.
     Returns the number of caches cleared; raises if NOTHING was cleared
-    (a silent no-op would let an A/B measure the same program twice —
-    the invalid-pair failure mode of the retired env-var sweep)."""
+    (a silent no-op would let an A/B measure the same program twice)."""
     cleared = 0
     from . import pallas_attention as pa, pallas_flash as pf, \
         pallas_pairwise as pp
     for mod, names in (
-            (pp, ('fused_pairwise_conv', 'fused_pairwise_conv_bx',
-                  'fused_pairwise_conv_bxf', 'fused_pairwise_conv_bwd',
+            (pp, ('fused_pairwise_conv', 'fused_pairwise_conv_bxf',
+                  'fused_pairwise_conv_bwd',
                   'fused_pairwise_conv_bwd_bxf')),
             (pa, ('_fused_attention_fwd_impl',
                   '_fused_attention_bwd_impl')),
@@ -303,9 +302,8 @@ def clear_kernel_caches() -> int:
                 f.clear_cache()
                 cleared += 1
     for mod, names in (
-            (pp, ('_fwd_partitioned', '_bx_partitioned',
-                  '_bxf_partitioned', '_bwd_partitioned',
-                  '_bwd_bxf_partitioned')),
+            (pp, ('_fwd_partitioned', '_bxf_partitioned',
+                  '_bwd_partitioned', '_bwd_bxf_partitioned')),
             (pa, ('_att_partitioned',))):
         for nm in names:
             f = getattr(mod, nm, None)
@@ -326,7 +324,8 @@ def clear_kernel_caches() -> int:
 def record_consult(kind: str, shape: Sequence[int], dtype: str,
                    source: str, blocks: Sequence[int]) -> None:
     """Called by the pick functions on every resolution. source is one
-    of 'env' / 'forced' / 'cache' / 'heuristic'."""
+    of 'forced' / 'cache' / 'heuristic', or 'env' from the so2 and flash
+    picks' own overrides."""
     key = (kind, tuple(int(s) for s in shape), dtype, source,
            tuple(int(b) for b in blocks))
     with _lock:
@@ -391,11 +390,9 @@ def admissible_candidates(kind: str, shape: Sequence[int]
                           ) -> List[Tuple[int, ...]]:
     """Tile-legal, VMEM-model-admissible candidate blocks for a shape —
     what scripts/tune_kernels.py is allowed to measure. Admission is
-    model-based and conservative ON PURPOSE: the env-override path
-    honors over-budget settings ("sweeps probe the budget edge"), and
-    the round-4 sweep paid for that with Mosaic VMEM compile failures at
-    bx/bxf (512, 16) and bx (256, 16) — those configs are excluded here
-    up front.
+    model-based and conservative ON PURPOSE: the round-4 sweep ran
+    over-budget settings and paid with Mosaic VMEM compile failures at
+    bxf (512, 16) — those configs are excluded here up front.
 
     Per kind:
       * 'plain': forward working set within the production 7 MiB budget
@@ -403,7 +400,7 @@ def admissible_candidates(kind: str, shape: Sequence[int]
         structural: the backward NEVER runs candidate blocks — it keeps
         its own 6 MiB bwd-model heuristic pick — so a forward candidate
         cannot regress the backward's VMEM fit.
-      * 'bx'/'bxf': forward model within MOSAIC_SCOPED_VMEM (12 MiB) —
+      * 'bxf': forward model within MOSAIC_SCOPED_VMEM (12 MiB) —
         the model already sits above the 6 MiB paper budget at the
         production-validated flagship default (~7.5 MiB), so the real
         ceiling with slack is the admission line. Same backward note.
@@ -437,7 +434,7 @@ def admissible_candidates(kind: str, shape: Sequence[int]
                     continue
                 if _vmem_plain(be, min(bif, IF), IF, O, P, mid) <= budget:
                     out.append((be, bif))
-    elif kind in ('bx', 'bxf'):
+    elif kind == 'bxf':
         from .pallas_pairwise import _round_up, _vmem_bx
         E, C, O, P, Q, F, mid = (int(s) for s in shape)
         for be in (128, 256, 512):
@@ -506,10 +503,9 @@ def _second_axis_candidates(full: int) -> List[int]:
 def validate_entry(kind: str, shape: Sequence[int],
                    blocks: Sequence[int]) -> bool:
     """Tile-quantum + VMEM-model gate applied by the pick functions to a
-    table hit before adopting it. Stricter than the env-override path
-    (which honors over-budget settings): a cache entry exists to be
-    trusted silently, so anything the admission model rejects is treated
-    as corrupt — warn and fall back to the heuristic."""
+    table hit before adopting it: a cache entry exists to be trusted
+    silently, so anything the admission model rejects is treated as
+    corrupt — warn and fall back to the heuristic."""
     ok = tuple(int(b) for b in blocks) in \
         set(admissible_candidates(kind, shape))
     if not ok:

@@ -30,7 +30,9 @@ import jax
 import jax.numpy as jnp
 
 from ..basis import get_basis
-from ..ops.conv import BackendSpec, ConvSE3, resolve_conv_backend
+from ..ops.conv import (
+    BackendSpec, ConvSE3, basis_layout, resolve_conv_backend,
+)
 from ..ops.trunk import SequentialTrunk
 from ..ops.core import LinearSE3, NormSE3
 from ..ops.egnn import EGnnNetwork
@@ -147,15 +149,11 @@ class SE3TransformerModule(nn.Module):
     # None -> auto (Pallas fused pairwise kernel on TPU, XLA elsewhere)
     pallas: Optional[bool] = None
     # contract the angular basis inside the pairwise kernel (forward):
-    # the V2 intermediate never touches HBM (kernels.pallas_pairwise bx)
+    # the V2 intermediate never touches HBM (kernels.pallas_pairwise, bxf)
     fuse_basis: bool = False
     # bf16 radial trunk/matmul (rotation-invariant inputs: preserves
     # equivariance, MXU-native speed — see ops.conv.radial_hidden)
     radial_bf16: bool = False
-    # bf16 STORAGE of the equivariant kernel operands (V2/basis/gathered
-    # features): halves the dominant HBM streams at ~1e-3 equivariance
-    # cost (quantizes tensors that rotate) — opt-in, see ops.conv
-    conv_bf16: bool = False
     pallas_interpret: bool = False  # tests: interpreter-mode conv kernel
     # None -> auto: fused per-degree attention kernel on TPU (sim/softmax/
     # weighted-sum in VMEM, one kv pass — kernels.pallas_attention)
@@ -799,18 +797,16 @@ class SE3TransformerModule(nn.Module):
                             if name in backends)
         extra_backends = sorted(set(backends.values()) - {'dense'})
 
-        # basis, in-trace (reference :1329). The fused bx kernel path
-        # takes the flat (p,f,q) layout: one padded minor axis (~1.1x)
+        # basis, in-trace (reference :1329). The basis-fused kernels
+        # take the flat (p,f,q) layout: one padded minor axis (~1.1x)
         # instead of the structured form's (Q,F)->(8,128) tile pad (up
-        # to ~60x HBM inflation at num_degrees=4); the convs unflatten
-        # automatically if dispatch resolves away from the kernel.
+        # to ~60x HBM inflation at num_degrees=4); ops.conv.contract_pair
+        # relays a basis that reaches it in the other layout.
         # Non-dense backends get their payload under their reserved key
         # instead — an all-so2 model skips the CG basis entirely (at
         # degree 6 that is 49 per-edge [P, Q, F] tensors never built).
-        from ..ops.conv import _use_pallas
-        layout = 'pfq_flat' if (
-            self.fuse_basis
-            and _use_pallas(self.pallas, self.pallas_interpret)) else 'pqf'
+        layout = basis_layout(self.fuse_basis, self.pallas,
+                              self.pallas_interpret)
         basis = {}
         with named_scope('basis'):
             if need_dense:
@@ -843,7 +839,6 @@ class SE3TransformerModule(nn.Module):
             edge_chunks=self.edge_chunks,
             fuse_basis=self.fuse_basis,
             radial_bf16=self.radial_bf16,
-            conv_bf16=self.conv_bf16,
             pallas_interpret=self.pallas_interpret)
 
         # project in + pre-convs (reference :1338-1344)
@@ -988,7 +983,6 @@ class SE3TransformerModule(nn.Module):
             shared_radial_hidden=self.shared_radial_hidden,
             edge_chunks=self.edge_chunks, fuse_basis=self.fuse_basis,
             radial_bf16=self.radial_bf16,
-            conv_bf16=self.conv_bf16,
             pallas_interpret=self.pallas_interpret, name='trunk')(
                 x, edge_info, rel_dist, basis, global_feats, pos_emb, mask)
 

@@ -53,7 +53,6 @@ class AttentionSE3(nn.Module):
     fuse_basis: bool = False
     pallas_interpret: bool = False
     radial_bf16: bool = False
-    conv_bf16: bool = False
     # conv backends for the value/key ConvSE3 paths (ops.conv
     # registry; resolved per layer by the model's conv_backend spec)
     backend_v: str = 'dense'
@@ -120,7 +119,6 @@ class AttentionSE3(nn.Module):
             edge_chunks=self.edge_chunks,
             fuse_basis=self.fuse_basis,
             radial_bf16=self.radial_bf16,
-            conv_bf16=self.conv_bf16,
             pallas_interpret=self.pallas_interpret)
 
         # named scopes ('attn_qkv' projections, 'attn_core' per-degree
@@ -294,10 +292,6 @@ class AttentionSE3(nn.Module):
         assert not self.linear_proj_keys, \
             'fuse_pairwise needs conv keys (linear_proj_keys gathers ' \
             'node-projected keys instead)'
-        assert not self.conv_bf16, \
-            'fuse_pairwise does not apply conv_bf16 (there is no ' \
-            'materialized V2/basis/gathered operand to store bf16 — ' \
-            'the knob would silently do nothing on this path)'
         neighbor_indices, neighbor_mask, _ = edge_info
 
         hidden_fiber = self.fiber.to(self.dim_head * h)
@@ -440,9 +434,6 @@ class AttentionSE3(nn.Module):
             'global attention consumes raw distances only (no ' \
             'fourier/edge features — the kernel rebuilds distances ' \
             'from coordinates per tile)'
-        assert not self.conv_bf16, \
-            'global attention has no materialized conv operand to ' \
-            'store bf16'
         coords = basis['global_coords']
         node_mask = basis.get('global_mask')
 
@@ -563,7 +554,6 @@ class AttentionBlockSE3(nn.Module):
     fuse_basis: bool = False
     pallas_interpret: bool = False
     radial_bf16: bool = False
-    conv_bf16: bool = False
     backend_v: str = 'dense'
     backend_k: str = 'dense'
     fuse_pairwise: bool = False
@@ -600,7 +590,6 @@ class AttentionBlockSE3(nn.Module):
                 edge_chunks=self.edge_chunks,
                 fuse_basis=self.fuse_basis,
                 radial_bf16=self.radial_bf16,
-                conv_bf16=self.conv_bf16,
                 pallas_interpret=self.pallas_interpret,
                 fuse_pairwise=self.fuse_pairwise,
                 flash_interpret=self.flash_interpret,

@@ -53,7 +53,7 @@ DEFAULT_MID_DIM = 128
 # tensors from basis.get_basis, optionally fused into the Pallas
 # kernels). Alternative backends register a pairwise contract callable
 #     impl(h, w3, b3, payload, x, *, d_in, d_out, pallas,
-#          pallas_interpret, edge_chunks, conv_bf16) -> [..., c_out, P]
+#          pallas_interpret, edge_chunks) -> [..., c_out, P]
 # sharing the dense path's parameter layout (w3 [mid, c_in*F, c_out],
 # b3 [c_in*F, c_out]) so backends can be swapped per layer with
 # identical checkpoints. `payload` is whatever the backend's model-side
@@ -296,51 +296,6 @@ def _pc_bwd(interpret, precision, res, g):
 _pairwise_contract_pallas.defvjp(_pc_fwd, _pc_bwd)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _pairwise_contract_pallas_bx(h, w3, b3, basis, x, interpret=False,
-                                 precision=None):
-    from ..kernels.pallas_pairwise import fused_pairwise_conv_bx
-    return fused_pairwise_conv_bx(h, w3, basis, x, b3=b3,
-                                  interpret=interpret,
-                                  precision=precision)
-
-
-def _pc_bx_fwd(h, w3, b3, basis, x, interpret=False, precision=None):
-    return (_pairwise_contract_pallas_bx(h, w3, b3, basis, x, interpret,
-                                         precision),
-            (h, w3, b3, basis, x))
-
-
-def _pc_bx_bwd(interpret, precision, res, g):
-    # V2 materializes only here, in the backward; the forward never wrote
-    # it to HBM. Reuses the fused backward kernel, then folds its dV2
-    # cotangent back through the basis contraction (dbasis feeds
-    # coordinate gradients when differentiable_coors is on).
-    from ..kernels.pallas_pairwise import fused_pairwise_conv_bwd
-    h, w3, b3, basis, x = res
-    E, P, Q, F = basis.shape
-    C = x.shape[1]
-    # conv_bf16 residuals arrive bf16 (that's the remat/HBM saving);
-    # gradient math runs f32 on the exactly-upcast quantized values
-    with named_scope('basis_contract'):
-        b32, x32 = basis.astype(jnp.float32), x.astype(jnp.float32)
-        v2 = jnp.einsum('epqf,ecq->epcf', b32, x32,
-                        precision=precision).reshape(E, P, C * F)
-    dh, dw3, dv2, db3 = fused_pairwise_conv_bwd(h, w3, v2, g, b3=b3,
-                                                interpret=interpret,
-                                                precision=precision)
-    with named_scope('basis_contract'):
-        dv2 = dv2.reshape(E, P, C, F)
-        dx = jnp.einsum('epqf,epcf->ecq', b32, dv2, precision=precision)
-        dbasis = jnp.einsum('ecq,epcf->epqf', x32, dv2,
-                            precision=precision)
-    return (dh.astype(h.dtype), dw3.astype(w3.dtype), db3.astype(b3.dtype),
-            dbasis.astype(basis.dtype), dx.astype(x.dtype))
-
-
-_pairwise_contract_pallas_bx.defvjp(_pc_bx_fwd, _pc_bx_bwd)
-
-
 @partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
 def _pairwise_contract_pallas_bxf(h, w3, b3, basis_flat, x, pqf,
                                   interpret=False, precision=None):
@@ -378,10 +333,16 @@ _pairwise_contract_pallas_bxf.defvjp(_pc_bxf_fwd, _pc_bxf_bwd)
 def unflatten_basis(basis_flat: jnp.ndarray, P: int, Q: int,
                     F: int) -> jnp.ndarray:
     """[..., P*F*Q] (p, f, q)-ordered flat basis -> [..., P, Q, F]
-    structured form (for the non-kernel paths that consume the
-    reference-shaped layout)."""
+    structured form (what the V2 einsum consumes)."""
     b = basis_flat.reshape(*basis_flat.shape[:-1], P, F, Q)
     return jnp.swapaxes(b, -1, -2)
+
+
+def flatten_basis(basis: jnp.ndarray) -> jnp.ndarray:
+    """unflatten_basis' inverse: [..., P, Q, F] -> [..., P*F*Q] in
+    (p, f, q) order (what the basis-fused kernels consume)."""
+    b = jnp.swapaxes(basis, -1, -2)
+    return b.reshape(*b.shape[:-3], -1)
 
 
 def _basis_is_flat(basis: jnp.ndarray, x: jnp.ndarray) -> bool:
@@ -389,6 +350,78 @@ def _basis_is_flat(basis: jnp.ndarray, x: jnp.ndarray) -> bool:
     axis than the neighbor features x [..., C, Q]; the structured form
     has one more."""
     return basis.ndim == x.ndim - 1
+
+
+def basis_layout(fuse_basis: bool, pallas: Optional[bool],
+                 pallas_interpret: bool) -> str:
+    """The get_basis layout contract_pair consumes without a relayout:
+    flat where it takes the basis-fused kernels, structured elsewhere."""
+    return 'pfq_flat' if fuse_basis and _use_pallas(
+        pallas, pallas_interpret) else 'pqf'
+
+
+def contract_pair(h: jnp.ndarray, w3, b3: jnp.ndarray,
+                  basis_pair: jnp.ndarray, x: jnp.ndarray,
+                  pqf: Tuple[int, int, int], *, pallas: Optional[bool],
+                  pallas_interpret: bool, edge_chunks: Optional[int],
+                  fuse_basis: bool, group: bool = False):
+    """The dense contraction of one degree pair, and the one place that
+    chooses its route: h [b,n,k,mid], w3 [mid,C*F,O], b3 [C*F,O],
+    basis_pair [b,n,k,P,Q,F] or flat [b,n,k,P*F*Q], x [b,n,k,C,Q],
+    pqf = (P, Q, F) -> (out [b,n,k,P,O], None).
+
+    fuse_basis on the Pallas path takes the basis-fused kernels (V2
+    exists only in VMEM, forward and backward; kernels.pallas_pairwise,
+    fused_pairwise_conv_bxf) on a flat basis; every other case builds
+    V2 = basis . x with an einsum on a structured one and hands it to
+    _radial_contract (the V2-given kernel, or XLA). A basis that arrives
+    in the other layout is relaid here. With `group` (ConvSE3's shared
+    radial trunk, which contracts all pairs of an output degree in one
+    V2-given launch) the second route returns (None, V2 [b,n,k,P,C*F])
+    for the caller to concatenate."""
+    P, Q, F = pqf
+    C, O = x.shape[-2], w3.shape[-1]
+    flat = _basis_is_flat(basis_pair, x)
+    if not (fuse_basis and _use_pallas(pallas, pallas_interpret)):
+        if flat:
+            basis_pair = unflatten_basis(basis_pair, P, Q, F)
+        # V2[..., P, (i, f)] = sum_Q B[..., P, Q, f] x[..., i, Q]
+        with named_scope('basis_contract'):
+            v2 = jnp.einsum('...pqf,...cq->...pcf', basis_pair, x)
+            v2 = v2.reshape(*v2.shape[:-2], C * F)
+        if group:
+            return None, v2
+        return _radial_contract(h, w3, b3, v2, pallas=pallas,
+                                pallas_interpret=pallas_interpret,
+                                edge_chunks=edge_chunks), None
+
+    if not flat:
+        basis_pair = flatten_basis(basis_pair)
+    if isinstance(w3, QuantTensor):
+        # quantized weights: dequantize as a TRANSIENT inside the traced
+        # program (a weight-sized temp, tiny next to the edge tensors
+        # this route streams); the param-tree argument is still the int8
+        # storage. The V2-given kernel gets the true in-tile epilogue.
+        w3 = w3.dequant()
+    # bias un-folded: separate [S, 1] kernel operand (see _radial_contract)
+    w3c = w3.astype(h.dtype)
+    prec = jax.config.jax_default_matmul_precision
+
+    def contract(h_c, basis_c, x_c):
+        lead_c = h_c.shape[:-1]
+        E = 1
+        for s in lead_c:
+            E *= s
+        out = _pairwise_contract_pallas_bxf(
+            h_c.reshape(E, h_c.shape[-1]), w3c, b3,
+            basis_c.reshape(E, P * F * Q), x_c.reshape(E, C, Q), pqf,
+            pallas_interpret, prec)
+        return out.reshape(*lead_c, P, O)
+
+    if edge_chunks is None:
+        return contract(h, basis_pair, x), None
+    return _stream_node_chunks(contract, (h, basis_pair, x),
+                               edge_chunks), None
 
 
 class PairwiseConvSE3(nn.Module):
@@ -411,19 +444,13 @@ class PairwiseConvSE3(nn.Module):
     # c_out * F) for huge configs (e.g. dim-512 flagship). None = off.
     edge_chunks: Optional[int] = None
     # contract the angular basis inside the Pallas kernels so the V2
-    # intermediate never touches HBM: forward, and with a flat basis
-    # backward too (a structured basis' backward materializes it once).
-    # Requires the Pallas path; ignored otherwise.
+    # intermediate never touches HBM, forward or backward. Requires the
+    # Pallas path; ignored otherwise.
     fuse_basis: bool = False
     # run the radial trunk + radial matmul in bf16 (MXU-native): its
     # inputs are rotation-invariant, so this preserves equivariance to
     # ~1e-6 unlike a global bf16 policy (see radial_hidden docstring)
     radial_bf16: bool = False
-    # store the EQUIVARIANT kernel operands (V2 / basis / gathered
-    # features) bf16: halves the dominant HBM streams of the
-    # bandwidth-bound contraction, at ~1e-3 equivariance cost (the
-    # quantized tensors rotate). Opt-in perf knob; see _radial_contract.
-    conv_bf16: bool = False
     # False = reference-ordered unfused path through RadialFunc (per-edge
     # [c_out, c_in, F] kernel tensors, reference :326-343); the numerics
     # oracle for the fused paths above. Param layout differs.
@@ -443,7 +470,8 @@ class PairwiseConvSE3(nn.Module):
     @nn.compact
     def __call__(self, edge_feats: jnp.ndarray, basis_slice: jnp.ndarray,
                  x: jnp.ndarray) -> jnp.ndarray:
-        """edge_feats [b,n,k,e]; basis_slice [b,n,k,P,Q,F] (dense) or the
+        """edge_feats [b,n,k,e]; basis_slice [b,n,k,P,Q,F] or flat
+        [b,n,k,P*F*Q] (dense; fused=False takes the first only) or the
         backend's payload (e.g. the so2 edge-frame dict); x
         [b,n,k,c_in,Q] -> [b,n,k,c_out,P]. (With a shared radial trunk,
         ConvSE3 fuses all pairs of an output degree itself and never
@@ -476,16 +504,7 @@ class PairwiseConvSE3(nn.Module):
                         d_in=self.degree_in, d_out=self.degree_out,
                         pallas=self.pallas,
                         pallas_interpret=self.pallas_interpret,
-                        edge_chunks=self.edge_chunks,
-                        conv_bf16=self.conv_bf16, **extra)
-
-        use_bx = self.fuse_basis and _use_pallas(self.pallas,
-                                                 self.pallas_interpret)
-        if _basis_is_flat(basis_slice, x) and not use_bx:
-            # a flat-layout basis reached a path that consumes the
-            # structured reference shape (e.g. fuse_basis on a CPU run
-            # without interpret mode)
-            basis_slice = unflatten_basis(basis_slice, P, Q, F)
+                        edge_chunks=self.edge_chunks, **extra)
 
         if not self.fused:
             with named_scope('radial'):
@@ -508,50 +527,26 @@ class PairwiseConvSE3(nn.Module):
         b3 = self.param('b3', nn.initializers.zeros, (IF, self.nc_out),
                         jnp.float32)
 
-        if use_bx:
-            out = _radial_contract_bx(
-                h, w3, b3, basis_slice, x,
-                pallas_interpret=self.pallas_interpret,
-                edge_chunks=self.edge_chunks, pqf=(P, Q, F),
-                conv_bf16=self.conv_bf16)
-            return jnp.swapaxes(out, -1, -2)  # [..., c_out, P]
-
-        # V2[..., P, (i, f)] = sum_Q B[..., P, Q, f] x[..., i, Q]
-        with named_scope('basis_contract'):
-            v2 = jnp.einsum('...pqf,...cq->...pcf', basis_slice, x)
-            v2 = v2.reshape(*v2.shape[:-2], IF)  # [..., P, c_in*F]
-
-        out = _radial_contract(h, w3, b3, v2, pallas=self.pallas,
+        out, _ = contract_pair(h, w3, b3, basis_slice, x, (P, Q, F),
+                               pallas=self.pallas,
                                pallas_interpret=self.pallas_interpret,
                                edge_chunks=self.edge_chunks,
-                               conv_bf16=self.conv_bf16)
+                               fuse_basis=self.fuse_basis)
         return jnp.swapaxes(out, -1, -2)  # [..., c_out, P]
 
 
 def _radial_contract(h: jnp.ndarray, w3: jnp.ndarray, b3: jnp.ndarray,
                      v2: jnp.ndarray, *, pallas: Optional[bool],
                      pallas_interpret: bool,
-                     edge_chunks: Optional[int],
-                     conv_bf16: bool = False) -> jnp.ndarray:
+                     edge_chunks: Optional[int]) -> jnp.ndarray:
     """Dispatch the fused radial-matmul x basis contraction:
     h [b,n,k,mid], w3 [mid,IF,O], b3 [IF,O], v2 [b,n,k,P,IF]
     -> [b,n,k,P,O] via the Pallas kernel / XLA einsums, optionally
     streaming the node axis in `edge_chunks` remat'd chunks (memory
     ceiling for huge channel counts: peak extra memory is one chunk's
-    R — XLA path — or just the kernel's VMEM tiles — Pallas path).
-
-    conv_bf16 stores the V2 operand bf16 — HALF the dominant HBM stream
-    (the program is bandwidth-bound, scripts/flop_audit.py) — while the
-    apply math stays f32 on the quantized values. Unlike radial_bf16
-    (invariant inputs, ~1e-6 equivariance cost) this quantizes an
-    EQUIVARIANT tensor: expect ~1e-3-level equivariance error, the same
-    class as a global bf16 matmul policy. Opt-in accordingly."""
+    R — XLA path — or just the kernel's VMEM tiles — Pallas path)."""
     P, IF = v2.shape[-2], v2.shape[-1]
     O = w3.shape[-1]
-    if conv_bf16:
-        # cast BEFORE the chunk-streaming split so the streamed HBM
-        # operand (and the remat residual) is already half-width
-        v2 = v2.astype(jnp.bfloat16)
 
     if _use_pallas(pallas, pallas_interpret):
         # The bias rides as its own [S, 1] kernel operand — folding it
@@ -621,68 +616,6 @@ def _radial_contract(h: jnp.ndarray, w3: jnp.ndarray, b3: jnp.ndarray,
     return _stream_node_chunks(contract, (h, v2), edge_chunks)
 
 
-def _radial_contract_bx(h: jnp.ndarray, w3: jnp.ndarray, b3: jnp.ndarray,
-                        basis: jnp.ndarray, x: jnp.ndarray, *,
-                        pallas_interpret: bool,
-                        edge_chunks: Optional[int],
-                        pqf: Optional[Tuple[int, int, int]] = None,
-                        conv_bf16: bool = False) -> jnp.ndarray:
-    """Basis-fused dispatch (Pallas only): h [b,n,k,mid], w3 [mid,C*F,O],
-    b3 [C*F,O], basis [b,n,k,P,Q,F] (or [b,n,k,P*F*Q] flat when it came
-    from get_basis(layout='pfq_flat') — pqf supplies (P, Q, F) then),
-    x [b,n,k,C,Q] -> [b,n,k,P,O]. Same contraction as _radial_contract
-    on V2 = basis . x, but V2 never exists outside kernel VMEM (see
-    kernels.pallas_pairwise, bx/bxf variants).
-
-    conv_bf16 stores the basis and gathered-feature operands bf16 (half
-    the kernel's biggest HBM streams; math stays f32 on the quantized
-    values — see _radial_contract's tradeoff note)."""
-    if isinstance(w3, QuantTensor):
-        # fuse_basis + quantized weights: dequantize as a TRANSIENT
-        # inside the traced program (a weight-sized temp, tiny next to
-        # the edge tensors this path streams) so the bx/bxf custom_vjp
-        # plumbing stays untouched; the param-tree argument is still
-        # the int8 storage. The plain-kernel path above gets the true
-        # in-tile epilogue.
-        w3 = w3.dequant()
-    flat = _basis_is_flat(basis, x)
-    if flat:
-        assert pqf is not None, 'flat basis needs explicit (P, Q, F)'
-        P, Q, F = pqf
-    else:
-        P, Q, F = basis.shape[-3:]
-    C = x.shape[-2]
-    O = w3.shape[-1]
-    if conv_bf16:
-        # before the chunk split: the streamed operands and the custom-vjp
-        # residuals are then half-width too
-        basis = basis.astype(jnp.bfloat16)
-        x = x.astype(jnp.bfloat16)
-    # bias un-folded: separate [S, 1] kernel operand (see _radial_contract)
-    w3c = w3.astype(h.dtype)
-    prec = jax.config.jax_default_matmul_precision
-
-    def contract(h_c, basis_c, x_c):
-        lead_c = h_c.shape[:-1]
-        E = 1
-        for s in lead_c:
-            E *= s
-        h2 = h_c.reshape(E, h_c.shape[-1])
-        if flat:
-            out = _pairwise_contract_pallas_bxf(
-                h2, w3c, b3, basis_c.reshape(E, P * F * Q),
-                x_c.reshape(E, C, Q), (P, Q, F), pallas_interpret, prec)
-        else:
-            out = _pairwise_contract_pallas_bx(
-                h2, w3c, b3, basis_c.reshape(E, P, Q, F),
-                x_c.reshape(E, C, Q), pallas_interpret, prec)
-        return out.reshape(*lead_c, P, O)
-
-    if edge_chunks is None:
-        return contract(h, basis, x)
-    return _stream_node_chunks(contract, (h, basis, x), edge_chunks)
-
-
 def pairwise_conv_contract(R: jnp.ndarray, B: jnp.ndarray,
                            x: jnp.ndarray) -> jnp.ndarray:
     """Reference-ordered fused contraction for one degree pair (kept for
@@ -711,7 +644,6 @@ class ConvSE3(nn.Module):
     shared_radial_hidden: bool = False
     fuse_basis: bool = False
     radial_bf16: bool = False
-    conv_bf16: bool = False
     # contraction backend for every degree pair of this layer
     # (CONV_BACKENDS; per-layer selection happens in the model via
     # resolve_conv_backend). Non-dense backends read their payload from
@@ -864,8 +796,6 @@ class ConvSE3(nn.Module):
                     edge_features, DEFAULT_MID_DIM,
                     dtype=jnp.bfloat16 if self.radial_bf16 else None)
 
-        fuse_bx = self.fuse_basis and _use_pallas(self.pallas,
-                                                  self.pallas_interpret)
         backend_impl = get_conv_backend(self.backend) \
             if self.backend != 'dense' else None
         so2_hoist = self.backend == 'so2'
@@ -911,8 +841,7 @@ class ConvSE3(nn.Module):
                         jnp.concatenate(z_segs, axis=-1),
                         pallas=self.pallas,
                         pallas_interpret=self.pallas_interpret,
-                        edge_chunks=self.edge_chunks,
-                        conv_bf16=self.conv_bf16)        # [..., P, O]
+                        edge_chunks=self.edge_chunks)    # [..., P, O]
                 acc = rotate_out(jnp.swapaxes(acc, -1, -2), so2_frames,
                                  degree_out)             # [..., O, P]
             elif so2_hoist:
@@ -925,7 +854,6 @@ class ConvSE3(nn.Module):
                         edge_chunks=self.edge_chunks,
                         fuse_basis=self.fuse_basis,
                         radial_bf16=self.radial_bf16,
-                        conv_bf16=self.conv_bf16,
                         backend=self.backend,
                         so2_edge_frame_io=True,
                         name=f'pair_{degree_in}_{degree_out}')(
@@ -938,44 +866,35 @@ class ConvSE3(nn.Module):
                 # only in (w3, b3, v2), all concatenable along the
                 # contracted IF axis: ONE fused contraction (one Pallas
                 # launch / one big MXU matmul) per output degree instead of
-                # one per degree pair. With fuse_basis the heterogeneous
-                # (Q, F) segments can't share a chunk axis, so it's one
-                # basis-fused launch per pair instead (same params).
+                # one per degree pair. On the basis-fused route the
+                # heterogeneous (Q, F) segments can't share a chunk axis,
+                # so it's one launch per pair instead (same params).
                 v2s, w3s, b3s = [], [], []
                 acc = None
                 for degree_in, m_in in self.fiber_in:
-                    F = to_order(min(degree_in, degree_out))
-                    P = to_order(degree_out)
-                    Q = to_order(degree_in)
-                    IF = m_in * F
                     w3, b3 = self._grouped_pair_params(
                         degree_in, degree_out, hidden.shape[-1], m_in,
                         m_out)
-                    basis_pair = basis[f'{degree_in},{degree_out}']
                     # the degree pair is a component of the op's path,
                     # not of the kernel's name (MODEL_SCOPES: `pair`)
                     with named_scope(f'pair_{degree_in}_{degree_out}'):
-                        if fuse_bx:
-                            y = _radial_contract_bx(
-                                hidden, w3, b3, basis_pair,
-                                gathered[str(degree_in)],
-                                pallas_interpret=self.pallas_interpret,
-                                edge_chunks=self.edge_chunks,
-                                pqf=(P, Q, F), conv_bf16=self.conv_bf16)
-                            acc = y if acc is None else acc + y
-                            continue
-                        if _basis_is_flat(basis_pair,
-                                          gathered[str(degree_in)]):
-                            basis_pair = unflatten_basis(basis_pair, P, Q,
-                                                         F)
-                        with named_scope('basis_contract'):
-                            v2 = jnp.einsum('...pqf,...cq->...pcf',
-                                            basis_pair,
-                                            gathered[str(degree_in)])
-                            v2s.append(v2.reshape(*v2.shape[:-2], IF))
-                    w3s.append(w3)
-                    b3s.append(b3)
-                if not fuse_bx:
+                        y, v2 = contract_pair(
+                            hidden, w3, b3,
+                            basis[f'{degree_in},{degree_out}'],
+                            gathered[str(degree_in)],
+                            (to_order(degree_out), to_order(degree_in),
+                             to_order(min(degree_in, degree_out))),
+                            pallas=self.pallas,
+                            pallas_interpret=self.pallas_interpret,
+                            edge_chunks=self.edge_chunks,
+                            fuse_basis=self.fuse_basis, group=True)
+                    if y is None:
+                        v2s.append(v2)
+                        w3s.append(w3)
+                        b3s.append(b3)
+                    else:
+                        acc = y if acc is None else acc + y
+                if v2s:
                     with named_scope(f'pair_all_{degree_out}'):
                         acc = _radial_contract(
                             hidden, concat_weights(w3s, axis=1),
@@ -983,8 +902,7 @@ class ConvSE3(nn.Module):
                             jnp.concatenate(v2s, axis=-1),
                             pallas=self.pallas,
                             pallas_interpret=self.pallas_interpret,
-                            edge_chunks=self.edge_chunks,
-                            conv_bf16=self.conv_bf16)
+                            edge_chunks=self.edge_chunks)
                 acc = jnp.swapaxes(acc, -1, -2)  # [..., c_out, P]
             else:
                 acc = None
@@ -999,7 +917,6 @@ class ConvSE3(nn.Module):
                         edge_chunks=self.edge_chunks,
                         fuse_basis=self.fuse_basis,
                         radial_bf16=self.radial_bf16,
-                        conv_bf16=self.conv_bf16,
                         backend=self.backend,
                         name=f'pair_{degree_in}_{degree_out}')(
                             edge_features,

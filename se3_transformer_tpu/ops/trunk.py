@@ -78,7 +78,6 @@ class SequentialTrunk(nn.Module):
     fuse_basis: bool = False
     pallas_interpret: bool = False
     radial_bf16: bool = False
-    conv_bf16: bool = False
     # per-block conv backends for the attention value/key ConvSE3 paths
     # (resolved by the model from its conv_backend spec; None = dense
     # everywhere — ops.conv.CONV_BACKENDS)
@@ -133,7 +132,6 @@ class SequentialTrunk(nn.Module):
                 edge_chunks=self.edge_chunks,
                 fuse_basis=self.fuse_basis,
                 radial_bf16=self.radial_bf16,
-                conv_bf16=self.conv_bf16,
                 pallas_interpret=self.pallas_interpret,
                 fuse_pairwise=(self.fused_attention[i]
                                if self.fused_attention else False),

@@ -16,8 +16,8 @@ contract [..., c_out, P] — through the eSCN factorization:
   3. radial      out_rot = _radial_contract(h, w3, b3, z)
                  — EXACTLY the dense path's fused radial matmul (z is
                  shape-identical to the dense V2), so the Pallas 'plain'
-                 kernel, conv_bf16 storage cast, and the PR 4 tuning
-                 table all apply to the so2 backend unchanged;
+                 kernel and the PR 4 tuning table apply to the so2
+                 backend unchanged;
   4. rotate-out  out = D_out out_rot      (frames.rotate_out)
 
 Tuning: the node-axis streaming of steps 1-4 is registered as kernel
@@ -115,7 +115,6 @@ def so2_pair_contract(h: jnp.ndarray, w3: jnp.ndarray, b3: jnp.ndarray,
                       d_out: int, pallas: Optional[bool],
                       pallas_interpret: bool,
                       edge_chunks: Optional[int],
-                      conv_bf16: bool = False,
                       edge_frame_io: bool = False) -> jnp.ndarray:
     """One (d_in -> d_out) pairwise contraction via the SO(2) reduction:
     h [b, n, k, mid], w3 [mid, C*F, O], b3 [C*F, O], x [b, n, k, C, Q]
@@ -157,8 +156,7 @@ def so2_pair_contract(h: jnp.ndarray, w3: jnp.ndarray, b3: jnp.ndarray,
         z = banded_z(xr, d_in, d_out, pad_rows=False)
         out_rot = _radial_contract(h_c, w3, b3, z, pallas=pallas,
                                    pallas_interpret=pallas_interpret,
-                                   edge_chunks=None,
-                                   conv_bf16=conv_bf16)  # [..., B, O]
+                                   edge_chunks=None)     # [..., B, O]
         out = jnp.swapaxes(out_rot, -1, -2)              # [..., O, B]
         if d_out > mmin:
             pad = [(0, 0)] * out.ndim

@@ -30,8 +30,8 @@ Spine reuse, per the family contract:
   * rotate-in / rotate-out come from so2/frames (hoisted once per
     input/output degree like ConvSE3's so2 branch);
   * the per-m apply is the existing ops.conv._radial_contract — the
-    Pallas 'plain' kernel, QuantTensor fused dequant, conv_bf16 cast
-    and node-axis streaming all serve v2 unchanged;
+    Pallas 'plain' kernel, QuantTensor fused dequant and node-axis
+    streaming all serve v2 unchanged;
   * node-axis chunking consults the SAME 'so2' tuning kind
     (so2.contract._pick_so2_chunks), so scripts/tune_kernels.py owns
     the knob for both families;
@@ -100,7 +100,6 @@ class V2ConvSE3(nn.Module):
     pallas_interpret: bool = False
     edge_chunks: Optional[int] = None
     radial_bf16: bool = False
-    conv_bf16: bool = False
 
     def _per_m_params(self, m: int, degree_in: int, degree_out: int,
                       mid: int, m_in: int, m_out: int):
@@ -201,8 +200,7 @@ class V2ConvSE3(nn.Module):
                     jnp.concatenate(bms, axis=0), v2_m,
                     pallas=self.pallas,
                     pallas_interpret=self.pallas_interpret,
-                    edge_chunks=chunks,
-                    conv_bf16=self.conv_bf16)      # [..., rows, O]
+                    edge_chunks=chunks)            # [..., rows, O]
                 if m == 0:
                     center = out_m[..., 0, :]
                 else:

@@ -80,7 +80,6 @@ class SE3TransformerV2Module(nn.Module):
     pallas_interpret: bool = False
     edge_chunks: Optional[int] = None
     radial_bf16: bool = False
-    conv_bf16: bool = False
 
     # the checkpoint/capability family stamp (training/checkpoint.py
     # guards restores on it; serving surfaces it)
@@ -159,8 +158,7 @@ class SE3TransformerV2Module(nn.Module):
             mid_dim=self.mid_dim, max_m=self.max_m,
             edge_dim=(edges.shape[-1] if edges is not None else 0),
             pallas=self.pallas, pallas_interpret=self.pallas_interpret,
-            edge_chunks=self.edge_chunks, radial_bf16=self.radial_bf16,
-            conv_bf16=self.conv_bf16)
+            edge_chunks=self.edge_chunks, radial_bf16=self.radial_bf16)
 
         with named_scope('conv_in'):
             x = V2ConvSE3(fiber_in, fiber_hidden, name='conv_in',

@@ -65,7 +65,6 @@ _HEAVY_TESTS = {
     'test_model_flat_basis_matches_structured',
     'test_recipe_forward_and_grad',
     'test_differentiable_coors_with_full_fast_path',
-    'test_conv_bf16_model_paths_agree_and_train',
     'test_hidden_and_out_fiber_dicts',
     'test_ring_sparse_bonded_beyond_radius_stay_valid',
     'test_convse3_fuse_basis_group_path',
@@ -86,7 +85,6 @@ _HEAVY_TESTS = {
     'test_edge_chunks_matches_default',
     'test_model_fuse_basis_matches_base',
     'test_fused_kernels_shape_fuzz',
-    'test_conv_bf16_equivariance_cost_bounded',
     'test_model_with_fused_attention_matches_einsum_path',
     'test_ring_sparse_jitter_parity_over_cap',
     'test_pallas_kernels_partition_under_pjit',
@@ -152,7 +150,7 @@ _HEAVY_TESTS = {
 # The kernels call `def_partition` with the Shardy arguments of the one
 # installed jax (0.9.0); the list has not been re-measured on it. The
 # fast kernel-LEVEL numerics tests (~45 s total:
-# fwd/bwd/bx/attention oracles, picker pins, conv_bf16 oracle) and
+# fwd/bwd/bxf/attention oracles, picker pins, the contract_pair door) and
 # tests/test_kernel_tuning.py stay tier-1.
 _SLOW_TESTS = {
     # test_pallas: model-level interpret programs
@@ -166,12 +164,9 @@ _SLOW_TESTS = {
     'test_shared_radial_group_path',
     'test_pairwise_conv_fuse_basis_matches_xla',
     'test_convse3_fuse_basis_group_path',
-    'test_bxf_kernel_matches_bx',
     'test_model_flat_basis_matches_structured',
     'test_model_fuse_basis_matches_base',
-    'test_fuse_basis_composes_with_edge_chunks_and_bf16',
-    'test_conv_bf16_model_paths_agree_and_train',
-    'test_conv_bf16_equivariance_cost_bounded',
+    'test_fuse_basis_composes_with_edge_chunks_and_radial_bf16',
     # test_ring: every test drives the ring collective model path
     'test_ring_knn_exact',
     'test_ring_knn_radius_semantics',

@@ -436,18 +436,6 @@ def test_model_fused_so2_equivariance_degree6():
     assert eq < 1e-4, f'so2-arm fused equivariance {eq} at degree 6'
 
 
-def test_fused_rejects_inapplicable_conv_bf16():
-    """conv_bf16 has no materialized operand to quantize on the fused
-    path — it must raise, not silently no-op while bench labels claim
-    it (the trunk.py remat_policy precedent)."""
-    feats, coors, mask = _model_inputs()
-    bad = SE3TransformerModule(fuse_pairwise=True, conv_bf16=True,
-                               **_MODEL_KW)
-    with pytest.raises(AssertionError, match='conv_bf16'):
-        bad.init(jax.random.PRNGKey(0), feats, coors, mask=mask,
-                 return_type=1)
-
-
 def test_flash_record_schema_roundtrip():
     from se3_transformer_tpu.observability.schema import (
         SchemaError, validate_record,

@@ -3,7 +3,7 @@
 pick functions (_pick_blocks / _pick_blocks_bx / _pick_block_n).
 
 Load-bearing contracts (ISSUE 4 acceptance):
-  * with no cache file and no overrides, every pick is BIT-IDENTICAL to
+  * with no cache file, every pick is BIT-IDENTICAL to
     the heuristic (the production-validated flagship picks are pinned);
   * a promoted entry round-trips persistence and demonstrably changes
     the pick, and the consult is logged for telemetry;
@@ -12,7 +12,7 @@ Load-bearing contracts (ISSUE 4 acceptance):
     rejected with a warning, never handed to Mosaic;
   * candidate enumeration is bwd-aware and excludes the configs the
     round-4 standalone sweep measured as Mosaic VMEM compile failures
-    (bx (256,16)/(512,16), bxf (512,16)).
+    (bxf (256,16)/(512,16)).
 
 Everything runs on CPU; the end-to-end check uses interpreter-mode
 kernels at tiny shapes.
@@ -41,8 +41,6 @@ def isolated_cache(tmp_path, monkeypatch):
     """Every test gets an empty cache dir and a clean consult log
     (tuning reads SE3_TPU_CACHE_PATH per call, unlike basis.py)."""
     monkeypatch.setenv('SE3_TPU_CACHE_PATH', str(tmp_path))
-    for var in ('SE3_TPU_BLOCK_E', 'SE3_TPU_BLOCK_IF', 'SE3_TPU_BLOCK_CB'):
-        monkeypatch.delenv(var, raising=False)
     tuning.reset_consults()
     yield tmp_path
 
@@ -123,16 +121,11 @@ def test_attention_bwd_invalid_entry_degrades_with_warning():
         assert _pick_block_n(*ATT_FLAGSHIP, bwd=True) == 64
 
 
-def test_bx_and_bxf_are_distinct_kinds():
-    tuning.promote('bxf', BX_FLAGSHIP, (256, 8))
-    assert _pick_blocks_bx(*BX_FLAGSHIP, kind='bxf') == (256, 8)
-    assert _pick_blocks_bx(*BX_FLAGSHIP, kind='bx') == (128, 8)
-
-
 def test_dtype_and_device_key_the_entry():
     tuning.promote('plain', PLAIN_CHUNKED, (256, 16), dtype='bfloat16')
     assert _pick_blocks(*PLAIN_CHUNKED) == (512, 16)  # f32 pick untouched
-    assert _pick_blocks(*PLAIN_CHUNKED, dtype='bfloat16') == (256, 16)
+    assert tuning.lookup('plain', PLAIN_CHUNKED, dtype='bfloat16') == (
+        (256, 16), 'cache')
     tuning.promote('plain', PLAIN_FLAGSHIP, (256, 16),
                    device_kind='TPU v5e')
     assert _pick_blocks(*PLAIN_FLAGSHIP) == (512, 16)  # we are 'cpu'
@@ -189,15 +182,6 @@ def test_vmem_illegal_entry_rejected_with_warning():
         assert _pick_blocks(*PLAIN_FLAGSHIP) == (512, 16)
 
 
-def test_env_override_beats_cache(monkeypatch):
-    tuning.promote('plain', PLAIN_CHUNKED, (256, 16))
-    monkeypatch.setenv('SE3_TPU_BLOCK_E', '128')
-    monkeypatch.setenv('SE3_TPU_BLOCK_IF', '8')
-    assert _pick_blocks(*PLAIN_CHUNKED) == (128, 8)
-    consults = tuning.consults()
-    assert consults[-1]['source'] == 'env'
-
-
 def test_forced_candidate_beats_cache():
     tuning.promote('plain', PLAIN_CHUNKED, (256, 16))
     with tuning.force('plain', (256, 32)):
@@ -215,18 +199,17 @@ def test_shape_pinned_force_does_not_leak_to_other_shapes():
                       dtype='float32'):
         assert _pick_blocks(*PLAIN_CHUNKED) == (256, 32)
         assert _pick_blocks(*PLAIN_FLAGSHIP) == (512, 16)  # heuristic
-        assert _pick_blocks(*PLAIN_CHUNKED, dtype='bfloat16') == (512, 16)
+        assert tuning.lookup('plain', PLAIN_CHUNKED,
+                             dtype='bfloat16') is None
     assert _pick_blocks(*PLAIN_CHUNKED) == (512, 16)
 
 
 def test_admissible_candidates_exclude_measured_mosaic_failures():
     # the round-4 sweep's Mosaic VMEM compile failures must be
     # excluded up front
-    bx = tuning.admissible_candidates('bx', BX_FLAGSHIP)
-    assert (256, 16) not in bx and (512, 16) not in bx
-    assert (128, 8) in bx  # the production-validated default
     bxf = tuning.admissible_candidates('bxf', BX_FLAGSHIP)
-    assert (512, 16) not in bxf
+    assert (256, 16) not in bxf and (512, 16) not in bxf
+    assert (128, 8) in bxf  # the production-validated default
     plain = tuning.admissible_candidates('plain', PLAIN_FLAGSHIP)
     assert (512, 16) in plain  # the measured end-to-end winner
     assert all(be % 128 == 0 and bif % 8 == 0 for be, bif in plain)
@@ -282,7 +265,7 @@ def test_seeded_entry_is_numerically_inert_end_to_end():
 
 def test_promote_is_read_modify_write():
     tuning.promote('plain', PLAIN_CHUNKED, (256, 16))
-    tuning.promote('bx', BX_FLAGSHIP, (256, 8))
+    tuning.promote('bxf', BX_FLAGSHIP, (256, 8))
     tuning.promote('plain', PLAIN_CHUNKED, (512, 8))  # overwrite by key
     ents = tuning.entries()
     assert len(ents) == 2

@@ -290,3 +290,20 @@ def test_sparse_neighbor_noise_rng_threading():
     k2 = {'neighbor_noise': jax.random.PRNGKey(2)}
     assert np.array_equal(apply(rngs=k1), apply(rngs=k1))
     assert not np.array_equal(apply(rngs=k1), apply(rngs=k2))
+
+
+def test_module_field_count_and_the_benchmark_configs_keys():
+    """The model's option count is pinned (ROADMAP D6 counts it in every
+    PR that touches the class), and no field a benchmark configuration
+    passes as a constructor argument may go with a deleted option."""
+    import json
+    import pathlib
+    from se3_transformer_tpu import SE3TransformerModule
+
+    # flax adds `parent` and `name` to every module's annotations
+    fields = set(SE3TransformerModule.__annotations__) - {'parent', 'name'}
+    assert len(fields) == 61, sorted(fields)
+    assert 'conv_bf16' not in fields
+    cfg = json.loads((pathlib.Path(__file__).parent.parent / 'benchmark'
+                      / 'configs' / 'd4-onehead-train.json').read_text())
+    assert set(cfg['overrides']) <= fields, set(cfg['overrides']) - fields

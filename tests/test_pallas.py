@@ -4,6 +4,8 @@ Runs the kernel in interpreter mode on CPU (tests/conftest.py forces the
 CPU backend); the same comparison runs on real TPU hardware via
 scripts/tpu_checks.py.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -457,9 +459,12 @@ def test_shared_radial_group_path():
     (257, 24, 16, 7, 7, 8, 7),
 ])
 def test_fused_bx_kernel_matches_einsum(shape):
+    """A structured basis, flattened and contracted in the kernel, equals
+    the einsum."""
     from se3_transformer_tpu.kernels.pallas_pairwise import (
-        fused_pairwise_conv_bx,
+        fused_pairwise_conv_bxf,
     )
+    from se3_transformer_tpu.ops.conv import flatten_basis
     E, mid, C, Q, F, O, P = shape
     rng = np.random.RandomState(sum(shape))
     h = jnp.asarray(rng.normal(size=(E, mid)), jnp.float32)
@@ -468,12 +473,126 @@ def test_fused_bx_kernel_matches_einsum(shape):
     basis = jnp.asarray(rng.normal(size=(E, P, Q, F)), jnp.float32)
     x = jnp.asarray(rng.normal(size=(E, C, Q)), jnp.float32)
 
-    out = fused_pairwise_conv_bx(h, w3, basis, x, b3=b3, interpret=True)
+    out = fused_pairwise_conv_bxf(h, w3, flatten_basis(basis), x, (P, Q, F),
+                                  b3=b3, interpret=True)
     v2 = jnp.einsum('epqf,ecq->epcf', basis, x).reshape(E, P, C * F)
     R = jnp.einsum('em,mko->eko', h, w3) + b3
     ref = jnp.einsum('epk,eko->epo', v2, R)
     scale = float(jnp.abs(ref).max()) + 1e-9
     assert jnp.abs(out - ref).max() / scale < 1e-5, shape
+
+
+# the one door of the dense contraction: ops.conv.contract_pair at the
+# (2, 1) pair, (P, Q, F) = (3, 5, 3), so a (q, f) mix-up in either
+# relayout cannot pass
+_PAIR_PQF = (3, 5, 3)
+
+
+def _pair_operands(seed, differentiable=False):
+    from se3_transformer_tpu.ops.conv import flatten_basis
+    rng = np.random.RandomState(seed)
+    (P, Q, F), (b, n, k, mid, C, O) = _PAIR_PQF, (1, 6, 3, 8, 4, 5)
+    f32 = lambda *shape: jnp.asarray(rng.normal(size=shape),  # noqa: E731
+                                     jnp.float32)
+    rel = f32(b, n, k, 3)
+    basis = get_basis(rel, 2, differentiable=differentiable)['2,1']
+    return dict(h=f32(b, n, k, mid), w3=f32(mid, C * F, O), b3=f32(C * F, O),
+                x=f32(b, n, k, C, Q), rel=rel, structured=basis,
+                flat=flatten_basis(basis))
+
+
+def _pair_einsum(h, w3, b3, basis, x):
+    v2 = jnp.einsum('...pqf,...cq->...pcf', basis, x)
+    v2 = v2.reshape(*v2.shape[:-2], -1)
+    R = jnp.einsum('...m,mko->...ko', h, w3) + b3
+    return jnp.einsum('...pk,...ko->...po', v2, R)
+
+
+@pytest.mark.parametrize('route', ['interpret', 'xla'])
+@pytest.mark.parametrize('fuse_basis', [True, False],
+                         ids=['fused', 'unfused'])
+@pytest.mark.parametrize('layout', ['flat', 'structured'])
+def test_contract_pair_every_route_matches_einsum(layout, fuse_basis, route):
+    """Whatever layout the basis arrives in, contract_pair takes the
+    basis-fused kernels exactly when fuse_basis meets the Pallas path,
+    the V2-given kernel on the Pallas path without it, XLA otherwise,
+    and every combination is the float32 einsum."""
+    from se3_transformer_tpu.ops.conv import contract_pair
+    ops = _pair_operands(5)
+    interpret = route == 'interpret'
+
+    def run(basis):
+        out, v2 = contract_pair(
+            ops['h'], ops['w3'], ops['b3'], basis, ops['x'], _PAIR_PQF,
+            pallas=False, pallas_interpret=interpret, edge_chunks=None,
+            fuse_basis=fuse_basis)
+        assert v2 is None
+        return out
+
+    launches = set(re.findall(r'fused_pairwise_conv\w*',
+                              str(jax.make_jaxpr(run)(ops[layout]))))
+    want = set() if not interpret else \
+        {'fused_pairwise_conv_bxf'} if fuse_basis else {'fused_pairwise_conv'}
+    assert launches == want, launches
+    ref = _pair_einsum(ops['h'], ops['w3'], ops['b3'], ops['structured'],
+                       ops['x'])
+    assert _rel(run(ops[layout]), ref) < 1e-5
+
+
+@pytest.mark.parametrize('differentiable', [False, True])
+def test_contract_pair_structured_fused_gradients(differentiable):
+    """A structured basis on the fused route: every cotangent comes out of
+    the basis-fused backward kernels, dbasis through the flatten's own
+    transpose, and equals jax.grad of the einsum form (down to the
+    coordinates the basis was built from, when it is differentiable)."""
+    from se3_transformer_tpu.ops.conv import contract_pair
+    ops = _pair_operands(6)
+
+    def loss(contract):
+        def f(h, w3, b3, x, rel):
+            basis = get_basis(rel, 2, differentiable=differentiable)['2,1']
+            return (contract(h, w3, b3, basis, x) ** 2).sum()
+        return f
+
+    def fused(h, w3, b3, basis, x):
+        return contract_pair(h, w3, b3, basis, x, _PAIR_PQF, pallas=False,
+                             pallas_interpret=True, edge_chunks=None,
+                             fuse_basis=True)[0]
+
+    args = tuple(ops[k] for k in ('h', 'w3', 'b3', 'x', 'rel'))
+    text = str(jax.make_jaxpr(jax.grad(loss(fused), argnums=(0, 1, 2, 3, 4)))(
+        *args))
+    assert set(re.findall(r'fused_pairwise_conv\w*', text)) == {
+        'fused_pairwise_conv_bxf', 'fused_pairwise_conv_bwd_bxf',
+        'fused_pairwise_conv_bwd_a', 'fused_pairwise_conv_bwd_b'}
+    got = jax.grad(loss(fused), argnums=(0, 1, 2, 3, 4))(*args)
+    want = jax.grad(loss(_pair_einsum), argnums=(0, 1, 2, 3, 4))(*args)
+    for name, a, b in zip(('dh', 'dw3', 'db3', 'dx', 'drel'), got, want):
+        if name == 'drel' and not differentiable:
+            assert not np.asarray(a).any() and not np.asarray(b).any()
+        else:
+            assert _rel(a, b) < 2e-5, (name, _rel(a, b))
+
+
+def test_contract_pair_group_returns_the_v2_segment():
+    """ConvSE3's shared trunk asks with group=True: the fused route still
+    answers with the pair's output, the V2-given routes with the V2
+    segment to concatenate, from either layout."""
+    from se3_transformer_tpu.ops.conv import contract_pair
+    ops = _pair_operands(7)
+    kw = dict(pallas=False, edge_chunks=None, group=True)
+    v2_ref = jnp.einsum('...pqf,...cq->...pcf', ops['structured'], ops['x'])
+    v2_ref = v2_ref.reshape(*v2_ref.shape[:-2], -1)
+    for layout in ('flat', 'structured'):
+        args = (ops['h'], ops['w3'], ops['b3'], ops[layout], ops['x'],
+                _PAIR_PQF)
+        out, v2 = contract_pair(*args, pallas_interpret=True,
+                                fuse_basis=True, **kw)
+        assert v2 is None and out.shape == (1, 6, 3, 3, 5)
+        for interpret, fuse in ((True, False), (False, True)):
+            out, v2 = contract_pair(*args, pallas_interpret=interpret,
+                                    fuse_basis=fuse, **kw)
+            assert out is None and _rel(v2, v2_ref) < 1e-6
 
 
 @pytest.mark.parametrize('d_in,d_out', [(0, 1), (1, 1), (2, 1), (1, 2)])
@@ -630,10 +749,9 @@ def test_bxf_backward_pick_fits_and_is_recorded():
     assert pp._pick_blocks_bxf_bwd(32768, 64, 24, 7, 7, 7, 128) == (512, 8)
     assert pp._pick_blocks_bxf_bwd(32768, 64, 24, 7, 1, 1, 128) == (512, 64)
     assert pp._pick_blocks_bxf_bwd(32768, 64, 64, 7, 7, 7, 128) == (128, 8)
-    # bfloat16-stored features tile 16 channels to a sublane block; a
-    # budget nothing fits gives the smallest legal blocks, not a loop
-    assert pp._pick_blocks_bxf_bwd(300, 16, 8, 7, 7, 7, 16, 'bfloat16',
-                                   vmem_budget=1) == (128, 16)
+    # a budget nothing fits gives the smallest legal blocks, not a loop
+    assert pp._pick_blocks_bxf_bwd(300, 16, 8, 7, 7, 7, 16,
+                                   vmem_budget=1) == (128, 8)
 
 
 @pytest.mark.parametrize('differentiable_coors', [False, True])
@@ -701,9 +819,9 @@ def test_convse3_bxf_gradients_match_xla(differentiable_coors):
 
 def test_flat_basis_layout_equivalence():
     """get_basis(layout='pfq_flat') holds exactly the structured values,
-    (p, f, q)-ordered; unflatten_basis round-trips to the reference
-    [P, Q, F] shape."""
-    from se3_transformer_tpu.ops.conv import unflatten_basis
+    (p, f, q)-ordered; unflatten_basis gives the reference [P, Q, F]
+    shape back and flatten_basis is its inverse, both ways round."""
+    from se3_transformer_tpu.ops.conv import flatten_basis, unflatten_basis
 
     rng = np.random.RandomState(3)
     rel = jnp.asarray(rng.normal(size=(2, 6, 4, 3)), jnp.float32)
@@ -717,49 +835,13 @@ def test_flat_basis_layout_equivalence():
             F = 2 * min(d_in, d_out) + 1
             assert flat[key].shape == (2, 6, 4, P * F * Q)
             back = unflatten_basis(flat[key], P, Q, F)
-            assert np.abs(np.asarray(back)
-                          - np.asarray(structured[key])).max() == 0.0
-
-
-def test_bxf_kernel_matches_bx():
-    """Flat-basis kernel (bxf) == structured bx, values and gradients
-    through every operand including the basis (differentiable_coors
-    path)."""
-    from se3_transformer_tpu.kernels.pallas_pairwise import (
-        fused_pairwise_conv_bx, fused_pairwise_conv_bxf,
-    )
-    rng = np.random.RandomState(7)
-    E, mid, C, O = 24, 9, 5, 6
-    P, Q, F = 5, 3, 3
-    h = jnp.asarray(rng.normal(size=(E, mid)), jnp.float32)
-    w3 = jnp.asarray(rng.normal(size=(mid, C * F, O)), jnp.float32)
-    b3 = jnp.asarray(rng.normal(size=(C * F, O)), jnp.float32)
-    basis = jnp.asarray(rng.normal(size=(E, P, Q, F)), jnp.float32)
-    x = jnp.asarray(rng.normal(size=(E, C, Q)), jnp.float32)
-    flat = jnp.swapaxes(basis, -1, -2).reshape(E, P * F * Q)
-
-    out_bx = fused_pairwise_conv_bx(h, w3, basis, x, b3=b3, interpret=True)
-    out_bxf = fused_pairwise_conv_bxf(h, w3, flat, x, (P, Q, F), b3=b3,
-                                      interpret=True)
-    assert np.abs(np.asarray(out_bx) - np.asarray(out_bxf)).max() < 1e-5
-
-    # gradients through the custom_vjp wrappers used by the conv
-    from se3_transformer_tpu.ops.conv import (
-        _pairwise_contract_pallas_bx, _pairwise_contract_pallas_bxf,
-    )
-    loss_bx = lambda h, bb, b, x: (_pairwise_contract_pallas_bx(  # noqa: E731
-        h, w3, bb, b, x, True, None) ** 2).sum()
-    loss_bxf = lambda h, bb, b, x: (_pairwise_contract_pallas_bxf(  # noqa: E731,E501
-        h, w3, bb, b, x, (P, Q, F), True, None) ** 2).sum()
-    g_bx = jax.grad(loss_bx, argnums=(0, 1, 2, 3))(h, b3, basis, x)
-    g_bxf = list(jax.grad(loss_bxf, argnums=(0, 1, 2, 3))(h, b3, flat, x))
-    g_bxf[2] = jnp.swapaxes(
-        g_bxf[2].reshape(E, P, F, Q), -1, -2)  # (p,f,q) -> (p,q,f)
-    # the two backwards sum in different orders (bx: einsums around the
-    # plain kernels; bxf: the basis-fused kernels), so to float32
-    # rounding of gradients that reach 5e2, not to an absolute 1e-4
-    for a, b in zip(g_bx, g_bxf):
-        assert _rel(b, a) < 1e-5
+            assert np.array_equal(np.asarray(back),
+                                  np.asarray(structured[key]))
+            assert np.array_equal(np.asarray(flatten_basis(back)),
+                                  np.asarray(flat[key]))
+            assert np.array_equal(
+                np.asarray(flatten_basis(structured[key])),
+                np.asarray(flat[key]))
 
 
 def test_model_flat_basis_matches_structured():
@@ -823,7 +905,7 @@ def test_model_fuse_basis_matches_base():
         assert np.abs(np.asarray(o1) - np.asarray(o2)).max() < 2e-5, shared
 
 
-def test_fuse_basis_composes_with_edge_chunks_and_bf16():
+def test_fuse_basis_composes_with_edge_chunks_and_radial_bf16():
     """All three conv perf knobs at once (basis-fused kernel, node-axis
     streaming, bf16 radial): matches the plain XLA path, grads finite."""
     rng = np.random.RandomState(17)
@@ -883,124 +965,3 @@ def test_pairwise_block_picker_production_validated_picks():
     assert _pick_blocks_bx(32768, 64, 64, 7, 7, 7, 128) == (128, 8)
     # tiny shapes keep the full-axis fast path
     assert _pick_blocks(128, 16, 8, 3, 32) == (128, 16)
-
-
-# --------------------------------------------------------------------- #
-# conv_bf16: bf16 STORAGE of the equivariant kernel operands
-# --------------------------------------------------------------------- #
-
-
-def test_conv_bf16_kernel_quantized_oracle():
-    """bf16 V2/basis/x operands: the kernel upcasts rows after the VMEM
-    load, so the result must EXACTLY equal the f32 kernel run on the
-    quantize-then-upcast operands (same math, half the storage)."""
-    from se3_transformer_tpu.kernels.pallas_pairwise import (
-        fused_pairwise_conv_bxf,
-    )
-    rng = np.random.RandomState(3)
-    E, mid, I, F, O, P = 40, 16, 4, 3, 10, 7
-    C, Q = 4, 5
-    h = jnp.asarray(rng.normal(size=(E, mid)), jnp.float32)
-    w3 = jnp.asarray(rng.normal(size=(mid, I * F, O)), jnp.float32)
-    b3 = jnp.asarray(rng.normal(size=(I * F, O)), jnp.float32)
-    v2 = jnp.asarray(rng.normal(size=(E, P, I * F)), jnp.float32)
-    v2_q = v2.astype(jnp.bfloat16)
-
-    out_bf16 = fused_pairwise_conv(h, w3, v2_q, b3=b3, interpret=True)
-    out_oracle = fused_pairwise_conv(h, w3, v2_q.astype(jnp.float32),
-                                     b3=b3, interpret=True)
-    assert np.array_equal(np.asarray(out_bf16), np.asarray(out_oracle))
-    # and the quantization error vs full precision is bf16-sized, not junk
-    out_f32 = fused_pairwise_conv(h, w3, v2, b3=b3, interpret=True)
-    rel = np.abs(np.asarray(out_bf16 - out_f32)).max() \
-        / np.abs(np.asarray(out_f32)).max()
-    assert 0 < rel < 3e-2, rel
-
-    w3x = jnp.asarray(rng.normal(size=(mid, C * F, O)), jnp.float32)
-    b3x = jnp.asarray(rng.normal(size=(C * F, O)), jnp.float32)
-    basis = jnp.asarray(rng.normal(size=(E, P, F, Q)), jnp.float32)
-    flat = basis.reshape(E, P * F * Q)
-    x = jnp.asarray(rng.normal(size=(E, C, Q)), jnp.float32)
-    fq, xq = flat.astype(jnp.bfloat16), x.astype(jnp.bfloat16)
-    out_bf16 = fused_pairwise_conv_bxf(h, w3x, fq, xq, (P, Q, F), b3=b3x,
-                                       interpret=True)
-    out_oracle = fused_pairwise_conv_bxf(
-        h, w3x, fq.astype(jnp.float32), xq.astype(jnp.float32),
-        (P, Q, F), b3=b3x, interpret=True)
-    assert np.array_equal(np.asarray(out_bf16), np.asarray(out_oracle))
-
-
-def test_conv_bf16_model_paths_agree_and_train():
-    """Model-level conv_bf16: Pallas-interpret and XLA dispatch compute
-    the same quantize-then-f32 semantics; output stays close to the f32
-    model; gradients are finite through both custom-vjp backwards."""
-    from se3_transformer_tpu import SE3TransformerModule
-
-    rng = np.random.RandomState(19)
-    feats = jnp.asarray(rng.normal(size=(1, 12, 8)), jnp.float32)
-    coors = jnp.asarray(rng.normal(size=(1, 12, 3)) * 2, jnp.float32)
-    mask = jnp.ones((1, 12), bool)
-
-    def build(**kw):
-        return SE3TransformerModule(
-            dim=8, depth=1, num_degrees=3, num_neighbors=6, heads=2,
-            dim_head=4, input_degrees=1, output_degrees=2,
-            reduce_dim_out=True, differentiable_coors=True, **kw)
-
-    base = build()
-    params = base.init(jax.random.PRNGKey(0), feats, coors, mask=mask,
-                       return_type=1)['params']
-    out_f32 = base.apply({'params': params}, feats, coors, mask=mask,
-                         return_type=1)
-
-    m_pallas = build(conv_bf16=True, pallas_interpret=True, pallas=True)
-    m_xla = build(conv_bf16=True, pallas=False)
-    out_p = m_pallas.apply({'params': params}, feats, coors, mask=mask,
-                           return_type=1)
-    out_x = m_xla.apply({'params': params}, feats, coors, mask=mask,
-                        return_type=1)
-    # identical quantization point, f32 math both sides: tight agreement
-    assert np.abs(np.asarray(out_p - out_x)).max() < 1e-4
-    # bf16-sized deviation from the f32 model, not garbage
-    denom = np.abs(np.asarray(out_f32)).max()
-    rel = np.abs(np.asarray(out_p - out_f32)).max() / denom
-    assert 0 < rel < 5e-2, rel
-
-    def loss(p, module):
-        out = module.apply({'params': p}, feats, coors, mask=mask,
-                           return_type=1)
-        return (out ** 2).sum()
-
-    for module in (m_pallas, m_xla):
-        g = jax.grad(loss)(params, module)
-        leaves = jax.tree_util.tree_leaves(g)
-        assert all(bool(jnp.isfinite(leaf).all()) for leaf in leaves)
-        assert any(float(jnp.abs(leaf).max()) > 0 for leaf in leaves)
-
-
-def test_conv_bf16_equivariance_cost_bounded():
-    """conv_bf16 quantizes equivariant tensors, so its equivariance error
-    is ~bf16-sized — orders above the f32 paths' ~1e-6 but bounded. The
-    documented tradeoff (ops/conv.py): this test pins the magnitude so a
-    regression to garbage (or a silent no-op of the flag) is caught."""
-    from se3_transformer_tpu import SE3TransformerModule
-    from se3_transformer_tpu.utils.validation import equivariance_l2
-
-    rng = np.random.RandomState(23)
-    feats = jnp.asarray(rng.normal(size=(1, 16, 8)), jnp.float32)
-    coors = jnp.asarray(rng.normal(size=(1, 16, 3)) * 2, jnp.float32)
-    mask = jnp.ones((1, 16), bool)
-    kw = dict(dim=8, depth=1, num_degrees=3, num_neighbors=6, heads=2,
-              dim_head=4, input_degrees=1, output_degrees=2,
-              reduce_dim_out=True, differentiable_coors=True)
-    base = SE3TransformerModule(**kw)
-    params = base.init(jax.random.PRNGKey(1), feats, coors, mask=mask,
-                       return_type=1)['params']
-    err_base = equivariance_l2(base, params, feats, coors, mask)
-    m = SE3TransformerModule(conv_bf16=True, pallas_interpret=True,
-                             pallas=True, **kw)
-    err_bf16 = equivariance_l2(m, params, feats, coors, mask)
-    assert err_base < 1e-4
-    assert err_bf16 < 5e-2
-    # the flag must actually quantize (a silent no-op would match f32)
-    assert err_bf16 > err_base

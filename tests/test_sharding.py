@@ -327,7 +327,7 @@ def test_pallas_kernels_partition_under_pjit():
     from jax.sharding import NamedSharding, PartitionSpec as P
     from se3_transformer_tpu.kernels.pallas_pairwise import (
         fused_pairwise_conv, fused_pairwise_conv_bwd,
-        fused_pairwise_conv_bx,
+        fused_pairwise_conv_bxf,
     )
 
     mesh = make_mesh(sp=8)
@@ -383,14 +383,15 @@ def test_pallas_kernels_partition_under_pjit():
         assert rel(a, b) < 1e-5
 
     # basis-fused forward, edge-sharded
-    bas0 = jnp.asarray(rng.normal(size=(E, Pp, Q, F)), jnp.float32)
+    bas0 = jnp.asarray(rng.normal(size=(E, Pp * F * Q)), jnp.float32)
     x0 = jnp.asarray(rng.normal(size=(E, C, Q)), jnp.float32)
     w3b0 = jnp.asarray(rng.normal(size=(mid, C * F, O)), jnp.float32)
-    ref2 = fused_pairwise_conv_bx(h0, w3b0, bas0, x0, interpret=True)
+    ref2 = fused_pairwise_conv_bxf(h0, w3b0, bas0, x0, (Pp, Q, F),
+                                   interpret=True)
     args = [jax.device_put(a, NamedSharding(mesh, s)) for a, s in
             [(h0, P('sp')), (w3b0, P()), (bas0, P('sp')), (x0, P('sp'))]]
-    fn2 = jax.jit(lambda h, w, b, x: fused_pairwise_conv_bx(
-        h, w, b, x, interpret=True))
+    fn2 = jax.jit(lambda h, w, b, x: fused_pairwise_conv_bxf(
+        h, w, b, x, (Pp, Q, F), interpret=True))
     out2 = fn2(*args)
     assert 'sp' in str(out2.sharding.spec)
     hlo2 = fn2.lower(*args).compile().as_text()

@@ -254,7 +254,7 @@ def test_so2_chunk_streaming_matches_unchunked():
     frames = edge_frames(jnp.asarray(rng.normal(size=(b, n, k, 3)), F32),
                          max(d_in, d_out))
     kwargs = dict(d_in=d_in, d_out=d_out, pallas=False,
-                  pallas_interpret=False, conv_bf16=False)
+                  pallas_interpret=False)
     ref = so2_pair_contract(h, w3, b3, frames, x, edge_chunks=None,
                             **kwargs)
     chunked = so2_pair_contract(h, w3, b3, frames, x, edge_chunks=3,

@@ -36,7 +36,7 @@ from se3_transformer_tpu.kernels.pallas_attention import (  # noqa: E402
 )
 from se3_transformer_tpu.kernels.pallas_pairwise import (  # noqa: E402
     fused_pairwise_conv, fused_pairwise_conv_bwd, fused_pairwise_conv_bwd_bxf,
-    fused_pairwise_conv_bx, fused_pairwise_conv_bxf,
+    fused_pairwise_conv_bxf,
 )
 
 # the flagship shape tuples (tests/test_kernel_tuning.py pins the block
@@ -110,17 +110,6 @@ def test_pairwise_backward_compiles(v5e, rdt, p, n_if, o):
         ((E, MID), rdt), ((MID, n_if, o), rdt), ((E, p, n_if), f32),
         ((E, p, o), f32), ((n_if, o), f32))
     assert calls == 2  # kernel A (dV2, dW3, dB3) and kernel B (dH)
-
-
-@RADIAL
-def test_pairwise_bx_compiles(v5e, rdt):
-    calls = compile_for(
-        v5e,
-        lambda h, w3, basis, x, b3: fused_pairwise_conv_bx(
-            h, w3, basis, x, b3),
-        ((E, MID), rdt), ((MID, C * F, O), rdt), ((E, P, Q, F), f32),
-        ((E, C, Q), f32), ((C * F, O), f32))
-    assert calls > 0
 
 
 @RADIAL

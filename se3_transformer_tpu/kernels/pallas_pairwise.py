@@ -995,15 +995,31 @@ def fused_pairwise_conv_bwd(h: jnp.ndarray, w3: jnp.ndarray,
 # outside: A writes dV2 as before and the wrapper reduces it against x in
 # XLA, which drops both when nothing asks for the basis' cotangent.
 #
-# Every loop is ROLLED but the P x F (or Q) body inside it, so the traced
-# kernel does not grow with cb. What a loop indexes is a leading, untiled
-# axis, one sublane row, or a tile-aligned row offset:
+# Every loop is ROLLED but the P (or Q) body inside it and one pass of
+# at most _O_PER_PASS o's, so the traced kernel does not grow with cb, and
+# with O only up to that constant. What a loop indexes is a leading,
+# untiled axis, one sublane row, or a tile-aligned row or lane offset:
 #   bt  [P*F, Q, E]    B, one [Q, E_b] slab per (p, f)
 #   xt  [Q, C, E]      gathered features; dxt the same
 #   dv2 [P*F, C, E]    A's output; the V2 scratch is one block of this
-#   R, dR scratches [cb*F*O, E_b], rows of i = (c, f) at i*O, with O
-#                      padded to a multiple of 8 by the wrapper (zero
-#                      rows of w3, b3 and g)
+#   gt  [P*O, E]       the cotangent, one row per (p, o), O padded to a
+#                      multiple of 8 by the wrapper (zero rows of w3, b3
+#                      and g), so that every row offset below is a tile's
+#   B's dR scratch [cb*F*O, E_b], rows of i = (c, f) at i*O: one V2 row
+#                      times a [O, E_b] slab of g per (p, i), and the
+#                      columns of w3f3 in w3's own order
+#   A's R, dR scratches [F*(cb/8)*O*8, E_b], rows ordered (f, t, o, c)
+#                      over the chunk's tiles t of 8 channels, c fastest:
+#                      the 8 channels of one (f, t, o) are ONE sublane
+#                      tile, as V2 and dv2 hold them for one (p, f). So
+#                      dV2's sum over o accumulates ACROSS tiles, a row of
+#                      g broadcast over the channels, with no sublane
+#                      reduction and no one-row store, and dR is built
+#                      from whole V2 tiles. w3t, b3t, dw3 and db3 take
+#                      that order chunk by chunk (_stack_rows,
+#                      _unstack_rows). B has no sum over o and gains
+#                      nothing from the order (PERF.md, PR 30), so it
+#                      keeps its own
 
 
 def _contract_over_q(bt_ref, xt_ref, v2_ref, Q):
@@ -1029,8 +1045,128 @@ def _row_chunks(S, rows=512):
     return [slice(s0, min(S, s0 + rows)) for s0 in range(0, S, rows)]
 
 
+def _stack_rows(a, cb, F):
+    """[Cp*F, O, ...], rows i = c*F + f, to the kernels' stacks
+    [n_c, F*O*cb, ...]: chunk n holds channels n*cb.., its rows ordered
+    (f, t, o, c) over the chunk's tiles t of 8 channels, c fastest."""
+    O = a.shape[1]
+    a = a.reshape(-1, cb // 8, 8, F, O, *a.shape[2:])
+    return jnp.moveaxis(a, (3, 1, 4, 2), (1, 2, 3, 4)).reshape(
+        a.shape[0], F * O * cb, *a.shape[5:])
+
+
+def _unstack_rows(s, cb, F, O):
+    """The inverse of _stack_rows: [n_c, F*O*cb, ...] to [Cp*F, O, ...]."""
+    s = s.reshape(s.shape[0], F, cb // 8, O, 8, *s.shape[2:])
+    return jnp.moveaxis(s, (1, 2, 3, 4), (3, 1, 4, 2)).reshape(
+        -1, O, *s.shape[5:])
+
+
+# o's per pass of kernel A's rolled o loop, written out in the pass: the
+# cell's O = 24 is one pass and its O = 64 two. Alone on the chip 8 a pass
+# cost the (3,3) launch 7% over 24 or 32 (the pass's first loads are not
+# hidden), and the traced body grows by 5 P lines an o (PERF.md, PR 30)
+_O_PER_PASS = 32
+
+
 def _dr_rows(c, f, F, O):
     return pl.ds(pl.multiple_of((c * F + f) * O, 8), O)
+
+
+def _stack_dv2_dr(gt_ref, v2_ref, r_ref, dr_ref, dv2_ref, *, P, O, F, cb):
+    """Kernel A's vector work over its stacks, one tile of 8 channels and
+    128 lanes (up to 512 where P is small) at a time:
+      dR[(f, t, o)]   = sum_p V2[(p, f), t] g[p, o]
+      dV2[(p, f), t]  = sum_o g[p, o] R[(f, t, o)]
+    the second accumulated over the same o loop in P registers and stored
+    once. A row of g reaches the tile's 8 channels by a stride-0 load:
+    the one form of a single-row load the compiler spreads over sublanes
+    at no vector operation, and it wants a static row, so the rolled o
+    loop steps ONE view of gt by whole tiles and the rows inside it are
+    Python's. Each g row is used for both products where it is loaded
+    and each dR tile stored where it is finished, so that nothing but
+    V2[p] and dV2[p] lives across an o."""
+    U = min(_O_PER_PASS, O)
+    n_t = cb // 8
+    # lanes of one pass: the P tiles of V2 and the P of dV2 it carries
+    # stay within a quarter of the 64 vector registers, and where P is
+    # small the wider tile gives its one chain of adds others to overlap
+    n_l = dr_ref.shape[1] // 128
+    lw = 128 * max(k for k in (1, 2, 4) if n_l % k == 0
+                   and (k == 1 or k * P <= 8))
+
+    def tile(lanes, t, f):
+        ch = pl.ds(pl.multiple_of(t * 8, 8), 8)
+        v2 = [v2_ref[p * F + f, ch, lanes] for p in range(P)]
+
+        def some(o0, n, dv2):
+            """o0 .. o0 + n of this tile; dv2 [p] so far"""
+            dv2 = list(dv2)
+            base = ((f * n_t + t) * O + o0) * 8
+            # one view for all P (o0 is a multiple of 8): row p*O + u of
+            # it is g[p, o0 + u]
+            g = gt_ref.at[pl.ds(pl.multiple_of(o0, 8), (P - 1) * O + n),
+                          lanes]
+            for u in range(n):
+                rows = pl.ds(pl.multiple_of(base + u * 8, 8), 8)
+                r = r_ref[rows, lanes]
+                dr = None
+                for p in range(P):
+                    gb = g[p * O + u:p * O + u + 1, :]
+                    term = v2[p] * gb
+                    dr = term if p == 0 else dr + term
+                    dv2[p] = dv2[p] + gb * r
+                dr_ref[rows, lanes] = dr
+            return tuple(dv2)
+
+        dv2 = (jnp.zeros((8, lw), jnp.float32),) * P
+        dv2 = jax.lax.fori_loop(0, O // U,
+                                lambda ou, dv2: some(ou * U, U, dv2), dv2)
+        if O % U:
+            dv2 = some(O - O % U, O % U, dv2)
+        for p in range(P):
+            dv2_ref[p * F + f, ch, lanes] = dv2[p]
+
+    def lane_group(l, carry):
+        lanes = pl.ds(pl.multiple_of(l * lw, 128), lw)
+
+        def tiles(t, carry):
+            def fs(f, carry):
+                tile(lanes, t, f)
+                return carry
+            return jax.lax.fori_loop(0, F, fs, carry)
+        return jax.lax.fori_loop(0, n_t, tiles, carry)
+
+    jax.lax.fori_loop(0, dr_ref.shape[1] // lw, lane_group, 0)
+
+
+def _dx_from_dv2(bt_ref, dv2_ref, dx_ref, *, PF, Q, cb):
+    """dx[q, c] = sum_(p, f) B[(p, f), q] dV2[(p, f), c]: the Q sums of a
+    group of channel tiles carried in registers over ONE rolled loop of
+    (p, f), so that dV2 is read once and a pass has Q products to overlap
+    (Q loops of one product each ran at the latency of a load, a multiply
+    and an add: 11 bundles for 8 vector operations, PERF.md, PR 30)."""
+    n_t = cb // 8
+    # tiles per group: Q accumulators of k tiles within half the registers
+    k = max(d for d in range(1, n_t + 1)
+            if n_t % d == 0
+            and d * (Q + 1) * (dx_ref.shape[2] // 128) <= 32)
+
+    def group(tg, carry):
+        ch = pl.ds(pl.multiple_of(tg * (8 * k), 8), 8 * k)
+
+        def add(pf, accs):
+            d = dv2_ref[pf, ch, :]
+            return tuple(acc + bt_ref[pf, q:q + 1, :] * d
+                         for q, acc in enumerate(accs))
+
+        zero = jnp.zeros((8 * k, dx_ref.shape[2]), jnp.float32)
+        accs = jax.lax.fori_loop(0, PF, add, (zero,) * Q)
+        for q in range(Q):
+            dx_ref[q, ch, :] = accs[q]
+        return carry
+
+    jax.lax.fori_loop(0, n_t // k, group, 0)
 
 
 def _bwd_bxf_a_kernel(ht_ref, h_ref, w3t_ref, b3t_ref, bt_ref, xt_ref,
@@ -1039,7 +1175,7 @@ def _bwd_bxf_a_kernel(ht_ref, h_ref, w3t_ref, b3t_ref, bt_ref, xt_ref,
                       mxu_dtype):
     e = pl.program_id(1)
     hb = ht_ref[:]
-    # R of the whole c-chunk, bias included (dV2 = g . R): [cb*F*O, E_b]
+    # R of the whole c-chunk, bias included (dV2 = g . R): [F*O*cb, E_b]
     for rows in _row_chunks(r_ref.shape[0]):
         r_ref[rows, :] = jax.lax.dot_general(
             w3t_ref[rows, :], hb,
@@ -1047,32 +1183,10 @@ def _bwd_bxf_a_kernel(ht_ref, h_ref, w3t_ref, b3t_ref, bt_ref, xt_ref,
             precision=precision,
             preferred_element_type=jnp.float32) + b3t_ref[rows, :]
     _contract_over_q(bt_ref, xt_ref, v2_ref, Q)
+    _stack_dv2_dr(gt_ref, v2_ref, r_ref, dr_ref, dv2_ref, P=P, O=O, F=F,
+                  cb=cb)
 
-    def channel(c, carry):
-        row = pl.ds(c, 1)
-        for f in range(F):
-            rows = _dr_rows(c, f, F, O)
-            r_i = r_ref[rows, :]                     # [O, E_b]
-            dr_i = None
-            for p in range(P):
-                pf = p * F + f
-                gp = gt_ref[p * O:(p + 1) * O, :]    # [O, E_b]
-                # dV2[(p, f), c] = sum_o g[p, o] R[(c, f), o]
-                dv2_ref[pf, row, :] = jnp.sum(gp * r_i, axis=0,
-                                              keepdims=True)
-                term = v2_ref[pf, row, :] * gp
-                dr_i = term if dr_i is None else dr_i + term
-            dr_ref[rows, :] = dr_i
-        return carry
-
-    jax.lax.fori_loop(0, cb, channel, 0)
-
-    for q in range(Q):
-        def add(pf, acc, q=q):
-            return acc + bt_ref[pf][q:q + 1, :] * dv2_ref[pf]
-
-        dx_ref[q] = jax.lax.fori_loop(
-            0, P * F, add, jnp.zeros(dx_ref.shape[1:], jnp.float32))
+    _dx_from_dv2(bt_ref, dv2_ref, dx_ref, PF=P * F, Q=Q, cb=cb)
 
     hp = h_ref[:]
     # as _bwd_a_kernel: dW3 rows by full-tile dots over the stacked dR,
@@ -1230,10 +1344,11 @@ def _fused_pairwise_conv_bwd_bxf_impl(h, w3, b3, basis, x, g, pqf,
                      ((0, 0), (0, 0), pad_e))
         xt = jnp.pad(x.transpose(2, 1, 0),
                      ((0, 0), (0, Cp - C), pad_e))            # [Q, Cp, Ep]
-        w3f = w3.reshape(mid, Cp * F * Op)
-        w3t = w3f.T                                           # [(c,f,o), mid]
-        b3t = b3.reshape(Cp * F * Op, 1)
-        w3f3 = w3f.reshape(mid, n_c, S).transpose(1, 0, 2)
+        # B's dR stack is rows (c, f, o), A's tiles (f, t, o) of 8 channels
+        w3f3 = w3.reshape(mid, n_c, S).transpose(1, 0, 2)
+        w3t = _stack_rows(w3.transpose(1, 2, 0), cb, F).reshape(
+            n_c * S, mid)
+        b3t = _stack_rows(b3, cb, F).reshape(n_c * S, 1)
 
     def vmem(shape, index_map, **kw):
         return pl.BlockSpec(shape, index_map, memory_space=pltpu.VMEM, **kw)
@@ -1292,9 +1407,9 @@ def _fused_pairwise_conv_bwd_bxf_impl(h, w3, b3, basis, x, g, pqf,
 
     with jax.named_scope('pairwise_layout'):
         dh = dht.T[:E]
-        dw3 = dw3t.reshape(Cp * F, Op, mid).transpose(2, 0, 1)[
-            :, :C * F, :O]
-        db3 = db3t.reshape(Cp * F, Op)[:C * F, :O]
+        dw3 = _unstack_rows(dw3t.reshape(n_c, S, mid), cb, F, Op).transpose(
+            2, 0, 1)[:, :C * F, :O]
+        db3 = _unstack_rows(db3t.reshape(n_c, S), cb, F, Op)[:C * F, :O]
         dx = dxt.transpose(2, 1, 0)[:E, :C]
     with jax.named_scope('basis_contract'):
         # dbasis[(p,f), q, e] = sum_c dV2[(p,f), c, e] x[q, c, e], on the
@@ -1346,8 +1461,10 @@ def fused_pairwise_conv_bwd_bxf(h: jnp.ndarray, w3: jnp.ndarray,
 
     Two launches under the plain backward's names (the roles are the
     same: A gives dW3, dB3, dV2 and here dx too, B gives dH); each builds
-    its V2 block in a VMEM scratch. MXU operands as
-    fused_pairwise_conv_bwd; everything else f32. dbasis is reduced from
+    its V2 block in a VMEM scratch. A's R and dR stacks lie in sublane
+    tiles of 8 channels, rows (f, t, o, c), so that dV2 sums over o
+    across tiles; B's dR in rows (c, f, o), as w3 has them. MXU operands
+    as fused_pairwise_conv_bwd; everything else f32. dbasis is reduced from
     A's dV2 in XLA and costs nothing where its result is unused.
     Partitions like the forward, partial sums reduced in the body."""
     if b3 is None:

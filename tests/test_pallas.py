@@ -679,19 +679,29 @@ def test_convse3_fuse_basis_group_path():
 # ------------------------------------------------------------------ #
 
 @RDT
-@pytest.mark.parametrize('d_in,d_out', [(i, o) for i in range(4)
-                                        for o in range(4)])
-def test_fused_bwd_bxf_kernel_matches_einsum(d_in, d_out, rdt, monkeypatch):
+@pytest.mark.parametrize('d_in,d_out,O,cb', [
+    (i, o, 6, 8) for i in range(4) for o in range(4)] + [
+    # one chunk of 16 channels: two sublane tiles to a row group of the
+    # stack, at P = 1 (F = 1) and at the widest pair
+    (1, 0, 6, 16), (3, 3, 6, 16),
+    # the cell's keys and values: O = 24, nothing padded
+    (2, 3, 24, 8),
+    # kernel A's rolled o loop: two passes of _O_PER_PASS and a shorter one
+    (0, 1, 72, 8),
+])
+def test_fused_bwd_bxf_kernel_matches_einsum(d_in, d_out, O, cb, rdt,
+                                             monkeypatch):
     """Both launches of the basis-fused backward against the einsum VJP,
     at every (P, Q, F) of degrees 0..3: three e-blocks of 128 for 300
     edges and two c-chunks of 8 for 13 channels (the pick is pinned, so
     both accumulations revisit and both axes are padded), O = 6 padded to
-    the sublane tile. dx and dbasis are float32 reductions of dV2 and
-    hold the float32 tolerance under bfloat16 h as well."""
+    the sublane tile. Kernel A's stacks lie in tiles of 8 channels, so a
+    chunk of 16 is two tiles to a row group. dx and dbasis are float32 reductions of dV2
+    and hold the float32 tolerance under bfloat16 h as well."""
     import functools
     from se3_transformer_tpu.kernels import pallas_pairwise as pp
     P, Q, F = 2 * d_out + 1, 2 * d_in + 1, 2 * min(d_in, d_out) + 1
-    E, mid, C, O = 300, 16, 13, 6
+    E, mid, C = 300, 16, 13
     h, w3, b3, _, g = _bwd_case(5 + 4 * d_in + d_out, E, mid, C * F, O, P,
                                 rdt)
     rng = np.random.RandomState(7)
@@ -699,7 +709,7 @@ def test_fused_bwd_bxf_kernel_matches_einsum(d_in, d_out, rdt, monkeypatch):
     x = jnp.asarray(rng.normal(size=(E, C, Q)), jnp.float32)
 
     monkeypatch.setattr(pp, '_pick_blocks_bxf_bwd',
-                        lambda *a, **k: (128, 8))
+                        lambda *a, **k: (128, cb))
     got = jax.jit(functools.partial(
         pp._fused_pairwise_conv_bwd_bxf_impl, pqf=(P, Q, F),
         interpret=True, precision=None))(h, w3, b3, basis, x, g)
@@ -724,6 +734,32 @@ def test_fused_bwd_bxf_kernel_matches_einsum(d_in, d_out, rdt, monkeypatch):
         else:  # see _assert_bwd_matches
             assert _rel(a, q) < 5e-4, (n, _rel(a, q))
             assert _rel(a, b) < 1e-2, (n, _rel(a, b))
+
+
+@pytest.mark.parametrize('F,O,cb', [(1, 6, 8), (3, 5, 8), (7, 24, 16)])
+def test_bxf_stack_order_round_trips(F, O, cb):
+    """The wrapper's stack order for kernel A, w3 -> rows of the R / dR
+    stacks -> dw3: with t = c // 8, row ((f*(cb/8) + t)*O + o)*8 + c % 8
+    of chunk n holds w3[:, (n*cb + c)*F + f, o], so that the 8 channels
+    of one (f, t, o) are one sublane tile, and _unstack_rows puts every
+    row back where it came from (a permutation got wrong is a gradient on
+    the wrong weight, which a norm would not show)."""
+    from se3_transformer_tpu.kernels import pallas_pairwise as pp
+    n_c, mid = 2, 3
+    w3 = jnp.arange(mid * n_c * cb * F * O, dtype=jnp.float32).reshape(
+        mid, n_c * cb * F, O)
+    rows = pp._stack_rows(w3.transpose(1, 2, 0), cb, F)
+    assert rows.shape == (n_c, F * O * cb, mid)
+    got, want = np.asarray(rows), np.asarray(w3)
+    for n, f, o, c in [(0, 0, 0, 0), (1, F - 1, O - 1, cb - 1),
+                       (1, F // 2, 2, 5), (0, F - 1, 1, 7)]:
+        row = ((f * (cb // 8) + c // 8) * O + o) * 8 + c % 8
+        assert (got[n, row] == want[:, (n * cb + c) * F + f, o]).all()
+    back = pp._unstack_rows(rows, cb, F, O).transpose(2, 0, 1)
+    assert back.shape == w3.shape and (np.asarray(back) == want).all()
+    # the bias column takes the same order
+    b3 = w3[0]
+    assert (np.asarray(pp._stack_rows(b3, cb, F)) == got[..., 0]).all()
 
 
 def test_bxf_backward_pick_fits_and_is_recorded():

@@ -171,9 +171,10 @@ def main(argv=None):
         except Exception:
             prior = []
     results = []
-    # the SE(3) recipes: the token decoder takes tokens, and has its own
-    # cell in the benchmark
-    names = args.configs or [n for n in RECIPES if n != 'token_decoder']
+    # the SE(3) recipes: the token decoders take tokens, and have their own
+    # cells in the benchmark
+    names = args.configs or [n for n in RECIPES if n not in (
+        'token_decoder', 'hybrid_decoder')]
     failed = []
 
     def merged():

@@ -60,8 +60,8 @@ from .timing import MODEL_SCOPES, PAIR_SCOPE, profile_trace
 __all__ = [
     'exclusive_durations', 'union_length', 'scope_leaf', 'scope_phase',
     'scope_pair',
-    'kernel_role', 'hlo_op_names', 'newest_xplane', 'xspace_class',
-    'read_xplane',
+    'kernel_role', 'compiler_launch_leaf', 'hlo_op_names', 'newest_xplane',
+    'xspace_class', 'read_xplane',
     'reduce_events', 'reduce_xplane', 'capture_step_profile',
     'profile_payload',
 ]
@@ -74,6 +74,11 @@ HOST_PLANE = '/host:CPU'
 OP_NAME_STAT = 'tf_op'
 PHASES = ('forward', 'backward', 'replay')
 KERNEL_ROLES = re.compile(r'^(fused_|pallas_attention_)')
+# launches the compiler writes itself and gives no path: the TPU's rewrite of
+# `jax.lax.ragged_dot` (ops/expert_layer.py::grouped_dot, under the scope
+# `moe_experts`) into Mosaic calls names them `ragged-dot-none.N` and
+# `ragged-dot-metadata.N` with that name alone as their op_name
+COMPILER_LAUNCH_LEAVES = ((re.compile(r'^ragged-dot'), 'moe_experts'),)
 _INSTRUCTION = re.compile(
     r'^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s.*?metadata=\{[^}]*?op_name="([^"]*)"',
     re.MULTILINE)
@@ -132,6 +137,15 @@ def kernel_role(name: str) -> Optional[str]:
     (`name=` on the pallas_call), or None for any other instruction."""
     fam = family(name)
     return fam if KERNEL_ROLES.match(fam) else None
+
+
+def compiler_launch_leaf(name: str) -> Optional[str]:
+    """The leaf of a launch that the compiler names and gives no path
+    (`COMPILER_LAUNCH_LEAVES`), or None."""
+    for pattern, leaf in COMPILER_LAUNCH_LEAVES:
+        if pattern.match(name):
+            return leaf
+    return None
 
 
 def hlo_op_names(hlo_text: str) -> Dict[str, str]:
@@ -399,7 +413,7 @@ def reduce_events(events: dict, op_names: Optional[Dict[str, str]] = None,
                 _add(kernel_s, role, secs)
                 _add(kernel_pair_s.setdefault(role, {}),
                      scope_pair(op) or 'none', secs)
-            leaf = scope_leaf(op, scopes)
+            leaf = scope_leaf(op, scopes) or compiler_launch_leaf(name)
             if leaf is None:
                 _add(unlabelled, family(name), secs)
                 continue

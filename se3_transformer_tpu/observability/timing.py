@@ -96,6 +96,21 @@ MODEL_SCOPES = (
     #                       two norms, concatenation and projection
     'lm_head',            # training/lm_loss.py: both heads' logits and
     #                       cross-entropies, chunk by chunk
+    # the hybrid decoder (models/hybrid_decoder.py) shares `embed`, `norm`,
+    # the expert layer's leaves and `lm_head`
+    'ssm_in',             # ops/state_space.py: the input projection and its
+    #                       split into z, xBC, dt
+    'ssm_conv',           # ops/state_space.py: the causal depthwise
+    #                       convolution and its activation
+    'ssm_scan',           # ops/state_space.py: from dt, A, x, B, C to y
+    #                       (the chunked scan, D included)
+    'ssm_gate',           # ops/state_space.py: the gated group norm
+    'ssm_out',            # ops/state_space.py: output projection
+    'mha_qkv',            # ops/grouped_attention.py: q, k, v projections,
+    #                       the key-value heads repeated
+    'mha_core',           # ops/grouped_attention.py: scores, softmax,
+    #                       weighted sum (the streaming kernel on a TPU)
+    'mha_out',            # ops/grouped_attention.py: output projection
     'loss',               # parallel/sharding.py train_step: what the
     #                       model's scopes do not claim inside the
     #                       differentiated loss

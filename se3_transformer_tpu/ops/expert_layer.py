@@ -6,6 +6,12 @@
     w_i = scale * s_i / (sum_chosen s + 1e-20)
     out = sum_{i chosen and held here} w_i Expert_i(x) + Shared(x)
 
+An expert, routed or shared, has one of two forms, by `hidden_act` (the name
+a published config gives the choice):
+
+    'silu'    (silu(x Wg) * (x Wu)) Wd     gated, three matrices
+    'relu2'   relu(x Wu)^2 Wd              squared ReLU, two matrices
+
 The router keeps all `n_experts` outputs; this chip holds experts
 `expert_rank * experts_held ...` of them and computes their part of the
 result. What the absent experts would add is left out (the partial result an
@@ -125,6 +131,21 @@ class SwiGLU(nn.Module):
         return dense(x.shape[-1], name='down')(nn.silu(gate) * up)
 
 
+class SquaredReLU(nn.Module):
+    """relu(x Wu)^2 Wd."""
+    width: int
+
+    @nn.compact
+    def __call__(self, x):
+        dense = partial(nn.Dense, use_bias=False)
+        up = dense(self.width, name='up')(x)
+        return dense(x.shape[-1], name='down')(jnp.square(nn.relu(up)))
+
+
+# hidden_act -> (the shared expert's module, whether an expert has a gate)
+EXPERT_FORMS = {'silu': (SwiGLU, True), 'relu2': (SquaredReLU, False)}
+
+
 def route(scores, bias, top_k: int, scale: float, normalize: bool):
     """scores [N, E] in (0, 1), bias [E] -> (chosen [N, k] int32, weights
     [N, k]). The bias moves the choice, never the weights."""
@@ -168,6 +189,7 @@ class ExpertLayer(nn.Module):
     experts_held: int
     expert_rank: int = 0
     shared_width: int = 0      # of the shared expert(s); 0: none
+    hidden_act: str = 'silu'   # the experts' form: a key of EXPERT_FORMS
     routed_scale: float = 1.0
     norm_topk: bool = True
     bf16_operands: bool = True   # of the grouped products (every other
@@ -201,20 +223,24 @@ class ExpertLayer(nn.Module):
             xs = _take_tokens(x, order, inverse)
         with named_scope('moe_experts'):
             dtype = jnp.bfloat16 if self.bf16_operands else None
-            w_gate, w_up = (self.param(f'experts_{name}', _expert_init,
-                                       (held, d, self.width))
-                            for name in ('gate', 'up'))
+            shared, gated = EXPERT_FORMS[self.hidden_act]
+
+            def experts_up(name):
+                return grouped_dot(xs, self.param(
+                    f'experts_{name}', _expert_init, (held, d, self.width)),
+                    load, dtype)
+
+            hidden = nn.silu(experts_up('gate')) * experts_up('up') \
+                if gated else jnp.square(nn.relu(experts_up('up')))
             w_down = self.param('experts_down', _expert_init,
                                 (held, self.width, d))
-            hidden = nn.silu(grouped_dot(xs, w_gate, load, dtype)) \
-                * grouped_dot(xs, w_up, load, dtype)
             ys = grouped_dot(hidden, w_down, load, dtype)
         with named_scope('moe_combine'):
             pairs = _permute_rows(ys, inverse, order).reshape(n, k, d)
             out = jnp.sum(pairs * weights[..., None], axis=1)
         if self.shared_width:
             with named_scope('shared_expert'):
-                out = out + SwiGLU(self.shared_width, name='shared')(x)
+                out = out + shared(self.shared_width, name='shared')(x)
         stats = dict(load=load, chosen=chosen, scores=scores,
                      dropped=jnp.sum(here, dtype=jnp.int32) - jnp.sum(load))
         return out, stats

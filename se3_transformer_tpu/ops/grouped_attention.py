@@ -1,0 +1,48 @@
+"""Grouped-query causal attention without rotation, for a decoder whose
+state-space layers carry position:
+
+    q = u Wq -> `heads` heads of `head_dim`;  k, v = u Wk, u Wv ->
+    `kv_heads` heads, each shared by heads / kv_heads query heads;
+    softmax(q k^T / sqrt(head_dim)), causal, in float32;  out = o Wo
+
+The core is the latent attention's (`ops/latent_attention.py`): JAX's
+streaming Pallas kernel on a TPU, blocks of queries elsewhere. Both take as
+many key-value heads as query heads, so the key-value heads are repeated
+here and autodiff sums their gradients over each group.
+"""
+from __future__ import annotations
+
+from functools import partial
+
+import flax.linen as nn
+import jax.numpy as jnp
+
+from ..observability import named_scope
+from .latent_attention import causal_attention
+
+
+class GroupedQueryAttention(nn.Module):
+    dim: int
+    heads: int
+    kv_heads: int
+    head_dim: int
+    block: int = 512      # of queries (and of keys, in the kernel)
+
+    @nn.compact
+    def __call__(self, x):
+        """x [B, T, dim] -> [B, T, dim]."""
+        b, t, _ = x.shape
+        h, kv, dh = self.heads, self.kv_heads, self.head_dim
+        assert h % kv == 0, (h, kv)
+        dense = partial(nn.Dense, use_bias=False)
+        with named_scope('mha_qkv'):
+            q = dense(h * dh, name='q')(x).reshape(b, t, h, dh)
+            k, v = (jnp.repeat(
+                dense(kv * dh, name=name)(x).reshape(b, t, kv, dh),
+                h // kv, axis=2) for name in ('k', 'v'))
+            q, k, v = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
+        with named_scope('mha_core'):
+            o = causal_attention(q, k, v, dh ** -0.5, self.block)
+        with named_scope('mha_out'):
+            o = o.transpose(0, 2, 1, 3).reshape(b, t, h * dh)
+            return dense(self.dim, name='out')(o)

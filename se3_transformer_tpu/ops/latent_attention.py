@@ -80,6 +80,15 @@ def causal_attention_flash(q, k, v, scale: float, block: int = 512):
     return out.astype(jnp.float32)
 
 
+def causal_attention(q, k, v, scale: float, block: int = 512):
+    """The streaming kernel where it can run (on a TPU, at a length its
+    tiles divide), blocks of queries elsewhere."""
+    core = causal_attention_flash \
+        if is_tpu_backend() and q.shape[2] % 128 == 0 \
+        else causal_attention_blocked
+    return core(q, k, v, scale, block)
+
+
 class LatentAttention(nn.Module):
     dim: int
     heads: int
@@ -119,12 +128,7 @@ class LatentAttention(nn.Module):
             q, k, v = (a.transpose(0, 2, 1, 3) for a in (q, k, kv[..., dn:]))
         scale = (dn + dr) ** -0.5
         with named_scope('latent_core'):
-            # the streaming kernel where it can run: on a TPU, at a length
-            # its tiles divide
-            core = causal_attention_flash \
-                if is_tpu_backend() and t % 128 == 0 \
-                else causal_attention_blocked
-            o = core(q, k, v, scale, self.block)
+            o = causal_attention(q, k, v, scale, self.block)
         with named_scope('latent_out'):
             o = o.transpose(0, 2, 1, 3).reshape(b, t, h * dv)
             return dense(self.dim, name='out')(o)

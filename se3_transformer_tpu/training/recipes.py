@@ -17,14 +17,18 @@ the driver tracks:
   * egnn_stress      — reversible depth-12 EGNN-hybrid large-graph
                        memory stress
 
-and one builder of the token decoder (models/token_decoder.py):
+and a builder of each token decoder, at tiny widths for tests (a benchmark
+configuration passes the published widths as overrides):
 
-  * token_decoder    — latent attention + held experts + prediction block,
-                       at tiny widths for tests; a benchmark configuration
-                       passes the published widths as overrides
+  * token_decoder    — latent attention + held experts + prediction block
+                       (models/token_decoder.py)
+  * hybrid_decoder   — layers by a pattern string: state-space mixers,
+                       two-matrix held experts, grouped-query attention
+                       (models/hybrid_decoder.py)
 """
 from __future__ import annotations
 
+from ..models.hybrid_decoder import HybridDecoder
 from ..models.se3_transformer import SE3TransformerModule
 from ..models.token_decoder import TokenDecoder
 
@@ -137,6 +141,21 @@ def token_decoder(**overrides) -> TokenDecoder:
     return TokenDecoder(**sizes)
 
 
+def hybrid_decoder(**overrides) -> HybridDecoder:
+    """Tiny widths by default (CPU tests): two state-space layers, two expert
+    layers (2 of 8 experts a token, 4 held here) and one attention layer.
+    Train it with `training.lm_loss.make_lm_loss(module)`."""
+    sizes = dict(
+        vocab_rows=48, hidden_size=32, hybrid_override_pattern='ME*ME',
+        mamba_num_heads=4, mamba_head_dim=8, ssm_state_size=8, n_groups=2,
+        chunk_size=8, moe_intermediate_size=16,
+        moe_shared_expert_intermediate_size=24, n_routed_experts=8,
+        num_experts_per_tok=2, experts_held=4, routed_scaling_factor=2.5,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=8)
+    sizes.update(overrides)
+    return HybridDecoder(**sizes)
+
+
 RECIPES = {
     'toy_denoise': toy_denoise,
     'flagship': flagship,
@@ -145,4 +164,5 @@ RECIPES = {
     'molecular_edges': molecular_edges,
     'egnn_stress': egnn_stress,
     'token_decoder': token_decoder,
+    'hybrid_decoder': hybrid_decoder,
 }

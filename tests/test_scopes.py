@@ -281,3 +281,63 @@ def test_a_tiny_decoder_step_has_every_decoder_leaf_and_the_three_phases():
         assert {(leaf, ph) for ph in profiling.PHASES} <= cells, leaf
     # no SE(3) leaf but the shared `norm`, `loss` and `optimizer`
     assert leaves <= set(DECODER_LEAVES) | {'norm', 'loss', 'optimizer'}
+
+
+# --------------------------------------------------------------------- #
+# the hybrid decoder's leaves: each met in a lowered step
+# --------------------------------------------------------------------- #
+HYBRID_LEAVES = ('ssm_in', 'ssm_conv', 'ssm_scan', 'ssm_gate', 'ssm_out',
+                 'mha_qkv', 'mha_core', 'mha_out')
+
+
+def test_the_hybrid_decoders_leaves_are_on_the_closed_list_and_new(labelled):
+    assert set(HYBRID_LEAVES) <= set(MODEL_SCOPES)
+    # none is a JAX or XLA primitive's name (`gather` taught that), none a
+    # component of an SE(3) path
+    import jax.extend.core as jex
+    import jax._src.lax.lax as lax_impl
+    primitives = {getattr(lax_impl, n).name for n in dir(lax_impl)
+                  if isinstance(getattr(lax_impl, n), jex.Primitive)}
+    assert len(primitives) > 50 and 'exp' in primitives
+    assert not set(HYBRID_LEAVES) & primitives
+    comps = {c for _, _, p in labelled for c in p.split(';')[0].split('/')}
+    assert not comps & set(HYBRID_LEAVES)
+
+
+def test_a_tiny_hybrid_step_has_every_leaf_and_the_three_phases():
+    import optax
+
+    from se3_transformer_tpu.parallel.sharding import make_sharded_train_step
+    from se3_transformer_tpu.training.lm_loss import make_lm_loss
+    from se3_transformer_tpu.training.recipes import RECIPES
+    module = RECIPES['hybrid_decoder'](attention_block=8)
+    tokens = jax.ShapeDtypeStruct((1, 16), jnp.int32)
+    params = jax.eval_shape(module.init, jax.random.PRNGKey(0),
+                            tokens)['params']
+    optimizer = optax.adam(1e-4)
+    step = make_sharded_train_step(make_lm_loss(module, chunk=8), optimizer)
+    text = step.lower(params, jax.eval_shape(optimizer.init, params),
+                      dict(tokens=tokens),
+                      jax.ShapeDtypeStruct((2,), jnp.uint32)
+                      ).as_text(debug_info=True)
+    # the scopes alone, as the decoders' readers take them (see above)
+    paths = {p.rsplit('/', 1)[0] for p in re.findall(
+        r'"(jit\(train_step\)/[^"]*)"', text)}
+    cells = {(profiling.scope_leaf(p), profiling.scope_phase(p))
+             for p in paths}
+    leaves = {leaf for leaf, _ in cells}
+    shared = {'embed', 'moe_router', 'moe_dispatch', 'moe_experts',
+              'moe_combine', 'shared_expert', 'lm_head', 'norm', 'loss',
+              'optimizer'}
+    assert leaves == set(HYBRID_LEAVES) | shared
+    # every layer is recomputed in the backward pass: its leaves have the
+    # three phases, but a leaf that is a layer's last product alone, whose
+    # output no cotangent needs (`mha_out` also lays the heads out again)
+    last = ('ssm_out',)
+    for leaf in HYBRID_LEAVES + ('moe_experts', 'shared_expert'):
+        phases = {ph for ph in profiling.PHASES
+                  if leaf not in last or ph != 'replay'}
+        assert {ph for lf, ph in cells if lf == leaf} == phases, leaf
+    # the carry of the chunk states keeps its scope: nothing of the step
+    # sits in a loop but the optimizer's and the sort's own
+    assert not any('ssm_scan' in p and 'while' in p for p in paths)

@@ -98,3 +98,22 @@ def test_the_same_numbers_from_a_file(step, red, tmp_path):
     assert sorted(h[1] for h in kept['host']) == ['loss_fetch', 'step_call']
     with pytest.raises(FileNotFoundError):
         profiling.reduce_xplane(str(tmp_path / 'plugins' / 'profile' / 'no'))
+
+
+def test_a_launch_the_compiler_names_is_filed_under_its_leaf():
+    """The TPU's grouped matrix product (`jax.lax.ragged_dot` rewritten into
+    Mosaic calls) carries its own name as op_name and no scope: it is the
+    expert layer's `moe_experts`, as a scoped operation beside it is; any
+    other instruction without a scope stays unlabelled."""
+    rows = [['ragged-dot-none.31', 0, 3e6, 'ragged-dot-none', None],
+            ['ragged-dot-metadata.2', 3e6, 1e6, 'ragged-dot-metadata', None],
+            ['fusion.7', 4e6, 2e6,
+             'jit(train_step)/loss/jvp(loss)/blocks_1/moe/moe_experts', None],
+            ['copy.12', 6e6, 1e6, None, None],
+            ['fusion.9', 7e6, 1e6, 'ragged-dot-like/mul', None]]
+    red = profiling.reduce_events({'device': {'/device:TPU:0': rows},
+                                   'host': [], 'selector': 'xla_ops'})
+    assert red['leaf_s'] == pytest.approx({'moe_experts': 6e-3})
+    assert red['unlabelled_s'] == pytest.approx(2e-3)
+    assert red['coverage'] == pytest.approx(0.75)
+    assert profiling.compiler_launch_leaf('fusion.9') is None

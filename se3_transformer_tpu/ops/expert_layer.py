@@ -18,11 +18,19 @@ result. What the absent experts would add is left out (the partial result an
 expert-parallel rank has before the exchange); nothing stands in for it.
 
 Nothing is dropped and every shape is static: the N * top_k (token, slot)
-pairs are sorted so that the pairs of a held expert come first, expert by
+pairs are sorted so that the H pairs of a held expert come first, expert by
 expert, and `jax.lax.ragged_dot` (a native grouped matrix product on the
-TPU) runs over the sorted rows with the count of each held expert; rows past
-the last held pair are not computed and read as zero. Every token choosing
-the same held experts fills all N * top_k rows, which is the static size.
+TPU) runs over sorted rows with the count of each held expert; rows past the
+last held pair are not computed and read as zero. So the first C sorted rows
+give the whole result whenever H <= C, and dispatch, the grouped products
+and combine work on C rows: `held_row_bound`, a multiple of the balanced
+expectation N * top_k * experts_held / n_experts that follows from the
+layer's shapes alone (`HELD_ROW_BOUND`). A layer whose routing puts more
+than C pairs here (H is a device scalar, so `jax.lax.cond` decides) takes
+all N * top_k rows instead, the static size that every token choosing the
+same held experts fills. Both are exact: the same products over the same
+pairs, and neither drops one. Where C reaches N * top_k (every expert held,
+tiny sizes) there is the full size alone.
 """
 from __future__ import annotations
 
@@ -117,6 +125,113 @@ def _permute_rows_bwd(inverse, g):
 
 
 _permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+
+
+# The rows of the bounded path: this multiple of the balanced expectation of
+# the held pairs, rounded up to this many rows (the grouped product's tile)
+HELD_ROW_BOUND = (2, 512)
+
+
+def held_row_bound(n_pairs: int, held: int, n_experts: int) -> int:
+    """C, from the layer's shapes alone; n_pairs where C would reach it."""
+    multiple, tile = HELD_ROW_BOUND
+    rows = -(-multiple * n_pairs * held // n_experts)
+    return min(n_pairs, -(-rows // tile) * tile)
+
+
+def _stages(rows, order, inverse, load, n, k, gated, dtype):
+    """(take, experts, combine) over the first `rows` sorted rows, which hold
+    every held pair: x [N, d] -> xs [rows, d] -> ys [rows, d] -> out [N, d].
+    At the full size both gathers go through `inverse`; below it a row is
+    added into its token's, and so is its cotangent."""
+    full = rows == n * k
+
+    def take(x):
+        if full:
+            return _take_tokens(x, order, inverse)
+        return x[order[:rows] // k]
+
+    def experts(xs, mats):
+        up = grouped_dot(xs, mats['up'], load, dtype)
+        hidden = nn.silu(grouped_dot(xs, mats['gate'], load, dtype)) * up \
+            if gated else jnp.square(nn.relu(up))
+        return grouped_dot(hidden, mats['down'], load, dtype)
+
+    def combine(ys, weights):
+        if full:
+            pairs = _permute_rows(ys, inverse, order).reshape(n, k, -1)
+            return jnp.sum(pairs * weights[..., None], axis=1)
+        head = order[:rows]
+        scaled = ys * weights.reshape(n * k)[head][:, None]
+        return jnp.zeros((n, ys.shape[1]), ys.dtype).at[head // k].add(scaled)
+
+    return take, experts, combine
+
+
+def _held_experts(stages, x, weights, mats):
+    """sum_{i chosen and held here} w_i Expert_i(x), each stage under its
+    leaf."""
+    take, experts, combine = stages
+    with named_scope('moe_dispatch'):
+        xs = take(x)
+    with named_scope('moe_experts'):
+        ys = experts(xs, mats)
+    with named_scope('moe_combine'):
+        return combine(ys, weights)
+
+
+def _held_experts_vjp(stages, x, weights, mats, g):
+    """The cotangents of `_held_experts` at g, stage by stage: a transform
+    wraps the first scope it meets (`jvp(moe_dispatch)`, which is no leaf),
+    so each stage is differentiated inside its scope, not the whole."""
+    take, experts, combine = stages
+    with named_scope('moe_dispatch'):
+        xs, take_t = jax.vjp(take, x)
+    with named_scope('moe_experts'):
+        ys, experts_t = jax.vjp(experts, xs, mats)
+    with named_scope('moe_combine'):
+        d_ys, d_weights = jax.vjp(combine, ys, weights)[1](g)
+    with named_scope('moe_experts'):
+        d_xs, d_mats = experts_t(d_ys)
+    with named_scope('moe_dispatch'):
+        return take_t(d_xs)[0], d_weights, d_mats
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _bounded_or_full(rows, gated, dtype, fits, x, weights, mats, order,
+                     inverse, load):
+    """`_held_experts` over the first `rows` sorted rows where the held pairs
+    fit them (`fits`), else over all N * k. One rule for both directions,
+    each with its own `cond` and nothing saved but the arguments:
+    differentiated as it stands, a `cond` hands its backward the residuals of
+    both branches, the untaken one's (the full size's operands and
+    pre-activations) as zeros."""
+    return _bounded_or_full_fwd(rows, gated, dtype, fits, x, weights, mats,
+                                order, inverse, load)[0]
+
+
+def _branches(fn, rows, gated, dtype, order, inverse, load, n, k):
+    return [partial(fn, _stages(r, order, inverse, load, n, k, gated, dtype))
+            for r in (rows, n * k)]
+
+
+def _bounded_or_full_fwd(rows, gated, dtype, fits, x, weights, mats, order,
+                         inverse, load):
+    bounded, full = _branches(_held_experts, rows, gated, dtype, order,
+                              inverse, load, *weights.shape)
+    out = jax.lax.cond(fits, bounded, full, x, weights, mats)
+    return out, (fits, x, weights, mats, order, inverse, load)
+
+
+def _bounded_or_full_bwd(rows, gated, dtype, res, g):
+    fits, x, weights, mats, order, inverse, load = res
+    bounded, full = _branches(_held_experts_vjp, rows, gated, dtype, order,
+                              inverse, load, *weights.shape)
+    return (None,) + jax.lax.cond(fits, bounded, full, x, weights, mats, g) \
+        + (None, None, None)
+
+
+_bounded_or_full.defvjp(_bounded_or_full_fwd, _bounded_or_full_bwd)
 
 
 class SwiGLU(nn.Module):
@@ -220,27 +335,25 @@ class ExpertLayer(nn.Module):
             order = jnp.argsort(key, stable=True).astype(jnp.int32)
             inverse = jnp.argsort(order).astype(jnp.int32)
             load = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
-            xs = _take_tokens(x, order, inverse)
-        with named_scope('moe_experts'):
-            dtype = jnp.bfloat16 if self.bf16_operands else None
-            shared, gated = EXPERT_FORMS[self.hidden_act]
-
-            def experts_up(name):
-                return grouped_dot(xs, self.param(
-                    f'experts_{name}', _expert_init, (held, d, self.width)),
-                    load, dtype)
-
-            hidden = nn.silu(experts_up('gate')) * experts_up('up') \
-                if gated else jnp.square(nn.relu(experts_up('up')))
-            w_down = self.param('experts_down', _expert_init,
-                                (held, self.width, d))
-            ys = grouped_dot(hidden, w_down, load, dtype)
-        with named_scope('moe_combine'):
-            pairs = _permute_rows(ys, inverse, order).reshape(n, k, d)
-            out = jnp.sum(pairs * weights[..., None], axis=1)
+        shared, gated = EXPERT_FORMS[self.hidden_act]
+        up, down = (held, d, self.width), (held, self.width, d)
+        shapes = dict(gate=up, up=up, down=down) if gated \
+            else dict(up=up, down=down)
+        mats = {name: self.param(f'experts_{name}', _expert_init, shape)
+                for name, shape in shapes.items()}
+        dtype = jnp.bfloat16 if self.bf16_operands else None
+        rows = held_row_bound(n * k, held, self.n_experts)
+        fits = jnp.sum(load) <= rows
+        if rows == n * k:
+            out = _held_experts(_stages(rows, order, inverse, load, n, k,
+                                        gated, dtype), x, weights, mats)
+        else:
+            out = _bounded_or_full(rows, gated, dtype, fits, x, weights, mats,
+                                   order, inverse, load)
         if self.shared_width:
             with named_scope('shared_expert'):
                 out = out + shared(self.shared_width, name='shared')(x)
         stats = dict(load=load, chosen=chosen, scores=scores,
-                     dropped=jnp.sum(here, dtype=jnp.int32) - jnp.sum(load))
+                     dropped=jnp.sum(here, dtype=jnp.int32) - jnp.sum(load),
+                     bounded=fits.astype(jnp.int32))
         return out, stats

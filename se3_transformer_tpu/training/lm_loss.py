@@ -54,6 +54,7 @@ def expert_counters(stats):
         moe_load_max=jnp.max(load),
         moe_load_mean=jnp.mean(load.astype(jnp.float32)),
         moe_dropped=sum(s['dropped'] for s in stats),
+        moe_bounded=sum(s['bounded'] for s in stats),
         moe_choice=jnp.stack([s['chosen'] for s in stats]).astype(jnp.uint8))
 
 

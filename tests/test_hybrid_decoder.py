@@ -16,6 +16,7 @@ import optax
 import pytest
 
 from se3_transformer_tpu.models.hybrid_decoder import HybridDecoder
+from se3_transformer_tpu.ops import expert_layer
 from se3_transformer_tpu.ops.expert_layer import ExpertLayer
 from se3_transformer_tpu.ops.grouped_attention import GroupedQueryAttention
 from se3_transformer_tpu.ops.state_space import Mamba2Mixer, chunked_scan
@@ -364,7 +365,16 @@ def test_grouped_query_attention_against_explicit_heads(ref, block):
 # ------------------------------------------------------------------ #
 # on the step factory; the other decoder's tree
 # ------------------------------------------------------------------ #
-def test_three_steps_on_the_one_step_factory_with_the_counters_in_aux(tiny):
+@pytest.mark.parametrize('bound', [expert_layer.HELD_ROW_BOUND, (1, 1)],
+                         ids=['the full size alone', 'a bound that binds'])
+def test_three_steps_on_the_one_step_factory_with_the_counters_in_aux(
+        tiny, monkeypatch, bound):
+    """With the layer's constant as it is these sizes have the full size
+    alone; at the balanced expectation itself a layer's held pairs fall on
+    either side of the bound, inside the recomputed blocks of the step."""
+    monkeypatch.setattr(expert_layer, 'HELD_ROW_BOUND', bound)
+    rows = expert_layer.held_row_bound(48 * 2, 4, 8)
+    assert rows == (48 * 2 if bound[0] == 2 else 48)
     module, params, tokens = tiny
     optimizer = optax.adam(1e-3)
     step = make_sharded_train_step(make_lm_loss(module, chunk=8), optimizer)
@@ -385,8 +395,10 @@ def test_three_steps_on_the_one_step_factory_with_the_counters_in_aux(tiny):
         assert int(aux['moe_load_max']) >= float(aux['moe_load_mean'])
         assert int(aux['moe_dropped']) == 0
         assert aux['moe_choice'].shape == (2, 48, 2)
-        held = (np.asarray(aux['moe_choice']) // 4 == 1).sum()   # rank 1
-        assert held == pairs
+        held = np.asarray(aux['moe_choice']) // 4 == 1           # rank 1
+        assert held.sum() == pairs
+        fit = held.sum(axis=(1, 2)) <= rows
+        assert int(aux['moe_bounded']) == fit.sum()
     assert losses[2] < losses[1] < losses[0]
 
 

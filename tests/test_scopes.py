@@ -341,3 +341,41 @@ def test_a_tiny_hybrid_step_has_every_leaf_and_the_three_phases():
     # the carry of the chunk states keeps its scope: nothing of the step
     # sits in a loop but the optimizer's and the sort's own
     assert not any('ssm_scan' in p and 'while' in p for p in paths)
+
+
+def test_both_sizes_of_the_expert_layer_keep_their_leaves(monkeypatch):
+    """Where the held-row bound is under N * k the layer is a `cond` in the
+    forward pass and one in the backward pass (ops/expert_layer.py): every
+    operation inside either branch of either is under `moe_dispatch`,
+    `moe_experts` or `moe_combine`, the backward ones as `backward`."""
+    from se3_transformer_tpu.ops import expert_layer
+    monkeypatch.setattr(expert_layer, 'HELD_ROW_BOUND', (1, 1))
+    layer = expert_layer.ExpertLayer(width=8, n_experts=8, top_k=2,
+                                     experts_held=4, shared_width=8)
+    assert expert_layer.held_row_bound(24 * 2, 4, 8) == 24
+    x = jax.ShapeDtypeStruct((24, 16), jnp.float32)
+    params = jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)['params']
+
+    def loss(params, x):
+        with jax.named_scope('loss'):
+            return jnp.square(jax.checkpoint(lambda p, x: layer.apply(
+                {'params': p}, x)[0])(params, x)).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, x).as_text(
+        debug_info=True)
+    paths = {p.rsplit('/', 1)[0] for p in re.findall(r'"(jit\(loss\)/[^"]*)"',
+                                                     text)}
+    inside = {p for p in paths if re.search(r'/branch_[01]_fun(/|$)', p)}
+    stages = {'moe_dispatch', 'moe_experts', 'moe_combine'}
+    cells = set()
+    for p in inside:
+        head, branch, tail = re.split(r'/(branch_[01]_fun)', p, maxsplit=1)
+        if not tail:
+            continue             # the branch's own plumbing: no operation
+        leaf = profiling.scope_leaf(p)
+        assert leaf in stages and leaf in tail.split('/'), p
+        backward = 'transpose(jvp(loss))' in head.split('/')
+        assert (profiling.scope_phase(p) == 'backward') == backward, p
+        cells.add((branch, backward, leaf))
+    assert cells == {(b, back, leaf) for b in ('branch_0_fun', 'branch_1_fun')
+                     for back in (False, True) for leaf in stages}

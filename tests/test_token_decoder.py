@@ -5,6 +5,7 @@ the program), at tiny widths in float32 on the CPU, and the properties the
 architecture states one by one."""
 import importlib.util
 import os
+from fractions import Fraction
 
 import jax
 import jax.numpy as jnp
@@ -13,6 +14,7 @@ import optax
 import pytest
 
 from se3_transformer_tpu.models.token_decoder import TokenDecoder
+from se3_transformer_tpu.ops import expert_layer
 from se3_transformer_tpu.ops.expert_layer import (
     ExpertLayer, balance_bias, grouped_dot, route,
 )
@@ -161,6 +163,60 @@ def test_nothing_is_dropped_when_every_token_picks_the_same_held_experts(
     want, _ = ref.expert_layer(_share(params, held, rank, True), x, m,
                                lambda w: w)
     np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-6)
+
+
+def _bound_rows_at(monkeypatch, c, held, n_pairs=24 * K):
+    """Patch the layer's one constant so that `held_row_bound` is c."""
+    monkeypatch.setattr(expert_layer, 'HELD_ROW_BOUND',
+                        (Fraction(c * E, n_pairs * held), 1))
+    assert expert_layer.held_row_bound(n_pairs, held, E) == min(c, n_pairs)
+
+
+@pytest.mark.parametrize('over', [-1, 0, 1, 'every pair'])
+@pytest.mark.parametrize('hidden_act', ['silu', 'relu2'])
+def test_the_bounded_rows_give_the_full_sizes_output_and_gradients(
+        monkeypatch, hidden_act, over):
+    """H held pairs against a bound of C rows, at H = C - 1, C, C + 1 and
+    N * k: the output and every gradient leaf are the full size's (which is
+    all there is while the constant reaches N * k), the first two from C
+    rows, the others from all rows by the fallback; nothing is dropped."""
+    held, rank = 4, 1
+    layer = _layer(held, rank, hidden_act=hidden_act)
+    x = jax.random.normal(jax.random.PRNGKey(1), (24, D))
+    cot = jax.random.normal(jax.random.PRNGKey(5), (24, D))
+    params = layer.init(jax.random.PRNGKey(2), x)['params']
+    bias = 0.05 * jax.random.normal(jax.random.PRNGKey(3), (E,))
+    if over == 'every pair':         # every token picks held experts 5 and 6
+        bias = bias.at[jnp.asarray([5, 6])].set(10.0)
+    params = dict(params, correction_bias=bias)
+
+    def run():                       # traced anew: the constant is read then
+        def scalar(params, x):
+            out, stats = layer.apply({'params': params}, x)
+            return jnp.sum(out * cot), (out, stats)
+        (_, (out, stats)), grads = jax.jit(jax.value_and_grad(
+            scalar, argnums=(0, 1), has_aux=True))(params, x)
+        return out, stats, grads
+
+    assert expert_layer.held_row_bound(24 * K, held, E) == 24 * K
+    want, stats, want_grads = run()
+    h = int(stats['load'].sum())
+    assert (h == 24 * K) if over == 'every pair' else (8 < h < 24 * K - 8)
+    assert int(stats['bounded']) == 1 and int(stats['dropped']) == 0
+
+    c = 24 if over == 'every pair' else h - over
+    _bound_rows_at(monkeypatch, c, held)
+    got, stats, got_grads = run()
+    assert int(stats['load'].sum()) == h and int(stats['dropped']) == 0
+    assert int(stats['bounded']) == (1 if over in (-1, 0) else 0)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    leaves = jax.tree_util.tree_flatten_with_path(got_grads)[0]
+    # the bias, the router, the experts' and the shared one's matrices, x
+    assert len(leaves) == (9 if hidden_act == 'silu' else 7)
+    for (path, a), b in zip(leaves, jax.tree_util.tree_leaves(want_grads)):
+        name, scale = jax.tree_util.keystr(path), float(jnp.linalg.norm(b))
+        assert (scale > 0) != ('correction_bias' in name), name
+        assert float(jnp.linalg.norm(a - b)) <= 1e-5 * scale, name
 
 
 def test_the_correction_bias_moves_the_choice_and_not_the_weights():
@@ -379,7 +435,16 @@ def test_chunked_cross_entropy_is_the_plain_one():
         assert float(got) == pytest.approx(want, rel=1e-5)
 
 
-def test_three_steps_on_the_one_step_factory_with_the_counters_in_aux(tiny):
+@pytest.mark.parametrize('bound', [expert_layer.HELD_ROW_BOUND, (1, 1)],
+                         ids=['the full size alone', 'a bound that binds'])
+def test_three_steps_on_the_one_step_factory_with_the_counters_in_aux(
+        tiny, monkeypatch, bound):
+    """With the layer's constant as it is these sizes have the full size
+    alone; at the balanced expectation itself a layer's held pairs fall on
+    either side of the bound, inside the recomputed blocks of the step."""
+    monkeypatch.setattr(expert_layer, 'HELD_ROW_BOUND', bound)
+    rows = expert_layer.held_row_bound(32 * 2, 4, 8)
+    assert rows == (32 * 2 if bound[0] == 2 else 32)
     module, params, tokens = tiny
     optimizer = optax.adam(1e-3)
     step = make_sharded_train_step(make_lm_loss(module, chunk=8), optimizer)
@@ -400,6 +465,8 @@ def test_three_steps_on_the_one_step_factory_with_the_counters_in_aux(tiny):
         assert int(aux['moe_load_max']) >= float(aux['moe_load_mean'])
         assert int(aux['moe_dropped']) == 0
         assert aux['moe_choice'].shape == (3, 32, 2)
-        held = (np.asarray(aux['moe_choice']) // 4 == 1).sum()   # rank 1
-        assert held == pairs
+        held = np.asarray(aux['moe_choice']) // 4 == 1           # rank 1
+        assert held.sum() == pairs
+        fit = held.sum(axis=(1, 2)) <= rows
+        assert int(aux['moe_bounded']) == fit.sum()
     assert losses[2] < losses[1] < losses[0]

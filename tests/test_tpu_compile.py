@@ -21,6 +21,7 @@ cases — a deviceless executable can be written to it but not read back
 without a chip.
 """
 import os
+import re
 
 os.environ.setdefault('TPU_LOG_DIR', 'disabled')  # else libtpu logs to /tmp
 os.environ.setdefault('ALLOW_MULTIPLE_LIBTPU_LOAD', '1')
@@ -440,6 +441,82 @@ def test_grouped_products_are_native_on_the_chip(v5e):
     assert text.count('ragged-dot') >= 2 and 'while' not in text
     dense = 2 * 32768 * 2048 * 1536
     assert compiled.cost_analysis()['flops'] < 2.5 * dense
+
+
+def _computations(text):
+    """{name: body} of the computations of compiled HLO text."""
+    out, name = {}, None
+    for line in text.splitlines():
+        m = re.match(r'^(?:ENTRY\s+)?%([\w.\-]+)\s+\(.*\{\s*$', line)
+        if m:
+            name = m.group(1)
+            out[name] = []
+        elif line.startswith('}'):
+            name = None
+        elif name:
+            out[name].append(line)
+    return {k: '\n'.join(v) for k, v in out.items()}
+
+
+def _with_callees(comps, name):
+    """The body of `name` and of everything it calls."""
+    seen, todo = set(), [name]
+    while todo:
+        c = todo.pop()
+        if c not in seen:
+            seen.add(c)
+            todo += [n for n in re.findall(r'%([\w.\-]+)', comps[c])
+                     if n in comps]
+    return '\n'.join(comps[c] for c in sorted(seen))
+
+
+def test_the_expert_layer_works_on_the_held_row_bound_at_the_hybrid_cells_size(
+        v5e):
+    """The hybrid cell's expert layer as its recomputed block runs it, forward
+    and backward: N * k = 49,152 pairs, `held_row_bound` 6,144. Each direction
+    is one `conditional`; the forward one hands on its output alone (no
+    branch's residuals, which the untaken one would fill with zeros); in the
+    bounded branch of both the grouped products walk 6,144 rows and nothing has
+    49,152 rows but vectors (sort keys, permutations, a weight a pair)."""
+    from se3_transformer_tpu.ops.expert_layer import (
+        ExpertLayer, held_row_bound,
+    )
+    n, d, k, width = 8192, 2688, 6, 1856
+    layer = ExpertLayer(width=width, n_experts=128, top_k=k, experts_held=8,
+                        shared_width=3712, hidden_act='relu2',
+                        routed_scale=2.5)
+    assert held_row_bound(n * k, 8, 128) == 6144
+    assert held_row_bound(8192 * 4, 8, 64) == 8192       # the GLM cell's
+    x = jax.ShapeDtypeStruct((n, d), f32, sharding=v5e)
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+        jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)['params'])
+
+    def loss(params, x):
+        return jnp.square(jax.checkpoint(
+            lambda p, x: layer.apply({'params': p}, x)[0])(params, x)).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    comps = _computations(text)
+    conds = re.findall(
+        r'= (\(.*?\)) conditional\(.*?branch_computations=\{%([\w.\-]+), '
+        r'%([\w.\-]+)\}', text)
+    # out [N, d]; the cotangents of x, the weights and the two matrices
+    outputs = [len(re.findall(r'\w+\[[\d,]*\]', r)) for r, _, _ in conds]
+    assert sorted(outputs) == [1, 4], conds
+    for n_out, (_, full, bounded) in zip(outputs, conds):   # cond(fits, ...)
+        n_products = {1: 2, 4: 6}[n_out]     # 2 forward; 2 again and 4 back
+        for name, rows in ((bounded, 6144), (full, 49152)):
+            body = _with_callees(comps, name)
+            products = re.findall(
+                r'%ragged-dot-none[\w.]* = f32\[(\d+),(\d+)', body)
+            assert len(products) == n_products, (name, products)
+            assert set(products) <= {
+                (str(rows), str(width)), (str(rows), str(d)),
+                ('8', str(width)), ('8', str(d))}, (name, products)
+            assert bool(re.search(r'\[49152,\d', body)) == (rows == 49152)
+            assert re.search(r's32\[49152\]', body)
 
 
 @pytest.mark.slow

@@ -1,36 +1,49 @@
-"""A causal token decoder whose layers follow a pattern string, each layer
+"""A causal token decoder whose layers follow a pattern string, each letter
 one pre-normed mixer with its residual:
 
-    h <- h + Mixer(RMSNorm(h)),   Mixer by the layer's letter:
+    h <- h + Mixer(RMSNorm(h)),   Mixer by the letter:
       M   a Mamba-2 state-space mixer          (ops/state_space.py)
+      C   a gated short convolution            (ops/short_conv.py)
+      *   grouped-query causal attention       (ops/grouped_attention.py),
+          with per-head q/k norms (`qk_norm`) and rotation (`rope_theta`;
+          None: none) where the model has them
       E   an expert layer that holds a share   (ops/expert_layer.py)
-      *   grouped-query causal attention       (ops/grouped_attention.py)
+      F   a dense gated feed-forward           (SwiGLU, ops/expert_layer.py)
 
-then a final RMSNorm and an untied head. No layer has attention AND a
-feed-forward; no rotation is applied (the state-space layers carry
-position); there is no prediction block.
+then a final RMSNorm and the head: a matrix of its own, or with
+`tie_word_embeddings` the embedding's transpose (no `head` subtree; the
+embedding then takes both gradients). A published layer is one letter (a
+layer of one mixer: `MEMEM*EME`) or two (an operator, then a feed-forward:
+`CF*ECECECE` is a convolution layer with a dense feed-forward, an attention
+layer and three convolution layers with experts); there is no prediction
+block. Only the fields of the mixers the pattern uses need be given.
 
 The fields carry the names a published `config.json` gives them;
 `experts_held`, `expert_rank` and `vocab_rows` say what this chip holds of an
 expert-parallel deployment, as in `models/token_decoder.py`, whose
 interface this shares: `hidden_states` stops before the head and returns
 (main, None, stats), `expert_layer_names` lists the expert layers' subtrees,
-so `training/lm_loss.py` trains both. `hybrid_override_pattern` is the
+`head_kernel` gives the head's matrix from a parameter tree, so
+`training/lm_loss.py` trains both. `hybrid_override_pattern` is the
 layers built here.
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import flax.linen as nn
 
 from ..observability import named_scope
-from ..ops.expert_layer import ExpertLayer
+from ..ops.expert_layer import ExpertLayer, SwiGLU
 from ..ops.grouped_attention import GroupedQueryAttention
 from ..ops.latent_attention import RMSNorm
+from ..ops.short_conv import ShortConvMixer
 from ..ops.state_space import Mamba2Mixer
 
 # letter -> (the mixer's module, its name in the parameter tree)
 MIXERS = {'M': (Mamba2Mixer, 'ssm'), 'E': (ExpertLayer, 'moe'),
-          '*': (GroupedQueryAttention, 'attn')}
+          '*': (GroupedQueryAttention, 'attn'),
+          'C': (ShortConvMixer, 'conv'), 'F': (SwiGLU, 'mlp')}
 
 
 class MixerBlock(nn.Module):
@@ -44,11 +57,15 @@ class MixerBlock(nn.Module):
         with named_scope('norm'):
             u = RMSNorm(self.eps, name='pre_norm')(h)
         module, name = MIXERS[self.kind]
-        if self.kind != 'E':
-            return h + module(**self.mixer, name=name)(u), None
-        b, t, d = u.shape
-        out, stats = module(**self.mixer, name=name)(u.reshape(b * t, d))
-        return h + out.reshape(b, t, d), stats
+        mixer = module(**self.mixer, name=name)
+        if self.kind == 'E':
+            b, t, d = u.shape
+            out, stats = mixer(u.reshape(b * t, d))
+            return h + out.reshape(b, t, d), stats
+        if self.kind == 'F':       # SwiGLU writes no scope of its own
+            with named_scope('dense_ff'):
+                return h + mixer(u), None
+        return h + mixer(u), None
 
 
 class HybridDecoder(nn.Module):
@@ -56,31 +73,39 @@ class HybridDecoder(nn.Module):
     hidden_size: int
     hybrid_override_pattern: str
     # M
-    mamba_num_heads: int
-    mamba_head_dim: int
-    ssm_state_size: int
-    n_groups: int
-    # E
-    moe_intermediate_size: int
-    moe_shared_expert_intermediate_size: int
-    n_routed_experts: int
-    num_experts_per_tok: int
-    experts_held: int
-    # *
-    num_attention_heads: int
-    num_key_value_heads: int
-    head_dim: int
-    expert_rank: int = 0
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    ssm_state_size: int = 0
+    n_groups: int = 0
     conv_kernel: int = 4
     chunk_size: int = 128
     use_conv_bias: bool = True
     time_step_min: float = 0.001
     time_step_max: float = 0.1
     time_step_floor: float = 1e-4
+    # C
+    conv_L_cache: int = 3
+    # E
+    moe_intermediate_size: int = 0
+    moe_shared_expert_intermediate_size: int = 0
+    n_routed_experts: int = 0
+    num_experts_per_tok: int = 0
+    experts_held: int = 0
+    expert_rank: int = 0
     mlp_hidden_act: str = 'relu2'
     routed_scaling_factor: float = 1.0
     norm_topk_prob: bool = True
+    norm_topk_eps: float = 1e-20
+    # F
+    intermediate_size: int = 0
+    # *
+    num_attention_heads: int = 0
+    num_key_value_heads: int = 0
+    head_dim: int = 0
+    qk_norm: bool = False
+    rope_theta: Optional[float] = None
     layer_norm_epsilon: float = 1e-5
+    tie_word_embeddings: bool = False
     # execution, not architecture (every block is recomputed in the
     # backward pass: its input alone is saved)
     attention_block: int = 512       # ops/latent_attention.py
@@ -99,6 +124,7 @@ class HybridDecoder(nn.Module):
                 time_step_min=self.time_step_min,
                 time_step_max=self.time_step_max,
                 time_step_floor=self.time_step_floor, eps=eps),
+            'C': dict(dim=self.hidden_size, taps=self.conv_L_cache),
             'E': dict(
                 width=self.moe_intermediate_size,
                 n_experts=self.n_routed_experts,
@@ -108,22 +134,32 @@ class HybridDecoder(nn.Module):
                 hidden_act=self.mlp_hidden_act,
                 routed_scale=self.routed_scaling_factor,
                 norm_topk=self.norm_topk_prob,
+                norm_topk_eps=self.norm_topk_eps,
                 bf16_operands=self.bf16_operands),
+            'F': dict(width=self.intermediate_size),
             '*': dict(
                 dim=self.hidden_size, heads=self.num_attention_heads,
                 kv_heads=self.num_key_value_heads, head_dim=self.head_dim,
-                block=self.attention_block)}
+                block=self.attention_block, qk_norm=self.qk_norm,
+                rope_theta=self.rope_theta, eps=eps)}
         block = nn.remat(MixerBlock)
         self.embedding = nn.Embed(self.vocab_rows, self.hidden_size)
         self.blocks = [block(kind, fields[kind], eps)
                        for kind in self.hybrid_override_pattern]
         self.final_norm = RMSNorm(eps)
-        self.head = nn.Dense(self.vocab_rows, use_bias=False)
+        if not self.tie_word_embeddings:
+            self.head = nn.Dense(self.vocab_rows, use_bias=False)
 
     def expert_layer_names(self):
         """The parameter subtrees with an expert layer, in `stats` order."""
         return [f'blocks_{i}' for i, kind in
                 enumerate(self.hybrid_override_pattern) if kind == 'E']
+
+    def head_kernel(self, params):
+        """The head's matrix [d, vocab_rows] from a parameter tree."""
+        if self.tie_word_embeddings:
+            return params['embedding']['embedding'].T
+        return params['head']['kernel']
 
     def hidden_states(self, tokens):
         """tokens [B, T] -> (main [B, T, d], None, stats): the head's normed
@@ -143,4 +179,6 @@ class HybridDecoder(nn.Module):
         sizes and `init`; training goes through `hidden_states`."""
         main, _, stats = self.hidden_states(tokens)
         with named_scope('lm_head'):
+            if self.tie_word_embeddings:
+                return self.embedding.attend(main), stats
             return self.head(main), stats

@@ -124,6 +124,10 @@ class TokenDecoder(nn.Module):
                                              self.num_hidden_layers)] \
             + ['mtp_block'] * self.num_nextn_predict_layers
 
+    def head_kernel(self, params):
+        """The head's matrix [d, vocab_rows] from a parameter tree."""
+        return params['head']['kernel']
+
     def hidden_states(self, tokens):
         """tokens [B, T] -> (main [B, T, d], next [B, T, d] or None, stats):
         the two heads' normed inputs. `main[t]` predicts token t + 1 and

@@ -107,10 +107,17 @@ MODEL_SCOPES = (
     'ssm_gate',           # ops/state_space.py: the gated group norm
     'ssm_out',            # ops/state_space.py: output projection
     'mha_qkv',            # ops/grouped_attention.py: q, k, v projections,
-    #                       the key-value heads repeated
+    #                       q/k norms and rotation where the model has
+    #                       them, the key-value heads repeated
     'mha_core',           # ops/grouped_attention.py: scores, softmax,
     #                       weighted sum (the streaming kernel on a TPU)
     'mha_out',            # ops/grouped_attention.py: output projection
+    # a pattern's `F` layers (models/hybrid_decoder.py) are filed under
+    # `dense_ff`
+    'sconv_in',           # ops/short_conv.py: the input projection and its
+    #                       split into B, C, X
+    'sconv_core',         # ops/short_conv.py: B * X, the taps, C * z
+    'sconv_out',          # ops/short_conv.py: output projection
     'loss',               # parallel/sharding.py train_step: what the
     #                       model's scopes do not claim inside the
     #                       differentiated loss

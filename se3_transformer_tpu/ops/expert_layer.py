@@ -3,7 +3,10 @@
     s = sigmoid(x Wr)                          [N, n_experts], float32
     chosen = top_k(s + b)                      b: the correction bias; it
                                                enters the choice only
-    w_i = scale * s_i / (sum_chosen s + 1e-20)
+    w_i = scale * s_i / (sum_chosen s + norm_topk_eps)
+                                               the normaliser is a field:
+                                               1e-20 (the default) and 1e-6
+                                               are both published
     out = sum_{i chosen and held here} w_i Expert_i(x) + Shared(x)
 
 An expert, routed or shared, has one of two forms, by `hidden_act` (the name
@@ -261,13 +264,15 @@ class SquaredReLU(nn.Module):
 EXPERT_FORMS = {'silu': (SwiGLU, True), 'relu2': (SquaredReLU, False)}
 
 
-def route(scores, bias, top_k: int, scale: float, normalize: bool):
+def route(scores, bias, top_k: int, scale: float, normalize: bool,
+          eps: float = 1e-20):
     """scores [N, E] in (0, 1), bias [E] -> (chosen [N, k] int32, weights
-    [N, k]). The bias moves the choice, never the weights."""
+    [N, k]). The bias moves the choice, never the weights; `eps` is the
+    normaliser's."""
     _, chosen = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
     picked = jnp.take_along_axis(scores, chosen, axis=-1)
     if normalize:
-        picked = picked / (picked.sum(axis=-1, keepdims=True) + 1e-20)
+        picked = picked / (picked.sum(axis=-1, keepdims=True) + eps)
     return chosen.astype(jnp.int32), scale * picked
 
 
@@ -307,6 +312,7 @@ class ExpertLayer(nn.Module):
     hidden_act: str = 'silu'   # the experts' form: a key of EXPERT_FORMS
     routed_scale: float = 1.0
     norm_topk: bool = True
+    norm_topk_eps: float = 1e-20   # added to the chosen scores' sum
     bf16_operands: bool = True   # of the grouped products (every other
     #                              product is rounded by the TPU's default)
 
@@ -326,7 +332,7 @@ class ExpertLayer(nn.Module):
                               (self.n_experts,))
             scores = nn.sigmoid(logits)
             chosen, weights = route(scores, bias, k, self.routed_scale,
-                                    self.norm_topk)
+                                    self.norm_topk, self.norm_topk_eps)
         with named_scope('moe_dispatch'):
             local = chosen - self.expert_rank * held
             here = (local >= 0) & (local < held)

@@ -1,8 +1,15 @@
-"""Grouped-query causal attention without rotation, for a decoder whose
-state-space layers carry position:
+"""Grouped-query causal attention; per-head q/k norms and rotation are
+optional:
 
     q = u Wq -> `heads` heads of `head_dim`;  k, v = u Wk, u Wv ->
     `kv_heads` heads, each shared by heads / kv_heads query heads;
+    `qk_norm`:     q, k <- RMSNorm over each head's channels, one scale of
+                   `head_dim` for the queries and one for the keys
+    `rope_theta`:  q, k <- rotation by positions 0 .. T - 1 at this base,
+                   all `head_dim` channels, pairs (i, i + head_dim / 2);
+                   None: no rotation (a decoder whose state-space layers
+                   carry position), and with `qk_norm` off the parameter
+                   tree and the arithmetic are what they were without both
     softmax(q k^T / sqrt(head_dim)), causal, in float32;  out = o Wo
 
 The core is the latent attention's (`ops/latent_attention.py`): JAX's
@@ -13,12 +20,14 @@ here and autodiff sums their gradients over each group.
 from __future__ import annotations
 
 from functools import partial
+from typing import Optional
 
 import flax.linen as nn
 import jax.numpy as jnp
 
 from ..observability import named_scope
-from .latent_attention import causal_attention
+from .latent_attention import RMSNorm, causal_attention
+from .rotary import apply_rotary_halves, rotary_angles
 
 
 class GroupedQueryAttention(nn.Module):
@@ -27,6 +36,9 @@ class GroupedQueryAttention(nn.Module):
     kv_heads: int
     head_dim: int
     block: int = 512      # of queries (and of keys, in the kernel)
+    qk_norm: bool = False
+    rope_theta: Optional[float] = None
+    eps: float = 1e-5     # of the q/k norms
 
     @nn.compact
     def __call__(self, x):
@@ -37,9 +49,16 @@ class GroupedQueryAttention(nn.Module):
         dense = partial(nn.Dense, use_bias=False)
         with named_scope('mha_qkv'):
             q = dense(h * dh, name='q')(x).reshape(b, t, h, dh)
-            k, v = (jnp.repeat(
-                dense(kv * dh, name=name)(x).reshape(b, t, kv, dh),
-                h // kv, axis=2) for name in ('k', 'v'))
+            k, v = (dense(kv * dh, name=name)(x).reshape(b, t, kv, dh)
+                    for name in ('k', 'v'))
+            if self.qk_norm:
+                q = RMSNorm(self.eps, name='q_norm')(q)
+                k = RMSNorm(self.eps, name='k_norm')(k)
+            if self.rope_theta is not None:
+                angles = rotary_angles(jnp.arange(t), dh, self.rope_theta)
+                q, k = (apply_rotary_halves(a, angles[None, :, None, :])
+                        for a in (q, k))
+            k, v = (jnp.repeat(a, h // kv, axis=2) for a in (k, v))
             q, k, v = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
         with named_scope('mha_core'):
             o = causal_attention(q, k, v, dh ** -0.5, self.block)

@@ -4,9 +4,11 @@ over the vocabulary rows held here:
     loss = CE(main head, token t + 1) + mtp_weight * CE(prediction block,
     token t + 2), each a mean over its valid positions
 
-The logits are taken in chunks of tokens, each chunk recomputed in the
-backward pass: two [tokens, vocab_rows] float32 arrays with their cotangents
-are never held. `aux` carries the expert layers' counters as device scalars
+The head's matrix is the module's to name (`head_kernel(params)`: a `head`
+subtree, or the embedding's transpose where the model ties them, which then
+takes both gradients). The logits are taken in chunks of tokens, each chunk
+recomputed in the backward pass: two [tokens, vocab_rows] float32 arrays with
+their cotangents are never held. `aux` carries the expert layers' counters as device scalars
 (fetched with the loss) and the step's choices (left on the device unless
 asked for).
 """
@@ -74,7 +76,7 @@ def make_lm_loss(module, mtp_weight: float = 0.3, chunk: int = 1024):
         b, t = tokens.shape
         main, ahead, stats = module.apply({'params': params}, tokens,
                                           method='hidden_states')
-        kernel = params['head']['kernel']
+        kernel = module.head_kernel(params)
         pos = jnp.broadcast_to(jnp.arange(t), (b, t)).reshape(-1)
         d = main.shape[-1]
         loss = chunked_cross_entropy(

@@ -25,6 +25,10 @@ configuration passes the published widths as overrides):
   * hybrid_decoder   — layers by a pattern string: state-space mixers,
                        two-matrix held experts, grouped-query attention
                        (models/hybrid_decoder.py)
+  * lfm2_decoder     — the same module, layers of two mixers: gated short
+                       convolutions or attention with q/k norms and rotation,
+                       then a dense or a three-matrix expert feed-forward;
+                       tied head
 """
 from __future__ import annotations
 
@@ -156,6 +160,22 @@ def hybrid_decoder(**overrides) -> HybridDecoder:
     return HybridDecoder(**sizes)
 
 
+def lfm2_decoder(**overrides) -> HybridDecoder:
+    """Tiny widths by default (CPU tests): a convolution layer with a dense
+    feed-forward, an attention layer and a convolution layer with experts (2
+    of 8 a token, 4 held here, no shared one), embedding and head tied. Train
+    it with `training.lm_loss.make_lm_loss(module)`."""
+    sizes = dict(
+        vocab_rows=48, hidden_size=32, hybrid_override_pattern='CF*ECE',
+        conv_L_cache=3, intermediate_size=48, moe_intermediate_size=16,
+        n_routed_experts=8, num_experts_per_tok=2, experts_held=4,
+        mlp_hidden_act='silu', norm_topk_eps=1e-6, num_attention_heads=4,
+        num_key_value_heads=2, head_dim=8, qk_norm=True, rope_theta=1e6,
+        tie_word_embeddings=True)
+    sizes.update(overrides)
+    return HybridDecoder(**sizes)
+
+
 RECIPES = {
     'toy_denoise': toy_denoise,
     'flagship': flagship,
@@ -165,4 +185,5 @@ RECIPES = {
     'egnn_stress': egnn_stress,
     'token_decoder': token_decoder,
     'hybrid_decoder': hybrid_decoder,
+    'lfm2_decoder': lfm2_decoder,
 }

@@ -379,3 +379,93 @@ def test_both_sizes_of_the_expert_layer_keep_their_leaves(monkeypatch):
         cells.add((branch, backward, leaf))
     assert cells == {(b, back, leaf) for b in ('branch_0_fun', 'branch_1_fun')
                      for back in (False, True) for leaf in stages}
+
+
+# --------------------------------------------------------------------- #
+# the gated short convolution's leaves (models/hybrid_decoder.py's `C`)
+# --------------------------------------------------------------------- #
+SCONV_LEAVES = ('sconv_in', 'sconv_core', 'sconv_out')
+
+
+def test_the_short_convolutions_leaves_are_on_the_closed_list_and_new(
+        labelled):
+    assert set(SCONV_LEAVES) <= set(MODEL_SCOPES)
+    assert not set(SCONV_LEAVES) & set(HYBRID_LEAVES + DECODER_LEAVES)
+    comps = {c for _, _, p in labelled for c in p.split(';')[0].split('/')}
+    assert not comps & set(SCONV_LEAVES)
+
+
+def test_every_operation_of_the_short_convolution_is_under_its_leaves():
+    """The operator alone, recomputed as its block is: forward, replay and
+    backward, every operation under `sconv_in`, `sconv_core` or `sconv_out`,
+    the products under the first and the last, the gates and taps under the
+    middle one."""
+    from se3_transformer_tpu.ops.short_conv import ShortConvMixer
+    mixer = ShortConvMixer(dim=16, taps=3)
+    u = jax.ShapeDtypeStruct((2, 12, 16), jnp.float32)
+    params = jax.eval_shape(mixer.init, jax.random.PRNGKey(0), u)['params']
+
+    def loss(params, u):
+        with jax.named_scope('loss'):
+            return jnp.square(jax.checkpoint(lambda p, u: mixer.apply(
+                {'params': p}, u))(params, u)).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, u).as_text(
+        debug_info=True)
+    found = set(re.findall(r'"(jit\(loss\)/[^"]*)"', text))
+    inside = {p for p in found if 'ShortConvMixer' in p.split('/')}
+    # what is left is the square, its sum and the recomputed block's call
+    assert {p.rsplit('/', 1)[1] for p in found - inside} <= {
+        'mul', 'broadcast_in_dim', 'remat2', 'reduce_sum', 'checkpoint'}
+    cells = set()
+    for p in inside:
+        scopes, primitive = p.rsplit('/', 1)
+        leaf = profiling.scope_leaf(scopes)
+        assert leaf in SCONV_LEAVES, p
+        cells.add((leaf, profiling.scope_phase(scopes)))
+        if primitive == 'dot_general':
+            assert leaf in ('sconv_in', 'sconv_out'), p
+        if primitive in ('mul', 'pad', 'slice'):
+            assert leaf == 'sconv_core', p
+    assert {leaf for leaf, _ in cells} == set(SCONV_LEAVES)
+    for leaf in ('sconv_in', 'sconv_core'):
+        assert {ph for lf, ph in cells if lf == leaf} == set(
+            profiling.PHASES), leaf
+    assert ('sconv_out', 'backward') in cells
+
+
+def test_a_tiny_step_of_two_mixer_layers_has_every_leaf_and_the_phases():
+    import optax
+
+    from se3_transformer_tpu.parallel.sharding import make_sharded_train_step
+    from se3_transformer_tpu.training.lm_loss import make_lm_loss
+    from se3_transformer_tpu.training.recipes import RECIPES
+    module = RECIPES['lfm2_decoder'](attention_block=8)
+    tokens = jax.ShapeDtypeStruct((2, 16), jnp.int32)
+    params = jax.eval_shape(module.init, jax.random.PRNGKey(0),
+                            tokens)['params']
+    optimizer = optax.adam(1e-4)
+    step = make_sharded_train_step(make_lm_loss(module, chunk=8), optimizer)
+    text = step.lower(params, jax.eval_shape(optimizer.init, params),
+                      dict(tokens=tokens),
+                      jax.ShapeDtypeStruct((2,), jnp.uint32)
+                      ).as_text(debug_info=True)
+    paths = {p.rsplit('/', 1)[0] for p in re.findall(
+        r'"(jit\(train_step\)/[^"]*)"', text)}
+    cells = {(profiling.scope_leaf(p), profiling.scope_phase(p))
+             for p in paths}
+    leaves = {leaf for leaf, _ in cells}
+    # q/k norms and rotation under `mha_qkv`, the dense feed-forward under
+    # `dense_ff`; no shared expert, no head of its own
+    assert leaves == set(SCONV_LEAVES) | {
+        'mha_qkv', 'mha_core', 'mha_out', 'dense_ff', 'embed', 'moe_router',
+        'moe_dispatch', 'moe_experts', 'moe_combine', 'lm_head', 'norm',
+        'loss', 'optimizer'}
+    for leaf in ('sconv_in', 'sconv_core', 'mha_qkv', 'mha_core', 'dense_ff',
+                 'moe_experts'):
+        assert {ph for lf, ph in cells if lf == leaf} == set(
+            profiling.PHASES), leaf
+    rotation = [p for p in paths if p.endswith('/attn/mha_qkv') or
+                '/attn/mha_qkv/' in p]
+    assert any('q_norm' in p for p in rotation)
+    assert any('k_norm' in p for p in rotation)

@@ -621,3 +621,69 @@ def test_hybrid_decoder_step_compiles_and_fits(v5e, monkeypatch, capsys):
               f'{mem.temp_size_in_bytes / 2**30:.2f} GiB, in all '
               f'{total / 2**30:.2f} GiB of 15.75')
     assert 10 * 2 ** 30 < total < 13.5 * 2 ** 30, mem
+
+
+# ------------------------------------------------------------------ #
+# the decoder with gated short convolutions, at the widths of its cell
+# ------------------------------------------------------------------ #
+def test_streaming_attention_compiles_at_heads_of_64(v5e):
+    """The same kernel at 32 heads of 64 over two sequences of 8,192 tokens
+    (the eight key-value heads already repeated), half a lane row a head,
+    unpadded: forward and both backward launches."""
+    from se3_transformer_tpu.ops.latent_attention import (
+        causal_attention_flash,
+    )
+
+    def loss(q, k, v):
+        return causal_attention_flash(q, k, v, 64 ** -0.5, 512).sum()
+
+    assert compile_for(v5e, jax.grad(loss, argnums=(0, 1, 2)),
+                       *[((2, 32, 8192, 64), jnp.float32)] * 3) == 3
+
+
+@pytest.mark.slow
+def test_lfm2_decoder_step_compiles_and_fits(v5e, monkeypatch, capsys):
+    """The benchmark's short-convolution cell: the published widths of its
+    configuration file on the one step factory at two sequences of 8,192
+    tokens, compiled for the chip (under a minute): the attention kernel
+    and the grouped products are in it, the gates and taps are XLA's, and
+    state plus temporaries fit; its memory is printed."""
+    import json
+
+    import optax
+    from se3_transformer_tpu.ops import latent_attention
+    from se3_transformer_tpu.parallel.sharding import make_sharded_train_step
+    from se3_transformer_tpu.training.lm_loss import make_lm_loss
+    from se3_transformer_tpu.training.recipes import RECIPES
+
+    monkeypatch.setattr(latent_attention, 'is_tpu_backend', lambda: True)
+    cfg = json.load(open(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        'benchmark', 'configs', 'lfm2-24b-a2b-ep8-train.json')))
+    module = RECIPES[cfg['recipe']](**cfg['model'], **cfg['overrides'])
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+            tree)
+
+    tokens = jax.ShapeDtypeStruct((2, 8192), jnp.int32)
+    params = jax.eval_shape(module.init, jax.random.PRNGKey(0),
+                            tokens)['params']
+    optimizer = optax.adam(1e-6)
+    compiled = make_sharded_train_step(
+        make_lm_loss(module, **cfg['loss']), optimizer).lower(
+        on_chip(params), on_chip(jax.eval_shape(optimizer.init, params)),
+        on_chip(dict(tokens=tokens)),
+        on_chip(jax.random.PRNGKey(1))).compile()
+    text = compiled.as_text()
+    assert 'flash_mha_bwd_dkv' in text and 'ragged-dot' in text
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    with capsys.disabled():
+        print(f'\nlfm2 step for a v5e: arguments '
+              f'{mem.argument_size_in_bytes / 2**30:.2f} GiB, temporaries '
+              f'{mem.temp_size_in_bytes / 2**30:.2f} GiB, in all '
+              f'{total / 2**30:.2f} GiB of 15.75')
+    assert 9 * 2 ** 30 < total < 12.5 * 2 ** 30, mem

@@ -36,7 +36,7 @@ import flax.linen as nn
 from ..observability import named_scope
 from ..ops.expert_layer import ExpertLayer, SwiGLU
 from ..ops.grouped_attention import GroupedQueryAttention
-from ..ops.latent_attention import RMSNorm
+from ..ops.latent_attention import SAVE_ATTN_CORE, RMSNorm
 from ..ops.short_conv import ShortConvMixer
 from ..ops.state_space import Mamba2Mixer
 
@@ -107,7 +107,8 @@ class HybridDecoder(nn.Module):
     layer_norm_epsilon: float = 1e-5
     tie_word_embeddings: bool = False
     # execution, not architecture (every block is recomputed in the
-    # backward pass: its input alone is saved)
+    # backward pass: its input is saved, and the streaming attention core's
+    # output and softmax statistics, so the replay launches no forward)
     attention_block: int = 512       # ops/latent_attention.py
     bf16_operands: bool = True       # ops/expert_layer.py
 
@@ -142,7 +143,7 @@ class HybridDecoder(nn.Module):
                 kv_heads=self.num_key_value_heads, head_dim=self.head_dim,
                 block=self.attention_block, qk_norm=self.qk_norm,
                 rope_theta=self.rope_theta, eps=eps)}
-        block = nn.remat(MixerBlock)
+        block = nn.remat(MixerBlock, policy=SAVE_ATTN_CORE)
         self.embedding = nn.Embed(self.vocab_rows, self.hidden_size)
         self.blocks = [block(kind, fields[kind], eps)
                        for kind in self.hybrid_override_pattern]

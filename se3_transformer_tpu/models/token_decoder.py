@@ -30,7 +30,9 @@ import jax.numpy as jnp
 
 from ..observability import named_scope
 from ..ops.expert_layer import ExpertLayer, SwiGLU
-from ..ops.latent_attention import LatentAttention, RMSNorm
+from ..ops.latent_attention import (
+    SAVE_ATTN_CORE, LatentAttention, RMSNorm,
+)
 
 
 class DecoderBlock(nn.Module):
@@ -81,7 +83,8 @@ class TokenDecoder(nn.Module):
     rope_theta: float = 10000.0
     rms_norm_eps: float = 1e-5
     # execution, not architecture (every block is recomputed in the
-    # backward pass: its input alone is saved)
+    # backward pass: its input is saved, and the streaming attention core's
+    # output and softmax statistics, so the replay launches no forward)
     attention_block: int = 512       # ops/latent_attention.py
     bf16_operands: bool = True       # ops/expert_layer.py
 
@@ -101,7 +104,7 @@ class TokenDecoder(nn.Module):
             shared_width=self.n_shared_experts * self.moe_intermediate_size,
             routed_scale=self.routed_scaling_factor,
             norm_topk=self.norm_topk_prob, bf16_operands=self.bf16_operands)
-        block = nn.remat(DecoderBlock)
+        block = nn.remat(DecoderBlock, policy=SAVE_ATTN_CORE)
         eps = self.rms_norm_eps
         self.embedding = nn.Embed(self.vocab_rows, self.hidden_size)
         self.blocks = [

@@ -13,8 +13,10 @@ head; value heads may be wider than the keys' un-rotated part:
 Training materializes kn and v (no absorbed form). The core never holds the
 [heads, T, T] scores: on a TPU it is JAX's streaming Pallas kernel
 (`jax.experimental.pallas.ops.tpu.flash_attention`: bfloat16 operands,
-float32 softmax and accumulation), elsewhere blocks of queries against the
-keys at or before them, each block recomputed in the backward pass.
+float32 softmax and accumulation; its output and softmax statistics are
+named, and a rematted block saves them instead of launching the forward
+again), elsewhere blocks of queries against the keys at or before them, each
+block recomputed in the backward pass.
 """
 from __future__ import annotations
 
@@ -23,6 +25,7 @@ from functools import partial
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ..observability import named_scope
 from ..utils.helpers import is_tpu_backend
@@ -65,19 +68,76 @@ def causal_attention_blocked(q, k, v, scale: float, block_q: int = 512):
     return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=2)
 
 
-def causal_attention_flash(q, k, v, scale: float, block: int = 512):
-    """The same on the TPU's streaming kernel; operands rounded to bfloat16
-    here, where the XLA form leaves it to the default matmul precision."""
+# What a block's replay must not rebuild: the streaming core's output and its
+# softmax statistics, named in the forward rule below. The decoders' blocks
+# are rematted under `SAVE_ATTN_CORE`, which keeps these and nothing else.
+ATTN_CORE_OUT, ATTN_CORE_STATS = 'attn_core_out', 'attn_core_stats'
+SAVE_ATTN_CORE = jax.checkpoint_policies.save_only_these_names(
+    ATTN_CORE_OUT, ATTN_CORE_STATS)
+
+
+def _flash_forward(q, k, v, scale, block, save_residuals):
+    """The library's forward launch at blocks of `block`: o, or (o, l, m)
+    with the softmax's sum and maximum a row, [B, H, T] float32."""
     from jax.experimental.pallas.ops.tpu import flash_attention as fa
     b = min(block, q.shape[2])
-    sizes = fa.BlockSizes(
-        block_q=b, block_k_major=b, block_k=b, block_b=1,
-        block_q_major_dkv=b, block_k_major_dkv=b, block_k_dkv=b,
-        block_q_dkv=b, block_k_major_dq=b, block_k_dq=b, block_q_dq=b)
+    return fa._flash_attention_impl(
+        q, k, v, None, None, save_residuals, True, scale, 1, b, b, b, False)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _flash_core(q, k, v, scale, block):
+    return _flash_forward(q, k, v, scale, block, False)
+
+
+def _flash_core_fwd(q, k, v, scale, block):
+    o, l, m = _flash_forward(q, k, v, scale, block, True)
+    o = checkpoint_name(o, ATTN_CORE_OUT)
+    l, m = (checkpoint_name(a, ATTN_CORE_STATS) for a in (l, m))
+    return o, (q, k, v, o, l, m)
+
+
+def _flash_core_bwd(scale, block, residuals, do):
+    """The library's backward rule (`flash_attention.py::
+    _flash_attention_bwd`) on the residuals above."""
+    from jax.experimental.pallas.ops.tpu import flash_attention as fa
+    q, k, v, o, l, m = residuals
+    b = min(block, q.shape[2])
+    di = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
+    common = dict(sm_scale=scale, causal=True,
+                  mask_value=fa.DEFAULT_MASK_VALUE, debug=False)
+    dk, dv = fa._flash_attention_bwd_dkv(
+        q, k, v, None, None, l, m, do, di, block_q_major=b, block_q=b,
+        block_k_major=b, block_k=b, **common)
+    dq, _ = fa._flash_attention_bwd_dq(
+        q, k, v, None, None, l, m, do, di, block_q_major=b, block_k_major=b,
+        block_k=b, **common)
+    return dq, dk, dv
+
+
+_flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
+
+
+@partial(jax.jit, static_argnames=('scale', 'block'))
+def flash_attention(q, k, v, scale, block):
+    """A jit of this name, as the library's entry is: it is the forward
+    launch's name in a trace and a component of all three launches' paths."""
+    return _flash_core(q, k, v, scale, block)
+
+
+def causal_attention_flash(q, k, v, scale: float, block: int = 512):
+    """The same on the TPU's streaming kernel; operands rounded to bfloat16
+    here, where the XLA form leaves it to the default matmul precision. The
+    library's three launches under a `custom_vjp` of the repo's own, so that
+    the forward's (o, l, m) carry names a remat policy can save: under
+    `SAVE_ATTN_CORE` a block's replay launches no forward. Undifferentiated,
+    the forward computes no statistics."""
+    if not q.shape == k.shape == v.shape:
+        raise NotImplementedError(
+            f'the streaming kernel takes q, k, v of one shape, not '
+            f'{q.shape}, {k.shape}, {v.shape}')
     q, k, v = (a.astype(jnp.bfloat16) for a in (q, k, v))
-    out = fa.flash_attention(q, k, v, causal=True, sm_scale=scale,
-                             block_sizes=sizes)
-    return out.astype(jnp.float32)
+    return flash_attention(q, k, v, scale, block).astype(jnp.float32)
 
 
 def causal_attention(q, k, v, scale: float, block: int = 512):

@@ -5,16 +5,19 @@ the program), at tiny widths in float32 on the CPU, and the properties the
 architecture states one by one."""
 import importlib.util
 import os
+import re
 from fractions import Fraction
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from test_hybrid_decoder import SIZES as HYBRID_SIZES
 
 from se3_transformer_tpu.models.token_decoder import TokenDecoder
-from se3_transformer_tpu.ops import expert_layer
+from se3_transformer_tpu.ops import expert_layer, latent_attention
 from se3_transformer_tpu.ops.expert_layer import (
     ExpertLayer, balance_bias, grouped_dot, route,
 )
@@ -470,3 +473,183 @@ def test_three_steps_on_the_one_step_factory_with_the_counters_in_aux(
         fit = held.sum(axis=(1, 2)) <= rows
         assert int(aux['moe_bounded']) == fit.sum()
     assert losses[2] < losses[1] < losses[0]
+
+
+# ------------------------------------------------------------------ #
+# the streaming core's own custom_vjp, its three launches stood in for
+# ------------------------------------------------------------------ #
+@pytest.fixture
+def core_stand_ins(monkeypatch):
+    """The library kernel's three launches as plain `jax.numpy` of the same
+    signatures and dtypes (they do not lower on a CPU). The forward is a
+    host callback, so a compiled program holds one custom call a launch and
+    a run counts them: `forwards` gets each one's `save_residuals`."""
+    from jax.experimental.pallas.ops.tpu import flash_attention as fa
+    forwards = []
+
+    def masked_scores(q, k, scale):
+        s = jnp.einsum('bhqd,bhkd->bhqk', q.astype(jnp.float32),
+                       k.astype(jnp.float32)) * scale
+        t = q.shape[2]
+        return jnp.where(jnp.tril(jnp.ones((t, t), bool)), s,
+                         fa.DEFAULT_MASK_VALUE)
+
+    def forward(q, k, v, ab, segment_ids, save_residuals, causal, sm_scale,
+                *blocks):
+        assert ab is None and segment_ids is None and causal
+
+        def on_host(q, k, v):
+            forwards.append(save_residuals)
+            q, k, v = (np.asarray(a, np.float32) for a in (q, k, v))
+            s = np.einsum('bhqd,bhkd->bhqk', q, k) * sm_scale
+            t = q.shape[2]
+            s = np.where(np.tril(np.ones((t, t), bool)), s,
+                         fa.DEFAULT_MASK_VALUE)
+            m = s.max(-1)
+            p = np.exp(s - m[..., None])
+            l = p.sum(-1)
+            o = np.einsum('bhqk,bhkd->bhqd', p / l[..., None], v)
+            return o.astype(np.float32), l, m
+
+        f32 = partial(jax.ShapeDtypeStruct, dtype=jnp.float32)
+        o, l, m = jax.pure_callback(
+            on_host, (f32(q.shape), f32(q.shape[:3]), f32(q.shape[:3])),
+            q, k, v)
+        o = o.astype(q.dtype)
+        return (o, l, m) if save_residuals else o
+
+    def p_and_ds(q, k, v, l, m, do, di, scale):
+        p = jnp.exp(masked_scores(q, k, scale) - m[..., None]) / l[..., None]
+        dp = jnp.einsum('bhqd,bhkd->bhqk', do.astype(jnp.float32),
+                        v.astype(jnp.float32))
+        return p, p * (dp - di[..., None]) * scale
+
+    def bwd_dkv(q, k, v, ab, segment_ids, l, m, do, di, *, sm_scale, causal,
+                **blocks):
+        assert ab is None and segment_ids is None and causal
+        p, ds = p_and_ds(q, k, v, l, m, do, di, sm_scale)
+        dk = jnp.einsum('bhqk,bhqd->bhkd', ds, q.astype(jnp.float32))
+        dv = jnp.einsum('bhqk,bhqd->bhkd', p, do.astype(jnp.float32))
+        return dk.astype(k.dtype), dv.astype(v.dtype)
+
+    def bwd_dq(q, k, v, ab, segment_ids, l, m, do, di, *, sm_scale, causal,
+               **blocks):
+        assert ab is None and segment_ids is None and causal
+        _, ds = p_and_ds(q, k, v, l, m, do, di, sm_scale)
+        dq = jnp.einsum('bhqk,bhkd->bhqd', ds, k.astype(jnp.float32))
+        return dq.astype(q.dtype), None
+
+    monkeypatch.setattr(fa, '_flash_attention_impl', forward)
+    monkeypatch.setattr(fa, '_flash_attention_bwd_dkv', bwd_dkv)
+    monkeypatch.setattr(fa, '_flash_attention_bwd_dq', bwd_dq)
+    latent_attention.flash_attention.clear_cache()   # a trace keeps its calls
+    yield forwards
+    latent_attention.flash_attention.clear_cache()
+
+
+def _qkv_and_block(t=64, d=16, block=16):
+    """q, k, v [1, 2, t, d] and a stand-in for a decoder block around a
+    core: what feeds it is replayed, what follows needs its output."""
+    kq, kk, kv, kw = jax.random.split(jax.random.PRNGKey(0), 4)
+    q, k, v = (jax.random.normal(key, (1, 2, t, d)) for key in (kq, kk, kv))
+    w = jax.random.normal(kw, (d, d)) / 4
+
+    def around(core):
+        return lambda q, k, v: jnp.tanh(
+            core(jnp.sin(q), k, v, d ** -0.5, block) @ w)
+    return (q, k, v), around
+
+
+@pytest.mark.parametrize('policy,launches', [
+    (latent_attention.SAVE_ATTN_CORE, 1), (None, 2)],
+    ids=["the blocks' policy", 'the default policy'])
+def test_a_replayed_block_holds_the_forward_core_once_under_the_blocks_policy(
+        core_stand_ins, policy, launches):
+    """Saved (o, l, m) leave the replay no forward to launch; with nothing
+    saved the forward pass and the replay each hold one. Either way both
+    carry statistics."""
+    qkv, around = _qkv_and_block()
+    block = jax.checkpoint(around(latent_attention.flash_attention),
+                           policy=policy)
+    grad = jax.jit(jax.grad(lambda *a: jnp.sum(block(*a) ** 2),
+                            argnums=(0, 1, 2)))
+    text = grad.lower(*qkv).compile().as_text()
+    assert len(re.findall(r'custom_call_target="[^"]*callback[^"]*"',
+                          text)) == launches
+    jax.block_until_ready(grad(*qkv))
+    assert core_stand_ins == [True] * launches
+
+
+@pytest.mark.parametrize('block', [16, 64])
+def test_the_streaming_cores_gradients_are_the_blocked_cores(core_stand_ins,
+                                                             block):
+    """The wiring itself in float32 (the entry rounds to bfloat16 before
+    it): output and the gradients of q, k, v under the blocks' policy."""
+    qkv, around = _qkv_and_block(block=block)
+
+    def value_and_grads(core, **remat):
+        f = jax.checkpoint(around(core), **remat)
+        return jax.jit(jax.value_and_grad(
+            lambda *a: jnp.sum(f(*a) ** 2), argnums=(0, 1, 2)))(*qkv)
+
+    got, got_grads = value_and_grads(latent_attention.flash_attention,
+                                     policy=latent_attention.SAVE_ATTN_CORE)
+    want, want_grads = value_and_grads(
+        latent_attention.causal_attention_blocked)
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    for g, w in zip(got_grads, want_grads):
+        np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-5)
+
+
+def test_the_primal_alone_asks_for_no_statistics(core_stand_ins):
+    """Undifferentiated (serving, `eval_shape`) the entry launches one
+    forward without (l, m), on bfloat16 operands, and returns float32."""
+    (q, k, v), _ = _qkv_and_block()
+    out = jax.jit(partial(latent_attention.causal_attention_flash,
+                          scale=0.25, block=16))(q, k, v)
+    assert out.dtype == jnp.float32 and core_stand_ins == [False]
+    want = latent_attention.causal_attention_blocked(q, k, v, 0.25, 16)
+    np.testing.assert_allclose(out, want, atol=3e-2)
+    shape = jax.eval_shape(partial(latent_attention.causal_attention_flash,
+                                   scale=0.25, block=16), q, k, v)
+    assert shape.shape == q.shape and core_stand_ins == [False]
+
+
+@pytest.mark.parametrize('recipe,sizes,layers', [
+    ('token_decoder', SIZES, 4), ('hybrid_decoder', HYBRID_SIZES, 1)])
+def test_a_decoders_step_launches_one_forward_core_an_attention_layer(
+        core_stand_ins, monkeypatch, recipe, sizes, layers):
+    """Both decoders' blocks carry the policy: on the streaming path (128
+    tokens, the platform check stood in for) the loss's gradient runs one
+    forward a layer and none in a block's replay; with nothing saved it runs
+    two and gives the same gradient, since what is saved is what the replay
+    would rebuild. The loss is the blocked path's to the kernel's bfloat16
+    rounding (the gradients' gap holds flipped expert choices too)."""
+    from se3_transformer_tpu.models import hybrid_decoder, token_decoder
+    tokens = jnp.asarray(
+        np.random.default_rng(0).integers(0, 48, (1, 128)), jnp.int32)
+
+    module = RECIPES[recipe](bf16_operands=False, attention_block=64, **sizes)
+    params = jax.jit(module.init)(jax.random.PRNGKey(0), tokens)['params']
+
+    def loss_and_grads():     # traced anew: the blocks read the patches
+        (loss, _), grads = jax.jit(jax.value_and_grad(
+            make_lm_loss(module, chunk=32), has_aux=True))(
+            params, dict(tokens=tokens), None)
+        return loss, grads
+
+    blocked, _ = loss_and_grads()
+    assert core_stand_ins == []                  # blocks of queries in XLA
+    monkeypatch.setattr(latent_attention, 'is_tpu_backend', lambda: True)
+    loss, grads = loss_and_grads()
+    assert core_stand_ins == [True] * layers
+    assert float(loss) == pytest.approx(float(blocked), rel=2e-3)
+    del core_stand_ins[:]
+    for models in (token_decoder, hybrid_decoder):
+        monkeypatch.setattr(models, 'SAVE_ATTN_CORE', None)
+    replayed_loss, replayed_grads = loss_and_grads()
+    assert core_stand_ins == [True] * 2 * layers
+    assert float(replayed_loss) == float(loss)
+    jax.tree_util.tree_map(
+        partial(np.testing.assert_allclose, rtol=1e-6, atol=1e-9),
+        grads, replayed_grads)

@@ -519,11 +519,32 @@ def test_the_expert_layer_works_on_the_held_row_bound_at_the_hybrid_cells_size(
             assert re.search(r's32\[49152\]', body)
 
 
+def _assert_one_forward_core_a_layer(text, layers, leaf):
+    """The streaming kernel's launches in a compiled step: `layers` of each
+    of the three and no forward in a block's replay (the blocks save its
+    output and statistics), each under the scope `leaf`, which the per-layer
+    metrics read, forward and backward alike."""
+    launches = re.findall(
+        r'%(flash_\w+)[.\d]* = [^\n]*tpu_custom_call[^\n]*op_name="([^"]*)"',
+        text)
+    by_role = {}
+    for name, path in launches:
+        assert f'/{leaf}/jit(flash_attention)/' in path, path
+        assert 'rematted_computation' not in path, path
+        assert ('transpose(' in path) == (name != 'flash_attention'), path
+        by_role.setdefault(name.split('_block_')[0], []).append(path)
+    assert {role: len(paths) for role, paths in by_role.items()} == {
+        'flash_attention': layers, 'flash_mha_bwd_dkv': layers,
+        'flash_mha_bwd_dq': layers}, by_role
+
+
 @pytest.mark.slow
-def test_token_decoder_step_compiles_and_fits(v5e, monkeypatch):
+def test_token_decoder_step_compiles_and_fits(v5e, monkeypatch, capsys):
     """The benchmark's decoder cell: the published widths of its
     configuration file on the one step factory, compiled for the chip (under
-    a minute): both kernels are in it and state plus temporaries fit."""
+    a minute): both kernels are in it, the attention kernel's forward once a
+    block, and state plus temporaries fit with the six blocks' saved
+    attention outputs (11.67 GiB; 11.26 with nothing saved)."""
     import json
 
     import optax
@@ -553,10 +574,16 @@ def test_token_decoder_step_compiles_and_fits(v5e, monkeypatch):
         on_chip(jax.random.PRNGKey(1))).compile()
     text = compiled.as_text()
     assert 'flash_mha_bwd_dkv' in text and 'ragged-dot' in text
+    _assert_one_forward_core_a_layer(text, 6, 'latent_core')
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
              + mem.output_size_in_bytes - mem.alias_size_in_bytes)
-    assert 10.5 * 2 ** 30 < total < 13 * 2 ** 30, mem
+    with capsys.disabled():
+        print(f'\ntoken decoder step for a v5e: arguments '
+              f'{mem.argument_size_in_bytes / 2**30:.2f} GiB, temporaries '
+              f'{mem.temp_size_in_bytes / 2**30:.2f} GiB, in all '
+              f'{total / 2**30:.2f} GiB of 15.75')
+    assert 11.5 * 2 ** 30 < total < 12 * 2 ** 30, mem
 
 
 # ------------------------------------------------------------------ #
@@ -612,6 +639,7 @@ def test_hybrid_decoder_step_compiles_and_fits(v5e, monkeypatch, capsys):
         on_chip(jax.random.PRNGKey(1))).compile()
     text = compiled.as_text()
     assert 'flash_mha_bwd_dkv' in text and 'ragged-dot' in text
+    _assert_one_forward_core_a_layer(text, 1, 'mha_core')
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
              + mem.output_size_in_bytes - mem.alias_size_in_bytes)
@@ -678,6 +706,7 @@ def test_lfm2_decoder_step_compiles_and_fits(v5e, monkeypatch, capsys):
         on_chip(jax.random.PRNGKey(1))).compile()
     text = compiled.as_text()
     assert 'flash_mha_bwd_dkv' in text and 'ragged-dot' in text
+    _assert_one_forward_core_a_layer(text, 1, 'mha_core')
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
              + mem.output_size_in_bytes - mem.alias_size_in_bytes)

@@ -399,6 +399,7 @@ def test_flagship_train_step_compiles_and_fits(v5e, monkeypatch):
         on_chip(params), on_chip(opt_state), on_chip(data),
         on_chip(jax.random.PRNGKey(1))).compile()
     assert compiled.as_text().count('tpu_custom_call') > 0
+    _assert_product_front_ends_agree(compiled)
     mem = compiled.memory_analysis()
     # donated state aliases its outputs
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
@@ -538,6 +539,39 @@ def _assert_one_forward_core_a_layer(text, layers, leaf):
         'flash_mha_bwd_dq': layers}, by_role
 
 
+def _assert_product_front_ends_agree(compiled):
+    """The trace reducer counts an instruction's products from the
+    `HloModuleProto` the chip's profiler stores beside a trace, through
+    fields of `hlo.proto` it declares by hand. The check of those is the
+    same rule on compiled HLO text (tests/hlo_text_reference.py): on one
+    compiled step the two agree on every instruction, to the operation; the
+    text prints an asynchronous pair under its own opcode (`slice-start`)
+    and the computation it wraps inline, which is all the proto has beyond
+    it."""
+    from hlo_text_reference import hlo_text_computations
+    from se3_transformer_tpu.observability import profiling
+    text = profiling.product_counts(hlo_text_computations(compiled.as_text()))
+    proto = profiling.hlo_proto_class()()
+    proto.hlo_module.ParseFromString(
+        compiled.runtime_executable().hlo_modules()[0]
+        .as_serialized_hlo_module_proto())
+    module = profiling.product_counts(
+        profiling.hlo_proto_computations(proto.hlo_module))
+    assert set(text) <= set(module)
+    for name, row in text.items():
+        other = module[name]
+        assert (row['flops'], row['products'], row['kind']) == (
+            other['flops'], other['products'], other['kind']), name
+        assert row['opcode'] == other['opcode'] or \
+            other['opcode'].startswith('async-'), name
+    assert all(module[name]['products'] == 0
+               for name in set(module) - set(text))
+    products = sum(row['products'] for name, row in text.items()
+                   if row['opcode'] != 'fusion')
+    assert products > 100
+    return text
+
+
 @pytest.mark.slow
 def test_token_decoder_step_compiles_and_fits(v5e, monkeypatch, capsys):
     """The benchmark's decoder cell: the published widths of its
@@ -575,6 +609,7 @@ def test_token_decoder_step_compiles_and_fits(v5e, monkeypatch, capsys):
     text = compiled.as_text()
     assert 'flash_mha_bwd_dkv' in text and 'ragged-dot' in text
     _assert_one_forward_core_a_layer(text, 6, 'latent_core')
+    _assert_product_front_ends_agree(compiled)
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
              + mem.output_size_in_bytes - mem.alias_size_in_bytes)
@@ -640,6 +675,7 @@ def test_hybrid_decoder_step_compiles_and_fits(v5e, monkeypatch, capsys):
     text = compiled.as_text()
     assert 'flash_mha_bwd_dkv' in text and 'ragged-dot' in text
     _assert_one_forward_core_a_layer(text, 1, 'mha_core')
+    _assert_product_front_ends_agree(compiled)
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
              + mem.output_size_in_bytes - mem.alias_size_in_bytes)
@@ -707,6 +743,7 @@ def test_lfm2_decoder_step_compiles_and_fits(v5e, monkeypatch, capsys):
     text = compiled.as_text()
     assert 'flash_mha_bwd_dkv' in text and 'ragged-dot' in text
     _assert_one_forward_core_a_layer(text, 1, 'mha_core')
+    _assert_product_front_ends_agree(compiled)
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
              + mem.output_size_in_bytes - mem.alias_size_in_bytes)

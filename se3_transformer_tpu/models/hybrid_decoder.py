@@ -137,7 +137,8 @@ class HybridDecoder(nn.Module):
                 norm_topk=self.norm_topk_prob,
                 norm_topk_eps=self.norm_topk_eps,
                 bf16_operands=self.bf16_operands),
-            'F': dict(width=self.intermediate_size),
+            'F': dict(width=self.intermediate_size,
+                      bf16_operands=self.bf16_operands),
             '*': dict(
                 dim=self.hidden_size, heads=self.num_attention_heads,
                 kv_heads=self.num_key_value_heads, head_dim=self.head_dim,

@@ -38,8 +38,8 @@ from ..ops.latent_attention import (
 class DecoderBlock(nn.Module):
     attention: dict            # LatentAttention's fields
     eps: float
-    dense_width: int = 0       # > 0: a SwiGLU of this width
-    experts: Optional[dict] = None   # else ExpertLayer's fields
+    dense: Optional[dict] = None     # SwiGLU's fields
+    experts: Optional[dict] = None   # else ExpertLayer's
 
     @nn.compact
     def __call__(self, h):
@@ -52,7 +52,7 @@ class DecoderBlock(nn.Module):
             f = RMSNorm(self.eps, name='ff_norm')(h)
         if self.experts is None:
             with named_scope('dense_ff'):
-                return h + SwiGLU(self.dense_width, name='mlp')(f), None
+                return h + SwiGLU(**self.dense, name='mlp')(f), None
         b, t, d = f.shape
         out, stats = ExpertLayer(**self.experts, name='moe')(
             f.reshape(b * t, d))
@@ -104,11 +104,13 @@ class TokenDecoder(nn.Module):
             shared_width=self.n_shared_experts * self.moe_intermediate_size,
             routed_scale=self.routed_scaling_factor,
             norm_topk=self.norm_topk_prob, bf16_operands=self.bf16_operands)
+        dense = dict(width=self.intermediate_size,
+                     bf16_operands=self.bf16_operands)
         block = nn.remat(DecoderBlock, policy=SAVE_ATTN_CORE)
         eps = self.rms_norm_eps
         self.embedding = nn.Embed(self.vocab_rows, self.hidden_size)
         self.blocks = [
-            block(attention, eps, dense_width=self.intermediate_size)
+            block(attention, eps, dense=dense)
             if i < self.first_k_dense_replace
             else block(attention, eps, experts=experts)
             for i in range(self.num_hidden_layers)]

@@ -15,6 +15,12 @@ a published config gives the choice):
     'silu'    (silu(x Wg) * (x Wu)) Wd     gated, three matrices
     'relu2'   relu(x Wu)^2 Wd              squared ReLU, two matrices
 
+The dense gated form (`SwiGLU`: the shared expert and the decoders' dense
+feed-forward) has a backward of its own, `gated_ff`: one pass over gate, up
+and dh = dy Wd^T writes d_gate, d_up and silu(gate) * up once, in the width
+the products round their operands to (bfloat16 where `bf16_operands`), and the
+five products of the weights' and x's cotangents read them.
+
 The router keeps all `n_experts` outputs; this chip holds experts
 `expert_rank * experts_held ...` of them and computes their part of the
 result. What the absent experts would add is left out (the partial result an
@@ -237,16 +243,75 @@ def _bounded_or_full_bwd(rows, gated, dtype, res, g):
 _bounded_or_full.defvjp(_bounded_or_full_fwd, _bounded_or_full_bwd)
 
 
+def _gated_operands(gate, up, dh, dtype):
+    """One pass over gate, up [N, width] (float32) and dh = dy Wd^T: the three
+    operands of the gated form's backward products, d_gate = dh * up *
+    silu'(gate), d_up = dh * silu(gate) and hidden = silu(gate) * up, each
+    written once in `dtype`. Behind the barrier, or XLA fuses each chain into
+    every product that reads it and computes it from the float32 tensors once
+    a pass over that operand's tiles."""
+    s = nn.sigmoid(gate)
+    act = gate * s
+    d_act = s * (1.0 + gate * (1.0 - s))
+    return jax.lax.optimization_barrier(tuple(
+        _cast(a, dtype) for a in (dh * up * d_act, dh * act, act * up)))
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(4,))
+def gated_ff(x, wg, wu, wd, operand_dtype=None):
+    """(silu(x wg) * (x wu)) wd for x [N, d], float32. The backward's five
+    products read `_gated_operands` in `operand_dtype` (None: as they are);
+    nothing else is rounded, and the cotangents are float32."""
+    return _gated_ff_fwd(x, wg, wu, wd, operand_dtype)[0]
+
+
+def _contract(a, i, b, j):
+    """Axis i of a with axis j of b, in float32."""
+    return jax.lax.dot_general(a, b, (((i,), (j,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _gated_ff_fwd(x, wg, wu, wd, operand_dtype):
+    gate, up = _contract(x, 1, wg, 0), _contract(x, 1, wu, 0)
+    return _contract(nn.silu(gate) * up, 1, wd, 0), (x, wg, wu, wd, gate, up)
+
+
+def _gated_ff_bwd(operand_dtype, res, dy):
+    x, wg, wu, wd, gate, up = res
+    dh = _contract(dy, 1, wd, 1)
+    d_gate, d_up, hidden = _gated_operands(gate, up, dh, operand_dtype)
+    dx = _contract(d_gate, 1, wg, 1) + _contract(d_up, 1, wu, 1)
+    return (dx, _contract(x, 0, d_gate, 0), _contract(x, 0, d_up, 0),
+            _contract(hidden, 0, dy, 0))
+
+
+gated_ff.defvjp(_gated_ff_fwd, _gated_ff_bwd)
+
+
+class _Kernel(nn.Module):
+    """A `nn.Dense`'s parameter (`<name>/kernel`) without its product."""
+    shape: tuple
+
+    @nn.compact
+    def __call__(self):
+        return self.param('kernel', nn.initializers.lecun_normal(),
+                          self.shape)
+
+
 class SwiGLU(nn.Module):
-    """(silu(x Wg) * (x Wu)) Wd."""
+    """(silu(x Wg) * (x Wu)) Wd, differentiated by `gated_ff`."""
     width: int
+    bf16_operands: bool = True   # of the backward's three built operands
 
     @nn.compact
     def __call__(self, x):
-        dense = partial(nn.Dense, use_bias=False)
-        gate = dense(self.width, name='gate')(x)
-        up = dense(self.width, name='up')(x)
-        return dense(x.shape[-1], name='down')(nn.silu(gate) * up)
+        d = x.shape[-1]
+        wg, wu, wd = (_Kernel(shape, name=name)() for name, shape in (
+            ('gate', (d, self.width)), ('up', (d, self.width)),
+            ('down', (self.width, d))))
+        y = gated_ff(x.reshape(-1, d), wg, wu, wd,
+                     jnp.bfloat16 if self.bf16_operands else None)
+        return y.reshape(x.shape)
 
 
 class SquaredReLU(nn.Module):
@@ -357,8 +422,10 @@ class ExpertLayer(nn.Module):
             out = _bounded_or_full(rows, gated, dtype, fits, x, weights, mats,
                                    order, inverse, load)
         if self.shared_width:
+            fields = dict(bf16_operands=self.bf16_operands) if gated else {}
             with named_scope('shared_expert'):
-                out = out + shared(self.shared_width, name='shared')(x)
+                out = out + shared(self.shared_width, **fields,
+                                   name='shared')(x)
         stats = dict(load=load, chosen=chosen, scores=scores,
                      dropped=jnp.sum(here, dtype=jnp.int32) - jnp.sum(load),
                      bounded=fits.astype(jnp.int32))

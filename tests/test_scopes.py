@@ -6,7 +6,7 @@ distinct role names with the old prefixes."""
 import ast
 import os
 import re
-from functools import partial
+from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
@@ -281,6 +281,51 @@ def test_a_tiny_decoder_step_has_every_decoder_leaf_and_the_three_phases():
         assert {(leaf, ph) for ph in profiling.PHASES} <= cells, leaf
     # no SE(3) leaf but the shared `norm`, `loss` and `optimizer`
     assert leaves <= set(DECODER_LEAVES) | {'norm', 'loss', 'optimizer'}
+
+
+@lru_cache(maxsize=None)
+def _tiny_step_text(recipe):
+    """A one-sequence step of `recipe` at its smallest, lowered."""
+    import optax
+
+    from se3_transformer_tpu.parallel.sharding import make_sharded_train_step
+    from se3_transformer_tpu.training.lm_loss import make_lm_loss
+    from se3_transformer_tpu.training.recipes import RECIPES
+    module = RECIPES[recipe](attention_block=8)
+    tokens = jax.ShapeDtypeStruct((1, 16), jnp.int32)
+    params = jax.eval_shape(module.init, jax.random.PRNGKey(0),
+                            tokens)['params']
+    optimizer = optax.adam(1e-4)
+    step = make_sharded_train_step(make_lm_loss(module, chunk=8), optimizer)
+    return step.lower(params, jax.eval_shape(optimizer.init, params),
+                      dict(tokens=tokens),
+                      jax.ShapeDtypeStruct((2,), jnp.uint32)
+                      ).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize('recipe,leaf', [
+    ('token_decoder', 'dense_ff'), ('token_decoder', 'shared_expert'),
+    ('lfm2_decoder', 'dense_ff')])
+def test_the_gated_rules_backward_files_under_its_callers_leaf(recipe, leaf):
+    """`ops/expert_layer.py::gated_ff` opens no scope of its own: its
+    backward is traced under the name stack of the call, so the one pass
+    (sigmoid, barrier) and the six products carry the caller's leaf with
+    phase backward, and the replay the forward rule's two products."""
+    text = _tiny_step_text(recipe)
+    met = {}
+    for scopes, primitive in (p.rsplit('/', 1) for p in re.findall(
+            r'"(jit\(train_step\)/[^"]*)"', text)):
+        if primitive == 'optimization_barrier':
+            assert profiling.scope_leaf(scopes) in (
+                'dense_ff', 'shared_expert'), scopes
+            assert profiling.scope_phase(scopes) == 'backward', scopes
+        if profiling.scope_leaf(scopes) == leaf:
+            met.setdefault(profiling.scope_phase(scopes), set()).add(
+                primitive)
+    assert {'optimization_barrier', 'logistic', 'dot_general',
+            'convert_element_type'} <= met['backward'], met
+    assert 'dot_general' in met['replay'] and \
+        'optimization_barrier' not in met['replay'] | met['forward'], met
 
 
 # --------------------------------------------------------------------- #

@@ -20,6 +20,7 @@ touches a chip. The persistent compilation cache is off around the
 cases — a deviceless executable can be written to it but not read back
 without a chip.
 """
+import math
 import os
 import re
 
@@ -572,6 +573,48 @@ def _assert_product_front_ends_agree(compiled):
     return text
 
 
+def _assert_gated_backward_products_are_plain(text, tokens, widths):
+    """The gated feed-forwards of a compiled step (`expert_layer.gated_ff`,
+    one a width of `widths`, under `dense_ff` or `shared_expert`): no product
+    of the backward holds SiLU's chain or reads a float32 [tokens, width]
+    tensor (dh is written by one, and read by the pass alone); the chain is
+    computed in one fusion a feed-forward outside the forward, and the six
+    products after it read what it wrote, in bfloat16."""
+    comps = _computations(text)
+    fused = set(re.findall(r'calls=%([\w.\-]+)', text))
+    chains, plain = [], 0
+    for name, body in comps.items():
+        if name in fused:
+            continue
+        for line in body.splitlines():
+            m = re.match(r'\s*(?:ROOT )?%[\w.\-]+ = .*? (fusion|convolution)'
+                         r'\((.*?)\), ', line)
+            path = re.search(r'op_name="([^"]*)"', line)
+            if not m or not path or not re.search(
+                    r'/(dense_ff|shared_expert)/', path.group(1)):
+                continue
+            path = path.group(1)
+            if 'transpose(' not in path:       # the forward
+                continue
+            called = re.search(r'calls=%([\w.\-]+)', line)
+            inside = _with_callees(comps, called.group(1)) if called else line
+            chain = 'exponential(' in inside or 'logistic(' in inside
+            if 'rematted_computation' in path or 'convolution(' not in inside:
+                chains += [path] * chain        # the replay's, or no product
+                continue
+            assert not chain, line[:300]
+            read = [comps[name].split(f'%{o} = ', 1)[1].split(' ', 1)[0]
+                    for o in re.findall(r'%([\w.\-]+)', m.group(2))
+                    if f'%{o} = ' in comps[name]]
+            for r in read:
+                dims = [int(d) for d in re.findall(r'\d+', r.split('{')[0])]
+                assert not (r.startswith('f32[') and dims[-1] in widths
+                            and math.prod(dims[:-1]) == tokens), (line[:300], r)
+            plain += any(r.startswith('bf16[') for r in read)
+    assert len(chains) == len(widths), chains
+    assert plain == 5 * len(widths), plain
+
+
 @pytest.mark.slow
 def test_token_decoder_step_compiles_and_fits(v5e, monkeypatch, capsys):
     """The benchmark's decoder cell: the published widths of its
@@ -610,6 +653,9 @@ def test_token_decoder_step_compiles_and_fits(v5e, monkeypatch, capsys):
     assert 'flash_mha_bwd_dkv' in text and 'ragged-dot' in text
     _assert_one_forward_core_a_layer(text, 6, 'latent_core')
     _assert_product_front_ends_agree(compiled)
+    _assert_gated_backward_products_are_plain(
+        text, 8192, [cfg['model']['intermediate_size']]
+        + [cfg['model']['moe_intermediate_size']] * 5)
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
              + mem.output_size_in_bytes - mem.alias_size_in_bytes)
@@ -744,6 +790,8 @@ def test_lfm2_decoder_step_compiles_and_fits(v5e, monkeypatch, capsys):
     assert 'flash_mha_bwd_dkv' in text and 'ragged-dot' in text
     _assert_one_forward_core_a_layer(text, 1, 'mha_core')
     _assert_product_front_ends_agree(compiled)
+    _assert_gated_backward_products_are_plain(
+        text, 2 * 8192, [cfg['model']['intermediate_size']])
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
              + mem.output_size_in_bytes - mem.alias_size_in_bytes)

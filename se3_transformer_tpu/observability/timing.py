@@ -103,7 +103,9 @@ MODEL_SCOPES = (
     'ssm_conv',           # ops/state_space.py: the causal depthwise
     #                       convolution and its activation
     'ssm_scan',           # ops/state_space.py: from dt, A, x, B, C to y
-    #                       (the chunked scan, D included)
+    #                       (the chunked scan, D included: on a TPU the
+    #                       launches `ssm_scan_fwd` and `ssm_scan_bwd` of
+    #                       kernels/pallas_scan.py, elsewhere einsums)
     'ssm_gate',           # ops/state_space.py: the gated group norm
     'ssm_out',            # ops/state_space.py: output projection
     'mha_qkv',            # ops/grouped_attention.py: q, k, v projections,

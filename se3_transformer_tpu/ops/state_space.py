@@ -13,13 +13,21 @@
 The scan is the chunked matrix form (`chunked_scan`): inside a chunk of Q
 tokens the masked product (L o C B^T)(dt x) with L_ij = exp(sum_{j<r<=i} dt_r
 A); one P x N state a chunk and head from the chunk's own tokens; the chunk
-states carried forward by one more masked product over the chunks (no
-`lax.scan`: inside a scanned body operations lose their scopes). Every
-exponent is a sum of non-positive terms taken before the exponential, so a
-chunk whose decay underflows gives zero, never a quotient of zeros. `dt`,
-the cumulative sums, the decays and the carried state are float32; the large
-products take the backend's default precision (on a TPU operands rounded to
-bfloat16, float32 accumulation). Autodiff differentiates it.
+states carried forward. Every exponent is a sum of non-positive terms taken
+before the exponential, so a chunk whose decay underflows gives zero, never a
+quotient of zeros. `dt`, the cumulative sums, the decays and the carried state
+are float32; the large products take the backend's default precision (on a
+TPU operands rounded to bfloat16, float32 accumulation).
+
+Two forms, one rule (`chunked_scan`). On a TPU, at widths Mosaic tiles, the
+two kernels of `kernels/pallas_scan.py` (`scan_kernels`): scores, decays, the
+masked product and the chunk states stay in VMEM, the state carried from
+chunk to chunk in a scratch, a backward launch of its own under a
+`custom_vjp`; XLA keeps the cumulative sums of dt A. Elsewhere XLA's einsums
+(`scan_einsums`), which autodiff differentiates and which are the kernels'
+oracle: there the chunk states are carried by one more masked product over
+the chunks (no `lax.scan`: inside a scanned body operations lose their
+scopes).
 """
 from __future__ import annotations
 
@@ -30,7 +38,9 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..kernels import pallas_scan
 from ..observability import named_scope
+from ..utils.helpers import is_tpu_backend
 
 
 def _decay_below(cum, axis):
@@ -48,22 +58,62 @@ def chunked_scan(x, dt, a, b, c, d, chunk: int):
     """x [B, T, H, P], dt [B, T, H] (positive), a [H] (negative), b, c
     [B, T, G, N], d [H] -> y [B, T, H, P] of the recurrence above, float32.
     T need not be a multiple of `chunk`: the tail is padded with dt = 0,
-    which neither decays nor feeds the state."""
-    bsz, t, h, p = x.shape
+    which neither decays nor feeds the state. The two kernels of
+    `kernels/pallas_scan.py` where they can run (on a TPU, at widths Mosaic
+    tiles), XLA's einsums elsewhere."""
+    t, h, p = x.shape[1:]
     g, n = b.shape[2:]
-    r = h // g
+    core = scan_kernels if is_tpu_backend() \
+        and pallas_scan.can_run(h, p, g, n, chunk) else scan_einsums
     pad = -t % chunk
     if pad:
         x, dt, b, c = (
             jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2))
             for v in (x, dt, b, c))
-    nc = (t + pad) // chunk
-    x = x.astype(jnp.float32)
+    return core(x.astype(jnp.float32), dt, a, b, c, d, chunk)[:, :t]
+
+
+def _chunk_cumsum(dt, a, chunk):
+    """The sum of dt A from a chunk's first token to each: [B, nc, Q, H]."""
+    bsz, t, h = dt.shape
+    return jnp.cumsum((dt * a).reshape(bsz, t // chunk, chunk, h), axis=2)
+
+
+def scan_kernels(x, dt, a, b, c, d, chunk: int, interpret: bool = False):
+    """`chunked_scan` at a whole number of chunks, the inside of a chunk, the
+    carry over the chunks and `+ D x` in the repo's kernels; the cumulative
+    sums are XLA's. The launches read x, B and C as views of one array: the
+    mixer hands over the three parts of one, and XLA takes the
+    concatenation of adjacent slices for the array they were cut from. They
+    read and write features by tokens, which is how XLA lays the mixer's
+    activations out on a TPU, so the transposes move nothing; each fuses
+    into the pass on its far side (the convolution's activation; the
+    gate's backward, which writes dy) and is filed with that pass."""
+    bsz, t, h, p = x.shape
+    g, n = b.shape[2:]
+    xbc = jnp.concatenate([v.reshape(bsz, t, -1) for v in (x, b, c)], -1)
+    cum = _chunk_cumsum(dt, a, chunk).reshape(dt.shape)
+    with named_scope('ssm_conv'):
+        xbc = xbc.swapaxes(1, 2)
+    y = pallas_scan.chunk_scan(
+        xbc, dt.swapaxes(1, 2), cum.swapaxes(1, 2),
+        cum.reshape(bsz, t, g, h // g).swapaxes(1, 2), d, (h, p, g, n), chunk,
+        interpret)
+    with named_scope('ssm_gate'):
+        return y.swapaxes(1, 2).reshape(x.shape)
+
+
+def scan_einsums(x, dt, a, b, c, d, chunk: int):
+    """The same in einsums, which autodiff differentiates: the path off the
+    TPU and the kernels' oracle."""
+    bsz, t, h, p = x.shape
+    g, n = b.shape[2:]
+    r = h // g
+    nc = t // chunk
     xd = (x * dt[..., None]).reshape(bsz, nc, chunk, g, r, p)
     b = b.reshape(bsz, nc, chunk, g, n)
     c = c.reshape(bsz, nc, chunk, g, n)
-    # sum of dt A from the chunk's first token to each: [B, nc, Q, G, R]
-    cum = jnp.cumsum((dt * a).reshape(bsz, nc, chunk, g, r), axis=2)
+    cum = _chunk_cumsum(dt, a, chunk).reshape(bsz, nc, chunk, g, r)
 
     # inside a chunk: y_i += sum_{j <= i} (C_i . B_j) L_ij dt_j x_j
     scores = jnp.einsum('zcign,zcjgn->zcgij', c, b,
@@ -85,8 +135,7 @@ def chunked_scan(x, dt, a, b, c, d, chunk: int):
     y = y + jnp.einsum('zcign,zcgrpn->zcigrp', c, entering,
                        preferred_element_type=jnp.float32) \
         * jnp.exp(cum)[..., None]
-    y = y.reshape(bsz, nc * chunk, h, p)[:, :t]
-    return y + x[:, :t] * d[:, None]
+    return y.reshape(bsz, t, h, p) + x * d[:, None]
 
 
 def _conv_taps(x, kernel, bias):

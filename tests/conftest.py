@@ -249,3 +249,19 @@ def enable_x64():
     jax.config.update('jax_enable_x64', True)
     yield
     jax.config.update('jax_enable_x64', False)
+
+
+@pytest.fixture
+def scan_on_kernels(monkeypatch):
+    """`ops/state_space.py::chunked_scan` takes the path it takes on a TPU
+    at tile-legal widths, the launches of `kernels/pallas_scan.py`
+    interpreted (float32 operands): the rule is steered here, the program
+    has no option for it."""
+    from functools import partial
+
+    from se3_transformer_tpu.ops import state_space
+    monkeypatch.setattr(state_space, 'is_tpu_backend', lambda: True)
+    monkeypatch.setattr(state_space.pallas_scan, 'can_run',
+                        lambda *widths: True)
+    monkeypatch.setattr(state_space, 'scan_kernels', partial(
+        state_space.scan_kernels, interpret=True))

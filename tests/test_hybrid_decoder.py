@@ -155,13 +155,13 @@ def test_balance_expert_load_finds_the_patterns_expert_layers(tiny):
 H, P, N, G = 4, 8, 8, 2
 
 
-def _scan_inputs(t, dt_scale=1.0, seed=20):
+def _scan_inputs(t, dt_scale=1.0, seed=20, groups=G):
     keys = jax.random.split(jax.random.PRNGKey(seed), 6)
     x = jax.random.normal(keys[0], (t, H, P))
     dt = dt_scale * jax.nn.softplus(jax.random.normal(keys[1], (t, H)) - 2.0)
     a = -jnp.exp(jax.random.uniform(keys[2], (H,), minval=0.0, maxval=2.7))
-    b = jax.random.normal(keys[3], (t, G, N))
-    c = jax.random.normal(keys[4], (t, G, N))
+    b = jax.random.normal(keys[3], (t, groups, N))
+    c = jax.random.normal(keys[4], (t, groups, N))
     d = 1 + 0.1 * jax.random.normal(keys[5], (H,))
     return x, dt, a, b, c, d
 
@@ -171,34 +171,73 @@ def _chunked(args, chunk):
     return chunked_scan(x[None], dt[None], a, b[None], c[None], d, chunk)[0]
 
 
+@pytest.fixture(params=['einsums', 'kernels'])
+def scan_path(request):
+    if request.param == 'kernels':
+        request.getfixturevalue('scan_on_kernels')
+    return request.param
+
+
+def _value_and_grads(args, chunk, cot):
+    return _chunked(args, chunk), jax.grad(
+        lambda *v: (_chunked(v, chunk) * cot).sum(), argnums=range(6))(*args)
+
+
 @pytest.mark.parametrize('t,chunk', [(32, 8), (29, 8), (5, 8), (16, 16)])
-def test_the_chunked_scan_is_the_literal_recurrence(ref, t, chunk):
+def test_the_chunked_scan_is_the_literal_recurrence(ref, scan_path, t, chunk):
     """At lengths that are and are not a multiple of the chunk, shorter than
-    one chunk, and one chunk exactly: values and every gradient."""
+    one chunk, and one chunk exactly: values and every gradient, in XLA's
+    einsums and in the kernels."""
     args = _scan_inputs(t)
     want = ref.scan_recurrence(*args)
-    np.testing.assert_allclose(_chunked(args, chunk), want, rtol=2e-5,
-                               atol=2e-5)
     cot = jax.random.normal(jax.random.PRNGKey(21), want.shape)
-    got_g = jax.grad(lambda *v: (_chunked(v, chunk) * cot).sum(),
-                     argnums=range(6))(*args)
+    got, got_g = _value_and_grads(args, chunk, cot)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
     want_g = jax.grad(lambda *v: (ref.scan_recurrence(*v) * cot).sum(),
                       argnums=range(6))(*args)
     for a, b in zip(got_g, want_g):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
 
 
-def test_a_chunk_whose_decay_underflows_gives_zero_and_no_nan(ref):
+@pytest.mark.parametrize('groups', [1, 2])
+@pytest.mark.parametrize('t,chunk,dt_scale', [
+    (32, 8, 1.0), (29, 8, 1.0), (5, 8, 1.0), (32, 8, 400.0)])
+def test_the_kernels_are_the_einsums(request, t, chunk, dt_scale, groups):
+    """The kernel path against the einsum path in float32: the value and
+    each of the six gradients to 1e-5 of the leaf, at a length the chunk
+    divides, one it does not and one shorter than a chunk, where every
+    chunk's decay underflows, with the heads in one group and in two."""
+    args = _scan_inputs(t, dt_scale, groups=groups)
+    cot = jax.random.normal(jax.random.PRNGKey(22), args[0].shape)
+    want, want_g = _value_and_grads(args, chunk, cot)
+    request.getfixturevalue('scan_on_kernels')
+    got, got_g = _value_and_grads(args, chunk, cot)
+    scale = {name: float(jnp.abs(b).max()) for name, b in zip(
+        ('y', 'x', 'dt', 'a', 'b', 'c', 'd'), (want,) + want_g)}
+    if dt_scale > 1:
+        # d a = sum_t dt_t g_t is there a sum of terms near dt's own
+        # gradient, times dt (50 a token), that cancel to a thousandth of
+        # their size: float32 leaves either path 0.013 off float64's
+        # -0.0057, and they are held to the terms' size, not the sum's
+        scale['a'] = float(jnp.abs(args[1]).sum(0).max()) * scale['dt'] \
+            / float(jnp.abs(args[2]).min())
+    for name, a, b in zip(scale, (got,) + got_g, (want,) + want_g):
+        assert np.isfinite(np.asarray(a)).all(), name
+        assert float(jnp.abs(a - b).max()) <= 1e-5 * scale[name], name
+
+
+def test_a_chunk_whose_decay_underflows_gives_zero_and_no_nan(ref, scan_path):
     """dt large enough that exp(sum of dt A) over a chunk is 0 in float32:
-    the state a chunk hands on is forgotten, nothing divides by it."""
+    the state a chunk hands on is forgotten, nothing divides by it, forward
+    and backward, on either path."""
     args = _scan_inputs(32, dt_scale=400.0)
     x, dt, a = args[:3]
     assert float(jnp.exp((dt * a).reshape(4, 8, H).sum(1)).max()) == 0.0
     want = ref.scan_recurrence(*args)
-    got = _chunked(args, 8)
+    cot = jax.random.normal(jax.random.PRNGKey(23), want.shape)
+    got, grads = _value_and_grads(args, 8, cot)
     assert np.isfinite(np.asarray(got)).all()
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
-    grads = jax.grad(lambda *v: _chunked(v, 8).sum(), argnums=range(6))(*args)
     assert all(np.isfinite(np.asarray(g)).all() for g in grads)
 
 

@@ -383,9 +383,38 @@ def test_a_tiny_hybrid_step_has_every_leaf_and_the_three_phases():
         phases = {ph for ph in profiling.PHASES
                   if leaf not in last or ph != 'replay'}
         assert {ph for lf, ph in cells if lf == leaf} == phases, leaf
-    # the carry of the chunk states keeps its scope: nothing of the step
-    # sits in a loop but the optimizer's and the sort's own
+    # off the TPU the scan is XLA's einsums, the carry of the chunk states
+    # one masked product that keeps its scope: nothing of the step sits in
+    # a loop but the optimizer's and the sort's own (on a TPU the carry is
+    # a kernel's scratch, and a launch is no `while` either)
     assert not any('ssm_scan' in p and 'while' in p for p in paths)
+
+
+def test_the_scans_launches_are_filed_under_ssm_scan_in_all_three_phases(
+        scan_on_kernels):
+    """The step as a TPU takes it (`chunked_scan`'s rule steered by the
+    fixture, the launches interpreted). Each launch is a jit of its own
+    (`_fwd`, `_bwd`: a step lowers each once), called under `ssm_scan` in
+    the forward pass and in a block's replay (`_fwd`) and in the backward
+    pass (`_bwd`, which the `custom_vjp` traces under the call's name
+    stack); inside it the launch's role is the path's next component, which
+    the chip's compiler joins to the call's and takes as the custom call's
+    name (`tests/test_tpu_compile.py` pins that on the compiled step)."""
+    text = _tiny_step_text.__wrapped__('hybrid_decoder')
+    roles = {'jit(_fwd)': 'ssm_scan_fwd', 'jit(_bwd)': 'ssm_scan_bwd'}
+    launches = set()
+    for path in re.findall(r'"(jit\(train_step\)/[^"]*)"', text):
+        scopes, _, last = path.rpartition('/')
+        if last in roles:
+            # the launch is issued inside the scope, not beside it
+            assert profiling.scope_leaf(scopes) == 'ssm_scan', path
+            assert f'"{roles[last]}/pallas_call"' in text
+            launches.add((roles[last], profiling.scope_phase(scopes)))
+    assert launches == {('ssm_scan_fwd', 'forward'),
+                        ('ssm_scan_fwd', 'replay'),
+                        ('ssm_scan_bwd', 'backward')}
+    # the einsum form is off this path: nothing [Q, Q] is left to XLA
+    assert 'zcgrij' not in text and 'zcign' not in text
 
 
 def test_both_sizes_of_the_expert_layer_keep_their_leaves(monkeypatch):

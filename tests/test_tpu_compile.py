@@ -684,21 +684,67 @@ def test_streaming_attention_compiles_at_the_hybrid_cells_size(v5e):
                        *[((1, 32, 8192, 128), jnp.float32)] * 3) == 3
 
 
+def test_the_scans_kernels_compile_at_the_hybrid_cells_size(v5e):
+    """`kernels/pallas_scan.py` at 8,192 tokens in chunks of 128, 64 heads of
+    64 in 8 groups, state 128: the forward launch alone, and under a
+    gradient the forward that saves the entering states and the backward,
+    each within the VMEM the launch asks for."""
+    from se3_transformer_tpu.kernels import pallas_scan
+    from se3_transformer_tpu.ops.state_space import scan_kernels
+    t, h, p, n, g, q = 8192, 64, 64, 128, 8, 128
+    assert pallas_scan.can_run(h, p, g, n, q)
+    shapes = [((1, t, h, p), f32), ((1, t, h), f32), ((h,), f32),
+              ((1, t, g, n), f32), ((1, t, g, n), f32), ((h,), f32)]
+
+    def loss(x, *rest):
+        return (scan_kernels(x, *rest, q) * x).sum()
+
+    assert compile_for(v5e, lambda *v: scan_kernels(*v, q), *shapes) == 1
+    assert compile_for(v5e, jax.grad(loss, argnums=tuple(range(6))),
+                       *shapes) == 2
+
+
+def _assert_the_scan_is_the_repos_kernels(text, layers, big):
+    """In a compiled step: a forward launch a state-space layer in the
+    forward pass and one in its block's replay, a backward launch a layer,
+    each under `ssm_scan`; and XLA keeps no float32 buffer of `big`
+    elements (a chunk's scores or decays over all heads) or more there."""
+    from se3_transformer_tpu.observability import profiling
+    launches = re.findall(
+        r'%(ssm_scan_\w+?)[.\d]* = [^\n]*tpu_custom_call[^\n]*'
+        r'op_name="([^"]*)"', text)
+    by_role = {}
+    for name, path in launches:
+        assert '/ssm_scan/' in path and f'/{name}/pallas_call' in path, path
+        phase = profiling.scope_phase(path)
+        by_role[name, phase] = by_role.get((name, phase), 0) + 1
+    assert by_role == {('ssm_scan_fwd', 'forward'): layers,
+                       ('ssm_scan_fwd', 'replay'): layers,
+                       ('ssm_scan_bwd', 'backward'): layers}, by_role
+    for shape, path in re.findall(
+            r' = (f32\[[\d,]*\])[^\n]*op_name="([^"]*/ssm_scan/[^"]*)"', text):
+        if 'pallas_call' in path:       # the launches' own x, y and states
+            continue
+        assert math.prod(int(d) for d in shape[4:-1].split(',') if d) < big, (
+            shape, path)
+
+
 @pytest.mark.slow
 def test_hybrid_decoder_step_compiles_and_fits(v5e, monkeypatch, capsys):
     """The benchmark's hybrid cell: the published widths of its configuration
     file on the one step factory, compiled for the chip (under a minute):
-    the attention kernel and the grouped products are in it, the scan is
-    XLA's, and state plus temporaries fit; its memory is printed."""
+    the attention kernel, the grouped products and the scan's two kernels
+    are in it, and state plus temporaries fit; its memory is printed."""
     import json
 
     import optax
-    from se3_transformer_tpu.ops import latent_attention
+    from se3_transformer_tpu.ops import latent_attention, state_space
     from se3_transformer_tpu.parallel.sharding import make_sharded_train_step
     from se3_transformer_tpu.training.lm_loss import make_lm_loss
     from se3_transformer_tpu.training.recipes import RECIPES
 
     monkeypatch.setattr(latent_attention, 'is_tpu_backend', lambda: True)
+    monkeypatch.setattr(state_space, 'is_tpu_backend', lambda: True)
     cfg = json.load(open(os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
         'benchmark', 'configs', 'nemotron-twotower-ep16-train.json')))
@@ -721,6 +767,8 @@ def test_hybrid_decoder_step_compiles_and_fits(v5e, monkeypatch, capsys):
     text = compiled.as_text()
     assert 'flash_mha_bwd_dkv' in text and 'ragged-dot' in text
     _assert_one_forward_core_a_layer(text, 1, 'mha_core')
+    # 64 chunks x 64 heads x 128 x 128: what the einsum form wrote a layer
+    _assert_the_scan_is_the_repos_kernels(text, 4, 64 * 64 * 128 * 128)
     _assert_product_front_ends_agree(compiled)
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes

@@ -40,6 +40,19 @@ all N * top_k rows instead, the static size that every token choosing the
 same held experts fills. Both are exact: the same products over the same
 pairs, and neither drops one. Where C reaches N * top_k (every expert held,
 tiny sizes) there is the full size alone.
+
+On a TPU the grouped products run at the widths the chip's product wants:
+XLA tiles a contracted or output width by the largest of 512 / 256 / 128
+that divides it, and at 128 a call is its thousands of programs' overhead
+(2,688 x 1,856: 1.7 to 2.5 ms a call where 3,072 x 2,048 takes 0.6 to 0.9).
+So a width that is no multiple of 256 is padded with zeros to the next
+multiple of 512 (`padded_width`, from the shapes and the backend alone;
+`PADDED_WIDTH`): `grouped_dot` pads the operands as it rounds them, the
+hidden width stays padded from `up` to `down` (relu(0)^2 = 0 and
+silu(0) * 0 = 0), and only the layer's output and the cotangents are
+sliced back. Zero columns and rows add nothing to a sum and round to
+nothing: the same products at the same precision. Widths that divide by 256
+(2,048 and 1,536) are left as they are, to the instruction.
 """
 from __future__ import annotations
 
@@ -51,6 +64,7 @@ import jax.numpy as jnp
 from jax.lax import RaggedDotDimensionNumbers
 
 from ..observability import named_scope
+from ..utils.helpers import is_tpu_backend
 
 # dW[g] = lhs[rows of g].T @ dy[rows of g]: the rows (axis 0 of both) are
 # the ragged, contracted dimension
@@ -66,37 +80,66 @@ def _head_rows(y, group_sizes):
     return jnp.where(rows < jnp.sum(group_sizes), y, 0.0)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3,))
-def grouped_dot(lhs, rhs, group_sizes, operand_dtype=None):
+def _widen(a, widths):
+    """Zeros after a's trailing axes, up to `widths`."""
+    pads = [(0, 0, 0)] * (a.ndim - len(widths)) + [
+        (0, w - s, 0) for s, w in zip(a.shape[-len(widths):], widths)]
+    return jax.lax.pad(a, jnp.zeros((), a.dtype), pads) \
+        if any(hi for _, hi, _ in pads) else a
+
+
+def _narrow(a, widths):
+    """The first `widths` of a's trailing axes: what `_widen` was given."""
+    shape = a.shape[:a.ndim - len(widths)] + tuple(widths)
+    return a if shape == a.shape else jax.lax.slice(a, (0,) * a.ndim, shape)
+
+
+def grouped_dot(lhs, rhs, group_sizes, operand_dtype=None, widths=None):
     """lhs [P, k] with rows sorted by group, rhs [G, k, n], group_sizes [G]
     -> [P, n] float32: row r of group g is lhs[r] @ rhs[g]. With
     `operand_dtype` the operands are rounded to it first (float32
-    accumulation either way); cotangents come back in float32."""
-    return _grouped_dot_fwd(lhs, rhs, group_sizes, operand_dtype)[0]
+    accumulation either way); cotangents come back in float32.
+
+    With `widths` = (k', n') the product runs at those widths, on operands
+    padded with zeros once they are rounded: -> [P, n'], columns past n zero,
+    for the caller to slice or to hand to the next product as they are (lhs
+    may come with k' columns: those past k meet zero rows). The backward
+    products read the padded operands the forward saved, and every cotangent
+    is sliced to its primal's shape."""
+    return _grouped_dot(lhs, rhs, group_sizes, operand_dtype,
+                        tuple(widths or rhs.shape[1:]))
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _grouped_dot(lhs, rhs, group_sizes, operand_dtype, widths):
+    return _grouped_dot_fwd(lhs, rhs, group_sizes, operand_dtype, widths)[0]
 
 
 def _cast(a, dtype):
     return a if dtype is None else a.astype(dtype)
 
 
-def _grouped_dot_fwd(lhs, rhs, group_sizes, operand_dtype):
-    lhs, rhs = _cast(lhs, operand_dtype), _cast(rhs, operand_dtype)
+def _grouped_dot_fwd(lhs, rhs, group_sizes, operand_dtype, widths):
+    unpadded = lhs.shape[1:], rhs.shape[1:]
+    lhs = _widen(_cast(lhs, operand_dtype), widths[:1])
+    rhs = _widen(_cast(rhs, operand_dtype), widths)
     y = jax.lax.ragged_dot(lhs, rhs, group_sizes,
                            preferred_element_type=jnp.float32)
-    return _head_rows(y, group_sizes), (lhs, rhs, group_sizes)
+    return _head_rows(y, group_sizes), (lhs, rhs, group_sizes, unpadded)
 
 
-def _grouped_dot_bwd(operand_dtype, res, dy):
-    lhs, rhs, group_sizes = res
+def _grouped_dot_bwd(operand_dtype, widths, res, dy):
+    lhs, rhs, group_sizes, unpadded = res
     dy = _cast(dy, operand_dtype)
     dlhs = jax.lax.ragged_dot(dy, rhs.swapaxes(1, 2), group_sizes,
                               preferred_element_type=jnp.float32)
     drhs = jax.lax.ragged_dot_general(lhs, dy, group_sizes, _DW_DIMS,
                                       preferred_element_type=jnp.float32)
-    return _head_rows(dlhs, group_sizes), drhs, None
+    return (_narrow(_head_rows(dlhs, group_sizes), unpadded[0]),
+            _narrow(drhs, unpadded[1]), None)
 
 
-grouped_dot.defvjp(_grouped_dot_fwd, _grouped_dot_bwd)
+_grouped_dot.defvjp(_grouped_dot_fwd, _grouped_dot_bwd)
 
 
 @jax.custom_vjp
@@ -148,6 +191,21 @@ def held_row_bound(n_pairs: int, held: int, n_experts: int) -> int:
     return min(n_pairs, -(-rows // tile) * tile)
 
 
+# On a TPU a width of a grouped product that is no multiple of the first is
+# padded to the next multiple of the second: XLA tiles a width by the largest
+# of 512 / 256 / 128 that divides it, and at 128 a call is its programs'
+# overhead (ROADMAP M9 has the picks by width)
+PADDED_WIDTH = (256, 512)
+
+
+def padded_width(width: int) -> int:
+    """The width the chip's grouped product runs `width` at."""
+    unless, tile = PADDED_WIDTH
+    if not is_tpu_backend() or width % unless == 0:
+        return width
+    return -(-width // tile) * tile
+
+
 def _stages(rows, order, inverse, load, n, k, gated, dtype):
     """(take, experts, combine) over the first `rows` sorted rows, which hold
     every held pair: x [N, d] -> xs [rows, d] -> ys [rows, d] -> out [N, d].
@@ -161,10 +219,15 @@ def _stages(rows, order, inverse, load, n, k, gated, dtype):
         return x[order[:rows] // k]
 
     def experts(xs, mats):
-        up = grouped_dot(xs, mats['up'], load, dtype)
-        hidden = nn.silu(grouped_dot(xs, mats['gate'], load, dtype)) * up \
-            if gated else jnp.square(nn.relu(up))
-        return grouped_dot(hidden, mats['down'], load, dtype)
+        # the hidden width stays padded from `up` to `down`: both forms
+        # send a zero column to a zero column
+        d = xs.shape[1]
+        wide = padded_width(d), padded_width(mats['up'].shape[2])
+        up = grouped_dot(xs, mats['up'], load, dtype, wide)
+        hidden = nn.silu(grouped_dot(xs, mats['gate'], load, dtype, wide)) \
+            * up if gated else jnp.square(nn.relu(up))
+        return grouped_dot(hidden, mats['down'], load, dtype,
+                           wide[::-1])[:, :d]
 
     def combine(ys, weights):
         if full:

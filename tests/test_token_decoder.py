@@ -325,6 +325,62 @@ def test_grouped_dot_leaves_rows_past_the_groups_zero_and_differentiates():
     np.testing.assert_allclose(out, dense(lhs, rhs), rtol=3e-2, atol=3e-2)
 
 
+@pytest.mark.parametrize('dtype', [None, jnp.bfloat16],
+                         ids=['float32', 'bfloat16'])
+@pytest.mark.parametrize('form', ['product', 'relu2', 'silu'])
+def test_padded_widths_give_the_unpadded_products_and_cotangents(
+        monkeypatch, form, dtype):
+    """The form a TPU takes at widths that are multiples of nothing (40 and
+    24, run at 64 and 32) against the widths as they are, the operands
+    rounded alike on both sides: one product called with `widths`, and both
+    expert forms through `experts` with the rule steered. Output and every
+    cotangent agree to 1e-6 of their largest entry, a cotangent has its
+    primal's shape (the padding's is dropped), and rows past the groups
+    read zero."""
+    d, width, rows = 40, 24, 20
+    sizes = jnp.asarray([6, 0, 9], jnp.int32)      # rows 15.. belong to none
+    keys = jax.random.split(jax.random.PRNGKey(9), 5)
+    xs = jax.random.normal(keys[0], (rows, d))
+    mats = {name: jax.random.normal(key, shape) / 6 for name, key, shape in (
+        ('up', keys[1], (3, d, width)), ('gate', keys[2], (3, d, width)),
+        ('down', keys[3], (3, width, d)))}
+    if form == 'product':
+        cot = jax.random.normal(keys[4], (rows, width))
+
+        def run(padded):
+            def out(xs, up):
+                widths = (64, 32) if padded else None
+                return grouped_dot(xs, up, sizes, dtype, widths)[:, :width]
+            return out, (xs, mats['up'])
+    else:
+        cot = jax.random.normal(keys[4], (rows, d))
+        if form == 'relu2':
+            del mats['gate']
+
+        def run(padded):
+            monkeypatch.setattr(expert_layer, 'is_tpu_backend',
+                                lambda: padded)
+            monkeypatch.setattr(expert_layer, 'PADDED_WIDTH', (16, 32))
+            assert expert_layer.padded_width(d) == (64 if padded else d)
+            assert expert_layer.padded_width(width) == (32 if padded
+                                                        else width)
+            assert expert_layer.padded_width(48) == 48
+            return expert_layer._stages(rows, None, None, sizes, rows, 1,
+                                        form == 'silu', dtype)[1], (xs, mats)
+
+    results = []
+    for padded in (False, True):
+        fn, args = run(padded)
+        out, vjp = jax.vjp(fn, *args)
+        results.append(jax.tree_util.tree_leaves((out, vjp(cot))))
+        assert out.shape == cot.shape and not np.asarray(out[15:]).any()
+        assert jax.tree_util.tree_map(jnp.shape, vjp(cot)) \
+            == jax.tree_util.tree_map(jnp.shape, args)
+    for want, got in zip(*results):
+        np.testing.assert_allclose(got, want, rtol=0,
+                                   atol=1e-6 * np.abs(want).max())
+
+
 # ------------------------------------------------------------------ #
 # latent attention
 # ------------------------------------------------------------------ #

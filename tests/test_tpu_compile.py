@@ -20,6 +20,8 @@ touches a chip. The persistent compilation cache is off around the
 cases — a deviceless executable can be written to it but not read back
 without a chip.
 """
+import base64
+import json
 import math
 import os
 import re
@@ -472,23 +474,11 @@ def _with_callees(comps, name):
     return '\n'.join(comps[c] for c in sorted(seen))
 
 
-def test_the_expert_layer_works_on_the_held_row_bound_at_the_hybrid_cells_size(
-        v5e):
-    """The hybrid cell's expert layer as its recomputed block runs it, forward
-    and backward: N * k = 49,152 pairs, `held_row_bound` 6,144. Each direction
-    is one `conditional`; the forward one hands on its output alone (no
-    branch's residuals, which the untaken one would fill with zeros); in the
-    bounded branch of both the grouped products walk 6,144 rows and nothing has
-    49,152 rows but vectors (sort keys, permutations, a weight a pair)."""
-    from se3_transformer_tpu.ops.expert_layer import (
-        ExpertLayer, held_row_bound,
-    )
-    n, d, k, width = 8192, 2688, 6, 1856
-    layer = ExpertLayer(width=width, n_experts=128, top_k=k, experts_held=8,
-                        shared_width=3712, hidden_act='relu2',
-                        routed_scale=2.5)
-    assert held_row_bound(n * k, 8, 128) == 6144
-    assert held_row_bound(8192 * 4, 8, 64) == 8192       # the GLM cell's
+def _expert_layer_grad(v5e, n, d, on_a_tpu=True, **fields):
+    """The gradient of an expert layer over x [n, d] as a recomputed block
+    runs it, lowered for the chip, the layer's rules seeing a TPU or not."""
+    from se3_transformer_tpu.ops import expert_layer
+    layer = expert_layer.ExpertLayer(**fields)
     x = jax.ShapeDtypeStruct((n, d), f32, sharding=v5e)
     params = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
@@ -498,8 +488,39 @@ def test_the_expert_layer_works_on_the_held_row_bound_at_the_hybrid_cells_size(
         return jnp.square(jax.checkpoint(
             lambda p, x: layer.apply({'params': p}, x)[0])(params, x)).sum()
 
-    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
-        params, x).compile().as_text()
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(expert_layer, 'is_tpu_backend', lambda: on_a_tpu)
+        return jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, x)
+
+
+HYBRID_EXPERTS = dict(width=1856, n_experts=128, top_k=6, experts_held=8,
+                      shared_width=3712, hidden_act='relu2', routed_scale=2.5)
+GLM_EXPERTS = dict(width=1536, n_experts=64, top_k=4, experts_held=8,
+                   shared_width=1536, hidden_act='silu', routed_scale=1.8)
+
+
+@pytest.fixture(scope='module')
+def hybrid_expert_layer(v5e):
+    """The hybrid cell's expert layer, compiled: 8,192 tokens of 2,688."""
+    return _expert_layer_grad(v5e, 8192, 2688,
+                              **HYBRID_EXPERTS).compile().as_text()
+
+
+def test_the_expert_layer_works_on_the_held_row_bound_at_the_hybrid_cells_size(
+        hybrid_expert_layer):
+    """The hybrid cell's expert layer as its recomputed block runs it, forward
+    and backward: N * k = 49,152 pairs, `held_row_bound` 6,144. Each direction
+    is one `conditional`; the forward one hands on its output alone (no
+    branch's residuals, which the untaken one would fill with zeros); in the
+    bounded branch of both the grouped products walk 6,144 rows, at the
+    widths the chip's product wants (2,688 and 1,856 padded to 3,072 and
+    2,048), and nothing has 49,152 rows but vectors (sort keys, permutations,
+    a weight a pair)."""
+    from se3_transformer_tpu.ops.expert_layer import held_row_bound
+    n, k, d, width = 8192, 6, '3072', '2048'
+    assert held_row_bound(n * k, 8, 128) == 6144
+    assert held_row_bound(8192 * 4, 8, 64) == 8192       # the GLM cell's
+    text = hybrid_expert_layer
     comps = _computations(text)
     conds = re.findall(
         r'= (\(.*?\)) conditional\(.*?branch_computations=\{%([\w.\-]+), '
@@ -514,11 +535,66 @@ def test_the_expert_layer_works_on_the_held_row_bound_at_the_hybrid_cells_size(
             products = re.findall(
                 r'%ragged-dot-none[\w.]* = f32\[(\d+),(\d+)', body)
             assert len(products) == n_products, (name, products)
-            assert set(products) <= {
-                (str(rows), str(width)), (str(rows), str(d)),
-                ('8', str(width)), ('8', str(d))}, (name, products)
+            assert set(products) <= {(str(rows), width), (str(rows), d),
+                                     ('8', width), ('8', d)}, (name, products)
             assert bool(re.search(r'\[49152,\d', body)) == (rows == 49152)
             assert re.search(r's32\[49152\]', body)
+
+
+def _grouped_product_tiles(text):
+    """{instruction: the operand dimensions of every `tpu.matmul` in its
+    body} over the grouped products of compiled HLO text: the tiles XLA
+    picked for the call, which it writes into the custom call's own Mosaic
+    module."""
+    tiles = {}
+    for line in text.splitlines():
+        m = re.match(r'\s*(?:ROOT )?%(ragged-dot-none[\w.]*) = .*'
+                     r'backend_config=(\{.*\})\s*$', line)
+        if m:
+            body = base64.b64decode(json.loads(m.group(2))[
+                'custom_call_config']['body']).decode()
+            tiles[m.group(1)] = [
+                int(v) for dims in re.findall(
+                    r'tpu\.matmul.*: vector<(\d+)x(\d+)x\w+>, '
+                    r'vector<(\d+)x(\d+)x\w+>,', body) for v in dims]
+            assert tiles[m.group(1)], line[:200]
+    return tiles
+
+
+def test_grouped_products_run_at_tiles_of_256_and_more_at_the_hybrid_cells_size(
+        hybrid_expert_layer):
+    """XLA tiles a width of a grouped product by the largest of 512 / 256 /
+    128 that divides it, and at 128 (2,688 = 21 x 128, 1,856 = 14.5 x 128) a
+    call is its programs' overhead: with the widths padded, every one of the
+    16 calls of the layer (2 + 6 a branch) multiplies tiles of 256 or more,
+    in both branches of both `conditional`s."""
+    tiles = _grouped_product_tiles(hybrid_expert_layer)
+    assert len(tiles) == 16, sorted(tiles)
+    assert min(min(dims) for dims in tiles.values()) >= 256, tiles
+    # the pads are passes of their own (XLA fuses none into a cast), each
+    # after the cast, at the operands' two bytes
+    pads = re.findall(r' = (b?f\d+)\[[\d,]*\][^\n]* pad\(',
+                      hybrid_expert_layer)
+    assert pads and set(pads) == {'bf16'}, pads
+
+
+def test_padded_widths_leave_the_glm_cells_expert_layer_as_it_was(v5e):
+    """2,048 and 1,536 divide by 512: at the GLM cell's size (the
+    short-convolution cell's widths too) the rule pads nothing, the layer
+    lowers to the same StableHLO whether its rules see a TPU or not, and
+    the products' tiles are 512 by 512."""
+    def lowered(on_a_tpu):
+        return _expert_layer_grad(v5e, 8192, 2048, on_a_tpu, **GLM_EXPERTS)
+
+    def stripped(text):           # of source locations
+        return re.sub(r'loc\(.*?\)|#loc.*', '', text)
+
+    on_a_tpu = lowered(True)
+    assert stripped(on_a_tpu.as_text()) == stripped(lowered(False).as_text())
+    assert 'stablehlo.pad' not in on_a_tpu.as_text()
+    tiles = _grouped_product_tiles(on_a_tpu.compile().as_text())
+    assert len(tiles) == 24, sorted(tiles)               # 3 + 9 a branch
+    assert {d for dims in tiles.values() for d in dims} == {512}, tiles
 
 
 def _assert_one_forward_core_a_layer(text, layers, leaf):
@@ -622,15 +698,14 @@ def test_token_decoder_step_compiles_and_fits(v5e, monkeypatch, capsys):
     a minute): both kernels are in it, the attention kernel's forward once a
     block, and state plus temporaries fit with the six blocks' saved
     attention outputs (11.67 GiB; 11.26 with nothing saved)."""
-    import json
-
     import optax
-    from se3_transformer_tpu.ops import latent_attention
+    from se3_transformer_tpu.ops import expert_layer, latent_attention
     from se3_transformer_tpu.parallel.sharding import make_sharded_train_step
     from se3_transformer_tpu.training.lm_loss import make_lm_loss
     from se3_transformer_tpu.training.recipes import RECIPES
 
     monkeypatch.setattr(latent_attention, 'is_tpu_backend', lambda: True)
+    monkeypatch.setattr(expert_layer, 'is_tpu_backend', lambda: True)
     cfg = json.load(open(os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
         'benchmark', 'configs', 'glm47-flash-ep8-train.json')))
@@ -735,16 +810,16 @@ def test_hybrid_decoder_step_compiles_and_fits(v5e, monkeypatch, capsys):
     file on the one step factory, compiled for the chip (under a minute):
     the attention kernel, the grouped products and the scan's two kernels
     are in it, and state plus temporaries fit; its memory is printed."""
-    import json
-
     import optax
-    from se3_transformer_tpu.ops import latent_attention, state_space
+    from se3_transformer_tpu.ops import (
+        expert_layer, latent_attention, state_space,
+    )
     from se3_transformer_tpu.parallel.sharding import make_sharded_train_step
     from se3_transformer_tpu.training.lm_loss import make_lm_loss
     from se3_transformer_tpu.training.recipes import RECIPES
 
-    monkeypatch.setattr(latent_attention, 'is_tpu_backend', lambda: True)
-    monkeypatch.setattr(state_space, 'is_tpu_backend', lambda: True)
+    for ops in (latent_attention, state_space, expert_layer):
+        monkeypatch.setattr(ops, 'is_tpu_backend', lambda: True)
     cfg = json.load(open(os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
         'benchmark', 'configs', 'nemotron-twotower-ep16-train.json')))
@@ -806,15 +881,14 @@ def test_lfm2_decoder_step_compiles_and_fits(v5e, monkeypatch, capsys):
     tokens, compiled for the chip (under a minute): the attention kernel
     and the grouped products are in it, the gates and taps are XLA's, and
     state plus temporaries fit; its memory is printed."""
-    import json
-
     import optax
-    from se3_transformer_tpu.ops import latent_attention
+    from se3_transformer_tpu.ops import expert_layer, latent_attention
     from se3_transformer_tpu.parallel.sharding import make_sharded_train_step
     from se3_transformer_tpu.training.lm_loss import make_lm_loss
     from se3_transformer_tpu.training.recipes import RECIPES
 
     monkeypatch.setattr(latent_attention, 'is_tpu_backend', lambda: True)
+    monkeypatch.setattr(expert_layer, 'is_tpu_backend', lambda: True)
     cfg = json.load(open(os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
         'benchmark', 'configs', 'lfm2-24b-a2b-ep8-train.json')))

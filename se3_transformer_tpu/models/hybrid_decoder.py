@@ -6,8 +6,11 @@ one pre-normed mixer with its residual:
       C   a gated short convolution            (ops/short_conv.py)
       *   grouped-query causal attention       (ops/grouped_attention.py),
           with per-head q/k norms (`qk_norm`) and rotation (`rope_theta`;
-          None: none) where the model has them
-      E   an expert layer that holds a share   (ops/expert_layer.py)
+          None: none) where the model has them; over the two streams of a
+          block-diffusion pass (`hidden_states(tokens, noised,
+          block_length)`) by `ops/block_diffusion.py`'s rule instead
+      E   an expert layer that holds a share   (ops/expert_layer.py),
+          routed by `scoring_func` ('sigmoid' or 'softmax')
       F   a dense gated feed-forward           (SwiGLU, ops/expert_layer.py)
 
 then a final RMSNorm and the head: a matrix of its own, or with
@@ -32,6 +35,7 @@ from __future__ import annotations
 from typing import Optional
 
 import flax.linen as nn
+import jax.numpy as jnp
 
 from ..observability import named_scope
 from ..ops.expert_layer import ExpertLayer, SwiGLU
@@ -52,8 +56,10 @@ class MixerBlock(nn.Module):
     eps: float
 
     @nn.compact
-    def __call__(self, h):
-        """h [B, T, d] -> (h, the expert layer's stats or None)."""
+    def __call__(self, h, positions=None, block_length: int = 0):
+        """h [B, T, d] -> (h, the expert layer's stats or None). `positions`
+        and `block_length` (static) are the attention mixer's: the two
+        streams of a block-diffusion pass (`hidden_states`)."""
         with named_scope('norm'):
             u = RMSNorm(self.eps, name='pre_norm')(h)
         module, name = MIXERS[self.kind]
@@ -65,6 +71,8 @@ class MixerBlock(nn.Module):
         if self.kind == 'F':       # SwiGLU writes no scope of its own
             with named_scope('dense_ff'):
                 return h + mixer(u), None
+        if self.kind == '*':
+            return h + mixer(u, positions, block_length), None
         return h + mixer(u), None
 
 
@@ -94,6 +102,7 @@ class HybridDecoder(nn.Module):
     expert_rank: int = 0
     mlp_hidden_act: str = 'relu2'
     routed_scaling_factor: float = 1.0
+    scoring_func: str = 'sigmoid'
     norm_topk_prob: bool = True
     norm_topk_eps: float = 1e-20
     # F
@@ -134,6 +143,7 @@ class HybridDecoder(nn.Module):
                 shared_width=self.moe_shared_expert_intermediate_size,
                 hidden_act=self.mlp_hidden_act,
                 routed_scale=self.routed_scaling_factor,
+                scoring_func=self.scoring_func,
                 norm_topk=self.norm_topk_prob,
                 norm_topk_eps=self.norm_topk_eps,
                 bf16_operands=self.bf16_operands),
@@ -144,7 +154,9 @@ class HybridDecoder(nn.Module):
                 kv_heads=self.num_key_value_heads, head_dim=self.head_dim,
                 block=self.attention_block, qk_norm=self.qk_norm,
                 rope_theta=self.rope_theta, eps=eps)}
-        block = nn.remat(MixerBlock, policy=SAVE_ATTN_CORE)
+        # `block_length` is static: argument 3 of `__call__`, self counted
+        block = nn.remat(MixerBlock, policy=SAVE_ATTN_CORE,
+                         static_argnums=(3,))
         self.embedding = nn.Embed(self.vocab_rows, self.hidden_size)
         self.blocks = [block(kind, fields[kind], eps)
                        for kind in self.hybrid_override_pattern]
@@ -163,16 +175,38 @@ class HybridDecoder(nn.Module):
             return params['embedding']['embedding'].T
         return params['head']['kernel']
 
-    def hidden_states(self, tokens):
+    def hidden_states(self, tokens, noised=None, block_length: int = 0):
         """tokens [B, T] -> (main [B, T, d], None, stats): the head's normed
         input (`main[t]` predicts token t + 1), no second head, one entry of
-        `stats` per expert layer."""
+        `stats` per expert layer.
+
+        With `noised` [B, T] (the tokens with some replaced by the mask id)
+        and a `block_length`, the pass of a decoder trained by diffusion over
+        blocks: both streams in one sequence of 2 T positions, noised first,
+        the two copies of a token at one rotary position, attention by
+        `ops/block_diffusion.py`'s rule (only `*` mixers look across tokens
+        here: a pattern with `M` or `C` has no such pass). `main` is the
+        noised stream's T positions alone (`main[t]` predicts token t, in
+        place); the clean stream's last layer feeds nothing. The expert
+        layers' stats are over all 2 T positions."""
+        positions = None
+        if noised is not None:
+            assert block_length and not set(
+                self.hybrid_override_pattern) & {'M', 'C'}, \
+                (block_length, self.hybrid_override_pattern)
+            with named_scope('bd_streams'):
+                t = tokens.shape[1]
+                tokens = jnp.concatenate((noised, tokens), axis=1)
+                positions = jnp.tile(jnp.arange(t), 2)
         with named_scope('embed'):
             h = self.embedding(tokens)
         stats = []
         for block in self.blocks:
-            h, s = block(h)
+            h, s = block(h, positions, block_length)
             stats += [s] if s is not None else []
+        if noised is not None:
+            with named_scope('bd_streams'):
+                h = h[:, :h.shape[1] // 2]
         with named_scope('norm'):
             return self.final_norm(h), None, stats
 

@@ -120,6 +120,14 @@ MODEL_SCOPES = (
     #                       split into B, C, X
     'sconv_core',         # ops/short_conv.py: B * X, the taps, C * z
     'sconv_out',          # ops/short_conv.py: output projection
+    # a decoder trained by diffusion over blocks (two streams in one pass)
+    'bd_core',            # ops/grouped_attention.py: the block-diffusion
+    #                       core (ops/block_diffusion.py: splash attention's
+    #                       three launches on a TPU) and the casts around
+    #                       it, apart from `mha_core`
+    'bd_streams',         # models/hybrid_decoder.py, training/lm_loss.py:
+    #                       building the two streams and their positions,
+    #                       cutting the noised one out, the weights
     'loss',               # parallel/sharding.py train_step: what the
     #                       model's scopes do not claim inside the
     #                       differentiated loss

@@ -1,6 +1,10 @@
 """An expert layer that is told which experts it holds.
 
-    s = sigmoid(x Wr)                          [N, n_experts], float32
+    s = sigmoid(x Wr)                          [N, n_experts], float32; or
+                                               softmax(x Wr) over all
+                                               n_experts (`scoring_func`,
+                                               the name published configs
+                                               give the choice)
     chosen = top_k(s + b)                      b: the correction bias; it
                                                enters the choice only
     w_i = scale * s_i / (sum_chosen s + norm_topk_eps)
@@ -391,6 +395,10 @@ class SquaredReLU(nn.Module):
 # hidden_act -> (the shared expert's module, whether an expert has a gate)
 EXPERT_FORMS = {'silu': (SwiGLU, True), 'relu2': (SquaredReLU, False)}
 
+# scoring_func -> the router's scores from its logits [N, n_experts]: each
+# expert alone, or over all the router's outputs, held here or not
+SCORING_FUNCS = {'sigmoid': nn.sigmoid, 'softmax': nn.softmax}
+
 
 def route(scores, bias, top_k: int, scale: float, normalize: bool,
           eps: float = 1e-20):
@@ -407,15 +415,18 @@ def route(scores, bias, top_k: int, scale: float, normalize: bool,
 BALANCE_RATES = (0.05, 0.001)    # of the first and the last step
 
 
-def balance_bias(scores, bias, top_k: int, steps: int = 300):
+def balance_bias(scores, bias, top_k: int, steps: int = 300,
+                 scale: float = 1.0):
     """The aux-loss-free balancing rule of the correction bias, run on fixed
     scores [N, E]: bias <- bias + rate * sign(mean load - load), `steps`
-    times with the rate falling geometrically (BALANCE_RATES). Training
-    applies the rule once a step as the data streams by; this is what it has
-    done to the buffer by the time the loads have settled."""
+    times with the rate falling geometrically (`scale` x BALANCE_RATES: the
+    rates are of sigmoid scores, which lie about 0.5; softmax scores lie
+    about 1 / E, and a step of 0.001 is wider than the gaps between them).
+    Training applies the rule once a step as the data streams by; this is
+    what it has done to the buffer by the time the loads have settled."""
     n, e = scores.shape
     target = n * top_k / e
-    rate, final_rate = BALANCE_RATES
+    rate, final_rate = (scale * r for r in BALANCE_RATES)
 
     def step(i, b):
         _, chosen = jax.lax.top_k(scores + b, top_k)
@@ -439,6 +450,7 @@ class ExpertLayer(nn.Module):
     shared_width: int = 0      # of the shared expert(s); 0: none
     hidden_act: str = 'silu'   # the experts' form: a key of EXPERT_FORMS
     routed_scale: float = 1.0
+    scoring_func: str = 'sigmoid'   # a key of SCORING_FUNCS
     norm_topk: bool = True
     norm_topk_eps: float = 1e-20   # added to the chosen scores' sum
     bf16_operands: bool = True   # of the grouped products (every other
@@ -458,7 +470,7 @@ class ExpertLayer(nn.Module):
                               precision=jax.lax.Precision.HIGHEST)(x)
             bias = self.param('correction_bias', nn.initializers.zeros,
                               (self.n_experts,))
-            scores = nn.sigmoid(logits)
+            scores = SCORING_FUNCS[self.scoring_func](logits)
             chosen, weights = route(scores, bias, k, self.routed_scale,
                                     self.norm_topk, self.norm_topk_eps)
         with named_scope('moe_dispatch'):

@@ -16,6 +16,13 @@ The core is the latent attention's (`ops/latent_attention.py`): JAX's
 streaming Pallas kernel on a TPU, blocks of queries elsewhere. Both take as
 many key-value heads as query heads, so the key-value heads are repeated
 here and autodiff sums their gradients over each group.
+
+Called with a `block_length` (static) the input is the two streams of a
+decoder trained by diffusion over blocks, [noised ; clean] along T, and
+`positions` [T] the rotation's (the two copies of a token share one): the
+core is then `ops/block_diffusion.py`'s, under its own leaf `bd_core`, and
+takes the key-value heads as they are. Projections, norms and rotation are
+the same arithmetic either way.
 """
 from __future__ import annotations
 
@@ -26,6 +33,7 @@ import flax.linen as nn
 import jax.numpy as jnp
 
 from ..observability import named_scope
+from .block_diffusion import block_diffusion_attention
 from .latent_attention import RMSNorm, causal_attention
 from .rotary import apply_rotary_halves, rotary_angles
 
@@ -41,8 +49,8 @@ class GroupedQueryAttention(nn.Module):
     eps: float = 1e-5     # of the q/k norms
 
     @nn.compact
-    def __call__(self, x):
-        """x [B, T, dim] -> [B, T, dim]."""
+    def __call__(self, x, positions=None, block_length: int = 0):
+        """x [B, T, dim] -> [B, T, dim]; positions [T] (None: 0 .. T - 1)."""
         b, t, _ = x.shape
         h, kv, dh = self.heads, self.kv_heads, self.head_dim
         assert h % kv == 0, (h, kv)
@@ -55,13 +63,21 @@ class GroupedQueryAttention(nn.Module):
                 q = RMSNorm(self.eps, name='q_norm')(q)
                 k = RMSNorm(self.eps, name='k_norm')(k)
             if self.rope_theta is not None:
-                angles = rotary_angles(jnp.arange(t), dh, self.rope_theta)
+                angles = rotary_angles(
+                    jnp.arange(t) if positions is None else positions, dh,
+                    self.rope_theta)
                 q, k = (apply_rotary_halves(a, angles[None, :, None, :])
                         for a in (q, k))
-            k, v = (jnp.repeat(a, h // kv, axis=2) for a in (k, v))
+            if not block_length:
+                k, v = (jnp.repeat(a, h // kv, axis=2) for a in (k, v))
             q, k, v = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
-        with named_scope('mha_core'):
-            o = causal_attention(q, k, v, dh ** -0.5, self.block)
+        if block_length:
+            with named_scope('bd_core'):
+                o = block_diffusion_attention(q, k, v, dh ** -0.5,
+                                              block_length, self.block)
+        else:
+            with named_scope('mha_core'):
+                o = causal_attention(q, k, v, dh ** -0.5, self.block)
         with named_scope('mha_out'):
             o = o.transpose(0, 2, 1, 3).reshape(b, t, h * dh)
             return dense(self.dim, name='out')(o)

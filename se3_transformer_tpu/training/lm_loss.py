@@ -11,6 +11,18 @@ recomputed in the backward pass: two [tokens, vocab_rows] float32 arrays with
 their cotangents are never held. `aux` carries the expert layers' counters as device scalars
 (fetched with the loss) and the step's choices (left on the device unless
 asked for).
+
+A second objective beside it, for a decoder trained by diffusion over blocks
+(`make_block_diffusion_loss`): the model reads a noised copy of the sequence
+and the clean one in one pass (`hidden_states(tokens, noised,
+block_length)`) and predicts the masked tokens in place, each weighted by
+1 / t:
+
+    loss = (1 / (B L)) sum_sequences sum_i m_i (1 / t) [logsumexp(h~_i W)
+                                                        - (h~_i W)[x_i]]
+
+over the noised stream's positions, in the same chunked head. The step draws
+nothing: the batch carries the noise (`noise_tokens` makes one).
 """
 from __future__ import annotations
 
@@ -26,26 +38,37 @@ def chunked_cross_entropy(h, kernel, targets, valid, chunk: int = 1024):
     over valid rows of logsumexp(h kernel) - (h kernel)[target], float32.
     All of it is the leaf `lm_head`."""
     with named_scope('lm_head'):
-        return _chunked_cross_entropy(h, kernel, targets, valid, chunk)
+        return _chunked_nll(h, kernel, targets, valid, chunk,
+                            lambda vc, nll: jnp.where(vc, nll, 0.0)) \
+            / jnp.maximum(jnp.sum(valid), 1)
 
 
-def _chunked_cross_entropy(h, kernel, targets, valid, chunk):
+def chunked_weighted_nll(h, kernel, targets, weight, chunk: int = 1024):
+    """The same head with a float32 weight [N] a row: the SUM over rows of
+    weight * (logsumexp(h kernel) - (h kernel)[target]); the caller
+    divides."""
+    with named_scope('lm_head'):
+        return _chunked_nll(h, kernel, targets, weight, chunk,
+                            lambda wc, nll: wc * nll)
+
+
+def _chunked_nll(h, kernel, targets, rows, chunk, weigh):
+    """sum over chunks of sum(weigh(rows, nll)), `rows` cut as h is."""
     n = h.shape[0]
     chunk = min(chunk, n)
     assert n % chunk == 0, (n, chunk)
 
     @jax.checkpoint
-    def one(hc, tc, vc):
+    def one(hc, tc, rc):
         logits = jnp.dot(hc, kernel, preferred_element_type=jnp.float32)
         picked = jnp.take_along_axis(logits, tc[:, None], axis=-1)[:, 0]
         nll = jax.nn.logsumexp(logits, axis=-1) - picked
-        return jnp.sum(jnp.where(vc, nll, 0.0))
+        return jnp.sum(weigh(rc, nll))
 
     # a Python loop, not a scan: inside a scanned, checkpointed body the
     # operations lose their scopes, and these are the head's products
-    total = sum(one(h[i:i + chunk], targets[i:i + chunk], valid[i:i + chunk])
-                for i in range(0, n, chunk))
-    return total / jnp.maximum(jnp.sum(valid), 1)
+    return sum(one(h[i:i + chunk], targets[i:i + chunk], rows[i:i + chunk])
+               for i in range(0, n, chunk))
 
 
 def expert_counters(stats):
@@ -95,21 +118,79 @@ def make_lm_loss(module, mtp_weight: float = 0.3, chunk: int = 1024):
     return loss_fn
 
 
-def balance_expert_load(module, params, batches, steps: int = 300):
+def noise_tokens(key, tokens, mask_id: int, eps: float = 1e-3):
+    """The forward process of a masked diffusion, for `make_block_diffusion_
+    loss`: one noise level t ~ U(eps, 1] a sequence, every token masked
+    independently with probability t. tokens [B, L] -> {'tokens', 'noised'
+    (tokens, `mask_id` where masked), 'weight' [B, L] float32 (1 / t where
+    masked, else 0)}."""
+    k_t, k_m = jax.random.split(key)
+    b = tokens.shape[0]
+    t = eps + (1.0 - eps) * (1.0 - jax.random.uniform(k_t, (b, 1)))
+    masked = jax.random.uniform(k_m, tokens.shape) < t
+    return dict(tokens=tokens,
+                noised=jnp.where(masked, mask_id, tokens).astype(tokens.dtype),
+                weight=jnp.where(masked, 1.0 / t, 0.0).astype(jnp.float32))
+
+
+def make_block_diffusion_loss(module, block_length: int, chunk: int = 1024):
+    """loss_fn(params, batch, rng) -> (loss, aux) for
+    `make_sharded_train_step`; batch = {'tokens' [B, L] int32, 'noised'
+    [B, L] int32, 'weight' [B, L] float32 = m / t}. `aux` carries the expert
+    layers' counters (over both streams' 2 L positions) and `bd_masked` (the
+    step's targets), `bd_weight` (the sum of the weights)."""
+
+    def loss_fn(params, batch, rng):
+        del rng
+        with named_scope('loss'):      # as `make_lm_loss` opens it
+            return _loss(params, batch['tokens'], batch['noised'],
+                         batch['weight'])
+
+    def _loss(params, tokens, noised, weight):
+        main, _, stats = module.apply(
+            {'params': params}, tokens, noised, block_length,
+            method='hidden_states')
+        d = main.shape[-1]
+        with named_scope('bd_streams'):
+            weight = weight.astype(jnp.float32).reshape(-1)
+            targets = tokens.reshape(-1)
+        loss = chunked_weighted_nll(
+            main.reshape(-1, d), module.head_kernel(params), targets, weight,
+            chunk) / tokens.size
+        with named_scope('bd_streams'):
+            aux = dict(loss_main=loss,
+                       bd_masked=jnp.sum(weight > 0, dtype=jnp.int32),
+                       bd_weight=jnp.sum(weight))
+        if stats:
+            aux.update(expert_counters(stats))
+        return loss, aux
+
+    return loss_fn
+
+
+def balance_expert_load(module, params, batches, steps: int = 300,
+                        block_length: int = 0):
     """`params` with every expert layer's correction bias settled by the
     aux-loss-free balancing rule (`ops.expert_layer.balance_bias`) on the
-    router's scores over `batches` (a list of {'tokens': [B, T]}), layer by
-    layer from the first: a layer's bias moves what every later layer sees.
-    Stands for what training has done to the buffer; it takes no gradient."""
+    router's scores over `batches` (a list of {'tokens': [B, T]}; with a
+    `block_length`, of block-diffusion batches, both streams' scores), layer
+    by layer from the first: a layer's bias moves what every later layer
+    sees. Stands for what training has done to the buffer; it takes no
+    gradient."""
     names = module.expert_layer_names()
-    scores_of = jax.jit(lambda p, tokens: [
-        s['scores'] for s in module.apply({'params': p}, tokens,
+    streams = (lambda b: (b['tokens'], b['noised'], block_length)) \
+        if block_length else (lambda b: (b['tokens'],))
+    scores_of = jax.jit(lambda p, batch: [
+        s['scores'] for s in module.apply({'params': p}, *streams(batch),
                                           method='hidden_states')[2]])
+    # the rule's rates at the scale of the scores: about 0.5, or 1 / E
+    scale = 2.0 / module.n_routed_experts \
+        if getattr(module, 'scoring_func', 'sigmoid') == 'softmax' else 1.0
     settle = jax.jit(lambda scores, bias: balance_bias(
-        scores, bias, module.num_experts_per_tok, steps))
+        scores, bias, module.num_experts_per_tok, steps, scale))
     for i, name in enumerate(names):
         scores = jnp.concatenate(
-            [scores_of(params, b['tokens'])[i] for b in batches])
+            [scores_of(params, b)[i] for b in batches])
         moe = params[name]['moe']
         bias = settle(scores, moe['correction_bias'])
         params = {**params, name: {**params[name], 'moe': {

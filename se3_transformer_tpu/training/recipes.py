@@ -29,6 +29,10 @@ configuration passes the published widths as overrides):
                        convolutions or attention with q/k norms and rotation,
                        then a dense or a three-matrix expert feed-forward;
                        tied head
+  * sdar_decoder     — the same module, layers of attention (q/k norms,
+                       rotation) and softmax-routed three-matrix experts,
+                       untied head; trained by diffusion over blocks
+                       (`training.lm_loss.make_block_diffusion_loss`)
 """
 from __future__ import annotations
 
@@ -176,6 +180,23 @@ def lfm2_decoder(**overrides) -> HybridDecoder:
     return HybridDecoder(**sizes)
 
 
+def sdar_decoder(**overrides) -> HybridDecoder:
+    """Tiny widths by default (CPU tests): two layers, each attention (4
+    query and 2 key-value heads, q/k norms, rotation) and then experts (2 of
+    8 a token by softmax scores, 4 held here, no shared one), untied head.
+    Train it with `training.lm_loss.make_block_diffusion_loss(module,
+    block_length)` on batches from `noise_tokens`; `make_lm_loss` trains the
+    same module next-token."""
+    sizes = dict(
+        vocab_rows=48, hidden_size=32, hybrid_override_pattern='*E*E',
+        moe_intermediate_size=16, n_routed_experts=8, num_experts_per_tok=2,
+        experts_held=4, mlp_hidden_act='silu', scoring_func='softmax',
+        num_attention_heads=4, num_key_value_heads=2, head_dim=8,
+        qk_norm=True, rope_theta=1e6, layer_norm_epsilon=1e-6)
+    sizes.update(overrides)
+    return HybridDecoder(**sizes)
+
+
 RECIPES = {
     'toy_denoise': toy_denoise,
     'flagship': flagship,
@@ -186,4 +207,5 @@ RECIPES = {
     'token_decoder': token_decoder,
     'hybrid_decoder': hybrid_decoder,
     'lfm2_decoder': lfm2_decoder,
+    'sdar_decoder': sdar_decoder,
 }

@@ -543,3 +543,69 @@ def test_a_tiny_step_of_two_mixer_layers_has_every_leaf_and_the_phases():
                 '/attn/mha_qkv/' in p]
     assert any('q_norm' in p for p in rotation)
     assert any('k_norm' in p for p in rotation)
+
+
+# --------------------------------------------------------------------- #
+# the block-diffusion pass's leaves (two streams under a block mask)
+# --------------------------------------------------------------------- #
+BD_LEAVES = ('bd_core', 'bd_streams')
+
+
+def test_the_block_diffusion_leaves_are_on_the_closed_list_and_new(labelled):
+    assert set(BD_LEAVES) <= set(MODEL_SCOPES)
+    assert not set(BD_LEAVES) & set(
+        HYBRID_LEAVES + DECODER_LEAVES + SCONV_LEAVES)
+    comps = {c for _, _, p in labelled for c in p.split(';')[0].split('/')}
+    assert not comps & set(BD_LEAVES)
+
+
+def _tiny_block_diffusion_step_paths(batch_of):
+    import optax
+
+    from se3_transformer_tpu.parallel.sharding import make_sharded_train_step
+    from se3_transformer_tpu.training import lm_loss
+    from se3_transformer_tpu.training.recipes import RECIPES
+    module = RECIPES['sdar_decoder'](attention_block=8)
+    tokens = jax.ShapeDtypeStruct((2, 16), jnp.int32)
+    params = jax.eval_shape(module.init, jax.random.PRNGKey(0),
+                            tokens)['params']
+    optimizer = optax.adam(1e-4)
+    loss_fn, batch = batch_of(lm_loss, module, tokens)
+    text = make_sharded_train_step(loss_fn, optimizer).lower(
+        params, jax.eval_shape(optimizer.init, params), batch,
+        jax.ShapeDtypeStruct((2,), jnp.uint32)).as_text(debug_info=True)
+    return {p.rsplit('/', 1)[0] for p in re.findall(
+        r'"(jit\(train_step\)/[^"]*)"', text)}
+
+
+def test_a_tiny_block_diffusion_step_has_every_leaf_and_the_three_phases():
+    """The core under `bd_core` in forward, replay and backward (off the TPU
+    the blocked form is recomputed; on it the replay holds no launch, which
+    `tests/test_tpu_compile.py` reads in the compiled step); the streams'
+    building under `bd_streams`; no operation under `mha_core`."""
+    def batch_of(lm_loss, module, tokens):
+        weight = jax.ShapeDtypeStruct(tokens.shape, jnp.float32)
+        return (lm_loss.make_block_diffusion_loss(module, 4, chunk=8),
+                dict(tokens=tokens, noised=tokens, weight=weight))
+
+    paths = _tiny_block_diffusion_step_paths(batch_of)
+    cells = {(profiling.scope_leaf(p), profiling.scope_phase(p))
+             for p in paths}
+    assert {leaf for leaf, _ in cells} == set(BD_LEAVES) | {
+        'mha_qkv', 'mha_out', 'embed', 'moe_router', 'moe_dispatch',
+        'moe_experts', 'moe_combine', 'lm_head', 'norm', 'loss', 'optimizer'}
+    for leaf in ('bd_core', 'mha_qkv', 'moe_experts', 'moe_router'):
+        assert {ph for lf, ph in cells if lf == leaf} == set(
+            profiling.PHASES), leaf
+    assert ('bd_streams', 'forward') in cells
+    core = [p for p in paths if '/bd_core' in p]
+    assert core and all('/attn/bd_core' in p for p in core)
+
+
+def test_the_same_module_trained_next_token_keeps_the_causal_leaf():
+    def batch_of(lm_loss, module, tokens):
+        return lm_loss.make_lm_loss(module, chunk=8), dict(tokens=tokens)
+
+    leaves = {profiling.scope_leaf(p)
+              for p in _tiny_block_diffusion_step_paths(batch_of)}
+    assert 'mha_core' in leaves and not leaves & set(BD_LEAVES)

@@ -923,3 +923,147 @@ def test_lfm2_decoder_step_compiles_and_fits(v5e, monkeypatch, capsys):
               f'{mem.temp_size_in_bytes / 2**30:.2f} GiB, in all '
               f'{total / 2**30:.2f} GiB of 15.75')
     assert 9 * 2 ** 30 < total < 12.5 * 2 ** 30, mem
+
+
+# ------------------------------------------------------------------ #
+# the block-diffusion core (ops/block_diffusion.py)
+# ------------------------------------------------------------------ #
+def _splash_launches(text):
+    """(role, op_name) of every splash launch in a compiled program. A
+    launch's line breaks inside its `kernel_metadata`, so the path is the
+    first `op_name` after the instruction's name."""
+    return re.findall(
+        r'%(splash_mha_(?:fwd|dq|dkv))\w*?[.\d]* = \(.*?'
+        r'metadata=\{op_name="([^"]*)"', text, flags=re.S)
+
+
+def test_the_block_diffusion_core_compiles_and_visits_288_tiles_a_head(v5e):
+    """The library's splash kernels under the two streams' mask at the
+    block-diffusion cell's size (32 query and 4 key-value heads of 128,
+    2 x 8,192 positions, blocks of 4 tokens, tiles of 512): forward and both
+    backward launches lower, and the forward's own tile table, read here at
+    compile time, holds 288 of a head's 1,024 tiles: a noised tile meets
+    itself and the clean prefix's i + 1 tiles, a clean tile i + 1
+    (sum of (i + 2) + (i + 1) over 16), where a causal core over 16,384
+    positions would visit 528."""
+    from se3_transformer_tpu.ops import block_diffusion as bd
+
+    kernel = bd.splash_kernel(32, 8192, 4, 512)
+    assert bd.visited_tiles(kernel) == 288 \
+        == sum((i + 2) + (i + 1) for i in range(16))
+    table = kernel.fwd_mask_info.block_mask
+    assert table.shape == (1, 32, 17)      # one table for every head; the
+    #                                        grid's width is the fullest row
+    assert 32 * 33 // 2 == 528 and 32 * 32 == 1024
+
+    def loss(q, k, v):
+        return bd.block_diffusion_attention_splash(
+            q, k, v, 128 ** -0.5, 4, 512).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        jax.ShapeDtypeStruct((1, 32, 16384, 128), f32, sharding=v5e),
+        *[jax.ShapeDtypeStruct((1, 4, 16384, 128), f32, sharding=v5e)] * 2
+    ).compile()
+    roles = [role for role, _ in _splash_launches(compiled.as_text())]
+    assert sorted(roles) == ['splash_mha_dkv', 'splash_mha_dq',
+                             'splash_mha_fwd'], roles
+
+
+@pytest.mark.slow
+def test_sdar_decoder_step_compiles_and_fits(v5e, monkeypatch, capsys):
+    """The benchmark's block-diffusion cell: the published widths of its
+    configuration file on the one step factory at one sequence of 8,192
+    tokens read twice, compiled for the chip (a minute): one forward launch
+    of the core a layer and none in a block's replay (the blocks save its
+    output and log-sum-exp), every launch under `bd_core`; the grouped
+    products are in it; state plus temporaries fit; its memory is
+    printed."""
+    import optax
+    from se3_transformer_tpu.ops import (
+        block_diffusion, expert_layer, latent_attention,
+    )
+    from se3_transformer_tpu.parallel.sharding import make_sharded_train_step
+    from se3_transformer_tpu.training.lm_loss import make_block_diffusion_loss
+    from se3_transformer_tpu.training.recipes import RECIPES
+
+    for mod in (block_diffusion, latent_attention, expert_layer):
+        monkeypatch.setattr(mod, 'is_tpu_backend', lambda: True)
+    cfg = json.load(open(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        'benchmark', 'configs', 'sdar-30b-a3b-ep8-train.json')))
+    module = RECIPES[cfg['recipe']](**cfg['model'], **cfg['overrides'])
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+            tree)
+
+    tokens = jax.ShapeDtypeStruct((1, 8192), jnp.int32)
+    batch = dict(tokens=tokens, noised=tokens,
+                 weight=jax.ShapeDtypeStruct((1, 8192), jnp.float32))
+    params = jax.eval_shape(module.init, jax.random.PRNGKey(0),
+                            tokens)['params']
+    optimizer = optax.adam(1e-6)
+    compiled = make_sharded_train_step(
+        make_block_diffusion_loss(module, **cfg['loss']), optimizer).lower(
+        on_chip(params), on_chip(jax.eval_shape(optimizer.init, params)),
+        on_chip(batch), on_chip(jax.random.PRNGKey(1))).compile()
+    text = compiled.as_text()
+    assert 'ragged-dot' in text and 'flash_attention' not in text
+    launches = _splash_launches(text)
+    by_role = {}
+    for role, path in launches:
+        assert '/attn/bd_core/' in path, path
+        assert 'rematted_computation' not in path, path
+        assert ('transpose(' in path) == (role != 'splash_mha_fwd'), path
+        by_role[role] = by_role.get(role, 0) + 1
+    assert by_role == {'splash_mha_fwd': 5, 'splash_mha_dkv': 5,
+                       'splash_mha_dq': 5}, by_role
+    _assert_product_front_ends_agree(compiled)
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    with capsys.disabled():
+        print(f'\nsdar step for a v5e: arguments '
+              f'{mem.argument_size_in_bytes / 2**30:.2f} GiB, temporaries '
+              f'{mem.temp_size_in_bytes / 2**30:.2f} GiB, in all '
+              f'{total / 2**30:.2f} GiB of 15.75')
+    assert 11.5 * 2 ** 30 < total < 13.5 * 2 ** 30, mem
+
+
+def test_the_causal_path_lowers_as_it_did_before_the_two_streams(v5e):
+    """`GroupedQueryAttention` at the short-convolution cell's widths, for
+    the chip: called as the causal decoders call it and called with the new
+    arguments at their defaults (no positions, no block length) it lowers to
+    one StableHLO, the library's causal kernel under `mha_core` and no
+    `bd_core` anywhere. (The three accepted cells' whole steps were lowered
+    from the parent's tree and from this one when the path was added: the
+    same text outside the Mosaic bodies, the same Mosaic modules.)"""
+    from se3_transformer_tpu.ops import latent_attention
+    from se3_transformer_tpu.ops.grouped_attention import (
+        GroupedQueryAttention,
+    )
+    attn = GroupedQueryAttention(dim=2048, heads=32, kv_heads=8, head_dim=64,
+                                 qk_norm=True, rope_theta=1e6)
+    x = jax.ShapeDtypeStruct((1, 8192, 2048), f32, sharding=v5e)
+    params = jax.eval_shape(attn.init, jax.random.PRNGKey(0), x)['params']
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+        params)
+
+    def stripped(text):           # of source locations and their lines
+        return re.sub(r'\n+', '\n', re.sub(r'loc\(.*?\)|#loc.*', '', text))
+
+    keep = latent_attention.is_tpu_backend
+    latent_attention.is_tpu_backend = lambda: True
+    try:
+        plain = jax.jit(lambda p, x: attn.apply({'params': p}, x)).lower(
+            params, x).as_text(debug_info=True)
+        defaults = jax.jit(lambda p, x: attn.apply(
+            {'params': p}, x, None, 0)).lower(params, x).as_text(
+            debug_info=True)
+    finally:
+        latent_attention.is_tpu_backend = keep
+    assert stripped(plain) == stripped(defaults)
+    assert 'mha_core' in plain and 'bd_core' not in plain
+    assert 'flash_attention' in plain and 'splash' not in plain

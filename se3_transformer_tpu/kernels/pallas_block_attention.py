@@ -1,0 +1,366 @@
+"""The attention core of a decoder trained by diffusion over blocks
+(`ops/block_diffusion.py`) as two Pallas kernels over a static table of the
+tiles that hold a visible pair.
+
+A sequence of L tokens is 2 L positions, noised then clean, cut into tiles of
+`tile` positions. With the tile dividing L and the block length dividing the
+tile, a (query tile, key tile) pair is one of five things (`tile_table`):
+
+    empty            no visible pair: not in the table, never visited
+    full             every pair visible: scores, softmax and products with
+                     no compare and no select (a noised or clean query tile
+                     against an earlier tile of the clean stream)
+    noised -> noised the query tile against itself:     r // bl == c // bl
+    noised -> clean  against its own tile, clean:       c // bl <  r // bl
+    clean  -> clean  the clean tile against itself:     c // bl <= r // bl
+
+r and c the offsets inside the tile, so a boundary tile's mask is one of
+three constants and is `low <= r // bl - c // bl <= high` for two scalars of
+the table (`boundary_mask`). At L = 8,192, blocks of 4 and tiles of 512 a
+head has 288 tiles of 1,024 in the table, 48 of them on a boundary.
+
+The table goes to both launches by scalar prefetch: the grid is (sequence,
+key-value head, table entry), exactly the visited tiles, in the order of
+their query tiles, and the index maps read the entry's tiles. A program
+loops over the query heads that share its key-value head, so k and v are
+loaded once for all of them. Two launches, named by role (`name=` on
+`pl.pallas_call`):
+
+    bd_core_fwd  s = q k^T [query, key]; the running maximum, sum and
+                 output of a query tile carried in VMEM scratch over its
+                 entries; at its last entry o = acc / l (rounded to the
+                 operands' width) and the log-sum-exp, one float32 a row,
+                 turned once into a lane row [1, tile]
+    bd_core_bwd  every score and dP once, five products a tile, scores
+                 transposed [key, query] so that the log-sum-exp and
+                 di = sum(o do) are lane rows as stored: p = exp(s - lse),
+                 dv += p do, dp = v do^T, ds = p (dp - di), dk += ds q,
+                 dq += ds^T k. dq of a query tile is summed in float32 in
+                 its output block, resident over the tile's entries; dk and
+                 dv of a whole key-value head [2L, D] float32 are resident
+                 in VMEM over all of its entries and query heads, so the
+                 key-value heads stay unrepeated and nothing is summed in
+                 HBM.
+
+`block_attention` ties them in a `jax.custom_vjp`; the forward's output and
+log-sum-exp carry `ATTN_CORE_OUT` / `ATTN_CORE_STATS`, so a block rematted
+under `SAVE_ATTN_CORE` replays no forward launch.
+
+Arithmetic: q (carrying the scale), k, v, p, do and ds rounded to bfloat16
+once, float32 accumulation in every product (float32 operands under
+`interpret`, where the CPU has no such product); maxima, exponentials, sums,
+log-sum-exp, the running output and every accumulator in float32. A masked
+score is a large finite negative, so a row whose tile hides every key
+(the first block's rows of a noised -> clean tile) stays finite and its
+weights vanish against the tile that holds its own block.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.ad_checkpoint import checkpoint_name
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ..ops.latent_attention import ATTN_CORE_OUT, ATTN_CORE_STATS
+
+LANES = 128
+# the backward holds a key-value head's dk and dv whole (2 x 8 MiB at 16,384
+# positions of 128, twice for the pipeline's buffers) beside a query tile's
+# q, do and dq for every head of the group and a tile's scores
+VMEM_LIMIT = 96 * 2 ** 20
+PARAMS = pltpu.CompilerParams(
+    dimension_semantics=('parallel', 'parallel', 'arbitrary'),
+    vmem_limit_bytes=VMEM_LIMIT)
+MASKED = -0.7 * float(np.finfo(np.float32).max)
+
+# the table's rows, and a tile's kinds
+QUERY, KEY, KIND, LOW, HIGH, FIRST, LAST = range(7)
+FULL, NOISED_NOISED, NOISED_CLEAN, CLEAN_CLEAN = range(4)
+FAR = 2 ** 30
+# a boundary tile shows the pairs with low <= r // bl - c // bl <= high
+BOUNDS = {FULL: (-FAR, FAR), NOISED_NOISED: (0, 0), NOISED_CLEAN: (1, FAR),
+          CLEAN_CLEAN: (0, FAR)}
+
+
+def can_run(length: int, block_length: int, tile: int, heads: int,
+            kv_heads: int, head_dim: int) -> bool:
+    """What the table and Mosaic's tiles ask: whole tiles in a stream and
+    whole blocks in a tile (so a boundary's mask is one of three constants),
+    tiles and heads of whole lane rows, whole groups of query heads; and
+    what the backward asks: a key-value head's dk and dv [2 length, head_dim]
+    float32, in the pipeline's two buffers each, in two thirds of the VMEM
+    it may use (16,384 tokens at heads of 128 fit, 32,768 do not)."""
+    return tile % LANES == 0 and length % tile == 0 \
+        and tile % block_length == 0 and head_dim % LANES == 0 \
+        and heads % kv_heads == 0 \
+        and 2 * 2 * 2 * length * head_dim * 4 <= 2 * VMEM_LIMIT // 3
+
+
+@functools.lru_cache(maxsize=None)
+def tile_table(length: int, block_length: int, tile: int) -> np.ndarray:
+    """[7, n] int32, a column a visited (query tile, key tile) of one head in
+    the order both launches take them: by query tile, noised stream first.
+    Rows: `QUERY`, `KEY` (tiles of the 2 length positions), `KIND`, `LOW` and
+    `HIGH` (`BOUNDS` of the kind), `FIRST` and `LAST` (of its query tile's
+    columns). Built once a process."""
+    assert length % tile == 0 and tile % block_length == 0, \
+        (length, block_length, tile)
+    n = length // tile
+    columns = []
+    for i in range(n):                  # a noised tile: itself, the clean
+        columns += [(i, i, NOISED_NOISED)]              # prefix, its own
+        columns += [(i, n + j, FULL) for j in range(i)]     # tile clean
+        columns += [(i, n + i, NOISED_CLEAN)]
+    for i in range(n):                  # a clean tile: block-causal
+        columns += [(n + i, n + j, FULL) for j in range(i)]
+        columns += [(n + i, n + i, CLEAN_CLEAN)]
+    query, key, kind = np.array(columns, np.int32).T
+    low, high = np.array([BOUNDS[int(c)] for c in kind], np.int32).T
+    new = np.diff(query, prepend=-1, append=-1) != 0
+    table = np.stack([query, key, kind, low, high, new[:-1], new[1:]]
+                     ).astype(np.int32)
+    table.setflags(write=False)
+    return table
+
+
+def floor_div(a, n: int):
+    """a // n for a >= 0; a shift where n is a power of two (the vector unit
+    has no integer division)."""
+    return a >> (n.bit_length() - 1) if n & (n - 1) == 0 else a // n
+
+
+def boundary_mask(low, high, tile: int, block_length: int,
+                  keys_in_lanes: bool = True):
+    """[tile, tile] booleans, queries by keys (keys by queries with
+    `keys_in_lanes` off): whether the query at offset r sees the key at
+    offset c of a boundary tile whose bounds are `low`, `high`. Comparisons
+    and one conjunction: Mosaic refuses a select between booleans."""
+    q_dim = 0 if keys_in_lanes else 1
+    blocks = [floor_div(jax.lax.broadcasted_iota(
+        jnp.int32, (tile, tile), dim), block_length) for dim in (0, 1)]
+    ahead = blocks[q_dim] - blocks[1 - q_dim]
+    return (ahead >= low) & (ahead <= high)
+
+
+def _dot(a, b, contract):
+    return jax.lax.dot_general(a, b, ((contract[:1], contract[1:]), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _nt(a, b):        # a [m, k], b [n, k] -> a b^T
+    return _dot(a, b, (1, 1))
+
+
+def _tn(a, b):        # a [k, m], b [k, n] -> a^T b
+    return _dot(a, b, (0, 0))
+
+
+def _nn(a, b):
+    return _dot(a, b, (1, 0))
+
+
+def _lanes(a, width: int):
+    """a [rows, LANES], every lane alike -> [rows, width]."""
+    return a if width == LANES else jnp.tile(a, (1, width // LANES))
+
+
+def _by_kind(tab, n, block_length, tile, keys_in_lanes, entry):
+    """`entry(hidden)` for table column n: `hidden` None on a full tile,
+    else what a boundary tile adds to its scores (0 or `MASKED`), built once
+    for all of a program's heads."""
+    @pl.when(tab[KIND, n] == FULL)
+    def _():
+        entry(None)
+
+    @pl.when(tab[KIND, n] != FULL)
+    def _():
+        seen = boundary_mask(tab[LOW, n], tab[HIGH, n], tile, block_length,
+                             keys_in_lanes)
+        entry(jnp.where(seen, 0.0, MASKED))
+
+
+# --------------------------------------------------------------------- #
+# forward
+# --------------------------------------------------------------------- #
+def _fwd_kernel(tab, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
+                acc_scr, *, block_length, od):
+    n = pl.program_id(2)
+    g, tile, d = q_ref.shape[1:]
+    last = tab[LAST, n] == 1
+
+    @pl.when(tab[FIRST, n] == 1)
+    def _():
+        m_scr[...] = jnp.full_like(m_scr, MASKED)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def entry(hidden):
+        k, v = k_ref[0, 0], v_ref[0, 0]
+
+        def head(h, _):         # a loop, so that a launch traces one head
+            s = _nt(q_ref[0, h], k)                       # [query, key]
+            if hidden is not None:
+                s = s + hidden
+            m_prev, l_prev = m_scr[h], l_scr[h]           # [tile, LANES]
+            m_next = jnp.maximum(m_prev,
+                                 jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - _lanes(m_next, tile))
+            alpha = jnp.exp(m_prev - m_next)
+            l_next = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+            acc = _lanes(alpha, d) * acc_scr[h] + _nn(p.astype(od), v)
+            m_scr[h], l_scr[h], acc_scr[h] = m_next, l_next, acc
+
+            @pl.when(last)
+            def _():
+                o_ref[0, h] = (acc / _lanes(l_next, d)).astype(o_ref.dtype)
+                # a row's log-sum-exp, alike in every lane -> rows in lanes
+                lse_ref[0, h] = (m_next + jnp.log(l_next)).T[:1]
+
+        jax.lax.fori_loop(0, g, head, None)
+
+    _by_kind(tab, n, block_length, tile, True, entry)
+
+
+def _specs(g, tile, d):
+    """The block of a group's query heads at a column's query tile, of a
+    key-value head at its key tile, and of the group's rows of statistics
+    [B, H, 1, 2L]."""
+    heads = pl.BlockSpec((1, g, tile, d),
+                         lambda z, c, n, tab: (z, c, tab[QUERY, n], 0))
+    keys = pl.BlockSpec((1, 1, tile, d),
+                        lambda z, c, n, tab: (z, c, tab[KEY, n], 0))
+    stats = pl.BlockSpec((1, g, 1, tile),
+                         lambda z, c, n, tab: (z, c, 0, tab[QUERY, n]))
+    return heads, keys, stats
+
+
+_STATIC = ('block_length', 'tile', 'interpret')
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _fwd(q, k, v, block_length, tile, interpret):
+    """q [B, H, 2L, D], k, v [B, KV, 2L, D] in the operands' width -> o like
+    q and the log-sum-exp [B, H, 1, 2L] float32. A jit of its own, so that a
+    step traces and lowers the launch once, not once a layer."""
+    b, h, t, d = q.shape
+    kv = k.shape[1]
+    g, f32 = h // kv, jnp.float32
+    table = tile_table(t // 2, block_length, tile)
+    heads, keys, stats = _specs(g, tile, d)
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, block_length=block_length,
+                          od=q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b, kv, table.shape[1]),
+            in_specs=[heads, keys, keys], out_specs=[heads, stats],
+            scratch_shapes=[pltpu.VMEM((g, tile, LANES), f32),
+                            pltpu.VMEM((g, tile, LANES), f32),
+                            pltpu.VMEM((g, tile, d), f32)]),
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct((b, h, 1, t), f32)],
+        compiler_params=PARAMS, interpret=interpret, name='bd_core_fwd',
+    )(jnp.asarray(table), q, k, v)
+
+
+# --------------------------------------------------------------------- #
+# backward
+# --------------------------------------------------------------------- #
+def _bwd_kernel(tab, q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
+                dq_ref, dk_ref, dv_ref, *, block_length, od):
+    n = pl.program_id(2)
+    g, tile, d = q_ref.shape[1:]
+
+    @pl.when(n == 0)
+    def _():
+        dk_ref[...] = jnp.zeros_like(dk_ref)
+        dv_ref[...] = jnp.zeros_like(dv_ref)
+
+    @pl.when(tab[FIRST, n] == 1)
+    def _():
+        dq_ref[...] = jnp.zeros_like(dq_ref)
+
+    def entry(hidden):
+        k, v = k_ref[0, 0], v_ref[0, 0]
+
+        def head(h, sums):
+            dk, dv = sums
+            q, do = q_ref[0, h], do_ref[0, h]
+            s = _nt(k, q)                                 # [key, query]
+            if hidden is not None:
+                s = s + hidden
+            p = jnp.exp(s - lse_ref[0, h])
+            ds = (p * (_nt(v, do) - di_ref[0, h])).astype(od)
+            dq_ref[0, h] += _tn(ds, k)
+            return dk + _nn(ds, q), dv + _nn(p.astype(od), do)
+
+        zero = jnp.zeros((tile, d), jnp.float32)
+        dk, dv = jax.lax.fori_loop(0, g, head, (zero, zero))
+        rows = pl.ds(pl.multiple_of(tab[KEY, n] * tile, tile), tile)
+        dk_ref[0, 0, rows, :] += dk
+        dv_ref[0, 0, rows, :] += dv
+
+    _by_kind(tab, n, block_length, tile, False, entry)
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _bwd(q, k, v, do, lse, di, block_length, tile, interpret):
+    """-> dq like q's shape, dk and dv like k's, float32."""
+    b, h, t, d = q.shape
+    kv = k.shape[1]
+    g, f32 = h // kv, jnp.float32
+    table = tile_table(t // 2, block_length, tile)
+    heads, keys, stats = _specs(g, tile, d)
+    whole = pl.BlockSpec((1, 1, t, d), lambda z, c, n, tab: (z, c, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, block_length=block_length,
+                          od=q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b, kv, table.shape[1]),
+            in_specs=[heads, keys, keys, heads, stats, stats],
+            out_specs=[heads, whole, whole]),
+        out_shape=[jax.ShapeDtypeStruct(q.shape, f32),
+                   jax.ShapeDtypeStruct(k.shape, f32),
+                   jax.ShapeDtypeStruct(k.shape, f32)],
+        compiler_params=PARAMS, interpret=interpret, name='bd_core_bwd',
+    )(jnp.asarray(table), q, k, v, do, lse, di)
+
+
+# --------------------------------------------------------------------- #
+# the core over a sequence's two streams
+# --------------------------------------------------------------------- #
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def block_attention(q, k, v, scale, block_length, tile, interpret=False):
+    """q [B, H, 2L, D], k, v [B, KV, 2L, D] (the noised stream, then the
+    clean one) -> [B, H, 2L, D] float32; shapes as `can_run` asks."""
+    return _block_attention_fwd(q, k, v, scale, block_length, tile,
+                                interpret)[0]
+
+
+def _block_attention_fwd(q, k, v, scale, block_length, tile, interpret):
+    od = jnp.float32 if interpret else jnp.bfloat16
+    q, k, v = (q * scale).astype(od), k.astype(od), v.astype(od)
+    o, lse = _fwd(q, k, v, block_length=block_length, tile=tile,
+                  interpret=interpret)
+    o = checkpoint_name(o, ATTN_CORE_OUT)
+    lse = checkpoint_name(lse, ATTN_CORE_STATS)
+    return o.astype(jnp.float32), (q, k, v, o, lse)
+
+
+def _block_attention_bwd(scale, block_length, tile, interpret, residuals,
+                         do):
+    q, k, v, o, lse = residuals
+    # do rounded once, and di from the rounded one: the products and the
+    # row sums then see one do, and XLA writes the output projection's dx
+    # in the operands' width with di summed in the same pass
+    do, f32 = do.astype(q.dtype), jnp.float32
+    di = jnp.sum(o.astype(f32) * do.astype(f32), axis=-1)[:, :, None]
+    dq, dk, dv = _bwd(q, k, v, do, lse, di, block_length=block_length,
+                      tile=tile, interpret=interpret)
+    return dq * scale, dk, dv
+
+
+block_attention.defvjp(_block_attention_fwd, _block_attention_bwd)

@@ -122,9 +122,11 @@ MODEL_SCOPES = (
     'sconv_out',          # ops/short_conv.py: output projection
     # a decoder trained by diffusion over blocks (two streams in one pass)
     'bd_core',            # ops/grouped_attention.py: the block-diffusion
-    #                       core (ops/block_diffusion.py: splash attention's
-    #                       three launches on a TPU) and the casts around
-    #                       it, apart from `mha_core`
+    #                       core (ops/block_diffusion.py: on a TPU the two
+    #                       launches of kernels/pallas_block_attention.py,
+    #                       `bd_core_fwd` and `bd_core_bwd`) and the casts
+    #                       and di = sum(o do) around them, apart from
+    #                       `mha_core`
     'bd_streams',         # models/hybrid_decoder.py, training/lm_loss.py:
     #                       building the two streams and their positions,
     #                       cutting the noised one out, the weights

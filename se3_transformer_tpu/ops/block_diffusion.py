@@ -22,31 +22,23 @@ grouped heads (q [B, H, 2L, D], k and v [B, KV, 2L, D], unrepeated):
     clean block the clean prefix through its own blocks; the clean stream's
     upper half and the clean -> noised quarter are never computed), the mask
     made from the stream ids, each block recomputed in the backward pass;
-  * on the TPU JAX's splash attention (`jax.experimental.pallas.ops.tpu.
-    splash_attention`) under a mask it computes inside the kernel from row
-    and column ids: tiles with no visible pair are never visited
-    (`visited_tiles` reads the launch's own tile table), and the forward's
-    output and log-sum-exp carry `ATTN_CORE_OUT`, which `SAVE_ATTN_CORE`
-    keeps, so a rematted block's replay launches no forward. The launches
-    are the library's: `splash_mha_fwd_residuals`, `splash_mha_dkv_*`,
-    `splash_mha_dq_*`.
+  * on the TPU the repo's own kernels (`kernels/pallas_block_attention.py`)
+    over a static table of the tiles that hold a visible pair: the grid is
+    those tiles and no other (`visited_tiles`), the rule runs on the tiles a
+    boundary crosses alone (`boundary_tiles`), and the forward's output and
+    log-sum-exp carry `ATTN_CORE_OUT` / `ATTN_CORE_STATS`, which
+    `SAVE_ATTN_CORE` keeps, so a rematted block's replay launches no
+    forward. The launches: `bd_core_fwd`, `bd_core_bwd`.
 """
 from __future__ import annotations
-
-from functools import lru_cache
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels import pallas_block_attention as kernels
+from ..kernels.pallas_block_attention import floor_div as _div
 from ..utils.helpers import is_tpu_backend
-from .latent_attention import ATTN_CORE_OUT
-
-
-def _div(a, n: int):
-    """a // n for a >= 0; a shift where n is a power of two (the kernel's
-    vector unit has no integer division)."""
-    return a >> (n.bit_length() - 1) if n & (n - 1) == 0 else a // n
 
 
 def _mod(a, n: int):
@@ -114,77 +106,27 @@ def block_diffusion_attention_blocked(q, k, v, scale: float,
     return jnp.concatenate(outs, axis=3).reshape(b, h, t2, d)
 
 
-def _streams_mask(length: int, block_length: int):
-    """The library's computable mask for `visible`: it evaluates the rule on
-    NumPy ids for the tile tables and inside the kernels on the tiles'."""
-    from jax.experimental.pallas.ops.tpu.splash_attention import (
-        splash_attention_mask as mask_lib,
-    )
-
-    class StreamsMask(mask_lib._ComputableMask):
-        def __eq__(self, other):
-            return isinstance(other, StreamsMask)
-
-        def __hash__(self):
-            return hash((StreamsMask, self.shape))
-
-    return StreamsMask(
-        shape=(2 * length, 2 * length),
-        mask_function=lambda q_ids, kv_ids: visible(
-            q_ids, kv_ids, length, block_length))
+def visited_tiles(length: int, block_length: int, tile: int) -> int:
+    """The (query tile, key tile) pairs a head's launches compute: the
+    columns of the table both take their grid from, none of them idle."""
+    return kernels.tile_table(length, block_length, tile).shape[1]
 
 
-@lru_cache(maxsize=None)
-def splash_kernel(heads: int, length: int, block_length: int, block: int,
-                  interpret: bool = False):
-    """The library's kernel object over `heads` query heads for a sequence
-    of `length` tokens (2 length positions) at tiles of `block`: the three
-    launches' tile tables, built once a process from the mask (seconds at
-    16,384 positions) as constants of whatever program calls it.
-    `interpret`: the kernels in Pallas's interpret mode, for a test off the
-    TPU."""
-    from jax.experimental.pallas.ops.tpu.splash_attention import (
-        splash_attention_kernel as splash,
-        splash_attention_mask as mask_lib,
-    )
-    mask = mask_lib.MultiHeadMask(
-        [_streams_mask(length, block_length)] * heads)
-    b = min(block, length)
-    sizes = splash.BlockSizes(
-        block_q=b, block_kv=b, block_kv_compute=b, block_q_dkv=b,
-        block_kv_dkv=b, block_kv_dkv_compute=b, block_q_dq=b, block_kv_dq=b)
-    with jax.ensure_compile_time_eval():
-        return splash.make_splash_mha(
-            mask, block_sizes=sizes, head_shards=1, q_seq_shards=1,
-            residual_checkpoint_name=ATTN_CORE_OUT, interpret=interpret)
-
-
-def visited_tiles(kernel) -> int:
-    """The (query tile, key tile) pairs a head's forward launch computes: the
-    non-zero entries of its tile table. The grid's other programs load
-    nothing and run nothing."""
-    table = np.asarray(kernel.fwd_mask_info.block_mask)
-    return int(np.count_nonzero(table)) // table.shape[0]
-
-
-def block_diffusion_attention_splash(q, k, v, scale: float,
-                                     block_length: int, block: int = 512,
-                                     interpret: bool = False):
-    """The same on the TPU's streaming kernel: operands rounded to bfloat16,
-    float32 softmax and accumulation, the scale folded into q (the library
-    takes none)."""
-    kernel = splash_kernel(q.shape[1], q.shape[2] // 2, block_length, block,
-                           interpret)
-    q = (q * scale).astype(jnp.bfloat16)
-    k, v = (a.astype(jnp.bfloat16) for a in (k, v))
-    return jax.vmap(kernel)(q, k, v).astype(jnp.float32)
+def boundary_tiles(length: int, block_length: int, tile: int) -> int:
+    """Those of them that evaluate the rule; the others are wholly visible
+    and run with no compare and no select."""
+    kinds = kernels.tile_table(length, block_length, tile)[kernels.KIND]
+    return int(np.count_nonzero(kinds != kernels.FULL))
 
 
 def block_diffusion_attention(q, k, v, scale: float, block_length: int,
                               block: int = 512):
-    """The streaming kernel where it can run (on a TPU, at a length its
-    tiles divide), blocks of queries elsewhere."""
-    core = block_diffusion_attention_splash \
-        if is_tpu_backend() and (q.shape[2] // 2) % 128 == 0 \
-        else block_diffusion_attention_blocked
-    return core(q, k, v, scale, block_length, block)
+    """The kernels where they can run (on a TPU, at the shapes of
+    `kernels.can_run`), blocks of queries elsewhere."""
+    length = q.shape[2] // 2
+    tile = min(block, length)
+    if is_tpu_backend() and kernels.can_run(
+            length, block_length, tile, q.shape[1], k.shape[1], q.shape[3]):
+        return kernels.block_attention(q, k, v, scale, block_length, tile)
+    return block_diffusion_attention_blocked(q, k, v, scale, block_length,
+                                             block)

@@ -9,6 +9,7 @@ decoders that were there to what they computed before this path existed."""
 import importlib
 import importlib.util
 import os
+import re
 import sys
 
 import jax
@@ -17,6 +18,7 @@ import numpy as np
 import optax
 import pytest
 
+from se3_transformer_tpu.kernels import pallas_block_attention as kernels
 from se3_transformer_tpu.ops import block_diffusion as bd
 from se3_transformer_tpu.ops.expert_layer import (
     SCORING_FUNCS, ExpertLayer, route,
@@ -184,23 +186,26 @@ def test_off_the_tpu_the_core_is_the_blocked_one(monkeypatch):
 
 
 def test_the_streaming_kernel_interpreted_is_the_blocked_core():
-    """The library's kernels under this mask, in interpret mode at tiles of
-    128 (two streams of 256 tokens: a noised tile, the clean prefix's tiles,
-    the clean stream's lower triangle), output and gradients."""
+    """The repo's two kernels (`kernels/pallas_block_attention.py`) in
+    interpret mode at tiles of 128 (two streams of 256 tokens, 4 query and 2
+    key-value heads of 128: a noised tile, the clean prefix's tiles, the
+    clean stream's lower triangle), output and the three gradients against
+    the blocked core at `highest`. Interpreted, the products' operands stay
+    float32, so the two agree far inside the bfloat16 tolerances."""
     length, heads, kv, dh = 256, 4, 2, 128
     keys = jax.random.split(jax.random.PRNGKey(5), 4)
     q = jax.random.normal(keys[0], (1, heads, 2 * length, dh))
     k, v = (jax.random.normal(key, (1, kv, 2 * length, dh))
             for key in keys[1:3])
     w = jax.random.normal(keys[3], q.shape)
-    kernel = bd.splash_kernel(heads, length, BK, 128, interpret=True)
+    assert kernels.can_run(length, BK, 128, heads, kv, dh)
     # 2 q tiles a stream: noised tile i meets itself and clean tiles 0..i,
     # clean tile i clean tiles 0..i: (2 + 3) + (1 + 2) of 16
-    assert bd.visited_tiles(kernel) == 8
+    assert bd.visited_tiles(length, BK, 128) == 8
+    assert bd.boundary_tiles(length, BK, 128) == 6
 
     def streamed(q, k, v):
-        return bd.block_diffusion_attention_splash(
-            q, k, v, dh ** -0.5, BK, 128, interpret=True)
+        return kernels.block_attention(q, k, v, dh ** -0.5, BK, 128, True)
 
     def blocked(q, k, v):
         return bd.block_diffusion_attention_blocked(q, k, v, dh ** -0.5, BK,
@@ -211,11 +216,149 @@ def test_the_streaming_kernel_interpreted_is_the_blocked_core():
     with jax.default_matmul_precision('highest'):
         want, g_want = jax.value_and_grad(
             lambda *a: jnp.sum(w * blocked(*a)), argnums=(0, 1, 2))(q, k, v)
-    # operands and output rounded to bfloat16
     np.testing.assert_allclose(got, want, rtol=2e-2)
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
     for a, b in zip(g_got, g_want):
         err = float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
-        assert err < 2e-2, err
+        assert err < 1e-5, err
+
+
+# (length, block length, tile): the cell's size; the interpreted test's; a
+# block length that is no power of two
+TABLES = [(8192, 4, 512), (256, 4, 128), (1536, 3, 384)]
+
+
+def _tile_ids(tile_index, tile, stride=1):
+    return np.arange(tile_index * tile, (tile_index + 1) * tile, stride)
+
+
+@pytest.mark.parametrize('length,block_length,tile', TABLES)
+def test_the_tile_table_is_the_tiles_with_a_visible_pair(length,
+                                                         block_length, tile):
+    """The table both launches take their grid from: a head's visited (query
+    tile, key tile) pairs in the order of their query tiles, a kind each.
+    288 visited and 48 on a boundary at the cell's size, 16 of each kind;
+    the tiles in it are those in which `visible` shows a pair, no other."""
+    table = kernels.tile_table(length, block_length, tile)
+    n = length // tile
+    query, key, kind = (table[row] for row in
+                        (kernels.QUERY, kernels.KEY, kernels.KIND))
+    assert table.shape == (7, n * (n + 2)) and table.dtype == np.int32
+    assert bd.visited_tiles(length, block_length, tile) == table.shape[1]
+    assert bd.boundary_tiles(length, block_length, tile) == 3 * n
+    for boundary in (kernels.NOISED_NOISED, kernels.NOISED_CLEAN,
+                     kernels.CLEAN_CLEAN):
+        assert np.count_nonzero(kind == boundary) == n
+    if length == 8192:
+        assert (table.shape[1], 3 * n, n) == (288, 48, 16)
+    # a query tile's columns are neighbours, opened and closed once
+    assert np.all(np.diff(query) >= 0)
+    assert np.array_equal(table[kernels.FIRST],
+                          np.diff(query, prepend=-1) != 0)
+    assert np.array_equal(table[kernels.LAST],
+                          np.diff(query, append=-1) != 0)
+    assert len(set(zip(query, key))) == table.shape[1]
+    # the rule, a row and a column a block
+    ids = np.arange(0, 2 * length, block_length)
+    seen = bd.visible(ids[:, None], ids[None, :], length, block_length)
+    per = tile // block_length
+    any_pair = seen.reshape(2 * n, per, 2 * n, per).any(axis=(1, 3))
+    in_table = np.zeros_like(any_pair)
+    in_table[query, key] = True
+    assert np.array_equal(any_pair, in_table)
+    every_pair = seen.reshape(2 * n, per, 2 * n, per).all(axis=(1, 3))
+    full = kind == kernels.FULL
+    assert every_pair[query[full], key[full]].all()
+    assert not every_pair[query[~full], key[~full]].any()
+
+
+@pytest.mark.parametrize('kind', ['NOISED_NOISED', 'NOISED_CLEAN',
+                                  'CLEAN_CLEAN'])
+@pytest.mark.parametrize('length,block_length,tile', TABLES[:1] + TABLES[2:])
+def test_a_boundary_kinds_mask_is_the_rule_on_its_tiles_ids(
+        length, block_length, tile, kind):
+    """What the kernels evaluate on a boundary tile, from the offsets inside
+    the tile and the table's two bounds, is `visible` on every such tile's
+    ids to the bit, either way up."""
+    table = kernels.tile_table(length, block_length, tile)
+    columns = np.flatnonzero(table[kernels.KIND] == getattr(kernels, kind))
+    assert len(columns) == length // tile
+    for n in columns:
+        low, high = (int(table[row, n])
+                     for row in (kernels.LOW, kernels.HIGH))
+        assert (low, high) == kernels.BOUNDS[getattr(kernels, kind)]
+        want = bd.visible(
+            _tile_ids(table[kernels.QUERY, n], tile)[:, None],
+            _tile_ids(table[kernels.KEY, n], tile)[None, :],
+            length, block_length)
+        got = np.asarray(kernels.boundary_mask(low, high, tile,
+                                               block_length))
+        assert got.dtype == np.bool_ and np.array_equal(got, want), n
+        assert not want.all() and want.any()
+    assert np.array_equal(np.asarray(kernels.boundary_mask(
+        low, high, tile, block_length, keys_in_lanes=False)), want.T)
+
+
+@pytest.mark.parametrize('length,block_length,tile', TABLES[:2])
+def test_a_full_tiles_ids_are_all_visible(length, block_length, tile):
+    table = kernels.tile_table(length, block_length, tile)
+    columns = np.flatnonzero(table[kernels.KIND] == kernels.FULL)
+    assert len(columns) == table.shape[1] - 3 * (length // tile)
+    for n in columns[::7]:
+        assert bd.visible(
+            _tile_ids(table[kernels.QUERY, n], tile)[:, None],
+            _tile_ids(table[kernels.KEY, n], tile)[None, :],
+            length, block_length).all(), n
+        assert tuple(table[[kernels.LOW, kernels.HIGH], n]) \
+            == kernels.BOUNDS[kernels.FULL]
+
+
+@pytest.mark.parametrize('case,length,block_length,block,head_dim,runs', [
+    ('the cell, shrunk', 256, 4, 128, 128, True),
+    ('a length the tile does not divide', 320, 4, 128, 128, False),
+    ('a block length that does not divide the tile', 384, 3, 128, 128,
+     False),
+    ('heads of 64', 256, 4, 128, 64, False),
+    ("more keys than the backward's resident dk and dv have room for",
+     32768, 4, 512, 128, False),
+])
+def test_on_a_tpu_the_kernels_run_where_can_run_holds(
+        monkeypatch, case, length, block_length, block, head_dim, runs):
+    """One path a platform: on a TPU the kernels at the shapes `can_run`
+    admits, the blocked core at any other, chosen from the shapes alone."""
+    assert kernels.can_run(length, block_length, min(block, length), 4, 2,
+                           head_dim) == runs, case
+    q, k, v = (jax.ShapeDtypeStruct((1, h, 2 * length, head_dim),
+                                    jnp.float32) for h in (4, 2, 2))
+    taken = []
+    monkeypatch.setattr(bd, 'is_tpu_backend', lambda: True)
+    monkeypatch.setattr(bd, 'block_diffusion_attention_blocked',
+                        lambda *a: taken.append('blocked') or a[0])
+    monkeypatch.setattr(kernels, 'block_attention',
+                        lambda *a: taken.append(('kernels',) + a[3:])
+                        or a[0])
+    bd.block_diffusion_attention(q, k, v, 0.5, block_length, block)
+    assert taken == ([('kernels', 0.5, block_length, block)] if runs
+                     else ['blocked']), case
+
+
+@pytest.mark.parametrize('policy,forwards', [('SAVE_ATTN_CORE', 1),
+                                             (None, 2)])
+def test_a_rematted_core_replays_no_forward_launch(policy, forwards):
+    """The forward's output and log-sum-exp carry the names
+    `SAVE_ATTN_CORE` keeps: the gradient of a block rematted under it holds
+    one forward launch; rematted whole (the control) it holds two."""
+    from se3_transformer_tpu.ops import latent_attention
+    q = jnp.ones((1, 2, 256, 128))
+    k = v = q[:, :1]
+    core = jax.checkpoint(
+        lambda q, k, v: kernels.block_attention(q, k, v, 0.1, BK, 128, True),
+        policy=policy and getattr(latent_attention, policy))
+    jaxpr = str(jax.make_jaxpr(jax.grad(
+        lambda *a: core(*a).sum(), argnums=(0, 1, 2)))(q, k, v))
+    found = re.findall(r'name=(bd_core_\w+)', jaxpr)
+    assert sorted(found) == ['bd_core_bwd'] + ['bd_core_fwd'] * forwards, \
+        found
 
 
 # ------------------------------------------------------------------ #

@@ -602,6 +602,44 @@ def test_a_tiny_block_diffusion_step_has_every_leaf_and_the_three_phases():
     assert core and all('/attn/bd_core' in p for p in core)
 
 
+def _launch_paths(jaxpr, outer='', found=None):
+    """(launch name, name stack) of every Pallas launch in a jaxpr."""
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        path = '/'.join(p for p in (outer, str(eqn.source_info.name_stack))
+                        if p)
+        if eqn.primitive.name == 'pallas_call':
+            found.append((eqn.params['name'], path))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _launch_paths(sub, path, found)
+    return found
+
+
+def test_on_a_tpu_the_cores_two_launches_are_filed_under_bd_core(
+        monkeypatch):
+    """`kernels/pallas_block_attention.py`'s launches, which the attention
+    layer takes on a TPU at shapes its `can_run` admits: `bd_core_fwd` in
+    the forward phase and `bd_core_bwd` in the backward, both under the
+    leaf `bd_core` (their own names are no leaves), so the readers of the
+    leaf read them whatever they are called."""
+    from se3_transformer_tpu.ops import block_diffusion
+    from se3_transformer_tpu.ops.grouped_attention import (
+        GroupedQueryAttention,
+    )
+    monkeypatch.setattr(block_diffusion, 'is_tpu_backend', lambda: True)
+    attn = GroupedQueryAttention(dim=32, heads=2, kv_heads=1, head_dim=128,
+                                 block=128, qk_norm=True, rope_theta=1e6)
+    x = jnp.ones((1, 512, 32))
+    params = jax.eval_shape(attn.init, jax.random.PRNGKey(0), x)['params']
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p: attn.apply(
+        {'params': p}, x, jnp.arange(512) % 256, 4).sum()))(params)
+    filed = {(name, profiling.scope_leaf(path), profiling.scope_phase(path))
+             for name, path in _launch_paths(jaxpr.jaxpr)}
+    assert filed == {('bd_core_fwd', 'bd_core', 'forward'),
+                     ('bd_core_bwd', 'bd_core', 'backward')}
+    assert not {'bd_core_fwd', 'bd_core_bwd'} & set(MODEL_SCOPES)
+
+
 def test_the_same_module_trained_next_token_keeps_the_causal_leaf():
     def batch_of(lm_loss, module, tokens):
         return lm_loss.make_lm_loss(module, chunk=8), dict(tokens=tokens)

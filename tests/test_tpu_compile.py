@@ -928,56 +928,58 @@ def test_lfm2_decoder_step_compiles_and_fits(v5e, monkeypatch, capsys):
 # ------------------------------------------------------------------ #
 # the block-diffusion core (ops/block_diffusion.py)
 # ------------------------------------------------------------------ #
-def _splash_launches(text):
-    """(role, op_name) of every splash launch in a compiled program. A
-    launch's line breaks inside its `kernel_metadata`, so the path is the
-    first `op_name` after the instruction's name."""
+def _core_launches(text):
+    """(role, op_name) of every launch of the block-diffusion core in a
+    compiled program. A launch's line breaks inside its `kernel_metadata`,
+    so the path is the first `op_name` after the instruction's name."""
     return re.findall(
-        r'%(splash_mha_(?:fwd|dq|dkv))\w*?[.\d]* = \(.*?'
-        r'metadata=\{op_name="([^"]*)"', text, flags=re.S)
+        r'%(bd_core_(?:fwd|bwd))[.\d]* = .*?metadata=\{op_name="([^"]*)"',
+        text, flags=re.S)
 
 
 def test_the_block_diffusion_core_compiles_and_visits_288_tiles_a_head(v5e):
-    """The library's splash kernels under the two streams' mask at the
+    """The repo's two kernels (`kernels/pallas_block_attention.py`) at the
     block-diffusion cell's size (32 query and 4 key-value heads of 128,
-    2 x 8,192 positions, blocks of 4 tokens, tiles of 512): forward and both
-    backward launches lower, and the forward's own tile table, read here at
-    compile time, holds 288 of a head's 1,024 tiles: a noised tile meets
-    itself and the clean prefix's i + 1 tiles, a clean tile i + 1
-    (sum of (i + 2) + (i + 1) over 16), where a causal core over 16,384
-    positions would visit 528."""
+    2 x 8,192 positions, blocks of 4 tokens, tiles of 512): forward and
+    backward lower for the chip, one launch each, and the table their grid
+    is taken from holds 288 of a head's 1,024 tiles, none idle: a noised
+    tile meets itself and the clean prefix's i + 1 tiles, a clean tile
+    i + 1 (sum of (i + 2) + (i + 1) over 16), where a causal core over
+    16,384 positions would visit 528; 48 of them evaluate the rule."""
+    from se3_transformer_tpu.kernels import pallas_block_attention as kernels
     from se3_transformer_tpu.ops import block_diffusion as bd
 
-    kernel = bd.splash_kernel(32, 8192, 4, 512)
-    assert bd.visited_tiles(kernel) == 288 \
+    assert kernels.can_run(8192, 4, 512, 32, 4, 128)
+    assert bd.visited_tiles(8192, 4, 512) == 288 \
         == sum((i + 2) + (i + 1) for i in range(16))
-    table = kernel.fwd_mask_info.block_mask
-    assert table.shape == (1, 32, 17)      # one table for every head; the
-    #                                        grid's width is the fullest row
+    assert bd.boundary_tiles(8192, 4, 512) == 48
     assert 32 * 33 // 2 == 528 and 32 * 32 == 1024
 
     def loss(q, k, v):
-        return bd.block_diffusion_attention_splash(
-            q, k, v, 128 ** -0.5, 4, 512).sum()
+        return kernels.block_attention(q, k, v, 128 ** -0.5, 4, 512).sum()
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         jax.ShapeDtypeStruct((1, 32, 16384, 128), f32, sharding=v5e),
         *[jax.ShapeDtypeStruct((1, 4, 16384, 128), f32, sharding=v5e)] * 2
     ).compile()
-    roles = [role for role, _ in _splash_launches(compiled.as_text())]
-    assert sorted(roles) == ['splash_mha_dkv', 'splash_mha_dq',
-                             'splash_mha_fwd'], roles
+    text = compiled.as_text()
+    roles = [role for role, _ in _core_launches(text)]
+    assert sorted(roles) == ['bd_core_bwd', 'bd_core_fwd'], roles
+    # the grid is the table: sequences x key-value heads x 288 entries
+    assert text.count('s32[7,288]') >= 2 and 'splash' not in text
+    # the log-sum-exp leaves as one float32 a row
+    assert 'f32[1,32,1,16384]' in text and 'f32[1,32,16384,128]{' in text
 
 
 @pytest.mark.slow
 def test_sdar_decoder_step_compiles_and_fits(v5e, monkeypatch, capsys):
     """The benchmark's block-diffusion cell: the published widths of its
     configuration file on the one step factory at one sequence of 8,192
-    tokens read twice, compiled for the chip (a minute): one forward launch
-    of the core a layer and none in a block's replay (the blocks save its
-    output and log-sum-exp), every launch under `bd_core`; the grouped
-    products are in it; state plus temporaries fit; its memory is
-    printed."""
+    tokens read twice, compiled for the chip (a minute): one forward and
+    one backward launch of the core a layer, the repo's own, and none in a
+    block's replay (the blocks save its output and log-sum-exp), every
+    launch under `bd_core`, no `splash` anywhere; the grouped products are
+    in it; state plus temporaries fit; its memory is printed."""
     import optax
     from se3_transformer_tpu.ops import (
         block_diffusion, expert_layer, latent_attention,
@@ -1010,15 +1012,14 @@ def test_sdar_decoder_step_compiles_and_fits(v5e, monkeypatch, capsys):
         on_chip(batch), on_chip(jax.random.PRNGKey(1))).compile()
     text = compiled.as_text()
     assert 'ragged-dot' in text and 'flash_attention' not in text
-    launches = _splash_launches(text)
+    assert 'splash' not in text
     by_role = {}
-    for role, path in launches:
+    for role, path in _core_launches(text):
         assert '/attn/bd_core/' in path, path
         assert 'rematted_computation' not in path, path
-        assert ('transpose(' in path) == (role != 'splash_mha_fwd'), path
+        assert ('transpose(' in path) == (role == 'bd_core_bwd'), path
         by_role[role] = by_role.get(role, 0) + 1
-    assert by_role == {'splash_mha_fwd': 5, 'splash_mha_dkv': 5,
-                       'splash_mha_dq': 5}, by_role
+    assert by_role == {'bd_core_fwd': 5, 'bd_core_bwd': 5}, by_role
     _assert_product_front_ends_agree(compiled)
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes

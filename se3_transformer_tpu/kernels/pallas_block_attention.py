@@ -21,38 +21,48 @@ head has 288 tiles of 1,024 in the table, 48 of them on a boundary.
 
 The table goes to both launches by scalar prefetch: the grid is (sequence,
 key-value head, table entry), exactly the visited tiles, in the order of
-their query tiles, and the index maps read the entry's tiles. A program
-loops over the query heads that share its key-value head, so k and v are
-loaded once for all of them. Two launches, named by role (`name=` on
-`pl.pallas_call`):
+their query tiles, and the index maps read the entry's tiles. Every operand
+is in the layout the projections write, token-major: q, o, do and dq
+[B, 2L, H D] with a block (1, tile, g D), the g query heads that share a
+key-value head side by side, a head a run of D lanes of it; k, v, dk and dv
+[B, 2L, KV D] with a block (1, tile, D); the statistics [B, H, 1, 2L]. A
+program loops over its group's heads, so k and v are loaded once for all of
+them, and nothing is laid out again on either side of a launch. Two
+launches, named by role (`name=` on `pl.pallas_call`):
 
     bd_core_fwd  s = q k^T [query, key]; the running maximum, sum and
                  output of a query tile carried in VMEM scratch over its
                  entries; at its last entry o = acc / l (rounded to the
                  operands' width) and the log-sum-exp, one float32 a row,
                  turned once into a lane row [1, tile]
-    bd_core_bwd  every score and dP once, five products a tile, scores
-                 transposed [key, query] so that the log-sum-exp and
-                 di = sum(o do) are lane rows as stored: p = exp(s - lse),
-                 dv += p do, dp = v do^T, ds = p (dp - di), dk += ds q,
-                 dq += ds^T k. dq of a query tile is summed in float32 in
-                 its output block, resident over the tile's entries; dk and
-                 dv of a whole key-value head [2L, D] float32 are resident
-                 in VMEM over all of its entries and query heads, so the
-                 key-value heads stay unrepeated and nothing is summed in
-                 HBM.
+    bd_core_bwd  at a query tile's first entry di = sum(o do), a lane row a
+                 head kept in scratch; then every score and dP once, five
+                 products a tile, scores transposed [key, query] so that
+                 the log-sum-exp and di are lane rows as stored:
+                 p = exp(s - lse), dv += p do, dp = v do^T,
+                 ds = p (dp - di), dk += ds q, dq += ds^T k. dq of a query
+                 tile is summed in float32 in its output block, resident
+                 over the tile's entries; dk and dv of a whole key-value
+                 head [2L, D] float32 are resident in VMEM over all of its
+                 entries and query heads, so the key-value heads stay
+                 unrepeated and nothing is summed in HBM.
 
-`block_attention` ties them in a `jax.custom_vjp`; the forward's output and
+`block_attention` ties them and the pass on either side of them
+(`kernels/pallas_qk_pass.py`: norm, rotation, scale and rounding of the
+projections' float32 outputs in one launch, `qk_pass_fwd`, and their
+transposes in another, `qk_pass_bwd`) in one `jax.custom_vjp`: under one
+rule dq, dk and dv go from the core to the pass in float32, and the
+projections get their cotangents rounded once. The forward's output and
 log-sum-exp carry `ATTN_CORE_OUT` / `ATTN_CORE_STATS`, so a block rematted
-under `SAVE_ATTN_CORE` replays no forward launch.
+under `SAVE_ATTN_CORE` replays the pass and no forward launch of the core.
 
 Arithmetic: q (carrying the scale), k, v, p, do and ds rounded to bfloat16
 once, float32 accumulation in every product (float32 operands under
-`interpret`, where the CPU has no such product); maxima, exponentials, sums,
-log-sum-exp, the running output and every accumulator in float32. A masked
-score is a large finite negative, so a row whose tile hides every key
-(the first block's rows of a noised -> clean tile) stays finite and its
-weights vanish against the tile that holds its own block.
+`interpret`, where the CPU has no such product); norm, rotation, maxima,
+exponentials, sums, log-sum-exp, the running output and every accumulator in
+float32. A masked score is a large finite negative, so a row whose tile
+hides every key (the first block's rows of a noised -> clean tile) stays
+finite and its weights vanish against the tile that holds its own block.
 """
 from __future__ import annotations
 
@@ -65,12 +75,14 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..observability import named_scope
 from ..ops.latent_attention import ATTN_CORE_OUT, ATTN_CORE_STATS
+from . import pallas_qk_pass as qk_pass
 
 LANES = 128
 # the backward holds a key-value head's dk and dv whole (2 x 8 MiB at 16,384
 # positions of 128, twice for the pipeline's buffers) beside a query tile's
-# q, do and dq for every head of the group and a tile's scores
+# q, o, do and dq for every head of the group and a tile's scores
 VMEM_LIMIT = 96 * 2 ** 20
 PARAMS = pltpu.CompilerParams(
     dimension_semantics=('parallel', 'parallel', 'arbitrary'),
@@ -189,7 +201,8 @@ def _by_kind(tab, n, block_length, tile, keys_in_lanes, entry):
 def _fwd_kernel(tab, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
                 acc_scr, *, block_length, od):
     n = pl.program_id(2)
-    g, tile, d = q_ref.shape[1:]
+    tile, d = k_ref.shape[1:]
+    g = q_ref.shape[2] // d
     last = tab[LAST, n] == 1
 
     @pl.when(tab[FIRST, n] == 1)
@@ -199,10 +212,11 @@ def _fwd_kernel(tab, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     def entry(hidden):
-        k, v = k_ref[0, 0], v_ref[0, 0]
+        k, v = k_ref[0], v_ref[0]
 
         def head(h, _):         # a loop, so that a launch traces one head
-            s = _nt(q_ref[0, h], k)                       # [query, key]
+            lanes = qk_pass.head_lanes(h, d)
+            s = _nt(q_ref[0, :, lanes], k)                # [query, key]
             if hidden is not None:
                 s = s + hidden
             m_prev, l_prev = m_scr[h], l_scr[h]           # [tile, LANES]
@@ -216,7 +230,8 @@ def _fwd_kernel(tab, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
 
             @pl.when(last)
             def _():
-                o_ref[0, h] = (acc / _lanes(l_next, d)).astype(o_ref.dtype)
+                o_ref[0, :, lanes] = (acc / _lanes(l_next, d)
+                                      ).astype(o_ref.dtype)
                 # a row's log-sum-exp, alike in every lane -> rows in lanes
                 lse_ref[0, h] = (m_next + jnp.log(l_next)).T[:1]
 
@@ -226,29 +241,30 @@ def _fwd_kernel(tab, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
 
 
 def _specs(g, tile, d):
-    """The block of a group's query heads at a column's query tile, of a
-    key-value head at its key tile, and of the group's rows of statistics
-    [B, H, 1, 2L]."""
-    heads = pl.BlockSpec((1, g, tile, d),
-                         lambda z, c, n, tab: (z, c, tab[QUERY, n], 0))
-    keys = pl.BlockSpec((1, 1, tile, d),
-                        lambda z, c, n, tab: (z, c, tab[KEY, n], 0))
+    """The block of a group's query heads at a column's query tile
+    [B, 2L, H D], of a key-value head at its key tile [B, 2L, KV D], and of
+    the group's rows of statistics [B, H, 1, 2L]."""
+    heads = pl.BlockSpec((1, tile, g * d),
+                         lambda z, c, n, tab: (z, tab[QUERY, n], c))
+    keys = pl.BlockSpec((1, tile, d),
+                        lambda z, c, n, tab: (z, tab[KEY, n], c))
     stats = pl.BlockSpec((1, g, 1, tile),
                          lambda z, c, n, tab: (z, c, 0, tab[QUERY, n]))
     return heads, keys, stats
 
 
-_STATIC = ('block_length', 'tile', 'interpret')
+_STATIC = ('head_dim', 'block_length', 'tile', 'interpret')
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
-def _fwd(q, k, v, block_length, tile, interpret):
-    """q [B, H, 2L, D], k, v [B, KV, 2L, D] in the operands' width -> o like
+def _fwd(q, k, v, head_dim, block_length, tile, interpret):
+    """q [B, 2L, H D], k, v [B, 2L, KV D] in the operands' width -> o like
     q and the log-sum-exp [B, H, 1, 2L] float32. A jit of its own, so that a
     step traces and lowers the launch once, not once a layer."""
-    b, h, t, d = q.shape
-    kv = k.shape[1]
-    g, f32 = h // kv, jnp.float32
+    b, t, hd = q.shape
+    d, kv = head_dim, k.shape[2] // head_dim
+    h, f32 = hd // d, jnp.float32
+    g = h // kv
     table = tile_table(t // 2, block_length, tile)
     heads, keys, stats = _specs(g, tile, d)
     return pl.pallas_call(
@@ -269,10 +285,11 @@ def _fwd(q, k, v, block_length, tile, interpret):
 # --------------------------------------------------------------------- #
 # backward
 # --------------------------------------------------------------------- #
-def _bwd_kernel(tab, q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
-                dq_ref, dk_ref, dv_ref, *, block_length, od):
+def _bwd_kernel(tab, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+                dq_ref, dk_ref, dv_ref, di_scr, *, block_length, od):
     n = pl.program_id(2)
-    g, tile, d = q_ref.shape[1:]
+    tile, d = k_ref.shape[1:]
+    g = q_ref.shape[2] // d
 
     @pl.when(n == 0)
     def _():
@@ -283,84 +300,109 @@ def _bwd_kernel(tab, q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
     def _():
         dq_ref[...] = jnp.zeros_like(dq_ref)
 
+        def row_sums(h, _):     # di = sum(o do), once a query tile
+            lanes = qk_pass.head_lanes(h, d)
+            di = jnp.sum(o_ref[0, :, lanes].astype(jnp.float32)
+                         * do_ref[0, :, lanes].astype(jnp.float32),
+                         axis=1, keepdims=True)
+            # a row's sum, alike in every lane -> rows in lanes
+            di_scr[h] = jnp.broadcast_to(di, (tile, LANES)).T[:1]
+
+        jax.lax.fori_loop(0, g, row_sums, None)
+
     def entry(hidden):
-        k, v = k_ref[0, 0], v_ref[0, 0]
+        k, v = k_ref[0], v_ref[0]
 
         def head(h, sums):
             dk, dv = sums
-            q, do = q_ref[0, h], do_ref[0, h]
+            lanes = qk_pass.head_lanes(h, d)
+            q, do = q_ref[0, :, lanes], do_ref[0, :, lanes]
             s = _nt(k, q)                                 # [key, query]
             if hidden is not None:
                 s = s + hidden
             p = jnp.exp(s - lse_ref[0, h])
-            ds = (p * (_nt(v, do) - di_ref[0, h])).astype(od)
-            dq_ref[0, h] += _tn(ds, k)
+            ds = (p * (_nt(v, do) - di_scr[h])).astype(od)
+            dq_ref[0, :, lanes] += _tn(ds, k)
             return dk + _nn(ds, q), dv + _nn(p.astype(od), do)
 
         zero = jnp.zeros((tile, d), jnp.float32)
         dk, dv = jax.lax.fori_loop(0, g, head, (zero, zero))
         rows = pl.ds(pl.multiple_of(tab[KEY, n] * tile, tile), tile)
-        dk_ref[0, 0, rows, :] += dk
-        dv_ref[0, 0, rows, :] += dv
+        dk_ref[0, rows, :] += dk
+        dv_ref[0, rows, :] += dv
 
     _by_kind(tab, n, block_length, tile, False, entry)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
-def _bwd(q, k, v, do, lse, di, block_length, tile, interpret):
-    """-> dq like q's shape, dk and dv like k's, float32."""
-    b, h, t, d = q.shape
-    kv = k.shape[1]
-    g, f32 = h // kv, jnp.float32
+def _bwd(q, k, v, o, do, lse, head_dim, block_length, tile, interpret):
+    """o and its cotangent like q -> dq like q's shape, dk and dv like k's,
+    float32."""
+    b, t, hd = q.shape
+    d, kv = head_dim, k.shape[2] // head_dim
+    g, f32 = hd // d // kv, jnp.float32
     table = tile_table(t // 2, block_length, tile)
     heads, keys, stats = _specs(g, tile, d)
-    whole = pl.BlockSpec((1, 1, t, d), lambda z, c, n, tab: (z, c, 0, 0))
+    whole = pl.BlockSpec((1, t, d), lambda z, c, n, tab: (z, 0, c))
     return pl.pallas_call(
         functools.partial(_bwd_kernel, block_length=block_length,
                           od=q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(b, kv, table.shape[1]),
-            in_specs=[heads, keys, keys, heads, stats, stats],
-            out_specs=[heads, whole, whole]),
+            in_specs=[heads, keys, keys, heads, heads, stats],
+            out_specs=[heads, whole, whole],
+            scratch_shapes=[pltpu.VMEM((g, 1, tile), f32)]),
         out_shape=[jax.ShapeDtypeStruct(q.shape, f32),
                    jax.ShapeDtypeStruct(k.shape, f32),
                    jax.ShapeDtypeStruct(k.shape, f32)],
         compiler_params=PARAMS, interpret=interpret, name='bd_core_bwd',
-    )(jnp.asarray(table), q, k, v, do, lse, di)
+    )(jnp.asarray(table), q, k, v, o, do, lse)
 
 
 # --------------------------------------------------------------------- #
-# the core over a sequence's two streams
+# from the projections' outputs to the core's, over a sequence's two streams
 # --------------------------------------------------------------------- #
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def block_attention(q, k, v, scale, block_length, tile, interpret=False):
-    """q [B, H, 2L, D], k, v [B, KV, 2L, D] (the noised stream, then the
-    clean one) -> [B, H, 2L, D] float32; shapes as `can_run` asks."""
-    return _block_attention_fwd(q, k, v, scale, block_length, tile,
-                                interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+def block_attention(q, k, v, norms, rotary, head_dim, scale, eps,
+                    block_length, tile, interpret=False):
+    """q [B, 2L, H D], k, v [B, 2L, KV D] float32 as the projections write
+    them (the noised stream, then the clean one); `norms` the scales [D] of
+    the queries' and the keys' RMSNorm, or None; `rotary` the rotation's
+    tables (`pallas_qk_pass.rotary_tables`), or None -> o [B, 2L, H D] in
+    the operands' width, as the output projection reads it; shapes as
+    `can_run` asks. One rule for the pass and the core, so that dq, dk and
+    dv go from `bd_core_bwd` to `qk_pass_bwd` in float32."""
+    return _block_attention_fwd(q, k, v, norms, rotary, head_dim, scale, eps,
+                                block_length, tile, interpret)[0]
 
 
-def _block_attention_fwd(q, k, v, scale, block_length, tile, interpret):
+def _block_attention_fwd(q, k, v, norms, rotary, head_dim, scale, eps,
+                         block_length, tile, interpret):
     od = jnp.float32 if interpret else jnp.bfloat16
-    q, k, v = (q * scale).astype(od), k.astype(od), v.astype(od)
-    o, lse = _fwd(q, k, v, block_length=block_length, tile=tile,
-                  interpret=interpret)
-    o = checkpoint_name(o, ATTN_CORE_OUT)
-    lse = checkpoint_name(lse, ATTN_CORE_STATS)
-    return o.astype(jnp.float32), (q, k, v, o, lse)
+    with named_scope('mha_qkv'):
+        qr, kr, vr = qk_pass.forward(q, k, v, norms, rotary, head_dim, scale,
+                                     eps, od, interpret)
+    with named_scope('bd_core'):
+        o, lse = _fwd(qr, kr, vr, head_dim, block_length, tile, interpret)
+        o = checkpoint_name(o, ATTN_CORE_OUT)
+        lse = checkpoint_name(lse, ATTN_CORE_STATS)
+    return o, (q, k, norms, rotary, qr, kr, vr, o, lse)
 
 
-def _block_attention_bwd(scale, block_length, tile, interpret, residuals,
-                         do):
-    q, k, v, o, lse = residuals
-    # do rounded once, and di from the rounded one: the products and the
-    # row sums then see one do, and XLA writes the output projection's dx
-    # in the operands' width with di summed in the same pass
-    do, f32 = do.astype(q.dtype), jnp.float32
-    di = jnp.sum(o.astype(f32) * do.astype(f32), axis=-1)[:, :, None]
-    dq, dk, dv = _bwd(q, k, v, do, lse, di, block_length=block_length,
-                      tile=tile, interpret=interpret)
-    return dq * scale, dk, dv
+def _block_attention_bwd(head_dim, scale, eps, block_length, tile, interpret,
+                         residuals, do):
+    q, k, norms, rotary, qr, kr, vr, o, lse = residuals
+    f32 = jnp.float32
+    with named_scope('bd_core'):
+        # do arrives in o's width, rounded once, as the output projection's
+        # dx writes it: the products and the row sums see one do
+        dq, dk, dv = _bwd(qr, kr, vr, o, do, lse, head_dim, block_length,
+                          tile, interpret)
+    with named_scope('mha_qkv'):
+        dq, dk, dv, dw = qk_pass.backward(dq, dk, dv, q, k, norms, rotary,
+                                          head_dim, scale, eps, qr.dtype,
+                                          interpret)
+    return dq.astype(f32), dk.astype(f32), dv.astype(f32), dw, None
 
 
 block_attention.defvjp(_block_attention_fwd, _block_attention_bwd)

@@ -693,7 +693,9 @@ def reduce_events(events: dict, op_names: Optional[Dict[str, str]] = None,
     on (program, instruction): product_s, product_flops, product_bytes
     {leaf: {phase: value}} over the events whose instruction holds at least
     one product XLA compiled (operations and bytes once per event: a loop's
-    body counts each time it ran); launch_s {leaf: s} over custom calls;
+    body counts each time it ran); launch_s {leaf: s} over custom calls
+    and launch_roles {leaf: {launch's family name: {phase: [events, s]}}},
+    the same seconds by what each launch is called;
     glue_s {leaf: {category: s}} over everything else (an event of a
     program the table lacks under the category `unknown`), so that the
     three sum to device_s and a leaf's remainder reads as `loop fusion`,
@@ -712,6 +714,7 @@ def reduce_events(events: dict, op_names: Optional[Dict[str, str]] = None,
     product_bytes: Dict[str, Dict[str, float]] = {}
     glue_s: Dict[str, Dict[str, float]] = {}
     launch_s: Dict[str, float] = {}
+    launch_roles: Dict[str, Dict[str, Dict[str, list]]] = {}
     device_s = labelled_s = 0.0
     n_events = from_hlo = 0
     tracks = {t: [r for r in rows if module is None or r[4] is None
@@ -746,6 +749,9 @@ def reduce_events(events: dict, op_names: Optional[Dict[str, str]] = None,
                 filed = leaf or UNLABELLED
                 if 'flops' in info and info['flops'] is None:
                     _add(launch_s, filed, secs)
+                    row = launch_roles.setdefault(filed, {}).setdefault(
+                        family(name), {}).setdefault(phase, [0, 0.0])
+                    row[0], row[1] = row[0] + 1, row[1] + secs
                 elif info.get('products'):
                     _add2(product_s, filed, phase, secs)
                     _add2(product_flops, filed, phase, info['flops'])
@@ -772,7 +778,8 @@ def reduce_events(events: dict, op_names: Optional[Dict[str, str]] = None,
         leaf_s=leaf_s, phase_s=phase_s, leaf_phase_s=leaf_phase_s,
         kernel_s=kernel_s, kernel_pair_s=kernel_pair_s,
         product_s=product_s, product_flops=product_flops,
-        product_bytes=product_bytes, launch_s=launch_s, glue_s=glue_s,
+        product_bytes=product_bytes, launch_s=launch_s,
+        launch_roles=launch_roles, glue_s=glue_s,
         unlabelled_top=[[k, v] for k, v in sorted(
             unlabelled.items(), key=lambda kv: -kv[1])[:top]],
         events=n_events, tracks=sorted(events['device']),
@@ -880,6 +887,23 @@ def format_products(red: dict, peak_flops: float, peak_bytes: float,
     return '\n'.join(lines)
 
 
+def format_launches(red: dict, steps: int = 1) -> str:
+    """A row a launch by its name (`name=` on a `pl.pallas_call`, or what
+    the compiler calls its own) under the leaf that files it: events and ms
+    a step, forward | replay | backward. How often a path engages: a layer
+    that fell back to XLA's own operations has no row."""
+    order = ('forward', 'replay', 'backward')
+    lines = [f'{"leaf / launch":<36}' + ''.join(
+        f'| {phase + ": events ms":<24}' for phase in order)]
+    for leaf, roles in sorted(red['launch_roles'].items()):
+        for role, phases in sorted(roles.items()):
+            cells = (phases.get(phase, (0, 0.0)) for phase in order)
+            lines.append(f'{leaf + " / " + role:<36}' + ''.join(
+                f'| {n / steps:8.2f} {1e3 * secs / steps:10.2f}     '
+                if n else '| ' + ' ' * 24 for n, secs in cells))
+    return '\n'.join(lines)
+
+
 def profile_payload(trace_dir: str, *, label: str,
                     hlo_text: Optional[str] = None,
                     scopes: Sequence[str] = MODEL_SCOPES,
@@ -943,6 +967,7 @@ def main(argv=None):
     peaks = device_peaks(args.device_kind)
     print(format_products(red, peaks['bf16_flops'],
                           peaks['hbm_bytes_per_sec'], args.steps))
+    print(format_launches(red, args.steps))
     parts = [sum(sum(v.values()) for v in red[k].values())
              for k in ('product_s', 'glue_s')]
     parts.append(sum(red['launch_s'].values()))

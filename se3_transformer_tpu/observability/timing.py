@@ -110,7 +110,11 @@ MODEL_SCOPES = (
     'ssm_out',            # ops/state_space.py: output projection
     'mha_qkv',            # ops/grouped_attention.py: q, k, v projections,
     #                       q/k norms and rotation where the model has
-    #                       them, the key-value heads repeated
+    #                       them, the key-value heads repeated; before the
+    #                       block-diffusion core's kernels norm, rotation,
+    #                       scale and rounding are the launches
+    #                       `qk_pass_fwd` and `qk_pass_bwd` of
+    #                       kernels/pallas_qk_pass.py
     'mha_core',           # ops/grouped_attention.py: scores, softmax,
     #                       weighted sum (the streaming kernel on a TPU)
     'mha_out',            # ops/grouped_attention.py: output projection
@@ -124,9 +128,8 @@ MODEL_SCOPES = (
     'bd_core',            # ops/grouped_attention.py: the block-diffusion
     #                       core (ops/block_diffusion.py: on a TPU the two
     #                       launches of kernels/pallas_block_attention.py,
-    #                       `bd_core_fwd` and `bd_core_bwd`) and the casts
-    #                       and di = sum(o do) around them, apart from
-    #                       `mha_core`
+    #                       `bd_core_fwd` and `bd_core_bwd`, di = sum(o do)
+    #                       inside the second), apart from `mha_core`
     'bd_streams',         # models/hybrid_decoder.py, training/lm_loss.py:
     #                       building the two streams and their positions,
     #                       cutting the noised one out, the weights

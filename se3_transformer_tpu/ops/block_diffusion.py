@@ -15,14 +15,18 @@ what a causal core over 2 L positions computes, and no subset of it. Every
 row sees a key (a noised token its own block, a clean one itself).
 
 Two forms, as the causal core has (`ops/latent_attention.py`), both over
-grouped heads (q [B, H, 2L, D], k and v [B, KV, 2L, D], unrepeated):
+grouped heads, unrepeated; `kernels_run` says which a layer takes:
 
-  * off the TPU, blocks of queries against static key extents (a noised
-    block meets its own blocks of the noised stream and the clean prefix, a
-    clean block the clean prefix through its own blocks; the clean stream's
-    upper half and the clean -> noised quarter are never computed), the mask
-    made from the stream ids, each block recomputed in the backward pass;
+  * off the TPU, over q [B, H, 2L, D], k and v [B, KV, 2L, D], blocks of
+    queries against static key extents (a noised block meets its own blocks
+    of the noised stream and the clean prefix, a clean block the clean
+    prefix through its own blocks; the clean stream's upper half and the
+    clean -> noised quarter are never computed), the mask made from the
+    stream ids, each block recomputed in the backward pass;
   * on the TPU the repo's own kernels (`kernels/pallas_block_attention.py`)
+    over q [B, 2L, H D], k and v [B, 2L, KV D], the projections' own layout
+    (norm, rotation, scale and rounding are one launch before them,
+    `kernels/pallas_qk_pass.py`, under the same differentiation rule), and
     over a static table of the tiles that hold a visible pair: the grid is
     those tiles and no other (`visited_tiles`), the rule runs on the tiles a
     boundary crosses alone (`boundary_tiles`), and the forward's output and
@@ -119,14 +123,10 @@ def boundary_tiles(length: int, block_length: int, tile: int) -> int:
     return int(np.count_nonzero(kinds != kernels.FULL))
 
 
-def block_diffusion_attention(q, k, v, scale: float, block_length: int,
-                              block: int = 512):
-    """The kernels where they can run (on a TPU, at the shapes of
-    `kernels.can_run`), blocks of queries elsewhere."""
-    length = q.shape[2] // 2
-    tile = min(block, length)
-    if is_tpu_backend() and kernels.can_run(
-            length, block_length, tile, q.shape[1], k.shape[1], q.shape[3]):
-        return kernels.block_attention(q, k, v, scale, block_length, tile)
-    return block_diffusion_attention_blocked(q, k, v, scale, block_length,
-                                             block)
+def kernels_run(length: int, block_length: int, block: int, heads: int,
+                kv_heads: int, head_dim: int) -> bool:
+    """Whether a layer of these shapes takes the kernels (on a TPU, at the
+    shapes of `kernels.can_run`, tiles of `block` or a stream's length) or
+    the blocked core, from the platform and the shapes alone."""
+    return is_tpu_backend() and kernels.can_run(
+        length, block_length, min(block, length), heads, kv_heads, head_dim)

@@ -13,16 +13,24 @@ optional:
     softmax(q k^T / sqrt(head_dim)), causal, in float32;  out = o Wo
 
 The core is the latent attention's (`ops/latent_attention.py`): JAX's
-streaming Pallas kernel on a TPU, blocks of queries elsewhere. Both take as
-many key-value heads as query heads, so the key-value heads are repeated
-here and autodiff sums their gradients over each group.
+streaming Pallas kernel on a TPU, blocks of queries elsewhere. Both take
+[B, H, T, D] and as many key-value heads as query heads, so the heads are
+laid out so and the key-value heads repeated here, and autodiff sums their
+gradients over each group.
 
 Called with a `block_length` (static) the input is the two streams of a
 decoder trained by diffusion over blocks, [noised ; clean] along T, and
 `positions` [T] the rotation's (the two copies of a token share one): the
 core is then `ops/block_diffusion.py`'s, under its own leaf `bd_core`, and
-takes the key-value heads as they are. Projections, norms and rotation are
-the same arithmetic either way.
+takes the key-value heads as they are. Where its kernels run
+(`block_diffusion.kernels_run`: on a TPU, heads of whole lane rows, whole
+tiles) nothing is laid out again on either side of them: the projections'
+outputs [B, T, H D] and [B, T, KV D] go through one launch that norms,
+rotates, scales and rounds them (`kernels/pallas_qk_pass.py`: `qk_pass_fwd`
+under `mha_qkv`, and `qk_pass_bwd` on the way back), the core's launches
+read and write that layout, and the output projection reads o [B, T, H D]
+as the core wrote it. Elsewhere the composition below and the blocked core.
+Projections, norms and rotation are the same arithmetic either way.
 """
 from __future__ import annotations
 
@@ -32,10 +40,21 @@ from typing import Optional
 import flax.linen as nn
 import jax.numpy as jnp
 
+from ..kernels import pallas_block_attention as kernels
+from ..kernels.pallas_qk_pass import rotary_tables
 from ..observability import named_scope
-from .block_diffusion import block_diffusion_attention
+from .block_diffusion import block_diffusion_attention_blocked, kernels_run
 from .latent_attention import RMSNorm, causal_attention
 from .rotary import apply_rotary_halves, rotary_angles
+
+
+class _Scale(nn.Module):
+    """An `RMSNorm`'s parameter (`<name>/scale`) without its arithmetic."""
+    width: int
+
+    @nn.compact
+    def __call__(self):
+        return self.param('scale', nn.initializers.ones, (self.width,))
 
 
 class GroupedQueryAttention(nn.Module):
@@ -55,29 +74,49 @@ class GroupedQueryAttention(nn.Module):
         h, kv, dh = self.heads, self.kv_heads, self.head_dim
         assert h % kv == 0, (h, kv)
         dense = partial(nn.Dense, use_bias=False)
+        one_pass = block_length and kernels_run(t // 2, block_length,
+                                                self.block, h, kv, dh)
+
+        def heads_of(a, n):     # the kernels take the products' own layout
+            return a if one_pass else a.reshape(b, t, n, dh)
+
+        def angles():
+            return rotary_angles(
+                jnp.arange(t) if positions is None else positions, dh,
+                self.rope_theta)
+
         with named_scope('mha_qkv'):
-            q = dense(h * dh, name='q')(x).reshape(b, t, h, dh)
-            k, v = (dense(kv * dh, name=name)(x).reshape(b, t, kv, dh)
+            q = heads_of(dense(h * dh, name='q')(x), h)
+            k, v = (heads_of(dense(kv * dh, name=name)(x), kv)
                     for name in ('k', 'v'))
-            if self.qk_norm:
-                q = RMSNorm(self.eps, name='q_norm')(q)
-                k = RMSNorm(self.eps, name='k_norm')(k)
-            if self.rope_theta is not None:
-                angles = rotary_angles(
-                    jnp.arange(t) if positions is None else positions, dh,
-                    self.rope_theta)
-                q, k = (apply_rotary_halves(a, angles[None, :, None, :])
-                        for a in (q, k))
-            if not block_length:
-                k, v = (jnp.repeat(a, h // kv, axis=2) for a in (k, v))
-            q, k, v = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
-        if block_length:
+            if one_pass:
+                norms = tuple(_Scale(dh, name=name)() for name in
+                              ('q_norm', 'k_norm')) if self.qk_norm else None
+                rotary = None if self.rope_theta is None \
+                    else rotary_tables(angles())
+            else:
+                if self.qk_norm:
+                    q = RMSNorm(self.eps, name='q_norm')(q)
+                    k = RMSNorm(self.eps, name='k_norm')(k)
+                if self.rope_theta is not None:
+                    ang = angles()
+                    q, k = (apply_rotary_halves(a, ang[None, :, None, :])
+                            for a in (q, k))
+                if not block_length:
+                    k, v = (jnp.repeat(a, h // kv, axis=2) for a in (k, v))
+                q, k, v = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
+        if one_pass:    # its scopes are the rule's own: `mha_qkv`, `bd_core`
+            o = kernels.block_attention(
+                q, k, v, norms, rotary, dh, dh ** -0.5, self.eps,
+                block_length, min(self.block, t // 2))
+        elif block_length:
             with named_scope('bd_core'):
-                o = block_diffusion_attention(q, k, v, dh ** -0.5,
-                                              block_length, self.block)
+                o = block_diffusion_attention_blocked(
+                    q, k, v, dh ** -0.5, block_length, self.block)
         else:
             with named_scope('mha_core'):
                 o = causal_attention(q, k, v, dh ** -0.5, self.block)
         with named_scope('mha_out'):
-            o = o.transpose(0, 2, 1, 3).reshape(b, t, h * dh)
+            if not one_pass:
+                o = o.transpose(0, 2, 1, 3).reshape(b, t, h * dh)
             return dense(self.dim, name='out')(o)

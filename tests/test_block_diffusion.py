@@ -175,52 +175,118 @@ def test_the_blocked_core_is_the_dense_masked_softmax(block):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
 
 
+def _attention(head_dim=128, **fields):
+    from se3_transformer_tpu.ops.grouped_attention import (
+        GroupedQueryAttention,
+    )
+    return GroupedQueryAttention(dim=32, heads=4, kv_heads=2,
+                                 head_dim=head_dim, **fields)
+
+
 def test_off_the_tpu_the_core_is_the_blocked_one(monkeypatch):
-    q = jax.random.normal(jax.random.PRNGKey(0), (1, 2, 2 * L, 8))
-    k = v = q[:, :1]
+    from se3_transformer_tpu.ops import grouped_attention
+    assert not bd.kernels_run(256, BK, 128, 4, 2, 128)
     calls = []
-    monkeypatch.setattr(bd, 'block_diffusion_attention_blocked',
+    monkeypatch.setattr(grouped_attention, 'block_diffusion_attention_blocked',
                         lambda *a: calls.append(a[4:]) or a[0])
-    bd.block_diffusion_attention(q, k, v, 1.0, BK, 8)
-    assert calls == [(BK, 8)]
+    attn = _attention(block=128, qk_norm=True, rope_theta=1e6)
+    x = jnp.ones((1, 512, 32))
+    params = attn.init(jax.random.PRNGKey(0), x)
+    attn.apply(params, x, jnp.arange(512) % 256, BK)
+    assert calls == [(BK, 128)]     # init is of the causal path
 
 
-def test_the_streaming_kernel_interpreted_is_the_blocked_core():
-    """The repo's two kernels (`kernels/pallas_block_attention.py`) in
-    interpret mode at tiles of 128 (two streams of 256 tokens, 4 query and 2
-    key-value heads of 128: a noised tile, the clean prefix's tiles, the
-    clean stream's lower triangle), output and the three gradients against
-    the blocked core at `highest`. Interpreted, the products' operands stay
-    float32, so the two agree far inside the bfloat16 tolerances."""
-    length, heads, kv, dh = 256, 4, 2, 128
-    keys = jax.random.split(jax.random.PRNGKey(5), 4)
-    q = jax.random.normal(keys[0], (1, heads, 2 * length, dh))
-    k, v = (jax.random.normal(key, (1, kv, 2 * length, dh))
+def _tokens(a):
+    """[B, n, T, D] -> [B, T, n D]."""
+    b, n, t, d = a.shape
+    return a.transpose(0, 2, 1, 3).reshape(b, t, n * d)
+
+
+def _composition(q, k, v, norms, angles, heads, kv_heads, eps, scale):
+    """What the layer does between its projections and its core where the
+    kernels do not run: `RMSNorm`'s arithmetic, `apply_rotary_halves`, the
+    heads laid out, then the scale as the core applies it; float32."""
+    from se3_transformer_tpu.ops.rotary import apply_rotary_halves
+    b, t, _ = q.shape
+    q, k, v = (a.reshape(b, t, n, -1) for a, n in
+               ((q, heads), (k, kv_heads), (v, kv_heads)))
+    if norms is not None:
+        q, k = (a * jax.lax.rsqrt(jnp.mean(a * a, axis=-1, keepdims=True)
+                                  + eps) * w for a, w in zip((q, k), norms))
+    if angles is not None:
+        q, k = (apply_rotary_halves(a, angles[None, :, None, :])
+                for a in (q, k))
+    q, k, v = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
+    return q * scale, k, v
+
+
+def _projected(length, heads, kv, dh, seed=5):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 6)
+    q = jax.random.normal(keys[0], (1, 2 * length, heads * dh))
+    k, v = (jax.random.normal(key, (1, 2 * length, kv * dh))
             for key in keys[1:3])
-    w = jax.random.normal(keys[3], q.shape)
+    norms = tuple(1 + 0.1 * jax.random.normal(key, (dh,))
+                  for key in keys[3:5])
+    w = jax.random.normal(keys[5], q.shape)
+    return q, k, v, norms, w
+
+
+def _rel(a, b):
+    return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+
+
+@pytest.mark.parametrize('norm', [True, False])
+@pytest.mark.parametrize('rotate', [True, False])
+def test_the_streaming_kernel_interpreted_is_the_blocked_core(norm, rotate):
+    """The rule that ships (`kernels.block_attention`: `qk_pass_fwd`, the
+    core's two launches and `qk_pass_bwd` under one differentiation rule,
+    every operand in the projections' own layout [B, 2L, H D]) in interpret
+    mode at tiles of 128 (two streams of 256 tokens, 4 query and 2 key-value
+    heads of 128: a noised tile, the clean prefix's tiles, the clean
+    stream's lower triangle), with and without norms and rotation, the two
+    copies of a token at one position: output and every gradient (the
+    projections' outputs and both norm scales) against today's composition
+    and the blocked core at `highest`. Interpreted, the products' operands
+    stay float32, so the two agree far inside the bfloat16 tolerances."""
+    from se3_transformer_tpu.kernels.pallas_qk_pass import rotary_tables
+    from se3_transformer_tpu.ops.rotary import rotary_angles
+    length, heads, kv, dh = 256, 4, 2, 128
+    q, k, v, norms, w = _projected(length, heads, kv, dh)
+    angles = rotary_angles(jnp.tile(jnp.arange(length), 2), dh, 1e6)
     assert kernels.can_run(length, BK, 128, heads, kv, dh)
     # 2 q tiles a stream: noised tile i meets itself and clean tiles 0..i,
     # clean tile i clean tiles 0..i: (2 + 3) + (1 + 2) of 16
     assert bd.visited_tiles(length, BK, 128) == 8
     assert bd.boundary_tiles(length, BK, 128) == 6
 
-    def streamed(q, k, v):
-        return kernels.block_attention(q, k, v, dh ** -0.5, BK, 128, True)
+    def streamed(q, k, v, norms):
+        return kernels.block_attention(
+            q, k, v, norms if norm else None,
+            rotary_tables(angles) if rotate else None, dh, dh ** -0.5, 1e-6,
+            BK, 128, True)
 
-    def blocked(q, k, v):
-        return bd.block_diffusion_attention_blocked(q, k, v, dh ** -0.5, BK,
-                                                    128)
+    def blocked(q, k, v, norms):
+        q, k, v = _composition(q, k, v, norms if norm else None,
+                               angles if rotate else None, heads, kv, 1e-6,
+                               dh ** -0.5)
+        return _tokens(bd.block_diffusion_attention_blocked(q, k, v, 1.0, BK,
+                                                            128))
 
     got, g_got = jax.value_and_grad(
-        lambda *a: jnp.sum(w * streamed(*a)), argnums=(0, 1, 2))(q, k, v)
+        lambda *a: jnp.sum(w * streamed(*a)), argnums=(0, 1, 2, 3))(
+        q, k, v, norms)
     with jax.default_matmul_precision('highest'):
         want, g_want = jax.value_and_grad(
-            lambda *a: jnp.sum(w * blocked(*a)), argnums=(0, 1, 2))(q, k, v)
+            lambda *a: jnp.sum(w * blocked(*a)), argnums=(0, 1, 2, 3))(
+            q, k, v, norms)
     np.testing.assert_allclose(got, want, rtol=2e-2)
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
-    for a, b in zip(g_got, g_want):
-        err = float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
-        assert err < 1e-5, err
+    for a, b in zip(jax.tree_util.tree_leaves(g_got),
+                    jax.tree_util.tree_leaves(g_want)):
+        if not norm and a.shape == (dh,):     # no norm: no scale is read
+            assert not a.any() and not b.any()
+            continue
+        assert _rel(a, b) < 1e-5, _rel(a, b)
 
 
 # (length, block length, tile): the cell's size; the interpreted test's; a
@@ -324,22 +390,52 @@ def test_a_full_tiles_ids_are_all_visible(length, block_length, tile):
 ])
 def test_on_a_tpu_the_kernels_run_where_can_run_holds(
         monkeypatch, case, length, block_length, block, head_dim, runs):
-    """One path a platform: on a TPU the kernels at the shapes `can_run`
-    admits, the blocked core at any other, chosen from the shapes alone."""
+    """One path a platform: on a TPU the layer takes the kernels (the one
+    pass and the core, in the projections' layout) at the shapes `can_run`
+    admits, the composition and the blocked core at any other, chosen from
+    the shapes alone; the parameter tree is the same either way."""
+    from se3_transformer_tpu.ops import grouped_attention
     assert kernels.can_run(length, block_length, min(block, length), 4, 2,
                            head_dim) == runs, case
-    q, k, v = (jax.ShapeDtypeStruct((1, h, 2 * length, head_dim),
-                                    jnp.float32) for h in (4, 2, 2))
     taken = []
+    monkeypatch.setattr(
+        grouped_attention, 'block_diffusion_attention_blocked',
+        lambda q, *a: taken.append('blocked') or q)
+    monkeypatch.setattr(
+        kernels, 'block_attention',
+        lambda q, k, v, norms, rotary, *a: taken.append(
+            ('kernels', q.shape, k.shape, len(norms), len(rotary)) + a)
+        or q)
+    attn = _attention(head_dim, block=block, qk_norm=True, rope_theta=1e6,
+                      eps=1e-6)
+    x = jax.ShapeDtypeStruct((1, 2 * length, 32), jnp.float32)
+    positions = jax.ShapeDtypeStruct((2 * length,), jnp.int32)
+
+    def tree():
+        taken.clear()
+        params = jax.eval_shape(
+            lambda x, p: attn.init(jax.random.PRNGKey(0), x, p, block_length),
+            x, positions)['params']
+        return jax.tree_util.tree_map(lambda a: a.shape, params)
+
+    off = tree()
+    assert taken == ['blocked'], case
     monkeypatch.setattr(bd, 'is_tpu_backend', lambda: True)
-    monkeypatch.setattr(bd, 'block_diffusion_attention_blocked',
-                        lambda *a: taken.append('blocked') or a[0])
-    monkeypatch.setattr(kernels, 'block_attention',
-                        lambda *a: taken.append(('kernels',) + a[3:])
-                        or a[0])
-    bd.block_diffusion_attention(q, k, v, 0.5, block_length, block)
-    assert taken == ([('kernels', 0.5, block_length, block)] if runs
-                     else ['blocked']), case
+    assert tree() == off == dict(
+        q=dict(kernel=(32, 4 * head_dim)), k=dict(kernel=(32, 2 * head_dim)),
+        v=dict(kernel=(32, 2 * head_dim)), out=dict(kernel=(4 * head_dim, 32)),
+        q_norm=dict(scale=(head_dim,)), k_norm=dict(scale=(head_dim,)))
+    assert taken == ([(
+        'kernels', (1, 2 * length, 4 * head_dim),
+        (1, 2 * length, 2 * head_dim), 2, 2, head_dim, head_dim ** -0.5,
+        1e-6, block_length, block)] if runs else ['blocked']), case
+
+
+def _core(q, k, v):
+    """The rule that ships with no norm and no rotation, interpreted, in and
+    out in the projections' layout."""
+    return kernels.block_attention(q, k, v, None, None, 128, 0.1, 1e-6, BK,
+                                   128, True)
 
 
 @pytest.mark.parametrize('policy,forwards', [('SAVE_ATTN_CORE', 1),
@@ -347,18 +443,97 @@ def test_on_a_tpu_the_kernels_run_where_can_run_holds(
 def test_a_rematted_core_replays_no_forward_launch(policy, forwards):
     """The forward's output and log-sum-exp carry the names
     `SAVE_ATTN_CORE` keeps: the gradient of a block rematted under it holds
-    one forward launch; rematted whole (the control) it holds two."""
+    one forward launch of the core; rematted whole (the control) it holds
+    two. The pass before the core is replayed either way (its outputs are
+    what the backward launch reads), and runs backward once."""
     from se3_transformer_tpu.ops import latent_attention
-    q = jnp.ones((1, 2, 256, 128))
-    k = v = q[:, :1]
+    q = jnp.ones((1, 256, 2 * 128))
+    k = v = q[:, :, :128]
     core = jax.checkpoint(
-        lambda q, k, v: kernels.block_attention(q, k, v, 0.1, BK, 128, True),
-        policy=policy and getattr(latent_attention, policy))
+        _core, policy=policy and getattr(latent_attention, policy))
     jaxpr = str(jax.make_jaxpr(jax.grad(
         lambda *a: core(*a).sum(), argnums=(0, 1, 2)))(q, k, v))
     found = re.findall(r'name=(bd_core_\w+)', jaxpr)
     assert sorted(found) == ['bd_core_bwd'] + ['bd_core_fwd'] * forwards, \
         found
+    found = re.findall(r'name=(qk_pass_\w+)', jaxpr)
+    assert sorted(found) == ['qk_pass_bwd'] + ['qk_pass_fwd'] * 2, found
+
+
+# ------------------------------------------------------------------ #
+# the one pass between the projections and the core
+# (kernels/pallas_qk_pass.py)
+# ------------------------------------------------------------------ #
+PASSES = [(dh, norm, rotate) for dh in (128, 64) for norm in (True, False)
+          for rotate in (True, False)]
+
+
+def _pass_case(dh, norm, rotate, length=128, heads=4, kv=2):
+    from se3_transformer_tpu.kernels.pallas_qk_pass import rotary_tables
+    from se3_transformer_tpu.ops.rotary import rotary_angles
+    q, k, v, norms, _ = _projected(length, heads, kv, dh, seed=11)
+    # the two copies of a token share a position
+    angles = rotary_angles(jnp.tile(jnp.arange(length), 2), dh, 1e6)
+    return (q, k, v), norms if norm else None, \
+        (angles, rotary_tables(angles)) if rotate else (None, None)
+
+
+@pytest.mark.parametrize('dh,norm,rotate', PASSES)
+def test_the_pass_is_todays_composition(dh, norm, rotate):
+    """`qk_pass_fwd` interpreted against `RMSNorm` -> `apply_rotary_halves`
+    -> scale -> round, over heads of 128 (the width the layer takes it at)
+    and of 64 (the arithmetic at any width), norm and rotation on and off,
+    two streams at shared positions: within 1e-6 in float32, within one
+    bfloat16 ulp after the rounding."""
+    from se3_transformer_tpu.kernels import pallas_qk_pass as qk_pass
+    qkv, norms, (angles, tables) = _pass_case(dh, norm, rotate)
+    want = [_tokens(a) for a in _composition(
+        *qkv, norms, angles, 4, 2, 1e-6, dh ** -0.5)]
+    got = qk_pass.forward(*qkv, norms, tables, dh, dh ** -0.5, 1e-6,
+                          jnp.float32, True)
+    for a, b in zip(got, want):
+        assert a.dtype == jnp.float32
+        np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
+    rounded = qk_pass.forward(*qkv, norms, tables, dh, dh ** -0.5, 1e-6,
+                              jnp.bfloat16, True)
+    for a, b in zip(rounded, want):
+        assert a.dtype == jnp.bfloat16
+        np.testing.assert_allclose(a.astype(jnp.float32),
+                                   b.astype(jnp.bfloat16).astype(jnp.float32),
+                                   rtol=2 ** -7, atol=1e-6)
+
+
+@pytest.mark.parametrize('dh,norm,rotate', PASSES)
+def test_the_pass_backward_is_the_compositions_gradient(dh, norm, rotate):
+    """`qk_pass_bwd` interpreted against autodiff of the composition at
+    float32 cotangents: the projections' three and both norm scales within
+    1e-6 relative; rounded, within a bfloat16 ulp of them."""
+    from se3_transformer_tpu.kernels import pallas_qk_pass as qk_pass
+    qkv, norms, (angles, tables) = _pass_case(dh, norm, rotate)
+    keys = jax.random.split(jax.random.PRNGKey(12), 3)
+    cts = [jax.random.normal(key, a.shape) for key, a in zip(keys, qkv)]
+
+    def composed(q, k, v, norms):
+        return [_tokens(a) for a in _composition(
+            q, k, v, norms, angles, 4, 2, 1e-6, dh ** -0.5)]
+
+    want = jax.vjp(composed, *qkv, norms)[1](cts)
+    got = qk_pass.backward(*cts, *qkv[:2], norms, tables, dh, dh ** -0.5,
+                           1e-6, jnp.float32, True)
+    for a, b in zip(got[:3], want[:3]):
+        assert _rel(a, b) < 1e-6, _rel(a, b)
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+    if norm:
+        for a, b in zip(got[3], want[3]):
+            assert a.shape == (dh,) and _rel(a, b) < 1e-6, _rel(a, b)
+    else:
+        assert got[3] is None
+    rounded = qk_pass.backward(*cts, *qkv[:2], norms, tables, dh,
+                               dh ** -0.5, 1e-6, jnp.bfloat16, True)
+    for a, b in zip(rounded[:3], want[:3]):
+        assert a.dtype == jnp.bfloat16
+        np.testing.assert_allclose(a.astype(jnp.float32), b, rtol=2 ** -7,
+                                   atol=1e-5)
 
 
 # ------------------------------------------------------------------ #

@@ -602,27 +602,42 @@ def test_a_tiny_block_diffusion_step_has_every_leaf_and_the_three_phases():
     assert core and all('/attn/bd_core' in p for p in core)
 
 
-def _launch_paths(jaxpr, outer='', found=None):
-    """(launch name, name stack) of every Pallas launch in a jaxpr."""
+def _eqn_paths(jaxpr, outer='', found=None):
+    """(equation, name stack) of every equation in a jaxpr, nested ones
+    under their callers' stacks; a launch counts as one, its body is not
+    the program's."""
     found = [] if found is None else found
     for eqn in jaxpr.eqns:
         path = '/'.join(p for p in (outer, str(eqn.source_info.name_stack))
                         if p)
+        found.append((eqn, path))
         if eqn.primitive.name == 'pallas_call':
-            found.append((eqn.params['name'], path))
+            continue
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            _launch_paths(sub, path, found)
+            _eqn_paths(sub, path, found)
     return found
 
 
+def _launch_paths(jaxpr):
+    """(launch name, name stack) of every Pallas launch in a jaxpr."""
+    return [(eqn.params['name'], path) for eqn, path in _eqn_paths(jaxpr)
+            if eqn.primitive.name == 'pallas_call']
+
+
+@pytest.mark.parametrize('rematted', [False, True])
 def test_on_a_tpu_the_cores_two_launches_are_filed_under_bd_core(
-        monkeypatch):
-    """`kernels/pallas_block_attention.py`'s launches, which the attention
-    layer takes on a TPU at shapes its `can_run` admits: `bd_core_fwd` in
-    the forward phase and `bd_core_bwd` in the backward, both under the
-    leaf `bd_core` (their own names are no leaves), so the readers of the
-    leaf read them whatever they are called."""
-    from se3_transformer_tpu.ops import block_diffusion
+        monkeypatch, rematted):
+    """What the attention layer takes on a TPU at shapes `can_run` admits:
+    `kernels/pallas_block_attention.py`'s launches, `bd_core_fwd` in the
+    forward phase and `bd_core_bwd` in the backward, both under the leaf
+    `bd_core` (their own names are no leaves), so the readers of the leaf
+    read them whatever they are called; and the one pass before and after
+    them (`kernels/pallas_qk_pass.py`), `qk_pass_fwd` and `qk_pass_bwd`
+    under `mha_qkv`: role names, no leaves. A block rematted as the
+    decoders' are replays the pass and no launch of the core. Nothing else
+    is issued under `bd_core`: no cast of q, k or v, and di = sum(o do) is
+    inside the backward launch."""
+    from se3_transformer_tpu.ops import block_diffusion, latent_attention
     from se3_transformer_tpu.ops.grouped_attention import (
         GroupedQueryAttention,
     )
@@ -631,13 +646,28 @@ def test_on_a_tpu_the_cores_two_launches_are_filed_under_bd_core(
                                  block=128, qk_norm=True, rope_theta=1e6)
     x = jnp.ones((1, 512, 32))
     params = jax.eval_shape(attn.init, jax.random.PRNGKey(0), x)['params']
-    jaxpr = jax.make_jaxpr(jax.grad(lambda p: attn.apply(
-        {'params': p}, x, jnp.arange(512) % 256, 4).sum()))(params)
+
+    def layer(p, x):
+        return attn.apply({'params': p}, x, jnp.arange(512) % 256, 4)
+
+    if rematted:
+        layer = jax.checkpoint(layer, policy=latent_attention.SAVE_ATTN_CORE)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p: layer(p, x).sum()))(params)
     filed = {(name, profiling.scope_leaf(path), profiling.scope_phase(path))
              for name, path in _launch_paths(jaxpr.jaxpr)}
     assert filed == {('bd_core_fwd', 'bd_core', 'forward'),
-                     ('bd_core_bwd', 'bd_core', 'backward')}
-    assert not {'bd_core_fwd', 'bd_core_bwd'} & set(MODEL_SCOPES)
+                     ('bd_core_bwd', 'bd_core', 'backward'),
+                     ('qk_pass_fwd', 'mha_qkv', 'forward'),
+                     ('qk_pass_bwd', 'mha_qkv', 'backward')} | (
+        {('qk_pass_fwd', 'mha_qkv', 'replay')} if rematted else set())
+    roles = {'bd_core_fwd', 'bd_core_bwd', 'qk_pass_fwd', 'qk_pass_bwd'}
+    assert not roles & set(MODEL_SCOPES)
+    under_core = {str(eqn.primitive) for eqn, path in _eqn_paths(jaxpr.jaxpr)
+                  if profiling.scope_leaf(path) == 'bd_core'}
+    # `name` marks what `SAVE_ATTN_CORE` keeps; remat rounds the saved o to
+    # its own width (`reduce_precision`, which changes nothing)
+    assert under_core <= {'pallas_call', 'jit', 'name',
+                          'reduce_precision'}, under_core
 
 
 def test_the_same_module_trained_next_token_keeps_the_causal_leaf():

@@ -597,6 +597,43 @@ def test_padded_widths_leave_the_glm_cells_expert_layer_as_it_was(v5e):
     assert {d for dims in tiles.values() for d in dims} == {512}, tiles
 
 
+def _program_digests(lowered):
+    """(the StableHLO of a lowered step outside its Mosaic bodies, its
+    Mosaic modules), each as the first 16 hex digits of a sha256, source
+    locations stripped from both: what the program asks of the compiler,
+    whatever lines its source stands on."""
+    import hashlib
+
+    from jax._src.lib.mlir import ir
+    bodies = []
+
+    def body(match):
+        ctx = ir.Context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            bodies.append(ir.Module.parse(base64.b64decode(
+                match.group(1))).operation.get_asm(enable_debug_info=False))
+        return f'body <{len(bodies)}>'
+
+    text = re.sub(r'loc\(.*?\)|#loc.*', '', lowered.as_text(debug_info=True))
+    text = re.sub(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', body, text)
+    text = re.sub(r'\n+', '\n', text)
+    return tuple(hashlib.sha256(t.encode()).hexdigest()[:16]
+                 for t in (text, '\n'.join(bodies)))
+
+
+# The three causal decoders' steps as the tree before the one pass
+# (`kernels/pallas_qk_pass.py`, PR 42) lowered them, by `_program_digests`:
+# that PR changed `GroupedQueryAttention`, which two of them run, and held
+# all three to what they were. A PR that means to change one of these
+# programs says so and pins what it made.
+LOWERED_BEFORE_THE_ONE_PASS = {
+    'token_decoder': ('fbc6acb3f2404bb1', 'cbb0f31268823c36'),
+    'hybrid': ('83999f68a01618b1', '163f4d791ee9e1c7'),
+    'lfm2': ('0690edcb2e6f7ca7', '390b269ab0cf6096'),
+}
+
+
 def _assert_one_forward_core_a_layer(text, layers, leaf):
     """The streaming kernel's launches in a compiled step: `layers` of each
     of the three and no forward in a block's replay (the blocks save its
@@ -720,10 +757,13 @@ def test_token_decoder_step_compiles_and_fits(v5e, monkeypatch, capsys):
     params = jax.eval_shape(module.init, jax.random.PRNGKey(0),
                             tokens)['params']
     optimizer = optax.adam(1e-4)
-    compiled = make_sharded_train_step(make_lm_loss(module), optimizer).lower(
+    lowered = make_sharded_train_step(make_lm_loss(module), optimizer).lower(
         on_chip(params), on_chip(jax.eval_shape(optimizer.init, params)),
         on_chip(dict(tokens=tokens)),
-        on_chip(jax.random.PRNGKey(1))).compile()
+        on_chip(jax.random.PRNGKey(1)))
+    assert _program_digests(lowered) \
+        == LOWERED_BEFORE_THE_ONE_PASS['token_decoder']
+    compiled = lowered.compile()
     text = compiled.as_text()
     assert 'flash_mha_bwd_dkv' in text and 'ragged-dot' in text
     _assert_one_forward_core_a_layer(text, 6, 'latent_core')
@@ -834,11 +874,13 @@ def test_hybrid_decoder_step_compiles_and_fits(v5e, monkeypatch, capsys):
     params = jax.eval_shape(module.init, jax.random.PRNGKey(0),
                             tokens)['params']
     optimizer = optax.adam(1e-6)
-    compiled = make_sharded_train_step(
+    lowered = make_sharded_train_step(
         make_lm_loss(module, **cfg['loss']), optimizer).lower(
         on_chip(params), on_chip(jax.eval_shape(optimizer.init, params)),
         on_chip(dict(tokens=tokens)),
-        on_chip(jax.random.PRNGKey(1))).compile()
+        on_chip(jax.random.PRNGKey(1)))
+    assert _program_digests(lowered) == LOWERED_BEFORE_THE_ONE_PASS['hybrid']
+    compiled = lowered.compile()
     text = compiled.as_text()
     assert 'flash_mha_bwd_dkv' in text and 'ragged-dot' in text
     _assert_one_forward_core_a_layer(text, 1, 'mha_core')
@@ -903,11 +945,13 @@ def test_lfm2_decoder_step_compiles_and_fits(v5e, monkeypatch, capsys):
     params = jax.eval_shape(module.init, jax.random.PRNGKey(0),
                             tokens)['params']
     optimizer = optax.adam(1e-6)
-    compiled = make_sharded_train_step(
+    lowered = make_sharded_train_step(
         make_lm_loss(module, **cfg['loss']), optimizer).lower(
         on_chip(params), on_chip(jax.eval_shape(optimizer.init, params)),
         on_chip(dict(tokens=tokens)),
-        on_chip(jax.random.PRNGKey(1))).compile()
+        on_chip(jax.random.PRNGKey(1)))
+    assert _program_digests(lowered) == LOWERED_BEFORE_THE_ONE_PASS['lfm2']
+    compiled = lowered.compile()
     text = compiled.as_text()
     assert 'flash_mha_bwd_dkv' in text and 'ragged-dot' in text
     _assert_one_forward_core_a_layer(text, 1, 'mha_core')
@@ -929,23 +973,26 @@ def test_lfm2_decoder_step_compiles_and_fits(v5e, monkeypatch, capsys):
 # the block-diffusion core (ops/block_diffusion.py)
 # ------------------------------------------------------------------ #
 def _core_launches(text):
-    """(role, op_name) of every launch of the block-diffusion core in a
-    compiled program. A launch's line breaks inside its `kernel_metadata`,
-    so the path is the first `op_name` after the instruction's name."""
+    """(role, op_name) of every launch of the block-diffusion core and of
+    the pass before and after it in a compiled program. A launch's line
+    breaks inside its `kernel_metadata`, so the path is the first `op_name`
+    after the instruction's name."""
     return re.findall(
-        r'%(bd_core_(?:fwd|bwd))[.\d]* = .*?metadata=\{op_name="([^"]*)"',
-        text, flags=re.S)
+        r'%((?:bd_core|qk_pass)_(?:fwd|bwd))[.\d]* = .*?'
+        r'metadata=\{op_name="([^"]*)"', text, flags=re.S)
 
 
 def test_the_block_diffusion_core_compiles_and_visits_288_tiles_a_head(v5e):
-    """The repo's two kernels (`kernels/pallas_block_attention.py`) at the
-    block-diffusion cell's size (32 query and 4 key-value heads of 128,
-    2 x 8,192 positions, blocks of 4 tokens, tiles of 512): forward and
-    backward lower for the chip, one launch each, and the table their grid
-    is taken from holds 288 of a head's 1,024 tiles, none idle: a noised
-    tile meets itself and the clean prefix's i + 1 tiles, a clean tile
-    i + 1 (sum of (i + 2) + (i + 1) over 16), where a causal core over
-    16,384 positions would visit 528; 48 of them evaluate the rule."""
+    """The repo's kernels (`kernels/pallas_block_attention.py`,
+    `kernels/pallas_qk_pass.py`) at the block-diffusion cell's size (32
+    query and 4 key-value heads of 128, 2 x 8,192 positions, blocks of 4
+    tokens, tiles of 512), in the projections' own layout: the pass and the
+    core lower for the chip forward and backward, one launch each, and the
+    table the core's grid is taken from holds 288 of a head's 1,024 tiles,
+    none idle: a noised tile meets itself and the clean prefix's i + 1
+    tiles, a clean tile i + 1 (sum of (i + 2) + (i + 1) over 16), where a
+    causal core over 16,384 positions would visit 528; 48 of them evaluate
+    the rule. No operand or result is laid out again around the launches."""
     from se3_transformer_tpu.kernels import pallas_block_attention as kernels
     from se3_transformer_tpu.ops import block_diffusion as bd
 
@@ -955,20 +1002,63 @@ def test_the_block_diffusion_core_compiles_and_visits_288_tiles_a_head(v5e):
     assert bd.boundary_tiles(8192, 4, 512) == 48
     assert 32 * 33 // 2 == 528 and 32 * 32 == 1024
 
-    def loss(q, k, v):
-        return kernels.block_attention(q, k, v, 128 ** -0.5, 4, 512).sum()
+    def loss(q, k, v, norms, rotary):
+        return kernels.block_attention(
+            q, k, v, norms, rotary, 128, 128 ** -0.5, 1e-6, 4, 512).astype(
+            f32).sum()
 
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-        jax.ShapeDtypeStruct((1, 32, 16384, 128), f32, sharding=v5e),
-        *[jax.ShapeDtypeStruct((1, 4, 16384, 128), f32, sharding=v5e)] * 2
-    ).compile()
+    def on_chip(*shape):
+        return jax.ShapeDtypeStruct(shape, f32, sharding=v5e)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+        on_chip(1, 16384, 32 * 128), on_chip(1, 16384, 4 * 128),
+        on_chip(1, 16384, 4 * 128), (on_chip(128), on_chip(128)),
+        (on_chip(16384, 128), on_chip(16384, 128))).compile()
     text = compiled.as_text()
     roles = [role for role, _ in _core_launches(text)]
-    assert sorted(roles) == ['bd_core_bwd', 'bd_core_fwd'], roles
+    assert sorted(roles) == ['bd_core_bwd', 'bd_core_fwd', 'qk_pass_bwd',
+                             'qk_pass_fwd'], roles
     # the grid is the table: sequences x key-value heads x 288 entries
     assert text.count('s32[7,288]') >= 2 and 'splash' not in text
-    # the log-sum-exp leaves as one float32 a row
-    assert 'f32[1,32,1,16384]' in text and 'f32[1,32,16384,128]{' in text
+    # the log-sum-exp leaves as one float32 a row; q, o and dq stay [T, H D]
+    assert 'f32[1,32,1,16384]' in text and 'f32[1,16384,4096]{' in text
+    assert not re.search(r'\[1,32,16384,128\]|\[1,16384,32,128\]', text)
+    assert not re.search(r' (copy|transpose)\(', text[text.index('ENTRY'):])
+
+
+def _assert_no_relayout_around_the_core(text, big):
+    """In a compiled step no instruction under `mha_qkv`, `bd_core` or
+    `mha_out` (forward, replay or backward) that computes nothing passes
+    over a tensor of `big` elements or more (q, o, do or dq: [16384, 32,
+    128] in the cell): no `copy`, no `transpose`, no fusion of converts and
+    layout changes alone. The products and the launches are all that read
+    and write them; what is left of XLA's own is named here (remat rounds
+    the saved o to its own width, `reduce-precision`, one pass a layer)."""
+    comps = _computations(text)
+    moves = {'copy', 'transpose', 'convert', 'bitcast', 'bitcast-convert',
+             'reshape', 'parameter', 'broadcast', 'slice', 'concatenate',
+             'tuple', 'get-tuple-element'}
+    left = {}
+    for line in text[text.index('ENTRY'):].splitlines():
+        m = re.match(r'\s*(?:ROOT )?%[\w.\-]+ = (\(.*?\)|\S+) ([\w\-]+)\(',
+                     line)
+        path = re.search(r'op_name="([^"]*)"', line)
+        if not m or not path or not re.search(
+                r'/attn/(mha_qkv|bd_core|mha_out)(/|$)', path.group(1)):
+            continue
+        shape, opcode = m.groups()
+        sizes = [math.prod(int(d) for d in dims.split(',') if d)
+                 for dims in re.findall(r'\w+\[([\d,]*)\]', shape)]
+        if max(sizes, default=0) < big or opcode in (
+                'custom-call', 'get-tuple-element', 'bitcast'):
+            continue
+        called = re.search(r'calls=%([\w.\-]+)', line)
+        inside = _with_callees(comps, called.group(1)) if called else line
+        opcodes = set(re.findall(r' = (?:\(.*?\)|\S+) ([\w\-]+)\(', inside))
+        assert not opcodes <= moves, (opcodes, line[:300])
+        if 'convolution' not in opcodes:
+            left[opcode] = left.get(opcode, 0) + 1
+    assert left == {'reduce-precision': 5}, left
 
 
 @pytest.mark.slow
@@ -978,8 +1068,11 @@ def test_sdar_decoder_step_compiles_and_fits(v5e, monkeypatch, capsys):
     tokens read twice, compiled for the chip (a minute): one forward and
     one backward launch of the core a layer, the repo's own, and none in a
     block's replay (the blocks save its output and log-sum-exp), every
-    launch under `bd_core`, no `splash` anywhere; the grouped products are
-    in it; state plus temporaries fit; its memory is printed."""
+    launch under `bd_core`, no `splash` anywhere; the one pass before the
+    core forward and in the replay and after it backward, under `mha_qkv`,
+    and nothing laid out again on either side of them; the grouped
+    products are in it; state plus temporaries fit; its memory is
+    printed."""
     import optax
     from se3_transformer_tpu.ops import (
         block_diffusion, expert_layer, latent_attention,
@@ -1013,13 +1106,22 @@ def test_sdar_decoder_step_compiles_and_fits(v5e, monkeypatch, capsys):
     text = compiled.as_text()
     assert 'ragged-dot' in text and 'flash_attention' not in text
     assert 'splash' not in text
+    from se3_transformer_tpu.observability import profiling
     by_role = {}
     for role, path in _core_launches(text):
-        assert '/attn/bd_core/' in path, path
-        assert 'rematted_computation' not in path, path
-        assert ('transpose(' in path) == (role == 'bd_core_bwd'), path
-        by_role[role] = by_role.get(role, 0) + 1
-    assert by_role == {'bd_core_fwd': 5, 'bd_core_bwd': 5}, by_role
+        leaf = '/attn/bd_core/' if role.startswith('bd_core') \
+            else '/attn/mha_qkv/'
+        assert leaf in path, path
+        key = role, profiling.scope_phase(path)
+        by_role[key] = by_role.get(key, 0) + 1
+    # the core once a layer each way and none in a replay; the pass before
+    # it again in the replay (its outputs are what the backward launch reads)
+    assert by_role == {('bd_core_fwd', 'forward'): 5,
+                       ('bd_core_bwd', 'backward'): 5,
+                       ('qk_pass_fwd', 'forward'): 5,
+                       ('qk_pass_fwd', 'replay'): 5,
+                       ('qk_pass_bwd', 'backward'): 5}, by_role
+    _assert_no_relayout_around_the_core(text, 16384 * 32 * 128)
     _assert_product_front_ends_agree(compiled)
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes

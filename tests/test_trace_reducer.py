@@ -470,7 +470,7 @@ def test_the_new_tables_sum_to_the_seconds_they_split(cpu_step):
 def test_existing_keys_equal_a_reduction_without_the_side_table(cpu_step):
     red, plain = cpu_step['with_table'], cpu_step['without']
     new = {'product_s', 'product_flops', 'product_bytes', 'launch_s',
-           'glue_s', 'flops_source'}
+           'launch_roles', 'glue_s', 'flops_source'}
     assert set(red) - {'source'} == set(plain)
     for key in set(plain) - new:
         assert red[key] == plain[key], key
@@ -568,6 +568,22 @@ def test_the_recorded_decoder_step_sums_and_its_other_tables(
                                                               abs=5e-3)
     assert 1e3 * red['launch_s']['moe_experts'] == pytest.approx(28.100,
                                                                  abs=5e-3)
+    # the same seconds by what each launch is called, with its events: one
+    # forward and two backward launches of the one attention layer
+    roles = red['launch_roles']
+    assert {leaf: sum(secs for phases in by_role.values()
+                      for _, secs in phases.values())
+            for leaf, by_role in roles.items()} == pytest.approx(
+        red['launch_s'])
+    assert sorted((role.split('_block_')[0], phase, n)
+                  for role, phases in roles['mha_core'].items()
+                  for phase, (n, _) in phases.items()) == [
+        ('flash_attention', 'forward', 1),
+        ('flash_mha_bwd_dkv', 'backward', 1),
+        ('flash_mha_bwd_dq', 'backward', 1)]
+    table = profiling.format_launches(red).splitlines()
+    assert table[0].startswith('leaf / launch') and len(table) == 1 + sum(
+        len(by_role) for by_role in roles.values())
     # a leaf's remainder reads by what the instructions are
     assert 'dense_ff' not in red['glue_s']       # all of it is products
     assert set(red['glue_s']['norm']) == {'loop fusion'}

@@ -50,7 +50,7 @@ sys.path.insert(0, HERE)
 sys.path.insert(0, os.path.join(HERE, 'scripts'))
 
 # the repo's own gates: equivariance at float32 matmul precision (README,
-# tests/test_equivariance.py, bench.py's on-chip twin) — taken relative to
+# tests/test_equivariance.py) — taken relative to
 # the output's scale, which at flagship width and random weights is far
 # from the toy models' O(1) — and the sharding tests' loss tolerance
 # (tests/test_sharding.py)

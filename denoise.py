@@ -16,7 +16,7 @@ enable_compilation_cache()
 
 from se3_transformer_tpu.training import DenoiseConfig, DenoiseTrainer
 from se3_transformer_tpu.training.checkpoint import CheckpointManager
-from se3_transformer_tpu.utils.observability import MetricLogger
+from se3_transformer_tpu.observability import MetricLogger
 
 
 def main():
@@ -81,8 +81,7 @@ def main():
     ap.add_argument('--cpu', action='store_true',
                     help='force the CPU backend (a chip belongs to one '
                          'process at a time: a second process that needs '
-                         'it fails or hangs at init; same switch as '
-                         'scripts/run_baselines.py --cpu)')
+                         'it fails or hangs at init)')
     args = ap.parse_args()
     if args.cpu:
         import jax

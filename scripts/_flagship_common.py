@@ -1,12 +1,7 @@
-"""Shared flagship train-step builder for chip_smoke.py and the
-diagnostic scripts.
-
-bench.py is the source of truth for the officially-timed program; this
-module mirrors its setup (seeds, denoise objective, adam(1e-4), donated
-make_sharded_train_step) so chip_smoke.py and bench_diag.py run the
-same program without hand-copied replicas drifting apart. Any change to bench.py's program must land here too — the
-bench_diag loss-sequence cross-check (same seeds => identical losses)
-catches a silent divergence.
+"""The flagship train step as chip_smoke.py builds it: seeds, denoise
+objective, adam(1e-4), donated make_sharded_train_step, on one device
+or over a mesh. `benchmark/harness/train.py` builds the same program
+from a cell's configuration; the benchmark is what times it.
 """
 import os
 import sys
@@ -17,7 +12,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 def build_flagship_step(fast=True, remat=None, chunks=None, nodes=1024,
                         dim=64, batch=1, mesh=None, **recipe_kwargs):
     """Returns (step, params, opt_state, data, key, module): the
-    bench-identical donated train step and its initial state.
+    donated train step and its initial state.
 
     remat: remat_policy override ('none' forces the policy off);
     chunks: edge_chunks override (0 = unchunked); recipe_kwargs: the

@@ -1,26 +1,20 @@
-"""Render telemetry / bench JSONL streams into one summary JSON.
+"""Render telemetry JSONL streams into one summary JSON.
 
 Usage:
     python scripts/obs_report.py STREAM.jsonl [MORE.jsonl ...]
         [--validate] [--out SUMMARY.json] [--anchor FLOAT]
-        [--code-rev REV] [--require kind[,kind...]]
+        [--require kind[,kind...]]
 
 --require gates the stream on record kinds (pipeline / comm / tune /
 cost / profile / serve / ... / assembly / mesh_sweep), each with its
-load-bearing check; the old --require-pipeline/--require-comm/
---require-tune flags are aliases.
+load-bearing check.
 
-Input species are auto-detected per record:
-  * bench records ({"metric", "value", "unit", ...} — BENCH_SESSION.jsonl,
-    BLOCK_AB.jsonl, BENCH_r0N.json lines): grouped by metric label with
-    best-of-session selection, best single timing window, and one-sided
-    outlier flagging — the machine version of the round-close summary.
-  * telemetry streams (kind=run_meta/step/flush/summary records from a
-    `denoise.py --telemetry` run): reduced to a bench-shaped record
-    (metric/value/unit/vs_baseline/step_ms/loss trajectory) with
-    per-phase p50/p95 and the retrace-warning count.
+A stream (kind=run_meta/step/flush/summary records from a
+`denoise.py --telemetry` run) is reduced to one record per run
+(metric/value/unit/vs_baseline/step_ms/loss trajectory) with per-phase
+p50/p95 and the retrace-warning count.
 
---validate additionally gates telemetry streams on the record schema
+--validate additionally gates the streams on the record schema
 (observability.schema) and exits non-zero on violation — `make
 obs-smoke` runs exactly that. Never initializes a device backend (no
 jax.devices()/default_backend() call anywhere on this path), so it
@@ -110,8 +104,7 @@ def _gate_cost(records):
     costs = [r for r in records if r.get('kind') == 'cost']
     if not costs:
         print('COST GATE: no cost records in the stream (was the run '
-              'ledgered — bench cost payload, engine warmup, '
-              '--cost-record?)', file=sys.stderr)
+              'ledgered — engine warmup, --cost-record?)', file=sys.stderr)
         return False
     empty = [r for r in costs if not r.get('peak_bytes')]
     if empty:
@@ -288,136 +281,6 @@ def _gate_fleet(records):
     return True
 
 
-def _gate_so2_sweep(records):
-    sweeps = [r for r in records if r.get('kind') == 'so2_sweep']
-    if not sweeps:
-        print('SO2 GATE: no so2_sweep records in the stream (was '
-              'scripts/so2_smoke.py / bench.py --degrees run?)',
-              file=sys.stderr)
-        return False
-    last = sweeps[-1]
-    degrees = last.get('degrees') or {}
-    bad_eq = [d for d, e in degrees.items()
-              if not isinstance(e.get('equivariance_l2_so2'),
-                                (int, float))
-              or e['equivariance_l2_so2'] >= 1e-4]
-    if bad_eq:
-        print(f'SO2 GATE: so2 equivariance L2 >= 1e-4 (or missing) at '
-              f'degree(s) {sorted(bad_eq)} — the reduced contraction '
-              f'broke equivariance', file=sys.stderr)
-        return False
-    ab = {d: e['dense_vs_so2'] for d, e in degrees.items()
-          if 'dense_vs_so2' in e}
-    if not ab:
-        print('SO2 GATE: no degree carries a dense arm — the sweep '
-              'proves equivariance but no A/B (the perf budgets need '
-              'dense_vs_so2)', file=sys.stderr)
-        return False
-    print(f'so2 gate ok: degrees {sorted(degrees)}, dense_vs_so2 '
-          f'{ab}, worst eq '
-          f'{max(e["equivariance_l2_so2"] for e in degrees.values()):.2e}'
-          f' (the win itself is enforced by scripts/perf_gate.py)',
-          file=sys.stderr)
-    return True
-
-
-def _gate_v2_sweep(records):
-    sweeps = [r for r in records if r.get('kind') == 'v2_sweep']
-    if not sweeps:
-        print('V2 GATE: no v2_sweep records in the stream (was '
-              'scripts/v2_smoke.py / bench.py --v2-degrees run?)',
-              file=sys.stderr)
-        return False
-    last = sweeps[-1]
-    degrees = last.get('degrees') or {}
-    bad_eq = [d for d, e in degrees.items()
-              if not isinstance(e.get('equivariance_l2_v2'),
-                                (int, float))
-              or e['equivariance_l2_v2'] >= 1e-4]
-    if bad_eq:
-        print(f'V2 GATE: v2 equivariance L2 >= 1e-4 (or missing) at '
-              f'degree(s) {sorted(bad_eq)} — the eSCN-direct family '
-              f'broke equivariance', file=sys.stderr)
-        return False
-    ab = {d: e['so2_vs_v2'] for d, e in degrees.items()
-          if 'so2_vs_v2' in e}
-    if not ab:
-        print('V2 GATE: no degree carries the v1+so2 baseline arm — '
-              'the sweep proves equivariance but no family A/B (the '
-              'perf budgets need so2_vs_v2)', file=sys.stderr)
-        return False
-    print(f'v2 gate ok: degrees {sorted(degrees)}, so2_vs_v2 '
-          f'{ab}, worst eq '
-          f'{max(e["equivariance_l2_v2"] for e in degrees.values()):.2e}'
-          f' (the win itself is enforced by scripts/perf_gate.py)',
-          file=sys.stderr)
-    return True
-
-
-def _gate_flash(records):
-    recs = [r for r in records if r.get('kind') == 'flash']
-    if not recs:
-        print('FLASH GATE: no flash records in the stream (was '
-              'scripts/flash_smoke.py / bench.py --flash run?)',
-              file=sys.stderr)
-        return False
-    last = recs[-1]
-    eq = last.get('equivariance_l2_fused')
-    if not isinstance(eq, (int, float)) or eq >= 1e-4:
-        print(f'FLASH GATE: fused equivariance L2 {eq!r} >= 1e-4 (or '
-              f'missing) — the streaming kernel broke equivariance',
-              file=sys.stderr)
-        return False
-    ratios = {k: last.get(k) for k in ('fused_vs_unfused',
-                                       'hbm_unfused_vs_fused')}
-    if any(not isinstance(v, (int, float)) or v <= 0
-           for v in ratios.values()):
-        print(f'FLASH GATE: degenerate A/B ratios {ratios} — the record '
-              f'proves no fused-vs-unfused comparison', file=sys.stderr)
-        return False
-    print(f'flash gate ok: {len(recs)} flash records, step ratio '
-          f'{ratios["fused_vs_unfused"]}, peak-HBM ratio '
-          f'{ratios["hbm_unfused_vs_fused"]}, eq {eq:.2e} (the wins '
-          f'themselves are enforced by scripts/perf_gate.py)',
-          file=sys.stderr)
-    return True
-
-
-def _gate_quant_ab(records):
-    recs = [r for r in records if r.get('kind') == 'quant_ab']
-    if not recs:
-        print('QUANT GATE: no quant_ab records in the stream (was '
-              'scripts/quant_smoke.py / bench.py --quant run?)',
-              file=sys.stderr)
-        return False
-    last = recs[-1]
-    parity = last.get('parity_max_abs')
-    if not isinstance(parity, (int, float)) or parity >= 1e-4:
-        print(f'QUANT GATE: implementation parity {parity!r} >= 1e-4 '
-              f'(or missing) — the quantized serving path (fused '
-              f'dequant epilogues / kernels / padding) added error '
-              f'beyond quantization itself', file=sys.stderr)
-        return False
-    eq = last.get('equivariance_l2')
-    if not isinstance(eq, (int, float)) or eq >= 1e-4:
-        print(f'QUANT GATE: quantized equivariance L2 {eq!r} >= 1e-4 '
-              f'(or missing) — weight-only quantization must preserve '
-              f'equivariance', file=sys.stderr)
-        return False
-    ratio = last.get('argument_bytes_ratio')
-    if not isinstance(ratio, (int, float)) or ratio <= 0:
-        print(f'QUANT GATE: degenerate argument_bytes_ratio {ratio!r} — '
-              f'the record proves no memory claim', file=sys.stderr)
-        return False
-    print(f"quant gate ok: {len(recs)} quant_ab records, mix "
-          f"{last.get('mix')!r}, argument-bytes ratio {ratio}, impl "
-          f"parity {parity:.2e}, quant error "
-          f"{last.get('quant_error_max_abs')}, eq {eq:.2e} (the ratio "
-          f"ceiling itself is enforced by scripts/perf_gate.py)",
-          file=sys.stderr)
-    return True
-
-
 def _gate_trace(records):
     recs = [r for r in records if r.get('kind') == 'trace']
     if not recs:
@@ -590,10 +453,8 @@ def _gate_transport(records):
 _REQUIRE_GATES = dict(pipeline=_gate_pipeline, comm=_gate_comm,
                       tune=_gate_tune, cost=_gate_cost,
                       profile=_gate_profile, serve=_gate_serve,
-                      so2_sweep=_gate_so2_sweep,
-                      v2_sweep=_gate_v2_sweep, flash=_gate_flash,
                       fault=_gate_fault, guard=_gate_guard,
-                      fleet=_gate_fleet, quant_ab=_gate_quant_ab,
+                      fleet=_gate_fleet,
                       trace=_gate_trace, slo=_gate_slo,
                       assembly=_gate_assembly,
                       mesh_sweep=_gate_mesh_sweep,
@@ -602,7 +463,7 @@ _REQUIRE_GATES = dict(pipeline=_gate_pipeline, comm=_gate_comm,
 
 def main(argv=None):
     ap = argparse.ArgumentParser(
-        description='aggregate telemetry/bench JSONL into one summary')
+        description='aggregate telemetry JSONL into one summary')
     ap.add_argument('paths', nargs='+', help='JSONL stream(s)')
     ap.add_argument('--validate', action='store_true',
                     help='gate telemetry streams on the record schema '
@@ -611,8 +472,6 @@ def main(argv=None):
                     help='also write the summary JSON to this path')
     ap.add_argument('--anchor', type=float, default=None,
                     help='vs_baseline anchor for telemetry throughput')
-    ap.add_argument('--code-rev', default=None,
-                    help='only summarize bench records with this code_rev')
     ap.add_argument('--require', default=None, metavar='KIND[,KIND...]',
                     help='gate the stream on record kinds: '
                          f'{sorted(_REQUIRE_GATES)}. Each kind runs its '
@@ -634,23 +493,10 @@ def main(argv=None):
                          'through an engine bucket with zero '
                          'post-warmup compiles and sub-1e-4 parity) '
                          'and exits non-zero on failure')
-    # legacy aliases for the unified --require flag (kept: Makefiles and
-    # session scripts in the wild still pass them)
-    ap.add_argument('--require-tune', action='store_true',
-                    help='alias for --require tune')
-    ap.add_argument('--require-comm', action='store_true',
-                    help='alias for --require comm')
-    ap.add_argument('--require-pipeline', action='store_true',
-                    help='alias for --require pipeline')
     args = ap.parse_args(argv)
 
     required = {k.strip() for k in (args.require or '').split(',')
                 if k.strip()}
-    for kind, legacy_on in (('tune', args.require_tune),
-                            ('comm', args.require_comm),
-                            ('pipeline', args.require_pipeline)):
-        if legacy_on:
-            required.add(kind)
     unknown = required - set(_REQUIRE_GATES)
     if unknown:
         print(f'unknown --require kinds {sorted(unknown)} '
@@ -679,8 +525,7 @@ def main(argv=None):
         if not _REQUIRE_GATES[kind](records):
             return 1
 
-    summary = summarize(records, anchor=args.anchor,
-                        code_rev=args.code_rev)
+    summary = summarize(records, anchor=args.anchor)
     text = json.dumps(summary, indent=1)
     print(text)
     if args.out:

@@ -1,29 +1,28 @@
-"""Automated perf-regression gate: committed budgets vs fresh records.
+"""Automated regression gate: committed budgets vs record streams.
 
-Five PRs of JSONL record streams (bench / comm / cost / serve / width
-rows) were evidence; this gate turns them into ENFORCED budgets. A
-committed budget file (PERF_BUDGETS.json, seeded from the round-5
-session records) declares per-metric floors/ceilings with noise
-margins — including per-mesh-axis collective-byte budgets, the
-enforcement mechanism ROADMAP item 5 asks for — and this script
-compares record streams against them, exiting non-zero with a
-readable diff on any breach.
+A committed budget file (PERF_BUDGETS.json) declares per-metric
+floors/ceilings with noise margins — proof bits, byte and memory
+ceilings read from HLO (per mesh axis too), zero-lost-request and
+equivariance facts; none reads a clock — and this script compares
+record streams against them, exiting non-zero with a readable diff on
+any breach.
 
     python scripts/perf_gate.py [RECORDS.jsonl ...]
         [--budgets PERF_BUDGETS.json] [--fresh-cost STREAM.jsonl]
         [--inject-regression [NAME]] [--strict]
 
 With no record paths, the committed evidence set is gated
-(BENCH_r05.json + WIDTH_TABLE.jsonl) — `make perf-gate` additionally
-produces a FRESH toy cost record (--fresh-cost compiles the toy
-denoise train step on CPU and ledgers it through observability.costs),
+(DEFAULT_RECORDS; tier-1 holds the same in tests/test_costs.py) —
+`make perf-gate` additionally produces a FRESH toy cost record
+(--fresh-cost compiles the toy denoise train step on CPU and ledgers it
+through observability.costs),
 then re-runs with --inject-regression and asserts the non-zero exit:
 the gate must both pass on healthy numbers AND actually fire.
 
 Budget semantics (see PERF_BUDGETS.json):
-  * `kind`   — which records the budget applies to: 'bench' (records
-    with metric/value/unit), 'width' (width_table rows), or a
-    telemetry `kind` (comm / cost / serve / profile ...).
+  * `kind`   — which records the budget applies to: 'width'
+    (width_table rows) or a telemetry `kind` (comm / cost / serve /
+    profile ...).
   * `match`  — field -> expected filters (dotted paths; a string value
     matches as substring, anything else as equality).
   * `field`  — dotted path of the gated value.
@@ -45,9 +44,8 @@ Budget semantics (see PERF_BUDGETS.json):
     one).
 
 Budgets whose kind has no matching record are SKIPPED (reported;
---strict turns them into failures) — the committed set mixes
-chip-session metrics with CPU-reproducible ones, and a CPU run must
-not fail for lacking a TPU.
+--strict turns them into failures): a smoke's own stream holds its
+own kinds only.
 """
 import argparse
 import json
@@ -60,19 +58,11 @@ sys.path.insert(0, REPO)
 
 DEFAULT_BUDGETS = os.path.join(REPO, 'PERF_BUDGETS.json')
 # SERVE_MULTI.jsonl: the banked `make serve-multi-smoke` stream, so the
-# serving budgets (zero post-warmup compiles, router latency ceiling,
-# continuous-admission proof bit) are judged by a plain `make perf-gate`.
-# SO2_SWEEP.jsonl: the banked `make so2-smoke` degree-sweep stream, so
-# the so2-vs-dense degree-4 win + throughput floor are judged too.
-# FLASH_AB.jsonl: the banked `make flash-smoke` streaming-attention A/B
-# stream, so the fused arm's step-time + peak-HBM wins and its
-# equivariance gate are judged by a plain `make perf-gate`.
+# serving budgets (zero post-warmup compiles, continuous-admission
+# proof bit) are judged by a plain `make perf-gate`.
 # CHAOS_SMOKE.jsonl: the banked `make chaos-smoke` fault-domain stream,
 # so the zero-lost-requests contract, the observed quarantine->recovery
 # transition, and the nonzero-injections proof bit are judged too.
-# QUANT_AB.jsonl: the banked `make quant-smoke` fp32-vs-int8-mix serving
-# A/B, so the argument-bytes ceiling, the implementation-parity gate,
-# and the quantized equivariance gate are judged too.
 # TRAIN_CHAOS.jsonl: the banked `make train-chaos-smoke` self-healing
 # training stream, so the zero-divergence contract, the observed
 # rollback, and the nonzero-injections proof bit are judged too.
@@ -94,25 +84,20 @@ DEFAULT_BUDGETS = os.path.join(REPO, 'PERF_BUDGETS.json')
 # by a plain `make perf-gate`.
 # TRANSPORT_AB.jsonl: the banked `make transport-smoke` loadgen A/B
 # (legacy connect-per-call JSON vs pooled multiplexed binary framing on
-# the same seeded workload), so the binary-vs-legacy QPS floor, the p99
-# ceiling, and the wire-bytes ceiling are judged by a plain
-# `make perf-gate`.
-DEFAULT_RECORDS = ('BENCH_r05.json', 'WIDTH_TABLE.jsonl',
-                   'SERVE_MULTI.jsonl', 'SO2_SWEEP.jsonl',
-                   'FLASH_AB.jsonl', 'CHAOS_SMOKE.jsonl',
-                   'QUANT_AB.jsonl', 'TRAIN_CHAOS.jsonl',
+# the same seeded workload), so the binary-vs-legacy wire-bytes ceiling
+# is judged by a plain `make perf-gate`.
+DEFAULT_RECORDS = ('WIDTH_TABLE.jsonl', 'SERVE_MULTI.jsonl',
+                   'CHAOS_SMOKE.jsonl', 'TRAIN_CHAOS.jsonl',
                    'FLEET_CHAOS.jsonl', 'SLO_SMOKE.jsonl',
-                   'V2_SWEEP.jsonl', 'ASSEMBLY_SWEEP.jsonl',
-                   'MESH_SWEEP.jsonl', 'TRANSPORT_AB.jsonl')
+                   'ASSEMBLY_SWEEP.jsonl', 'MESH_SWEEP.jsonl',
+                   'TRANSPORT_AB.jsonl')
 
 
 # --------------------------------------------------------------------- #
 # record loading / classification
 # --------------------------------------------------------------------- #
 def load_records(path):
-    """JSONL stream, JSON list, or a single JSON object. BENCH_r0N.json
-    wrappers ({"cmd", "rc", "parsed": {...bench record...}}) contribute
-    their parsed record."""
+    """JSONL stream, JSON list, or a single JSON object."""
     with open(path) as f:
         text = f.read()
     try:
@@ -122,8 +107,6 @@ def load_records(path):
     if isinstance(data, list):
         return [r for r in data if isinstance(r, dict)]
     if isinstance(data, dict):
-        if isinstance(data.get('parsed'), dict):
-            return [data['parsed']]
         return [data]
     from se3_transformer_tpu.observability.report import load_jsonl
     return load_jsonl(path)
@@ -132,8 +115,6 @@ def load_records(path):
 def record_kind(rec):
     if 'kind' in rec:
         return rec['kind']
-    if 'metric' in rec and 'value' in rec and 'unit' in rec:
-        return 'bench'
     if rec.get('weak_scaling') or 'per_shard_total_gb' in rec:
         return 'width'
     return None
@@ -247,9 +228,7 @@ def synthesize_breach(budget):
     proves the gate actually fires."""
     rec = {}
     kind = budget.get('kind')
-    if kind == 'bench':
-        rec.update(metric='synthetic', value=0.0, unit='synthetic')
-    elif kind == 'width':
+    if kind == 'width':
         rec['weak_scaling'] = True
     else:
         rec['kind'] = kind

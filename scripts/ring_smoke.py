@@ -12,7 +12,7 @@ three gates, exit non-zero on any miss:
   2. COMM SCHEMA — the run writes a telemetry stream (run_meta + one
      `comm` record per traced arm) that observability.schema validates;
      the Makefile target re-gates it through
-     `scripts/obs_report.py --require-comm`.
+     `scripts/obs_report.py --require comm`.
   3. ALL-GATHER-FREE — the traced sp=8 forward of the exchange arm
      contains no full-width [b, N, ...] all-gather (the artifact the
      exchange exists to kill), while the dense control arm is REQUIRED

@@ -15,17 +15,17 @@ the WIRE is the variable) is driven through both transports:
 Per arm: QPS (closed-loop wall clock), p50/p99 request latency, and
 bytes-on-wire per call off the transport's own counters. The verdict
 rides ONE schema'd `transport` record banked to TRANSPORT_AB.jsonl —
-`qps_binary_vs_legacy` (floor 3x), `p99_binary_vs_legacy` (ceiling),
-`wire_bytes_binary_vs_legacy` (ceiling) — judged by the committed
-PERF_BUDGETS.json entries via scripts/perf_gate.py, with the
+`qps_binary_vs_legacy` and `p99_binary_vs_legacy` (this host's clock:
+recorded, not budgeted) and `wire_bytes_binary_vs_legacy` (a count,
+judged by the committed PERF_BUDGETS.json ceiling via
+scripts/perf_gate.py) — with the
 qualitative invariants (zero errors, zero frame errors, zero
 mid-workload reconnects, in-flight depth actually > 1) gated by
 `obs_report --require transport`.
 
-`--inject-regression` writes a corrupted record (QPS win gone, p99
-blown, wire FATTER than JSON) and requires perf_gate.py to FIRE on
-it, then exits 1 — proving the budgets bite (the Makefile asserts
-rc==1).
+`--inject-regression` writes a corrupted record (a wire FATTER than
+JSON) and requires perf_gate.py to FIRE on it, then exits 1 — proving
+the budget bites (the Makefile asserts rc==1).
 
     python scripts/transport_loadgen.py [--metrics TRANSPORT_AB.jsonl]
         [--requests 240] [--concurrency 8] [--length 768] [--seed 0]
@@ -311,8 +311,8 @@ def inject_regression(args, run_id):
         transport=dict(connections_opened=2, reconnects=0,
                        peak_in_flight=8, bytes_sent=1, bytes_received=1,
                        frame_errors=0),
-        # the three regressions the budgets exist to catch: the QPS
-        # win gone, p99 blown past JSON, and a wire FATTER than JSON
+        # the regression the budget exists to catch: a wire FATTER
+        # than JSON
         qps_binary_vs_legacy=1.0,
         p99_binary_vs_legacy=10.0,
         wire_bytes_binary_vs_legacy=2.0)
@@ -325,8 +325,8 @@ def inject_regression(args, run_id):
     sys.stderr.write(proc.stderr)
     if proc.returncode == 0:
         print('INJECTED REGRESSION NOT CAUGHT: perf_gate passed a '
-              'record with QPS ratio 1.0, p99 ratio 10.0, and wire '
-              'ratio 2.0 — the transport budgets are not wired')
+              'record with wire ratio 2.0 — the transport budget is '
+              'not wired')
         return 2
     print('perf gate FIRED on the injected transport regression '
           f'(rc={proc.returncode}) — budgets are live')

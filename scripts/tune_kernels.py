@@ -1,14 +1,11 @@
 """First-class kernel block-size autotuner (END-TO-END, shape-keyed).
 
-Supersedes the retired scripts/kernel_tune.py subprocess-per-env-var
-sweep. That sweep timed STANDALONE kernels — and its rankings were
-measured OPPOSITE to end-to-end rankings (flipping the picker from its
-standalone winner cost the production conservative flagship 2.7x,
-294.97 -> 107.51 nodes*steps/s, commit d0cd10d / BENCH_SESSION.jsonl).
-This tuner therefore never times a kernel in isolation:
+A standalone kernel timing ranks block shapes the other way round
+from the step they run in (root PERF.md section 6 has three
+instances), so this tuner never times a kernel in isolation:
 
-  1. build the REAL bench-style train step (recipes + synthetic batch +
-     make_sharded_train_step — the program the records are made of) and
+  1. build the real train step (recipes + synthetic batch +
+     make_sharded_train_step) and
      trace it once: the kernels' pick functions record every
      (kind, shape, dtype) they resolved — those are the tuning targets;
   2. per target, enumerate only tile-legal, VMEM-model-admissible
@@ -69,7 +66,7 @@ def _emit(args, rec):
 
 
 def _build_step(args):
-    """The real bench-style program: module + synthetic batch + sharded
+    """The real program: module + synthetic batch + sharded
     train step factory. Returns (make_step, state) where make_step()
     hands back a FRESH jitted step (each candidate must re-trace so the
     pick functions re-run) and state carries params/opt_state/data."""
@@ -87,8 +84,8 @@ def _build_step(args):
     from se3_transformer_tpu.training import recipes
 
     if args.smoke:
-        # interpret-mode toy: same program shape as the CPU liveness
-        # bench, with the Pallas kernels forced through the interpreter
+        # interpret-mode toy: the Pallas kernels forced through the
+        # interpreter
         # so the pick functions actually resolve on CPU.
         # --conv-backend so2 traces the banded SO(2) path instead, so
         # the 'so2' kind's streaming chunks become tuning targets
@@ -179,9 +176,8 @@ def _build_step(args):
 
 
 def _measure_window(step, state, steps):
-    """One timed end-to-end window; returns nodes*steps/sec. Same
-    close-the-clock semantics as bench.py: the tail is host-fetched
-    before the clock stops."""
+    """One timed end-to-end window; returns nodes*steps/sec. The tail
+    is host-fetched before the clock stops."""
     import jax
     t0 = time.monotonic()
     params, opt_state = state['params'], state['opt_state']

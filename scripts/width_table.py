@@ -14,8 +14,8 @@ node count to prove the label-width program actually runs end to end.
 The numbers are XLA:CPU SPMD estimates — layouts/fusion differ from TPU
 (measured on-chip: dim=64 needs the remat recipe to fit 16 GB, which
 matches this harness's estimate within ~20%) — so the table is stated
-as the scaling story, with the dim=64 single-chip point anchored by the
-real-HBM measurements in BENCH_SESSION.jsonl.
+as the scaling story; the dim=64 single-chip point is the benchmark's
+`hbm_reserved_gib.train` in `d4_onehead_train` (PERF_LEDGER.jsonl).
 
 Usage (fresh process per device count — the virtual device count is
 fixed at backend init):
@@ -28,7 +28,7 @@ fixed at backend init):
 Writes crash-safe JSONL to WIDTH_TABLE.jsonl (append). --weak-scaling
 rows carry a `comm` payload (collective classes/bytes + the full-width
 all-gather scan of the traced HLO); --ab measures the overlapped+sparse
-vs serialized+dense comm arms in one process (docs/PERF.md's table).
+vs serialized+dense comm arms in one process.
 --mesh-sweep instead walks every (dp, sp, tp) mesh point covering the
 device count through the composed-parallelism route (params+opt state
 over (dp, tp), ring sp when sp>1, donation pinned through explicit
@@ -59,7 +59,7 @@ def _setup(n_devices: int):
 
 
 def _flagship_step(jax, mesh, dim, n, k, tp, compile_only=True):
-    """Lower + compile the exact bench.py training program (flagship_fast
+    """Lower + compile the flagship training program (flagship_fast
     recipe, denoise objective, adam) over the mesh; returns (compiled,
     compile_s, example_args)."""
     import jax.numpy as jnp
@@ -168,7 +168,7 @@ def weak_scaling_point(jax, n_devices, per_device_nodes, dim, k, steps=3,
     so ideal weak scaling here is wall-clock LINEAR in total nodes (not
     flat); the rows record step_s only — the overhead factor
     step_s / (sp * step_s_at_sp1) is derived downstream from the sp=1
-    row (docs/PERF.md does this), and per-shard memory should stay
+    row, and per-shard memory should stay
     ~flat (the actual weak-scaling claim).
 
     overlap/exchange are the PR-5 comm knobs (parallel/ring.py,
@@ -441,7 +441,7 @@ def main(argv=None):
                     help='with --weak-scaling: also write a schema-valid '
                          'telemetry stream (run_meta + one comm record '
                          'per arm) for scripts/obs_report.py '
-                         '--require-comm')
+                         '--require comm')
     args = ap.parse_args(argv)
 
     jax = _setup(args.devices)

@@ -334,7 +334,7 @@ class InferenceEngine:
         # drop the kernel jit caches first: picks resolve at trace time,
         # so a kernel traced earlier in-process (training, a prior
         # engine) would compile these buckets without recording a single
-        # consult (the masquerading failure bench.py also guards)
+        # consult
         if any(self._key(b) not in self._executables
                for b in self.buckets):
             tuning.clear_kernel_caches()

@@ -165,7 +165,7 @@ def fused_attention_fits(J: int, D: int, bwd: bool = True) -> bool:
     to the XLA path when this is False (e.g. num_neighbors~512 at a wide
     dim_head) instead of surfacing a Mosaic VMEM error.
 
-    bwd=True is DELIBERATELY conservative (ADVICE r3 #2): the module
+    bwd=True is DELIBERATELY conservative: the module
     dispatch cannot know whether the caller will differentiate, so it
     budgets for the ~2x backward working set even in inference-only use.
     A config whose forward fits but backward doesn't therefore runs XLA;

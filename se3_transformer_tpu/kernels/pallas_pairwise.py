@@ -28,11 +28,10 @@ possible puts the EDGE axis on lanes:
     outT [P*O, E] -> transpose/reshape outside -> out [E, P, O]
 
     The bias rides as its own [S, 1] operand rather than folded into the
-    matmul (a ones column on h / bias row on w3, the pre-round-4 design):
-    folding made the contraction dim mid+1 = 129, and the MXU contracts
-    in 128-chunks — the dominant dot (~95% of ALL flagship FLOPs, see
-    utils/flops.py) paid a second, 1/129-useful pass, a structural ~2x
-    tax on every path. mid stays exactly 128 now.
+    matmul (a ones column on h / bias row on w3): folding makes the
+    contraction dim mid+1 = 129, and the MXU contracts in 128-chunks —
+    the dominant dot would pay a second, 1/129-useful pass, a
+    structural ~2x tax on every path. mid stays exactly 128.
 
 The grid is (n_e, n_if) with the out block revisited across the inner
 if-axis (consecutive revisits — the legal TPU accumulation pattern), so
@@ -112,8 +111,8 @@ def _consult_table(kind, shape, heuristic_fn):
     the pick; a cache entry failing the tile-quantum / VMEM admission
     model degrades to the heuristic with a warning. The table's dtype
     key is float32: V2, the basis and x reach these kernels in no other.
-    Every resolution is recorded for telemetry (bench record / serving
-    warmup / run report)."""
+    Every resolution is recorded for telemetry (serving warmup / run
+    report)."""
     from . import tuning
     dtype = 'float32'
     hit = tuning.lookup(kind, shape, dtype=dtype)
@@ -144,39 +143,27 @@ def _pick_blocks(E: int, IF: int, O: int, P: int, mid: int,
     the pick is bit-identical to the heuristic (regression-pinned in
     tests/test_kernel_tuning.py).
 
-    Budget: 7 MiB forward / 6 MiB backward. The forward bump is an
-    END-TO-END measured adoption (the only kind this picker accepts —
-    see the warning below): it moves the flagship plain pick from
-    (512, 8) to (512, 16), which benched 336.21 vs 296.26
-    nodes·steps/s (+13.5%) on the conservative flagship, direction
-    confirmed across alternating A/B pairs under one-sided host noise
-    (04:0xZ pair: 300.77 vs 131.01; BENCH_SESSION.jsonl). block_if is non-monotonic end-to-end: 8 → 296, 16 → 336,
-    32 → 107 — the budget admits exactly the measured-best middle. The
-    backward keeps 6 MiB: its ~2x working set was never measured past
-    it, and the A/B's backward ran the unchanged heuristic. NOTE
-    (ADVICE r4 #4): non-flagship shapes inherit the 7 MiB forward
-    budget unvalidated — given the measured end-to-end non-monotonicity
-    of block_if, re-A/B before trusting a changed pick at a new shape.
+    Budget: 7 MiB forward / 6 MiB backward. The forward's 7 admits the
+    flagship plain pick (512, 16), the middle of three block_if values
+    that ranked non-monotonically in the step (8, 16, 32: 16 fastest,
+    32 slowest by 3x). The backward keeps 6 MiB: its ~2x working set
+    was never measured past it. Other shapes inherit the 7 MiB forward
+    budget unvalidated — re-rank in the step before trusting a changed
+    pick at a new shape.
 
     Mosaic block-shape rule: every blocked dim must either cover the full
     array or be divisible by its tile quantum — so block_if is the full IF
     (n_if == 1) or a multiple of 8, and block_e a multiple of 128.
 
-    A MEASURED WARNING about re-tuning this from standalone sweeps: the
-    round-4 KERNEL_TUNE sweep timed the STANDALONE plain kernel at the
-    unchunked flagship shape (E=32768/IF=1024/O=7*... on a v5e) and
-    ranked (256, 32) 18x faster than this picker's (512, 8) — but
-    flipping the picker to prefer block_if (commit d0cd10d) made the
-    REAL conservative flagship — the same contraction at E=4096 per
-    chunk under lax.map+remat — 2.7x SLOWER end-to-end (294.97 ->
-    107.51 nodes*steps/s, BENCH_SESSION.jsonl 00:47Z vs 01:39Z, same
-    chip, kernel_smoke green both times). The standalone-vs-production
-    rankings are OPPOSITE: inside the chunked/remat program the large
+    The preference is block_e first, and it is not to be re-tuned from
+    a standalone sweep: alone, the plain kernel at the unchunked
+    flagship shape ranked (256, 32) far ahead of (512, 8), and the step
+    — the same contraction at E=4096 per chunk under lax.map+remat —
+    ran 2.7x slower with it. Inside the chunked/remat program the large
     w3/R tiles of a wide block_if evict the lax.map body's working set
     and the e-grid shortens 8x, while standalone the tiny block_if=8
-    tiles are DMA-bound. The picker therefore keeps the
-    production-validated preference (block_e first); only re-rank from
-    END-TO-END bench numbers, never from standalone kernel timings."""
+    tiles are DMA-bound. Re-rank only from the step's own time (the
+    benchmark's d4 cell)."""
     if vmem_budget is None:
         vmem_budget = (6 if bwd else 7) * 2 ** 20  # see docstring
 
@@ -384,7 +371,7 @@ def _edge_o_axes(arg_shapes, e_pos, o_pos):
     operand that carries the factor (positions parsed from the rule
     string) — resolving e from h alone would silently drop the edge
     sharding when h arrives replicated but v2/basis/x/g carry it, and
-    GSPMD would then all-gather the edge tensors (ADVICE r2 #1). A mesh
+    GSPMD would then all-gather the edge tensors. A mesh
     axis can't shard both factors — on collision the edge sharding wins
     and the o-carrying operands get resharded by the partitioner."""
     def first(positions):
@@ -557,13 +544,11 @@ def _pick_blocks_bx(E: int, C: int, O: int, P: int, Q: int, F: int,
     Resolution order mirrors _pick_blocks: the measured shape-keyed
     table (kernels.tuning, kind 'bxf'), then the heuristic below.
 
-    The round-4 KERNEL_TUNE standalone sweep at the flagship bxf shape
-    measured the default (128, 8) within 2% of the best other pick
-    (7.896 vs 7.723 ms at (512, 8)) — and the plain picker's cautionary
-    tale applies (see _pick_blocks: a standalone-sweep-derived
-    "improvement" cost the production conservative path 2.7x), so the
-    budget and ordering stay as production-validated; the end-to-end
-    tuner (scripts/tune_kernels.py) is the experimentation path."""
+    Alone, the kernel ranks the default (128, 8) within 2% of the best
+    other pick at the flagship bxf shape, and a standalone ranking is
+    not the step's (see _pick_blocks), so the budget and ordering stay;
+    the end-to-end tuner (scripts/tune_kernels.py) is the
+    experimentation path."""
     def _heuristic():
         for block_e in (512, 256, 128):
             if block_e > _round_up(E, 128):
@@ -579,11 +564,11 @@ def _pick_blocks_bx(E: int, C: int, O: int, P: int, Q: int, F: int,
         # even the smallest block exceeds the model budget: the estimate
         # mirrors the loop's accounting at (128, 8). The flagship bxf
         # shape (P=7, Q=7, F=7, O=64, mid=128) lands here at ~7.5 MiB and
-        # is PRODUCTION-VALIDATED on the v5e (round-4 kernel_smoke +
-        # bench at record throughput) — the model is conservative, so
-        # estimates within a margin of that validated point stay SILENT
-        # (ADVICE r4 #3: a warning that fires on every healthy flagship
-        # run trains users to ignore it). Only genuinely larger shapes
+        # runs on the v5e (the benchmark's d4 cell is that shape) — the
+        # model is conservative, so estimates within a margin of that
+        # validated point stay SILENT (a warning that fires on every
+        # healthy flagship run trains users to ignore it). Only
+        # genuinely larger shapes
         # get the heads-up that pre-explains a real Mosaic VMEM failure.
         total = _vmem_bx(128, 8, O, P, Q, F, mid)
         validated_silence = 9 * 2 ** 20  # flagship 7.5 MiB + margin

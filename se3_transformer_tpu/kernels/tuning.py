@@ -1,8 +1,8 @@
 """Shape-keyed kernel block-config autotuner table.
 
-The fused pairwise-conv and attention Pallas kernels carry the flagship
-head-to-head win (docs/PERF.md), but their block sizes historically came
-from a static VMEM-budget heuristic validated only at the flagship shape
+The fused pairwise-conv and attention Pallas kernels carry most of the
+flagship step (root PERF.md section 5), but their block sizes
+historically came from a static VMEM-budget heuristic validated only at the flagship shape
 — `_pick_blocks` itself warns that non-flagship shapes inherit the 7 MiB
 forward budget unvalidated, and that standalone-sweep rankings were
 measured OPPOSITE to end-to-end rankings (the d0cd10d regression:
@@ -27,11 +27,11 @@ variable of their own first, and log it as source 'env')
 
 Entries enter the cache ONLY through `promote()`, and the supported
 promoter (scripts/tune_kernels.py) measures candidates END-TO-END
-through the real bench step — never the standalone kernel — and
+through the real train step — never the standalone kernel — and
 requires a win over the incumbent across alternating A/B pairs. Every
 consult (cache hit, forced candidate, 'env', or heuristic fallback) is
-recorded in an in-process log that bench.py, the serving engine's AOT
-warmup, and the run report surface, so an adopted pick is always
+recorded in an in-process log that the serving engine's AOT warmup
+and the run report surface, so an adopted pick is always
 distinguishable from a heuristic one in telemetry.
 
 Unlike basis.CACHE_PATH (frozen at import), the cache directory env var
@@ -340,7 +340,7 @@ def reset_consults() -> None:
 def consults() -> List[dict]:
     """Every distinct pick resolution since the last reset, as dicts
     ({kernel, shape, dtype, source, blocks, count}) — the payload
-    bench.py and the serving warmup attach to their records."""
+    the serving warmup attaches to its records."""
     with _lock:
         items = sorted(_consults.items())
     return [dict(kernel=k, shape=list(s), dtype=d, source=src,
@@ -350,7 +350,7 @@ def consults() -> List[dict]:
 
 def snapshot() -> Dict[Tuple, int]:
     """Opaque marker for consults_since — lets concurrent consumers
-    (bench record, serving warmup) report their own deltas without
+    (serving warmup, the tuner) report their own deltas without
     resetting the shared log out from under each other."""
     with _lock:
         return dict(_consults)
@@ -390,9 +390,9 @@ def admissible_candidates(kind: str, shape: Sequence[int]
                           ) -> List[Tuple[int, ...]]:
     """Tile-legal, VMEM-model-admissible candidate blocks for a shape —
     what scripts/tune_kernels.py is allowed to measure. Admission is
-    model-based and conservative ON PURPOSE: the round-4 sweep ran
-    over-budget settings and paid with Mosaic VMEM compile failures at
-    bxf (512, 16) — those configs are excluded here up front.
+    model-based and conservative ON PURPOSE: over-budget settings fail
+    in Mosaic's VMEM allocation at compile time (bxf (512, 16) did), so
+    those configs are excluded here up front.
 
     Per kind:
       * 'plain': forward working set within the production 7 MiB budget

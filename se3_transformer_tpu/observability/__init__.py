@@ -1,7 +1,6 @@
 """First-class telemetry subsystem.
 
-Supersedes the old `utils/observability.py` single file (which remains as
-a re-export shim). Four pillars:
+Four pillars:
 
   * `metrics`  — `MetricAccumulator`, an on-device running-statistics
     pytree carried through the jitted train step (zero host syncs on hot
@@ -16,17 +15,15 @@ a re-export shim). Four pillars:
   * `timing`   — `PhaseTimer`: host-side wall-clock reservoirs with
     windowed p50/p95/max per phase, plus `named_scope` / `profile_trace`
     for device-side (xprof) attribution of the model phases.
-  * `report`   — aggregate one or more JSONL streams (telemetry runs or
-    banked bench records) into the round-close summary shape: best-of-
-    window selection, outlier flagging, vs_baseline. CLI:
-    `scripts/obs_report.py`.
+  * `report`   — aggregate one or more telemetry JSONL streams into
+    one summary per run. CLI: `scripts/obs_report.py`.
 
 Two attribution pillars joined in PR 6:
 
   * `costs`    — HLO cost ledger: any lowered/AOT executable ->
     schema'd `cost` record (flops/bytes via `cost_analysis()` with an
     HLO-parse fallback, peak HBM split argument/output/temp, per-class
-    collective bytes). Consumed by bench.py, the training step
+    collective bytes). Consumed by the training step
     factories, `InferenceEngine.warmup` (one record per shape bucket),
     and scripts/width_table.py; enforced by scripts/perf_gate.py.
   * `profiling` — the one trace reducer: the profiler's `.xplane.pb`
@@ -66,8 +63,7 @@ from .schema import (  # noqa: F401
     SCHEMA_VERSION, validate_record, validate_stream,
 )
 from .report import (  # noqa: F401
-    load_jsonl, summarize_bench_records, summarize_telemetry,
-    summarize_tune_records,
+    load_jsonl, summarize_telemetry, summarize_tune_records,
 )
 from .costs import (  # noqa: F401
     cost_payload, step_cost_payload,

@@ -2,9 +2,8 @@
 schema'd `cost` record.
 
 Every perf claim this repo makes is ultimately a claim about flops,
-bytes, or peak HBM — yet until PR 6 the record stream carried only
-wall-clock plus a hand-derived flops model (utils/flops.py). This
-module turns any lowered/AOT executable into a machine-checkable
+bytes, or peak HBM. This module turns any lowered/AOT executable into
+a machine-checkable
 `cost` record body (observability.schema kind='cost'):
 
   * `flops` / `bytes_accessed` — XLA's `compiled.cost_analysis()`,
@@ -12,11 +11,11 @@ module turns any lowered/AOT executable into a machine-checkable
     compiled HLO text on backends where cost_analysis returns None
     (the `source` field says which path produced the numbers, so a
     fallback estimate can never masquerade as the real analysis).
-    NOTE the known blindness (utils/flops.py docstring): Pallas-kernel
-    FLOPs are invisible to BOTH paths, and lax.map bodies count once
-    instead of trip-count times — `cost` records measure the
-    XLA-visible program; the analytic estimator remains the honest
-    whole-program count and bench records carry both.
+    NOTE the known blindness: Pallas-kernel FLOPs are invisible to
+    BOTH paths, and lax.map bodies count once instead of trip-count
+    times — `cost` records measure the XLA-visible program; the
+    benchmark counts the whole step analytically
+    (`benchmark/harness/counts.py`).
   * `memory` / `peak_bytes` — `compiled.memory_analysis()` split into
     argument/output/temp (the per-shard footprint estimate
     scripts/width_table.py has used since PR 5's weak-scaling rows;
@@ -29,7 +28,7 @@ module turns any lowered/AOT executable into a machine-checkable
     verbatim from PR 5's `parallel.exchange.analyze_hlo_comm`, so a
     cost record of a sharded program also ledgers its communication.
 
-Consumers: bench.py (every record), `InferenceEngine.warmup` (one
+Consumers: `InferenceEngine.warmup` (one
 record per shape bucket — serving capacity planning reads
 memory-per-bucket off the stream), `DenoiseTrainer` (the training step
 factories' compiled program), scripts/width_table.py, and
@@ -172,8 +171,8 @@ def cost_payload(compiled, *, label: str, hlo_text: Optional[str] = None,
         # REFUSE to fabricate a zero split: a peak_bytes=0 record
         # passes every memory ceiling vacuously, silently disarming
         # the exact budgets scripts/perf_gate.py exists to enforce.
-        # Callers guard this call — a missing record is loud (bench
-        # stderr, width_table's memory_analysis_error field, a failed
+        # Callers guard this call — a missing record is loud
+        # (width_table's memory_analysis_error field, a failed
         # perf-gate fresh-cost arm), a zeroed one is a lie.
         raise RuntimeError(
             'memory_analysis unavailable on this executable/backend — '

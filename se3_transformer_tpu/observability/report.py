@@ -1,19 +1,9 @@
-"""Aggregate JSONL streams into the round-close summary shape.
+"""Aggregate telemetry JSONL streams into one summary per run.
 
-Two input species, one output shape:
-
-  * banked bench records (BENCH_SESSION.jsonl / BLOCK_AB.jsonl /
-    BENCH_r0N.json lines — `{"metric", "value", "unit", ...}`):
-    grouped by metric label, best-of-session selection, one-sided
-    outlier flagging (host dispatch-latency spikes are strictly
-    additive, so low windows are noise, high ones are real), vs_baseline
-    carried
-    from the best record. This is the machine version of what the
-    round-close process hand-built from 30-line comment blocks.
-  * telemetry streams (schema.py records from a `--telemetry` run):
-    reduced to a bench-shaped record (metric/value/unit/vs_baseline/
-    step_ms/loss trajectory) with per-phase p50/p95 and the retrace
-    count riding along.
+A stream of schema.py records from a `--telemetry` run is reduced to
+one record per run_id: metric/value/unit/vs_baseline/step_ms, the loss
+trajectory, per-phase p50/p95, the retrace count, and the views of the
+pipeline, tune, comm, cost and profile records that rode along.
 
 Pure Python on purpose: `scripts/obs_report.py` must run without
 initializing a backend (a chip belongs to one process at a time, and
@@ -24,15 +14,10 @@ from __future__ import annotations
 import json
 from typing import List, Optional
 
-# one-sided noise gate: host-side noise only ever makes a window
-# SLOWER, so a record more than this far below its group's best is
-# flagged as a suspected-noise outlier (round 4's 199.24 vs 296 row)
-OUTLIER_RATIO = 0.85
-
 
 def load_jsonl(path: str, strict: bool = False) -> List[dict]:
-    """Parse a JSONL file. Non-JSON lines are skipped (bench session
-    logs can carry stderr interleaving) unless strict=True."""
+    """Parse a JSONL file. Non-JSON lines are skipped (a log can carry
+    stderr interleaving) unless strict=True."""
     records = []
     with open(path) as f:
         for i, line in enumerate(f):
@@ -50,62 +35,13 @@ def load_jsonl(path: str, strict: bool = False) -> List[dict]:
     return records
 
 
-def _is_bench_record(rec: dict) -> bool:
-    return 'metric' in rec and 'value' in rec and 'unit' in rec
-
-
-def summarize_bench_records(records: List[dict],
-                            code_rev: Optional[str] = None,
-                            outlier_ratio: float = OUTLIER_RATIO) -> dict:
-    """Group bench records by metric label; per group report the best
-    record (bench shape preserved), every observed value, the best
-    single timing window, and flagged outliers."""
-    recs = [r for r in records if _is_bench_record(r)]
-    if code_rev:
-        recs = [r for r in recs if r.get('code_rev') == code_rev]
-    groups = {}
-    for r in recs:
-        groups.setdefault(r['metric'], []).append(r)
-
-    out_groups = []
-    for metric in sorted(groups):
-        rs = groups[metric]
-        # an implausible-throughput record (rate above bf16 peak — the
-        # 19:29Z artifact class) never wins the group; it is flagged
-        plausible = [r for r in rs if not r.get('implausible_throughput')]
-        best = max(plausible or rs, key=lambda r: r['value'])
-        window_rates = [w for r in plausible
-                        for w in (r.get('window_rates') or [r['value']])]
-        values = sorted((r['value'] for r in rs), reverse=True)
-        outliers = sorted(
-            {r['value'] for r in rs
-             if r['value'] < outlier_ratio * best['value']
-             or r.get('implausible_throughput')})
-        g = dict(best)  # the bench record shape, verbatim
-        g.update(
-            runs=len(rs),
-            values=values,
-            window_best=max(window_rates) if window_rates
-            else best['value'],
-            outliers=outliers,
-        )
-        out_groups.append(g)
-
-    return dict(kind='bench_summary',
-                n_records=len(recs),
-                code_rev=code_rev,
-                outlier_ratio=outlier_ratio,
-                groups=out_groups)
-
-
 def summarize_telemetry(records: List[dict],
                         anchor: Optional[float] = None) -> List[dict]:
-    """Reduce telemetry stream(s) to bench-shaped run summaries.
+    """Reduce telemetry stream(s) to run summaries.
 
-    Returns one dict per run_id, in stream order, each matching the
-    bench.py record shape (metric/value/unit/vs_baseline/step_ms/
-    window_rates/steps_trained/loss trajectory) plus per-phase
-    percentiles and the retrace-warning count."""
+    Returns one dict per run_id, in stream order (metric/value/unit/
+    vs_baseline/step_ms/window_rates/steps_trained/loss trajectory)
+    plus per-phase percentiles and the retrace-warning count."""
     runs = {}
     order = []
     for rec in records:
@@ -157,8 +93,8 @@ def summarize_telemetry(records: List[dict],
                         if f.get('nodes_steps_per_sec')]
         value = summary.get('nodes_steps_per_sec')
         if value is None and window_rates:
-            # best-of-windows, the bench.py chip estimator (one-sided
-            # host noise only slows a window down)
+            # best-of-windows (one-sided host noise only slows a
+            # window down)
             value = max(window_rates)
 
         timing = summary.get('timing') or {}
@@ -375,13 +311,8 @@ def summarize_fleet_records(records: List[dict]) -> dict:
     )
 
 
-def summarize(records: List[dict], anchor: Optional[float] = None,
-              code_rev: Optional[str] = None):
-    """Auto-detect the stream species and summarize. A mixed stream is
-    summarized as bench records if any are present (telemetry runs in
-    the same file still summarize via their run_ids)."""
-    if any(_is_bench_record(r) for r in records):
-        return summarize_bench_records(records, code_rev=code_rev)
+def summarize(records: List[dict], anchor: Optional[float] = None):
+    """One run's summary, or a `telemetry_summary` of several."""
     tele = summarize_telemetry(records, anchor=anchor)
     if len(tele) == 1:
         return tele[0]

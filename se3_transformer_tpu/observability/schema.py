@@ -74,15 +74,6 @@ A stream is JSONL; every record carries `kind` and `run_id`. Kinds:
                    (forward / backward / replay, same shape as scopes),
                    kernels (per launch role) and roofline utilization
                    vs the bf16 MXU peak.
-  flash            fused-vs-XLA streaming-attention A/B
-                   (bench.flash_main via scripts/flash_smoke.py):
-                   label, fused_step_ms / unfused_step_ms and the
-                   load-bearing trio: fused_vs_unfused (step-time
-                   ratio), hbm_unfused_vs_fused (peak-HBM ratio from
-                   the PR 6 cost ledger — the activation-memory claim)
-                   and equivariance_l2_fused (the streaming kernel must
-                   still be equivariant). `make flash-smoke` gates on
-                   it and PERF_BUDGETS.json enforces both wins.
   guard            training-side fault-domain evidence for one guarded
                    run (training.guardian, exercised by
                    scripts/train_chaos_smoke.py): the counter set
@@ -127,42 +118,6 @@ A stream is JSONL; every record carries `kind` and `run_id`. Kinds:
                    serve-fleet-smoke` and obs_report --require fleet
                    gate on it, and a fleet record with an empty
                    host_transitions log proves nothing was exercised).
-  quant_ab         fp32-vs-quantized-mix serving A/B
-                   (bench.quant_main via scripts/quant_smoke.py): mix
-                   (the quant.rules precision mix), buckets (per-bucket
-                   {fp32_ms, quant_ms, quant_vs_fp32} — the
-                   latency-vs-error tradeoff banked per bucket), and
-                   the load-bearing quartet: argument_bytes_ratio
-                   (quantized/fp32 argument bytes off the PR 6 cost
-                   ledger — the per-replica memory claim),
-                   parity_max_abs (quant engine vs the fp32 REFERENCE
-                   EVALUATION of the same quantized weights — the
-                   serving path must add nothing beyond quantization
-                   itself; gated at 1e-4), quant_error_max_abs (vs the
-                   raw fp32 engine — the accuracy tradeoff, banked not
-                   hidden), equivariance_l2 (worst over the swept
-                   degrees; weight-only quantization must preserve
-                   equivariance). `make quant-smoke` gates it and
-                   PERF_BUDGETS.json enforces ratio + parity +
-                   equivariance.
-  so2_sweep        per-degree so2-vs-dense contraction A/B
-                   (bench.degrees_main via scripts/so2_smoke.py):
-                   label, degrees (per-max-degree {so2_step_ms,
-                   so2_nodes_steps_per_sec, equivariance_l2_so2 — the
-                   load-bearing gate field — and, where the dense arm
-                   ran, dense_step_ms + dense_vs_so2 + parity_l2}).
-                   `make so2-smoke` gates on it and PERF_BUDGETS.json
-                   enforces the degree-4 win + throughput floor.
-  v2_sweep         per-degree v2-vs-(v1+so2) model-family A/B
-                   (bench.v2_degrees_main via scripts/v2_smoke.py):
-                   label, degrees (per-max-degree {v2_step_ms,
-                   v2_nodes_steps_per_sec, equivariance_l2_v2 — the
-                   load-bearing gate field — v2_peak_hbm_bytes off the
-                   cost ledger, and, where the v1+so2 arm ran,
-                   so2_step_ms + so2_vs_v2 — the family A/B ratio}).
-                   `make v2-smoke` gates on it and PERF_BUDGETS.json
-                   enforces the degree-6 win + throughput floor +
-                   equivariance ceiling.
   trace            fleet-wide request-tracing evidence for one run
                    (observability.tracing.trace_record_body, exercised
                    by scripts/slo_smoke.py and the chaos smokes):
@@ -236,10 +191,9 @@ from typing import Iterable, Union
 SCHEMA_VERSION = 1
 
 KNOWN_KINDS = ('run_meta', 'step', 'flush', 'retrace_warning', 'pipeline',
-               'serve', 'tune', 'comm', 'cost', 'profile', 'so2_sweep',
-               'v2_sweep', 'flash', 'fault', 'guard', 'fleet', 'quant_ab',
-               'trace', 'slo', 'assembly', 'mesh_sweep', 'transport',
-               'summary')
+               'serve', 'tune', 'comm', 'cost', 'profile', 'fault', 'guard',
+               'fleet', 'trace', 'slo', 'assembly', 'mesh_sweep',
+               'transport', 'summary')
 
 _REQUIRED = {
     'run_meta': ('run_id', 'schema_version', 'backend', 'code_rev', 'host'),
@@ -296,13 +250,6 @@ _REQUIRED = {
     'fleet': ('run_id', 'label', 'hosts', 'host_transitions',
               'recoveries', 'cross_host_retries', 'request_failures',
               'timeouts', 'rollouts', 'rollbacks', 'lost_requests'),
-    # the memory ratio + the parity/equivariance figures are the
-    # load-bearing quartet of the quantized-serving contract: a record
-    # that cannot say the mix is smaller, implementation-faithful, AND
-    # still equivariant — with its accuracy cost banked — proves nothing
-    'quant_ab': ('run_id', 'label', 'mix', 'buckets',
-                 'argument_bytes_ratio', 'parity_max_abs',
-                 'quant_error_max_abs', 'equivariance_l2'),
     # orphan_spans + completeness_total are the load-bearing pair of
     # the tracing contract: a trace record that cannot say whether
     # every answered-or-structured-failed request produced exactly one
@@ -319,21 +266,6 @@ _REQUIRED = {
     'slo': ('run_id', 'label', 'hosts', 'availability', 'answered',
             'request_failures', 'timeouts', 'buckets', 'error_budget',
             'breaker_dwell', 'rollouts'),
-    # equivariance_l2_so2 per degree is the load-bearing field of the
-    # backend contract: a sweep record that cannot say the reduced
-    # contraction is still equivariant proves nothing about the speedup
-    'so2_sweep': ('run_id', 'label', 'degrees'),
-    # same contract for the model-family A/B: equivariance_l2_v2 per
-    # degree is load-bearing — a family sweep that cannot say the
-    # per-m parameterization is still equivariant proves nothing
-    'v2_sweep': ('run_id', 'label', 'degrees'),
-    # the ratio pair + the equivariance figure are the load-bearing
-    # trio of the streaming-attention contract: a flash record that
-    # cannot say whether the fused arm was faster, smaller, AND still
-    # equivariant proves nothing
-    'flash': ('run_id', 'label', 'fused_step_ms', 'unfused_step_ms',
-              'fused_vs_unfused', 'hbm_unfused_vs_fused',
-              'equivariance_l2_fused'),
     # the large-assembly serving contract (kNN-free global attention):
     # the memory ratio vs the materialized control arm, parity,
     # equivariance, AND proof the request was actually served through
@@ -796,15 +728,6 @@ def validate_record(rec: dict, index=None) -> dict:
             _fail(index, f'profile.device_time_ms must be a '
                          f'non-negative number, got '
                          f'{rec["device_time_ms"]!r}')
-    if kind == 'flash':
-        for field in ('fused_step_ms', 'unfused_step_ms',
-                      'fused_vs_unfused', 'hbm_unfused_vs_fused',
-                      'equivariance_l2_fused'):
-            val = rec[field]
-            if not isinstance(val, (int, float)) or isinstance(val, bool) \
-                    or val < 0:
-                _fail(index, f'flash.{field} must be a non-negative '
-                             f'number, got {val!r}')
     if kind == 'assembly':
         for field in ('n', 'bucket', 'post_warmup_compiles'):
             val = rec[field]
@@ -879,28 +802,6 @@ def validate_record(rec: dict, index=None) -> dict:
                 if missing:
                     _fail(index, f'axis_collectives[{label!r}][{cls!r}] '
                                  f'missing {missing}')
-    if kind == 'quant_ab':
-        if not isinstance(rec['mix'], str) or not rec['mix']:
-            _fail(index, f'quant_ab.mix must be a non-empty string, '
-                         f'got {rec["mix"]!r}')
-        buckets = rec['buckets']
-        if not isinstance(buckets, dict) or not buckets:
-            _fail(index, 'quant_ab.buckets must be a non-empty object '
-                         '(bucket -> per-arm latency entry)')
-        for bucket, entry in buckets.items():
-            missing = [k for k in ('fp32_ms', 'quant_ms', 'quant_vs_fp32')
-                       if not isinstance(entry, dict) or k not in entry]
-            if missing:
-                _fail(index, f'quant_ab.buckets[{bucket!r}] missing '
-                             f'{missing} (the per-bucket latency A/B IS '
-                             f'the tradeoff record)')
-        for field in ('argument_bytes_ratio', 'parity_max_abs',
-                      'quant_error_max_abs', 'equivariance_l2'):
-            val = rec[field]
-            if not isinstance(val, (int, float)) or isinstance(val, bool) \
-                    or val < 0:
-                _fail(index, f'quant_ab.{field} must be a non-negative '
-                             f'number, got {val!r}')
     if kind == 'trace':
         for field in ('traces', 'complete_trees', 'orphan_spans',
                       'spans_total', 'retry_hops', 'redispatch_hops',
@@ -971,48 +872,6 @@ def validate_record(rec: dict, index=None) -> dict:
                 or not isinstance(rollouts.get('rollbacks'), int):
             _fail(index, f'slo.rollouts must carry int count and '
                          f'rollbacks, got {rollouts!r}')
-    if kind == 'so2_sweep':
-        degrees = rec['degrees']
-        if not isinstance(degrees, dict) or not degrees:
-            _fail(index, 'so2_sweep.degrees must be a non-empty object '
-                         '(max degree -> A/B entry)')
-        for deg, entry in degrees.items():
-            if not isinstance(entry, dict):
-                _fail(index, f'degrees[{deg!r}] must be an object')
-            for field in ('so2_step_ms', 'so2_nodes_steps_per_sec',
-                          'equivariance_l2_so2'):
-                val = entry.get(field)
-                if not isinstance(val, (int, float)) or val < 0 \
-                        or isinstance(val, bool):
-                    _fail(index, f'degrees[{deg!r}].{field} must be a '
-                                 f'non-negative number, got {val!r}')
-            if 'dense_step_ms' in entry and \
-                    not isinstance(entry.get('dense_vs_so2'),
-                                   (int, float)):
-                _fail(index, f'degrees[{deg!r}] carries dense_step_ms '
-                             f'but no numeric dense_vs_so2 — the A/B '
-                             f'ratio IS the record')
-    if kind == 'v2_sweep':
-        degrees = rec['degrees']
-        if not isinstance(degrees, dict) or not degrees:
-            _fail(index, 'v2_sweep.degrees must be a non-empty object '
-                         '(max degree -> A/B entry)')
-        for deg, entry in degrees.items():
-            if not isinstance(entry, dict):
-                _fail(index, f'degrees[{deg!r}] must be an object')
-            for field in ('v2_step_ms', 'v2_nodes_steps_per_sec',
-                          'equivariance_l2_v2'):
-                val = entry.get(field)
-                if not isinstance(val, (int, float)) or val < 0 \
-                        or isinstance(val, bool):
-                    _fail(index, f'degrees[{deg!r}].{field} must be a '
-                                 f'non-negative number, got {val!r}')
-            if 'so2_step_ms' in entry and \
-                    not isinstance(entry.get('so2_vs_v2'),
-                                   (int, float)):
-                _fail(index, f'degrees[{deg!r}] carries so2_step_ms '
-                             f'but no numeric so2_vs_v2 — the family '
-                             f'A/B ratio IS the record')
     if kind in ('flush', 'summary'):
         timing = rec['timing']
         if not isinstance(timing, dict):

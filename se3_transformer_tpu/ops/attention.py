@@ -278,8 +278,8 @@ class AttentionSE3(nn.Module):
                     global_feats: Optional[Features],
                     pos_emb) -> Features:
         """The streaming-kernel path: same parameters, same function as
-        the unfused path above (parity-gated in tests/test_flash.py and
-        `make flash-smoke`) — but the per-edge basis, the
+        the unfused path above (parity-gated in tests/test_flash.py)
+        — but the per-edge basis, the
         gathered/keyed features, and the score tensor are built per
         VMEM tile inside kernels.pallas_flash instead of in HBM."""
         from ..kernels.pallas_flash import flash_attention

@@ -11,7 +11,7 @@ fuses into the consumers (LinearSE3's einsum, the radial-contract
 Pallas/XLA paths, the flash kernel's in-tile radial matmul), so the
 full-precision weights never materialize on device; every shipped mix
 is gated on the equivariance-L2 harness + quantized-vs-fp32 parity
-(`make quant-smoke`, tests/test_quant.py).
+(tests/test_quant.py).
 
     from se3_transformer_tpu import quant
     qparams, report = quant.quantize_params(params, 'int8_mix')

@@ -53,14 +53,14 @@ def toy_denoise() -> SE3TransformerModule:
 def flagship(dim: int = 64, num_neighbors: int = 32,
              valid_radius: float = 1e5, depth: int = 6,
              **overrides) -> SE3TransformerModule:
-    """overrides: extra SE3TransformerModule fields (e.g. a denoise bench
+    """overrides: extra SE3TransformerModule fields (e.g. a denoise run
     passes output_degrees=2, reduce_dim_out=True for a vector head —
     the default output_degrees=1 model is scalar-out).
 
     Memory: a dim=64 deg-4 TRAINING step at 1024 nodes needs ~24 GB of
     HBM un-checkpointed (the [E, P, sum c_in*F] edge tensors of all 6
-    blocks' convs are saved for the backward; measured OOM on a 16 GB
-    v5e, round-3 session log) — so the flagship recipe is defined WITH
+    blocks' convs are saved for the backward; out of memory on a 16 GB
+    v5e) — so the flagship recipe is defined WITH
     reversible=True (per-block remat) and edge_chunks=8 (the edge
     contraction streams in remat'd node chunks): that is what 'fits one
     v5e' means here."""
@@ -83,20 +83,16 @@ def flagship_fast(dim: int = 64, num_neighbors: int = 32,
     HBM, forward or backward (the kernels rebuild its rows in VMEM from
     the flat basis; dx leaves backward A), and after the MXU one-hot
     gather fix the whole
-    dim=64/n=1024 reversible training step fits one 16 GB v5e outright.
-    Measured on chip (round 4; record deleted with PR 21): edge_chunks=8 ->
-    309.3, =2 -> 322.3, unchunked -> 394.28 nodes*steps/s — the chunk
-    streaming's lax.map tax costs 27%.
+    dim=64/n=1024 reversible training step fits one 16 GB v5e outright,
+    and the chunk streaming's lax.map costs time the fit no longer needs.
 
-    Round-4 third wave: remat_policy='save_conv_outputs' is the default
-    — the reversible backward replay stores the ConvSE3 outputs
-    (~1.7 GB) instead of re-running the radial contraction. Measured
-    on chip (idle host, hardened fetch_sync timing): 416.1 -> 529.5
-    nodes*steps/s (+27%); loss trajectory and reduced-twin equivariance
-    identical. The conservative flagship stays policy-free both as the
-    guaranteed-fit memory recipe at any width (the saved outputs scale
-    with dim; no fuse_basis => V2 materializes per chunk) and as the
-    stable round-over-round RECORD definition."""
+    remat_policy='save_conv_outputs' is the default: the reversible
+    backward replay stores the ConvSE3 outputs (~1.7 GB) instead of
+    re-running the radial contraction (`replay_ms_per_step.train` in the
+    benchmark's d4 cell reads what is left of the replay). The
+    conservative flagship stays policy-free as the guaranteed-fit memory
+    recipe at any width (the saved outputs scale with dim; no
+    fuse_basis => V2 materializes per chunk)."""
     overrides.setdefault('reversible', True)
     overrides.setdefault('edge_chunks', None)
     if overrides['reversible']:  # the policy is meaningless (and raises)

@@ -1,8 +1,4 @@
 from .serialization import save_params, load_params
-from .observability import (
-    MetricAccumulator, MetricLogger, PhaseTimer, RetraceWatchdog,
-    named_scope, profile_trace,
-)
 from .helpers import (
     exists, default, uniq, to_order, map_values, safe_cat, cast_tuple,
     batched_index_select, masked_mean, fourier_encode, broadcat, benchmark,

@@ -6,7 +6,7 @@ every process after the first start hot. The cache directory is part of
 the cache key, so it must never move: `JAX_COMPILATION_CACHE_DIR`, when
 set, places it (JAX reads the variable itself — nothing here overrides
 it); otherwise it is one fixed directory inside the checkout. Called by
-chip_smoke.py, bench.py, denoise.py and the scripts; users can call it
+chip_smoke.py, denoise.py and the scripts; users can call it
 once at program start.
 """
 from __future__ import annotations

@@ -64,7 +64,7 @@ def batched_index_select(values: jnp.ndarray, indices: jnp.ndarray, axis: int = 
     Equivalent of reference utils.py:56 (batched_index_select) expressed with
     jnp.take_along_axis so XLA lowers it to a single gather.
 
-    CONTRACT (ADVICE r3 #1): indices must be IN-RANGE [0, n) and `values`
+    CONTRACT: indices must be IN-RANGE [0, n) and `values`
     FINITE. On TPU, large float gathers dispatch to a one-hot MXU matmul
     (`_onehot_gather`) whose semantics diverge from the CPU take path
     exactly outside this contract: OOB indices yield zero rows (take
@@ -264,17 +264,3 @@ def fetch_sync_tail(tree) -> None:
     leaves = jax.tree_util.tree_leaves(tree)
     if leaves:
         _np.asarray(leaves[0].ravel()[:1])
-
-
-def loss_trajectory_fields(losses) -> dict:
-    """Training-sanity fields shared by every banked perf record
-    (bench.py, scripts/run_baselines.py): a fast-but-diverging run must
-    be visible from the JSON alone (VERDICT r4 next #4). One definition
-    so the two record streams can never silently disagree."""
-    import numpy as np
-    return dict(
-        loss_first=round(float(losses[0]), 4),
-        loss_last=round(float(losses[-1]), 4),
-        loss_decreased=bool(losses[-1] < losses[0])
-        and bool(np.all(np.isfinite(losses))),
-    )

@@ -1,4 +1,5 @@
-"""Runtime validation helpers shared by bench.py and scripts/tpu_checks.py."""
+"""Runtime validation helpers shared by the smokes, scripts/tpu_checks.py
+and the tests."""
 from __future__ import annotations
 
 import jax

@@ -64,8 +64,7 @@ EdgeInfo = Tuple[Optional[jnp.ndarray], Optional[jnp.ndarray],
 # v2's compact default trunk width: the per-m blocks are [mid, 2C, O]
 # instead of v1's [mid, C*F, O], so the trunk that feeds them can be
 # narrow without starving the contraction (EquiformerV2 uses the same
-# regime). This is the main measured lever behind the degree-6 win in
-# V2_SWEEP.jsonl.
+# regime).
 DEFAULT_V2_MID_DIM = 32
 
 

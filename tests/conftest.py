@@ -103,9 +103,6 @@ _HEAVY_TESTS = {
     'test_dataset_feeds_model',
     'test_ring_knn_feeds_model',
     'test_global_feats_dict_input',
-    'test_toy_keeps_frozen_single_window',
-    'test_record_schema',
-    'test_rate_consistent_with_step_ms',
     # pipeline tier (PR 3): the trainer-backed pipeline tests compile
     # the denoise model (re-measure with --durations after re-tiering)
     'test_donated_batch_matches_non_donated_and_resumes',
@@ -128,7 +125,7 @@ _HEAVY_TESTS = {
     'test_flash_fused_pairwise_quantized_matches_unfused',
     'test_quantized_equivariance_degrees_2_4',
     'test_engine_restore_time_quantization_and_mix_parity',
-    'test_engine_fp8_mix_if_available',
+    'test_engine_from_params_mix_parity_and_argument_bytes',
     'test_fsdp_sharded_opt_state_train_and_restore',
     # guardian tier (PR 14): the rollback-parity and kill-and-resume
     # proofs each run a control arm + a chaos arm of the toy trainer

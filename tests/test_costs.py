@@ -341,14 +341,26 @@ def test_obs_report_require_cost_profile(tmp_path, scripts_path, capsys):
     capsys.readouterr()
 
 
-def test_obs_report_legacy_flags_alias_require(tmp_path, scripts_path,
-                                               capsys):
+def test_obs_report_require_comm(tmp_path, scripts_path, capsys):
     import obs_report
-    comm = _stream(tmp_path / 'comm.jsonl', [dict(
-        kind='comm', sp=2, ring_steps=2, overlap=True, exchange=True,
-        collectives={}, full_width_all_gathers=[], all_gather_free=True)])
-    assert obs_report.main([comm, '--require-comm']) == 0
-    assert obs_report.main([comm, '--require', 'comm']) == 0
+
+    def comm(name, **over):
+        body = dict(
+            kind='comm', sp=2, ring_steps=2, overlap=True, exchange=True,
+            collectives={}, full_width_all_gathers=[],
+            all_gather_free=True)
+        body.update(over)
+        return _stream(tmp_path / name, [body])
+
+    assert obs_report.main([comm('clean.jsonl'), '--require', 'comm']) == 0
+    # an exchange arm that still gathers full width fails the gate, and
+    # so does a stream whose only arm is the dense control
+    assert obs_report.main([
+        comm('dirty.jsonl', all_gather_free=False,
+             full_width_all_gathers=['f32[1,64,8]']),
+        '--require', 'comm']) == 1
+    assert obs_report.main([comm('dense.jsonl', exchange=False),
+                            '--require', 'comm']) == 1
     capsys.readouterr()
 
 
@@ -360,8 +372,9 @@ def gate(tmp_path, scripts_path):
     import perf_gate
 
     budgets = dict(version=1, default_margin=0.1, budgets=[
-        dict(name='tput_floor', kind='bench',
-             match={'metric': 'toy'}, field='value', min=100.0),
+        dict(name='admissions_floor', kind='serve',
+             match={'label': 'toy'}, field='continuous_admissions',
+             min=100.0),
         dict(name='mem_ceiling', kind='cost',
              match={'label': 'toy'}, field='peak_bytes',
              max=1000, margin=0.2),
@@ -386,7 +399,7 @@ def gate(tmp_path, scripts_path):
 
 
 GOOD = [
-    dict(metric='toy(run)', value=150.0, unit='u'),
+    dict(kind='serve', label='toy(run)', continuous_admissions=150.0),
     dict(kind='cost', label='toy', peak_bytes=900),
     dict(kind='comm', exchange=True, all_gather_free=True,
          collectives={}),
@@ -416,9 +429,9 @@ def test_perf_gate_latest_record_wins(gate, capsys):
 
 def test_perf_gate_margin_is_applied(gate, capsys):
     # min 100 at margin 10% -> floor 90
-    edge = [dict(GOOD[0], value=91.0)] + GOOD[1:]
+    edge = [dict(GOOD[0], continuous_admissions=91.0)] + GOOD[1:]
     assert gate(edge) == 0
-    below = [dict(GOOD[0], value=89.0)] + GOOD[1:]
+    below = [dict(GOOD[0], continuous_admissions=89.0)] + GOOD[1:]
     assert gate(below) == 1
     capsys.readouterr()
 
@@ -429,9 +442,9 @@ def test_perf_gate_injection_fires_every_budget(gate, capsys):
 
 
 def test_perf_gate_skip_vs_strict(gate, capsys):
-    only_bench = [GOOD[0]]
-    assert gate(only_bench) == 0                       # others skip
-    assert gate(only_bench, extra=('--strict',)) == 1  # skips fail
+    only_serve = [GOOD[0]]
+    assert gate(only_serve) == 0                       # others skip
+    assert gate(only_serve, extra=('--strict',)) == 1  # skips fail
     out = capsys.readouterr().out
     assert '[SKIP]' in out
 
@@ -533,6 +546,54 @@ def test_perf_gate_committed_budgets_are_loadable(scripts_path):
     for b in spec['budgets']:
         assert b.get('name') and b.get('kind') and b.get('field')
         assert sum(k in b for k in ('min', 'max', 'equals')) == 1
+
+
+ROOT = os.path.dirname(SCRIPTS)
+# the root's record files, each banked by a smoke and judged by a
+# committed budget: scripts/perf_gate.py's DEFAULT_RECORDS
+COMMITTED_RECORDS = (
+    'WIDTH_TABLE.jsonl', 'SERVE_MULTI.jsonl', 'CHAOS_SMOKE.jsonl',
+    'TRAIN_CHAOS.jsonl', 'FLEET_CHAOS.jsonl', 'SLO_SMOKE.jsonl',
+    'ASSEMBLY_SWEEP.jsonl', 'MESH_SWEEP.jsonl', 'TRANSPORT_AB.jsonl')
+
+
+def _gate_lines(perf_gate, capsys, paths):
+    """(rc, {budget name: 'ok' | 'FAIL' | 'SKIP'}) of the committed
+    budgets over `paths`."""
+    import re
+    capsys.readouterr()
+    rc = perf_gate.main(list(paths))
+    verdicts = {name: tag for tag, name in re.findall(
+        r'^\[ *(\w+) *\] ([^:]+):', capsys.readouterr().out, re.M)}
+    return rc, verdicts
+
+
+@pytest.mark.parametrize('name', COMMITTED_RECORDS)
+def test_committed_record_passes_committed_budgets(name, scripts_path,
+                                                   capsys):
+    import perf_gate
+    rc, verdicts = _gate_lines(perf_gate, capsys,
+                               [os.path.join(ROOT, name)])
+    assert rc == 0, verdicts
+    assert 'ok' in verdicts.values(), f'{name}: no budget judged'
+
+
+def test_every_committed_budget_is_judged_by_a_committed_record(
+        scripts_path, capsys):
+    import perf_gate
+    assert set(perf_gate.DEFAULT_RECORDS) == set(COMMITTED_RECORDS)
+    rc, verdicts = _gate_lines(
+        perf_gate, capsys,
+        [os.path.join(ROOT, name) for name in COMMITTED_RECORDS])
+    assert rc == 0, verdicts
+    with open(perf_gate.DEFAULT_BUDGETS) as f:
+        kinds = {b['name']: b['kind'] for b in json.load(f)['budgets']}
+    assert set(verdicts) == set(kinds)
+    # judged on a fresh record, not a banked one: `cost` by `make
+    # perf-gate` (--fresh-cost); `comm` is what `make ring-smoke`
+    # streams, and no committed file holds one
+    skipped = {kinds[n] for n, tag in verdicts.items() if tag == 'SKIP'}
+    assert skipped == {'cost', 'comm'}, verdicts
 
 
 # --------------------------------------------------------------------- #

@@ -302,8 +302,7 @@ _MODEL_KW = dict(dim=8, depth=1, num_degrees=2, output_degrees=2,
 @pytest.mark.parametrize('backend', ['dense', 'so2'])
 def test_model_fused_matches_unfused(backend):
     """Identical params, masked batch: fuse_pairwise == unfused trunk
-    (the end-to-end parity the flash-smoke gate enforces at 1e-4; here
-    the tolerance is roundoff)."""
+    (the end-to-end parity; the tolerance is roundoff)."""
     feats, coors, mask = _model_inputs()
     unf = SE3TransformerModule(conv_backend=backend, **_MODEL_KW)
     fus = SE3TransformerModule(conv_backend=backend, fuse_pairwise=True,
@@ -324,22 +323,38 @@ def test_model_fused_matches_unfused(backend):
     assert float(jnp.abs(o1 - o2).max()) < 1e-5
 
 
-def test_model_fused_grads_match_unfused():
-    feats, coors, mask = _model_inputs()
-    unf = SE3TransformerModule(**_MODEL_KW)
-    fus = SE3TransformerModule(fuse_pairwise=True, **_MODEL_KW)
+@pytest.mark.parametrize('n, k, num_degrees, peak_ratio', [
+    (20, 5, 2, None),
+    # where the per-edge tensors outweigh the node-resident ones the
+    # streaming arm's step must be the smaller one: the basis, the
+    # keyed features and the scores never exist, and its custom_vjp
+    # saves inputs only (2.0x here by XLA's static analysis)
+    (64, 12, 4, 1.5)])
+def test_model_fused_grads_match_unfused(n, k, num_degrees, peak_ratio):
+    feats, coors, mask = _model_inputs(n)
+    kw = dict(_MODEL_KW, num_neighbors=k, num_degrees=num_degrees)
+    unf = SE3TransformerModule(**kw)
+    fus = SE3TransformerModule(fuse_pairwise=True, **kw)
     params = jax.jit(fus.init, static_argnames=('return_type',))(
         jax.random.PRNGKey(0), feats, coors, mask=mask,
         return_type=1)['params']
 
-    def loss(mod):
-        return lambda p: (mod.apply({'params': p}, feats, coors,
-                                    mask=mask, return_type=1) ** 2).mean()
-    g1 = jax.grad(loss(unf))(params)
-    g2 = jax.grad(loss(fus))(params)
+    def step(mod):
+        def loss(p):
+            return (mod.apply({'params': p}, feats, coors, mask=mask,
+                              return_type=1) ** 2).mean()
+        return jax.jit(jax.grad(loss)).lower(params).compile()
+    steps = [step(unf), step(fus)]
+    g1, g2 = (s(params) for s in steps)
     for a, b in zip(jax.tree_util.tree_leaves(g1),
                     jax.tree_util.tree_leaves(g2)):
         assert float(jnp.abs(a - b).max()) < 1e-5
+    if peak_ratio is not None:
+        peak_unf, peak_fus = (
+            m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes
+            for m in (s.memory_analysis() for s in steps))
+        assert peak_unf >= peak_ratio * peak_fus, (peak_unf, peak_fus)
 
 
 def test_model_per_block_selection_mirrors_conv_backend():
@@ -420,34 +435,20 @@ def test_model_fused_reversible_trunk_composes():
         assert float(jnp.abs(a - b).max()) < 1e-5
 
 
-@pytest.mark.slow
-def test_model_fused_so2_equivariance_degree6():
-    """The so2 arm's whole point: fused attention at degree 6 without a
-    dense basis, equivariant to the repo bar."""
+@pytest.mark.parametrize('backend, num_degrees', [
+    ('dense', 4),
+    pytest.param('so2', 7, marks=pytest.mark.slow)])
+def test_model_fused_equivariance(backend, num_degrees):
+    """The streaming path is equivariant to the repo bar: the dense arm
+    at degree 3, and the so2 arm's whole point, fused attention at
+    degree 6 without a dense basis."""
     from se3_transformer_tpu.utils.validation import equivariance_l2
     feats, coors, mask = _model_inputs()
-    fus = SE3TransformerModule(conv_backend='so2', fuse_pairwise=True,
+    fus = SE3TransformerModule(conv_backend=backend, fuse_pairwise=True,
                                tie_key_values=True,
-                               **{**_MODEL_KW, 'num_degrees': 7})
+                               **{**_MODEL_KW, 'num_degrees': num_degrees})
     params = jax.jit(fus.init, static_argnames=('return_type',))(
         jax.random.PRNGKey(0), feats, coors, mask=mask,
         return_type=1)['params']
     eq = equivariance_l2(fus, params, feats, coors, mask)
-    assert eq < 1e-4, f'so2-arm fused equivariance {eq} at degree 6'
-
-
-def test_flash_record_schema_roundtrip():
-    from se3_transformer_tpu.observability.schema import (
-        SchemaError, validate_record,
-    )
-    rec = dict(kind='flash', run_id='r', label='flash_ab',
-               fused_step_ms=10.0, unfused_step_ms=12.0,
-               fused_vs_unfused=1.2, hbm_unfused_vs_fused=2.5,
-               equivariance_l2_fused=1e-7)
-    validate_record(rec)
-    bad = dict(rec)
-    bad.pop('hbm_unfused_vs_fused')
-    with pytest.raises(SchemaError):
-        validate_record(bad)
-    with pytest.raises(SchemaError):
-        validate_record(dict(rec, equivariance_l2_fused=-1.0))
+    assert eq < 1e-4, f'{backend}-arm fused equivariance {eq}'

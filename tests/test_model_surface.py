@@ -307,3 +307,53 @@ def test_module_field_count_and_the_benchmark_configs_keys():
     cfg = json.loads((pathlib.Path(__file__).parent.parent / 'benchmark'
                       / 'configs' / 'd4-onehead-train.json').read_text())
     assert set(cfg['overrides']) <= fields, set(cfg['overrides']) - fields
+
+
+def test_makefile_recipes_and_docs_name_files_that_exist():
+    """Every `python <path>` of a Makefile recipe, and every script,
+    root record file, root program and `make` target (in backticks, or
+    opening a line of a code block) that README.md and docs/*.md name,
+    is in the tree: a deletion takes its
+    mentions with it."""
+    import pathlib
+    import re
+    root = pathlib.Path(__file__).parent.parent
+
+    makefile = (root / 'Makefile').read_text()
+    recipes = [line for line in makefile.splitlines()
+               if line.startswith('\t')]
+    targets = set(re.findall(r'^([a-z][\w-]*):', makefile, re.M))
+    named = {('Makefile', path) for line in recipes
+             for path in re.findall(r'python3? ([\w./-]+\.py)\b', line)}
+    assert len(named) >= 15, named
+    # `scripts/x.py` anywhere; a `*.py`, `*.json` or `*.jsonl` where the
+    # name stands alone (no directory before it, no `*` or `<` in it)
+    in_docs = re.compile(
+        r'(?<![\w./*<>-])((?:scripts/)?[A-Za-z_][\w-]*\.(?:py|jsonl?))\b')
+    no_target = set()
+    for doc in [root / 'README.md', *sorted((root / 'docs').glob('*.md'))]:
+        text = doc.read_text()
+        named |= {(doc.name, path) for path in in_docs.findall(text)}
+        made = re.findall(
+            r'`make ([a-z][\w-]*)|^make ([a-z][\w-]*) +#', text, re.M)
+        no_target |= {(doc.name, t) for pair in made for t in pair
+                      if t and t not in targets}
+    assert not no_target, sorted(no_target)
+    # a bare name may also be a module or a configuration spoken of
+    # without its directory
+    inside = {p.name for d in ('se3_transformer_tpu', 'scripts', 'tests',
+                               'benchmark', 'examples')
+              for p in (root / d).rglob('*.*')}
+    not_ours = {
+        # the reference repository's files (docs/PARITY.md), a model
+        # hub's, a usage line's placeholder
+        'irr_repr.py', 'se3_transformer_pytorch.py', 'reversible.py',
+        'utils.py', 'config.json', 'COMM.jsonl',
+        # written beside a checkpoint at run time
+        'guard_state.json'}
+
+    def known(path):
+        return (root / path).exists() or (
+            '/' not in path and path in inside | not_ours)
+    missing = sorted(item for item in named if not known(item[1]))
+    assert not missing, missing

@@ -1,9 +1,9 @@
 """Telemetry subsystem tests (observability package): accumulator-under-
 jit numerics vs a numpy reference, the one-sync-per-flush contract,
 retrace watchdog behaviour, logger schema/context-manager/mirror fixes,
-and obs_report reproducing the round-5 best-of-two numbers from a
-checked-in fixture. All CPU-only and cheap (tiny jitted fns — the one
-model-level test uses the smallest trainable config)."""
+and the run summary obs_report renders. All CPU-only and cheap (tiny
+jitted fns — the one model-level test uses the smallest trainable
+config)."""
 import json
 import os
 import warnings
@@ -19,14 +19,11 @@ from se3_transformer_tpu.observability import (
 )
 from se3_transformer_tpu.observability import metrics as obs_metrics
 from se3_transformer_tpu.observability.report import (
-    load_jsonl, summarize, summarize_bench_records, summarize_telemetry,
+    load_jsonl, summarize, summarize_telemetry,
 )
 from se3_transformer_tpu.observability.schema import (
     SchemaError, validate_record, validate_stream,
 )
-
-FIXTURE = os.path.join(os.path.dirname(__file__), 'fixtures',
-                       'bench_round5.jsonl')
 
 
 # --------------------------------------------------------------------- #
@@ -265,60 +262,7 @@ def test_schema_rejects_malformed_records():
 # --------------------------------------------------------------------- #
 # report / obs_report
 # --------------------------------------------------------------------- #
-def test_obs_report_reproduces_round5_best_of_two():
-    """The checked-in fixture holds the six round-5 session records
-    (code_rev 4fff503): the summary's per-group best values must equal
-    the round-5 anchors the round close hand-selected — conservative
-    337.07 (the idle-host block_ab arm beat the bench-stage 331.11),
-    fast 536.76, and the cb16 A/B arms."""
-    recs = load_jsonl(FIXTURE)
-    summary = summarize_bench_records(recs)
-    assert summary['n_records'] == 6
-    by_metric = {g['metric']: g for g in summary['groups']}
-
-    cons = by_metric['denoise_train_nodes_steps_per_sec_per_chip'
-                     '(flagship,dim=64,depth=6,n=1024,deg=4,k=32,'
-                     'backend=tpu)']
-    assert cons['value'] == 337.07          # bench.py RECORD anchor
-    assert cons['runs'] == 3
-    assert cons['values'] == [337.07, 332.51, 331.11]
-    assert cons['window_best'] == 337.07
-    assert cons['outliers'] == []           # all within the noise gate
-
-    fast = by_metric['denoise_train_nodes_steps_per_sec_per_chip'
-                     '(flagship_fast,dim=64,depth=6,n=1024,deg=4,k=32,'
-                     'backend=tpu,fast)']
-    assert fast['value'] == 536.76          # bench.py FAST_RECORD anchor
-    assert fast['equivariance_l2'] == pytest.approx(1.074e-06, rel=1e-3)
-
-    cb16 = by_metric['denoise_train_nodes_steps_per_sec_per_chip'
-                     '(flagship,dim=64,depth=6,cb16,n=1024,deg=4,k=32,'
-                     'backend=tpu)']
-    assert cb16['value'] == 383.34
-
-    # every record in the fixture is pinned to the round-5 tree hash
-    rev = '4fff5033a376139b437500b2ce6eb432810e46b4'
-    assert summarize_bench_records(recs, code_rev=rev)['n_records'] == 6
-    assert summarize_bench_records(recs, code_rev='bogus')['groups'] == []
-
-
-def test_report_flags_one_sided_outliers():
-    recs = [dict(metric='m(x)', value=300.0, unit='u', vs_baseline=1.0),
-            dict(metric='m(x)', value=297.0, unit='u', vs_baseline=1.0),
-            # a host-latency-poisoned window: far below best
-            dict(metric='m(x)', value=199.0, unit='u', vs_baseline=0.66),
-            # an impossible rate: flagged regardless of magnitude
-            dict(metric='m(x)', value=2487.0, unit='u', vs_baseline=9.4,
-                 implausible_throughput=True)]
-    g = summarize_bench_records(recs)['groups'][0]
-    # the implausible record never wins the group; both bad rows flagged
-    assert g['value'] == 300.0
-    assert 199.0 in g['outliers'] and 2487.0 in g['outliers']
-    assert 297.0 not in g['outliers']
-    assert g['values'][0] == 2487.0  # every observed value still listed
-
-
-def test_summarize_telemetry_matches_bench_shape(tmp_path):
+def test_summarize_telemetry_run_record_shape(tmp_path):
     path = str(tmp_path / 'tele.jsonl')
     with MetricLogger(path, mirror=None) as lg:
         lg.log_record(
@@ -344,8 +288,6 @@ def test_summarize_telemetry_matches_bench_shape(tmp_path):
     runs = summarize_telemetry(load_jsonl(path))
     assert len(runs) == 1
     r = runs[0]
-    # the bench.py record shape (test_bench_record.py::test_record_schema
-    # checks the same keys on real bench output)
     assert r['metric'].startswith('denoise_train_nodes_steps_per_sec')
     assert 'backend=' in r['metric'] and 'denoise,test' in r['metric']
     assert r['value'] == 500.0
@@ -358,22 +300,13 @@ def test_summarize_telemetry_matches_bench_shape(tmp_path):
     # vs an anchor
     anchored = summarize_telemetry(load_jsonl(path), anchor=250.0)[0]
     assert anchored['vs_baseline'] == 2.0
-    # summarize() auto-detects the species and unwraps the single run
+    # summarize() unwraps the single run
     assert summarize(load_jsonl(path))['value'] == 500.0
 
 
 # --------------------------------------------------------------------- #
-# shim + trainer end-to-end
+# trainer end-to-end
 # --------------------------------------------------------------------- #
-def test_utils_observability_shim_reexports():
-    from se3_transformer_tpu import observability as pkg
-    from se3_transformer_tpu.utils import observability as shim
-    assert shim.MetricLogger is pkg.MetricLogger
-    assert shim.named_scope is pkg.named_scope
-    assert shim.profile_trace is pkg.profile_trace
-    assert shim.MetricAccumulator is pkg.MetricAccumulator
-
-
 def test_trainer_telemetry_end_to_end(tmp_path, monkeypatch):
     """Telemetry through the real DenoiseTrainer (smallest trainable
     config): schema-valid stream, per-phase p50/p95 in every flush, zero

@@ -971,14 +971,12 @@ def test_fuse_basis_composes_with_edge_chunks_and_radial_bf16():
 
 
 def test_pairwise_block_picker_production_validated_picks():
-    """Pin the picker outputs the END-TO-END bench validated (round 4):
-    the conservative flagship's chunked plain contraction runs at
-    (512, 8) — a sweep-derived flip to (256, 32) measured 2.7x SLOWER
-    end-to-end (BENCH_SESSION.jsonl 294.97 -> 107.51, commit d0cd10d,
-    reverted) although the STANDALONE kernel sweep ranks those blocks
-    the other way around. Changing these picks requires a new on-chip
-    bench A/B, not a kernel-level sweep; see the _pick_blocks
-    docstring."""
+    """Pin the picker outputs the step validated: the conservative
+    flagship's chunked plain contraction runs at (512, 8) — a
+    sweep-derived flip to (256, 32) ran 2.7x slower in the step
+    although the kernel alone ranks those blocks the other way around.
+    Changing these picks needs a run of the benchmark's cell on the
+    chip, not a kernel-level sweep; see the _pick_blocks docstring."""
     from se3_transformer_tpu.kernels.pallas_pairwise import (
         _pick_blocks, _pick_blocks_bx,
     )

@@ -153,29 +153,6 @@ def test_concat_weights_quantized_and_mixed():
         np.concatenate([quant.dequantize(a), plain], axis=1), atol=1e-7)
 
 
-def test_schema_quant_ab_record():
-    from se3_transformer_tpu.observability.schema import (
-        SchemaError, validate_record,
-    )
-    rec = dict(kind='quant_ab', run_id='r', label='l', mix='int8_mix',
-               buckets={'12': dict(fp32_ms=1.0, quant_ms=1.1,
-                                   quant_vs_fp32=0.9)},
-               argument_bytes_ratio=0.28, parity_max_abs=5e-7,
-               quant_error_max_abs=5e-3, equivariance_l2=2e-7)
-    validate_record(rec)
-    for field in ('mix', 'parity_max_abs', 'argument_bytes_ratio'):
-        bad = dict(rec)
-        del bad[field]
-        with pytest.raises(SchemaError):
-            validate_record(bad)
-    bad = dict(rec, buckets={'12': dict(fp32_ms=1.0)})
-    with pytest.raises(SchemaError):
-        validate_record(bad)
-    bad = dict(rec, parity_max_abs=-1.0)
-    with pytest.raises(SchemaError):
-        validate_record(bad)
-
-
 # --------------------------------------------------------------------- #
 # kernel: the Pallas scale-column epilogue (interpret mode)
 # --------------------------------------------------------------------- #
@@ -427,19 +404,38 @@ def test_engine_restore_time_quantization_and_mix_parity(toy, tmp_path):
         stats['quant']['params_bytes_fp32']
 
 
-def test_engine_fp8_mix_if_available(toy):
-    if quant.fp8_dtype() is None:
+@pytest.mark.parametrize('mix', ['int8_mix', 'fp8_mix'])
+def test_engine_from_params_mix_parity_and_argument_bytes(toy, mix):
+    """An engine built from a param tree (no checkpoint): its compiled
+    buckets take at most 0.6x the fp32 engine's argument bytes, and it
+    adds nothing to quantization itself — padded and unpadded rows
+    agree with the fp32 evaluation of the same quantized weights."""
+    if mix == 'fp8_mix' and quant.fp8_dtype() is None:
         pytest.skip('no fp8-e4m3 dtype in this jax build')
     from se3_transformer_tpu.inference import InferenceEngine
+    from se3_transformer_tpu.native.loader import pad_to_bucket
     cfg, module, host, batch = toy
-    e = InferenceEngine(module, host, buckets=(12,), batch_size=1,
-                        precision='fp8_mix')
-    qtree, _ = quant.quantize_params(host, 'fp8_mix')
-    ref = InferenceEngine(module, _dequant_tree(qtree), buckets=(12,),
-                          batch_size=1)
+    kw = dict(buckets=(12, 24), batch_size=2)
+    e = InferenceEngine(module, host, precision=mix, **kw)
+    fp32 = InferenceEngine(module, host, **kw)
+    qtree, _ = quant.quantize_params(host, mix)
+    ref = InferenceEngine(module, _dequant_tree(qtree), **kw)
+
+    def argument_bytes(engine):
+        return engine.cost_payloads[engine._key(24)]['memory'][
+            'argument_bytes']
+    e.warmup()
+    fp32.warmup()
+    assert argument_bytes(e) <= 0.6 * argument_bytes(fp32)
+
     rng = np.random.RandomState(9)
-    tok = rng.randint(0, cfg.num_tokens, size=10)
-    crd = rng.normal(size=(10, 3)).astype(np.float32)
-    out = np.asarray(e.predict(tok, crd))
-    out_ref = np.asarray(ref.predict(tok, crd))
-    assert np.abs(out - out_ref).max() < 1e-4
+    tok = rng.randint(0, cfg.num_tokens, size=12)
+    crd = rng.normal(size=(12, 3)).astype(np.float32)
+    out_u = np.asarray(e.predict(tok, crd))
+    ref_u = np.asarray(ref.predict(tok, crd))
+    t, c, m = pad_to_bucket([tok], [crd], 24, batch_size=2)
+    out_p = np.asarray(e.run(24, t, c, m))[0, :12]
+    ref_p = np.asarray(ref.run(24, t, c, m))[0, :12]
+    assert np.abs(out_u - ref_u).max() < 1e-4
+    assert np.abs(out_p - ref_p).max() < 1e-4
+    assert np.abs(out_u - out_p).max() < 1e-4

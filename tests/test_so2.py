@@ -264,32 +264,6 @@ def test_so2_chunk_streaming_matches_unchunked():
 
 
 # --------------------------------------------------------------------- #
-# sweep record schema
-# --------------------------------------------------------------------- #
-def test_so2_sweep_schema():
-    from se3_transformer_tpu.observability.schema import (
-        SchemaError, validate_record,
-    )
-    entry = dict(so2_step_ms=10.0, so2_nodes_steps_per_sec=100.0,
-                 equivariance_l2_so2=1e-7)
-    good = dict(kind='so2_sweep', run_id='r', label='sweep',
-                degrees={'4': dict(entry, dense_step_ms=13.0,
-                                   dense_vs_so2=1.3),
-                         '6': entry})
-    validate_record(good)
-    with pytest.raises(SchemaError, match='non-empty'):
-        validate_record(dict(good, degrees={}))
-    with pytest.raises(SchemaError, match='equivariance_l2_so2'):
-        bad = {k: v for k, v in entry.items()
-               if k != 'equivariance_l2_so2'}
-        validate_record(dict(good, degrees={'4': bad}))
-    with pytest.raises(SchemaError, match='dense_vs_so2'):
-        validate_record(dict(good,
-                             degrees={'4': dict(entry,
-                                                dense_step_ms=13.0)}))
-
-
-# --------------------------------------------------------------------- #
 # model level (slow tier: multi-pair compiles on the 1-core CPU host)
 # --------------------------------------------------------------------- #
 def _model_data(n=24, dim=8, seed=0):

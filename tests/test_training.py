@@ -92,7 +92,7 @@ def test_params_serialization_roundtrip(tmp_path):
 
 def test_metric_logger(tmp_path):
     import json, os
-    from se3_transformer_tpu.utils.observability import MetricLogger
+    from se3_transformer_tpu.observability import MetricLogger
     path = os.path.join(tmp_path, 'metrics.jsonl')
     logger = MetricLogger(path, mirror=None)
     logger.log(1, loss=0.5, grad_norm=jnp.asarray(2.0))

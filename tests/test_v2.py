@@ -610,29 +610,6 @@ def test_serve_schema_rejects_malformed_model_families():
             '0': dict(snap, model_family='')}))
 
 
-def test_v2_sweep_schema():
-    from se3_transformer_tpu.observability.schema import (
-        SchemaError, validate_record,
-    )
-    entry = dict(v2_step_ms=10.0, v2_nodes_steps_per_sec=100.0,
-                 equivariance_l2_v2=1e-6)
-    validate_record(dict(kind='v2_sweep', run_id='r', label='t',
-                         degrees={'6': dict(entry, so2_step_ms=30.0,
-                                            so2_vs_v2=3.0)}))
-    with pytest.raises(SchemaError, match='degrees'):
-        validate_record(dict(kind='v2_sweep', run_id='r', label='t',
-                             degrees={}))
-    with pytest.raises(SchemaError, match='equivariance_l2_v2'):
-        validate_record(dict(kind='v2_sweep', run_id='r', label='t',
-                             degrees={'4': dict(
-                                 v2_step_ms=1.0,
-                                 v2_nodes_steps_per_sec=1.0)}))
-    with pytest.raises(SchemaError, match='so2_vs_v2'):
-        validate_record(dict(kind='v2_sweep', run_id='r', label='t',
-                             degrees={'4': dict(entry,
-                                                so2_step_ms=3.0)}))
-
-
 # --------------------------------------------------------------------- #
 # end to end: train -> checkpoint -> serve
 # --------------------------------------------------------------------- #

@@ -1,6 +1,25 @@
-"""The attention core of a decoder trained by diffusion over blocks
-(`ops/block_diffusion.py`) as two Pallas kernels over a static table of the
-tiles that hold a visible pair.
+"""An attention core whose mask is a static rule, as two Pallas kernels over
+a table of the tiles that hold a visible pair. Two rules, each a pair
+(kind, n) that names its leaf and its launches (`<kind>_core`,
+`<kind>_core_fwd`, `<kind>_core_bwd`):
+
+    ('bd', block_length)   the two streams of a decoder trained by diffusion
+                           over blocks (`ops/block_diffusion.py`), below
+    ('swa', window)        a causal sliding window over T positions
+                           (`ops/sliding_window.py`): query i sees key j
+                           exactly when 0 <= i - j < window. A query tile
+                           meets the key tiles m = 0, 1, ... tiles back
+                           while m tile - (tile - 1) < window: the diagonal
+                           (m = 0), whole tiles, and the far edge, a
+                           boundary tile's mask being `low <= r - c <= high`
+                           at (-m tile, window - 1 - m tile)
+                           (`window_table`). At T = 16,384, a window of
+                           4,096 and tiles of 512 a head has 252 tiles in
+                           the table where the causal triangle has 528, 56
+                           of them on a boundary; a window of T or more is
+                           the causal triangle.
+
+The block-diffusion rule:
 
 A sequence of L tokens is 2 L positions, noised then clean, cut into tiles of
 `tile` positions. With the tile dividing L and the block length dividing the
@@ -91,25 +110,33 @@ MASKED = -0.7 * float(np.finfo(np.float32).max)
 
 # the table's rows, and a tile's kinds
 QUERY, KEY, KIND, LOW, HIGH, FIRST, LAST = range(7)
-FULL, NOISED_NOISED, NOISED_CLEAN, CLEAN_CLEAN = range(4)
+FULL, NOISED_NOISED, NOISED_CLEAN, CLEAN_CLEAN, WINDOW_EDGE = range(5)
 FAR = 2 ** 30
 # a boundary tile shows the pairs with low <= r // bl - c // bl <= high
 BOUNDS = {FULL: (-FAR, FAR), NOISED_NOISED: (0, 0), NOISED_CLEAN: (1, FAR),
           CLEAN_CLEAN: (0, FAR)}
 
 
+def launches_run(positions: int, tile: int, heads: int, kv_heads: int,
+                 head_dim: int) -> bool:
+    """What Mosaic's tiles ask of either rule: whole tiles, tiles and heads
+    of whole lane rows, whole groups of query heads (of any size: the
+    programs loop over a group's heads); and what the backward asks: a
+    key-value head's dk and dv [positions, head_dim] float32, in the
+    pipeline's two buffers each, in two thirds of the VMEM it may use
+    (32,768 positions at heads of 128 fit, 65,536 do not)."""
+    return tile % LANES == 0 and positions % tile == 0 \
+        and head_dim % LANES == 0 and heads % kv_heads == 0 \
+        and 2 * 2 * positions * head_dim * 4 <= 2 * VMEM_LIMIT // 3
+
+
 def can_run(length: int, block_length: int, tile: int, heads: int,
             kv_heads: int, head_dim: int) -> bool:
-    """What the table and Mosaic's tiles ask: whole tiles in a stream and
-    whole blocks in a tile (so a boundary's mask is one of three constants),
-    tiles and heads of whole lane rows, whole groups of query heads; and
-    what the backward asks: a key-value head's dk and dv [2 length, head_dim]
-    float32, in the pipeline's two buffers each, in two thirds of the VMEM
-    it may use (16,384 tokens at heads of 128 fit, 32,768 do not)."""
-    return tile % LANES == 0 and length % tile == 0 \
-        and tile % block_length == 0 and head_dim % LANES == 0 \
-        and heads % kv_heads == 0 \
-        and 2 * 2 * 2 * length * head_dim * 4 <= 2 * VMEM_LIMIT // 3
+    """`launches_run` over a sequence's two streams, and what the
+    block-diffusion table asks: whole tiles in a stream and whole blocks in
+    a tile (so a boundary's mask is one of three constants)."""
+    return length % tile == 0 and tile % block_length == 0 \
+        and launches_run(2 * length, tile, heads, kv_heads, head_dim)
 
 
 @functools.lru_cache(maxsize=None)
@@ -130,13 +157,57 @@ def tile_table(length: int, block_length: int, tile: int) -> np.ndarray:
     for i in range(n):                  # a clean tile: block-causal
         columns += [(n + i, n + j, FULL) for j in range(i)]
         columns += [(n + i, n + i, CLEAN_CLEAN)]
-    query, key, kind = np.array(columns, np.int32).T
-    low, high = np.array([BOUNDS[int(c)] for c in kind], np.int32).T
+    return _table([column + BOUNDS[column[2]] for column in columns])
+
+
+def _table(columns):
+    """[7, n] from (query, key, kind, low, high) a column, sorted by query
+    tile."""
+    query, key, kind, low, high = np.array(columns, np.int64).T
     new = np.diff(query, prepend=-1, append=-1) != 0
     table = np.stack([query, key, kind, low, high, new[:-1], new[1:]]
                      ).astype(np.int32)
     table.setflags(write=False)
     return table
+
+
+@functools.lru_cache(maxsize=None)
+def window_table(positions: int, window: int, tile: int) -> np.ndarray:
+    """The same table for a causal window over `positions`: a query tile i
+    against the key tiles i - m that hold a pair with 0 <= distance <
+    window, distance = m tile + r - c. A tile is `FULL` where every offset
+    pair lies inside (m >= 1 and (m + 1) tile <= window), else its bounds
+    are on r - c (the launches' granule is 1). Any window >= 1; one of
+    `positions` or more gives the causal triangle."""
+    assert positions % tile == 0 and window >= 1, (positions, window, tile)
+    columns = []
+    for i in range(positions // tile):
+        for m in range(min(i, (window + tile - 2) // tile), -1, -1):
+            low, high = -m * tile, window - 1 - m * tile
+            whole = low <= 1 - tile and high >= tile - 1
+            columns += [(i, i - m, FULL if whole else WINDOW_EDGE,
+                         max(low, -FAR), min(high, FAR))]
+    return _table(columns)
+
+
+def rule_table(rule, positions: int, tile: int) -> np.ndarray:
+    """The table of `rule` = (kind, n) over `positions` (both streams of a
+    block-diffusion sequence, or a window's T)."""
+    kind, n = rule
+    return tile_table(positions // 2, n, tile) if kind == 'bd' \
+        else window_table(positions, n, tile)
+
+
+def _core_scope(rule):
+    """The leaf a rule's two launches run under (each a literal, as the
+    closed list of leaves is checked)."""
+    return named_scope('bd_core') if rule[0] == 'bd' \
+        else named_scope('swa_core')
+
+
+def _granule(rule) -> int:
+    """What a boundary tile's mask divides its offsets by."""
+    return rule[1] if rule[0] == 'bd' else 1
 
 
 def floor_div(a, n: int):
@@ -253,22 +324,23 @@ def _specs(g, tile, d):
     return heads, keys, stats
 
 
-_STATIC = ('head_dim', 'block_length', 'tile', 'interpret')
+_STATIC = ('head_dim', 'rule', 'tile', 'interpret')
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
-def _fwd(q, k, v, head_dim, block_length, tile, interpret):
+def _fwd(q, k, v, head_dim, rule, tile, interpret):
     """q [B, 2L, H D], k, v [B, 2L, KV D] in the operands' width -> o like
-    q and the log-sum-exp [B, H, 1, 2L] float32. A jit of its own, so that a
-    step traces and lowers the launch once, not once a layer."""
+    q and the log-sum-exp [B, H, 1, 2L] float32 (a window's T positions
+    where these lines say 2L). A jit of its own, so that a step traces and
+    lowers the launch once, not once a layer."""
     b, t, hd = q.shape
     d, kv = head_dim, k.shape[2] // head_dim
     h, f32 = hd // d, jnp.float32
     g = h // kv
-    table = tile_table(t // 2, block_length, tile)
+    table = rule_table(rule, t, tile)
     heads, keys, stats = _specs(g, tile, d)
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, block_length=block_length,
+        functools.partial(_fwd_kernel, block_length=_granule(rule),
                           od=q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(b, kv, table.shape[1]),
@@ -278,7 +350,8 @@ def _fwd(q, k, v, head_dim, block_length, tile, interpret):
                             pltpu.VMEM((g, tile, d), f32)]),
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct((b, h, 1, t), f32)],
-        compiler_params=PARAMS, interpret=interpret, name='bd_core_fwd',
+        compiler_params=PARAMS, interpret=interpret,
+        name=f'{rule[0]}_core_fwd',
     )(jnp.asarray(table), q, k, v)
 
 
@@ -335,17 +408,17 @@ def _bwd_kernel(tab, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
-def _bwd(q, k, v, o, do, lse, head_dim, block_length, tile, interpret):
+def _bwd(q, k, v, o, do, lse, head_dim, rule, tile, interpret):
     """o and its cotangent like q -> dq like q's shape, dk and dv like k's,
     float32."""
     b, t, hd = q.shape
     d, kv = head_dim, k.shape[2] // head_dim
     g, f32 = hd // d // kv, jnp.float32
-    table = tile_table(t // 2, block_length, tile)
+    table = rule_table(rule, t, tile)
     heads, keys, stats = _specs(g, tile, d)
     whole = pl.BlockSpec((1, t, d), lambda z, c, n, tab: (z, 0, c))
     return pl.pallas_call(
-        functools.partial(_bwd_kernel, block_length=block_length,
+        functools.partial(_bwd_kernel, block_length=_granule(rule),
                           od=q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(b, kv, table.shape[1]),
@@ -355,49 +428,53 @@ def _bwd(q, k, v, o, do, lse, head_dim, block_length, tile, interpret):
         out_shape=[jax.ShapeDtypeStruct(q.shape, f32),
                    jax.ShapeDtypeStruct(k.shape, f32),
                    jax.ShapeDtypeStruct(k.shape, f32)],
-        compiler_params=PARAMS, interpret=interpret, name='bd_core_bwd',
+        compiler_params=PARAMS, interpret=interpret,
+        name=f'{rule[0]}_core_bwd',
     )(jnp.asarray(table), q, k, v, o, do, lse)
 
 
 # --------------------------------------------------------------------- #
-# from the projections' outputs to the core's, over a sequence's two streams
+# from the projections' outputs to the core's, under either rule
 # --------------------------------------------------------------------- #
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
-def block_attention(q, k, v, norms, rotary, head_dim, scale, eps,
-                    block_length, tile, interpret=False):
+def block_attention(q, k, v, norms, rotary, head_dim, scale, eps, rule, tile,
+                    interpret=False):
     """q [B, 2L, H D], k, v [B, 2L, KV D] float32 as the projections write
-    them (the noised stream, then the clean one); `norms` the scales [D] of
-    the queries' and the keys' RMSNorm, or None; `rotary` the rotation's
-    tables (`pallas_qk_pass.rotary_tables`), or None -> o [B, 2L, H D] in
-    the operands' width, as the output projection reads it; shapes as
-    `can_run` asks. One rule for the pass and the core, so that dq, dk and
-    dv go from `bd_core_bwd` to `qk_pass_bwd` in float32."""
+    them (the noised stream, then the clean one; under a window's rule one
+    stream of T positions); `norms` the scales [D] of the queries' and the
+    keys' RMSNorm, or None; `rotary` the rotation's tables
+    (`pallas_qk_pass.rotary_tables`), or None; `rule` = ('bd', block_length)
+    or ('swa', window) -> o [B, 2L, H D] in the operands' width, as the
+    output projection reads it; shapes as `can_run` / `launches_run` ask.
+    One rule of differentiation for the pass and the core, so that dq, dk
+    and dv go from the core's backward launch to `qk_pass_bwd` in float32;
+    the core's launches under the leaf `<kind>_core`."""
     return _block_attention_fwd(q, k, v, norms, rotary, head_dim, scale, eps,
-                                block_length, tile, interpret)[0]
+                                rule, tile, interpret)[0]
 
 
-def _block_attention_fwd(q, k, v, norms, rotary, head_dim, scale, eps,
-                         block_length, tile, interpret):
+def _block_attention_fwd(q, k, v, norms, rotary, head_dim, scale, eps, rule,
+                         tile, interpret):
     od = jnp.float32 if interpret else jnp.bfloat16
     with named_scope('mha_qkv'):
         qr, kr, vr = qk_pass.forward(q, k, v, norms, rotary, head_dim, scale,
                                      eps, od, interpret)
-    with named_scope('bd_core'):
-        o, lse = _fwd(qr, kr, vr, head_dim, block_length, tile, interpret)
+    with _core_scope(rule):
+        o, lse = _fwd(qr, kr, vr, head_dim, rule, tile, interpret)
         o = checkpoint_name(o, ATTN_CORE_OUT)
         lse = checkpoint_name(lse, ATTN_CORE_STATS)
     return o, (q, k, norms, rotary, qr, kr, vr, o, lse)
 
 
-def _block_attention_bwd(head_dim, scale, eps, block_length, tile, interpret,
+def _block_attention_bwd(head_dim, scale, eps, rule, tile, interpret,
                          residuals, do):
     q, k, norms, rotary, qr, kr, vr, o, lse = residuals
     f32 = jnp.float32
-    with named_scope('bd_core'):
+    with _core_scope(rule):
         # do arrives in o's width, rounded once, as the output projection's
         # dx writes it: the products and the row sums see one do
-        dq, dk, dv = _bwd(qr, kr, vr, o, do, lse, head_dim, block_length,
-                          tile, interpret)
+        dq, dk, dv = _bwd(qr, kr, vr, o, do, lse, head_dim, rule, tile,
+                          interpret)
     with named_scope('mha_qkv'):
         dq, dk, dv, dw = qk_pass.backward(dq, dk, dv, q, k, norms, rotary,
                                           head_dim, scale, eps, qr.dtype,

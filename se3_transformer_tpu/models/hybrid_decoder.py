@@ -9,8 +9,18 @@ one pre-normed mixer with its residual:
           None: none) where the model has them; over the two streams of a
           block-diffusion pass (`hidden_states(tokens, noised,
           block_length)`) by `ops/block_diffusion.py`'s rule instead
+      W   the same attention under a sliding window: a query sees the
+          `sliding_window_size` keys that end with itself, rotated at
+          `sliding_rope_theta`. Window and rotation are the kind's, not the
+          model's: `*EWEWEWE` is a global layer without rotation
+          (`rope_theta` None) and three sliding layers with it
       E   an expert layer that holds a share   (ops/expert_layer.py),
-          routed by `scoring_func` ('sigmoid' or 'softmax')
+          routed by `scoring_func` ('sigmoid' or 'softmax'), its experts of
+          the form `mlp_hidden_act` ('silu', 'relu' or 'relu2'). With
+          `moe_enable_early_router` (the router placed before attention) it
+          is routed by the normed input of the attention step before it
+          (`*` or `W`), which that step hands on, while its experts read
+          its own normed input
       F   a dense gated feed-forward           (SwiGLU, ops/expert_layer.py)
 
 then a final RMSNorm and the head: a matrix of its own, or with
@@ -47,32 +57,44 @@ from ..ops.state_space import Mamba2Mixer
 # letter -> (the mixer's module, its name in the parameter tree)
 MIXERS = {'M': (Mamba2Mixer, 'ssm'), 'E': (ExpertLayer, 'moe'),
           '*': (GroupedQueryAttention, 'attn'),
+          'W': (GroupedQueryAttention, 'attn'),
           'C': (ShortConvMixer, 'conv'), 'F': (SwiGLU, 'mlp')}
+ATTENTION = '*W'
 
 
 class MixerBlock(nn.Module):
     kind: str                  # a key of MIXERS
     mixer: dict                # the mixer's fields
     eps: float
+    early_router: bool = False     # an attention step hands its normed
+    #                                input on, for the next step's router
 
     @nn.compact
-    def __call__(self, h, positions=None, block_length: int = 0):
-        """h [B, T, d] -> (h, the expert layer's stats or None). `positions`
-        and `block_length` (static) are the attention mixer's: the two
-        streams of a block-diffusion pass (`hidden_states`)."""
+    def __call__(self, h, positions=None, block_length: int = 0,
+                 routing_input=None):
+        """h [B, T, d] -> (h, the expert layer's stats or None; with
+        `early_router` an attention step's normed input [B, T, d] in their
+        place). `positions` and `block_length` (static) are the attention
+        mixer's: the two streams of a block-diffusion pass
+        (`hidden_states`). `routing_input` [B, T, d] is the expert layer's:
+        what its router reads where that is not the step's own normed
+        input."""
         with named_scope('norm'):
             u = RMSNorm(self.eps, name='pre_norm')(h)
         module, name = MIXERS[self.kind]
         mixer = module(**self.mixer, name=name)
         if self.kind == 'E':
             b, t, d = u.shape
-            out, stats = mixer(u.reshape(b * t, d))
+            out, stats = mixer(
+                u.reshape(b * t, d), None if routing_input is None
+                else routing_input.reshape(b * t, d))
             return h + out.reshape(b, t, d), stats
         if self.kind == 'F':       # SwiGLU writes no scope of its own
             with named_scope('dense_ff'):
                 return h + mixer(u), None
-        if self.kind == '*':
-            return h + mixer(u, positions, block_length), None
+        if self.kind in ATTENTION:
+            return h + mixer(u, positions, block_length), \
+                u if self.early_router else None
         return h + mixer(u), None
 
 
@@ -105,6 +127,7 @@ class HybridDecoder(nn.Module):
     scoring_func: str = 'sigmoid'
     norm_topk_prob: bool = True
     norm_topk_eps: float = 1e-20
+    moe_enable_early_router: bool = False
     # F
     intermediate_size: int = 0
     # *
@@ -113,6 +136,9 @@ class HybridDecoder(nn.Module):
     head_dim: int = 0
     qk_norm: bool = False
     rope_theta: Optional[float] = None
+    # W: `*`'s heads under a window, with a rotation of its own
+    sliding_window_size: int = 0
+    sliding_rope_theta: Optional[float] = None
     layer_norm_epsilon: float = 1e-5
     tie_word_embeddings: bool = False
     # execution, not architecture (every block is recomputed in the
@@ -125,6 +151,10 @@ class HybridDecoder(nn.Module):
         assert set(self.hybrid_override_pattern) <= set(MIXERS), \
             self.hybrid_override_pattern
         eps = self.layer_norm_epsilon
+        attention = dict(
+            dim=self.hidden_size, heads=self.num_attention_heads,
+            kv_heads=self.num_key_value_heads, head_dim=self.head_dim,
+            block=self.attention_block, qk_norm=self.qk_norm, eps=eps)
         fields = {
             'M': dict(
                 dim=self.hidden_size, num_heads=self.mamba_num_heads,
@@ -149,17 +179,17 @@ class HybridDecoder(nn.Module):
                 bf16_operands=self.bf16_operands),
             'F': dict(width=self.intermediate_size,
                       bf16_operands=self.bf16_operands),
-            '*': dict(
-                dim=self.hidden_size, heads=self.num_attention_heads,
-                kv_heads=self.num_key_value_heads, head_dim=self.head_dim,
-                block=self.attention_block, qk_norm=self.qk_norm,
-                rope_theta=self.rope_theta, eps=eps)}
+            '*': dict(attention, rope_theta=self.rope_theta),
+            'W': dict(attention, rope_theta=self.sliding_rope_theta,
+                      window=self.sliding_window_size)}
         # `block_length` is static: argument 3 of `__call__`, self counted
         block = nn.remat(MixerBlock, policy=SAVE_ATTN_CORE,
                          static_argnums=(3,))
         self.embedding = nn.Embed(self.vocab_rows, self.hidden_size)
-        self.blocks = [block(kind, fields[kind], eps)
-                       for kind in self.hybrid_override_pattern]
+        self.blocks = [
+            block(kind, fields[kind], eps,
+                  self.moe_enable_early_router and kind in ATTENTION)
+            for kind in self.hybrid_override_pattern]
         self.final_norm = RMSNorm(eps)
         if not self.tie_word_embeddings:
             self.head = nn.Dense(self.vocab_rows, use_bias=False)
@@ -200,10 +230,14 @@ class HybridDecoder(nn.Module):
                 positions = jnp.tile(jnp.arange(t), 2)
         with named_scope('embed'):
             h = self.embedding(tokens)
-        stats = []
-        for block in self.blocks:
-            h, s = block(h, positions, block_length)
-            stats += [s] if s is not None else []
+        stats, handed = [], None
+        for kind, block in zip(self.hybrid_override_pattern, self.blocks):
+            h, s = block(h, positions, block_length,
+                         handed if kind == 'E' else None)
+            if kind == 'E':
+                stats.append(s)
+            else:       # an attention step's normed input, or None
+                handed = s
         if noised is not None:
             with named_scope('bd_streams'):
                 h = h[:, :h.shape[1] // 2]

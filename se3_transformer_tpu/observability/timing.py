@@ -130,6 +130,12 @@ MODEL_SCOPES = (
     #                       launches of kernels/pallas_block_attention.py,
     #                       `bd_core_fwd` and `bd_core_bwd`, di = sum(o do)
     #                       inside the second), apart from `mha_core`
+    'swa_core',           # ops/grouped_attention.py: the core of a layer
+    #                       with a sliding window (ops/sliding_window.py:
+    #                       on a TPU the same two launches under the
+    #                       window's table, `swa_core_fwd` and
+    #                       `swa_core_bwd`), apart from `mha_core`, which a
+    #                       decoder's global layers keep
     'bd_streams',         # models/hybrid_decoder.py, training/lm_loss.py:
     #                       building the two streams and their positions,
     #                       cutting the noised one out, the weights

@@ -13,17 +13,24 @@
                                                are both published
     out = sum_{i chosen and held here} w_i Expert_i(x) + Shared(x)
 
-An expert, routed or shared, has one of two forms, by `hidden_act` (the name
-a published config gives the choice):
+An expert, routed or shared, has one of three forms, by `hidden_act` (the
+name a published config gives the choice):
 
-    'silu'    (silu(x Wg) * (x Wu)) Wd     gated, three matrices
+    'silu'    (silu(x Wg) * (x Wu)) Wd     gated, three matrices (SwiGLU)
+    'relu'    (relu(x Wg) * (x Wu)) Wd     gated, three matrices (ReGLU)
     'relu2'   relu(x Wu)^2 Wd              squared ReLU, two matrices
 
 The dense gated form (`SwiGLU`: the shared expert and the decoders' dense
 feed-forward) has a backward of its own, `gated_ff`: one pass over gate, up
-and dh = dy Wd^T writes d_gate, d_up and silu(gate) * up once, in the width
+and dh = dy Wd^T writes d_gate, d_up and act(gate) * up once, in the width
 the products round their operands to (bfloat16 where `bf16_operands`), and the
-five products of the weights' and x's cotangents read them.
+five products of the weights' and x's cotangents read them; the gate's
+activation and its derivative are the rule's parameter (`GATE_ACTS`).
+
+The router reads the rows its experts read, or rows handed to it apart from
+them (`routing_input`: a decoder whose router is placed before attention
+routes a token by the attention step's normed input, while its experts read
+the feed-forward step's).
 
 The router keeps all `n_experts` outputs; this chip holds experts
 `expert_rank * experts_held ...` of them and computes their part of the
@@ -210,11 +217,12 @@ def padded_width(width: int) -> int:
     return -(-width // tile) * tile
 
 
-def _stages(rows, order, inverse, load, n, k, gated, dtype):
+def _stages(rows, order, inverse, load, n, k, act, dtype):
     """(take, experts, combine) over the first `rows` sorted rows, which hold
     every held pair: x [N, d] -> xs [rows, d] -> ys [rows, d] -> out [N, d].
-    At the full size both gathers go through `inverse`; below it a row is
-    added into its token's, and so is its cotangent."""
+    `act`: the gate's activation, or None for the squared ReLU's two
+    matrices. At the full size both gathers go through `inverse`; below it a
+    row is added into its token's, and so is its cotangent."""
     full = rows == n * k
 
     def take(x):
@@ -223,13 +231,13 @@ def _stages(rows, order, inverse, load, n, k, gated, dtype):
         return x[order[:rows] // k]
 
     def experts(xs, mats):
-        # the hidden width stays padded from `up` to `down`: both forms
-        # send a zero column to a zero column
+        # the hidden width stays padded from `up` to `down`: every form
+        # sends a zero column to a zero column
         d = xs.shape[1]
         wide = padded_width(d), padded_width(mats['up'].shape[2])
         up = grouped_dot(xs, mats['up'], load, dtype, wide)
-        hidden = nn.silu(grouped_dot(xs, mats['gate'], load, dtype, wide)) \
-            * up if gated else jnp.square(nn.relu(up))
+        hidden = act(grouped_dot(xs, mats['gate'], load, dtype, wide)) \
+            * up if act else jnp.square(nn.relu(up))
         return grouped_dot(hidden, mats['down'], load, dtype,
                            wide[::-1])[:, :d]
 
@@ -274,7 +282,7 @@ def _held_experts_vjp(stages, x, weights, mats, g):
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def _bounded_or_full(rows, gated, dtype, fits, x, weights, mats, order,
+def _bounded_or_full(rows, act, dtype, fits, x, weights, mats, order,
                      inverse, load):
     """`_held_experts` over the first `rows` sorted rows where the held pairs
     fit them (`fits`), else over all N * k. One rule for both directions,
@@ -282,26 +290,26 @@ def _bounded_or_full(rows, gated, dtype, fits, x, weights, mats, order,
     differentiated as it stands, a `cond` hands its backward the residuals of
     both branches, the untaken one's (the full size's operands and
     pre-activations) as zeros."""
-    return _bounded_or_full_fwd(rows, gated, dtype, fits, x, weights, mats,
+    return _bounded_or_full_fwd(rows, act, dtype, fits, x, weights, mats,
                                 order, inverse, load)[0]
 
 
-def _branches(fn, rows, gated, dtype, order, inverse, load, n, k):
-    return [partial(fn, _stages(r, order, inverse, load, n, k, gated, dtype))
+def _branches(fn, rows, act, dtype, order, inverse, load, n, k):
+    return [partial(fn, _stages(r, order, inverse, load, n, k, act, dtype))
             for r in (rows, n * k)]
 
 
-def _bounded_or_full_fwd(rows, gated, dtype, fits, x, weights, mats, order,
+def _bounded_or_full_fwd(rows, act, dtype, fits, x, weights, mats, order,
                          inverse, load):
-    bounded, full = _branches(_held_experts, rows, gated, dtype, order,
+    bounded, full = _branches(_held_experts, rows, act, dtype, order,
                               inverse, load, *weights.shape)
     out = jax.lax.cond(fits, bounded, full, x, weights, mats)
     return out, (fits, x, weights, mats, order, inverse, load)
 
 
-def _bounded_or_full_bwd(rows, gated, dtype, res, g):
+def _bounded_or_full_bwd(rows, act, dtype, res, g):
     fits, x, weights, mats, order, inverse, load = res
-    bounded, full = _branches(_held_experts_vjp, rows, gated, dtype, order,
+    bounded, full = _branches(_held_experts_vjp, rows, act, dtype, order,
                               inverse, load, *weights.shape)
     return (None,) + jax.lax.cond(fits, bounded, full, x, weights, mats, g) \
         + (None, None, None)
@@ -310,26 +318,40 @@ def _bounded_or_full_bwd(rows, gated, dtype, res, g):
 _bounded_or_full.defvjp(_bounded_or_full_fwd, _bounded_or_full_bwd)
 
 
-def _gated_operands(gate, up, dh, dtype):
+def _silu_and_slope(gate):
+    s = nn.sigmoid(gate)
+    return gate * s, s * (1.0 + gate * (1.0 - s))
+
+
+def _relu_and_slope(gate):
+    return nn.relu(gate), (gate > 0).astype(gate.dtype)
+
+
+# the gate's activation -> (the function the forward applies, (act(gate),
+# act'(gate)) for the backward's one pass)
+GATE_ACTS = {'silu': (nn.silu, _silu_and_slope),
+             'relu': (nn.relu, _relu_and_slope)}
+
+
+def _gated_operands(gate, up, dh, dtype, act_and_slope):
     """One pass over gate, up [N, width] (float32) and dh = dy Wd^T: the three
     operands of the gated form's backward products, d_gate = dh * up *
-    silu'(gate), d_up = dh * silu(gate) and hidden = silu(gate) * up, each
+    act'(gate), d_up = dh * act(gate) and hidden = act(gate) * up, each
     written once in `dtype`. Behind the barrier, or XLA fuses each chain into
     every product that reads it and computes it from the float32 tensors once
     a pass over that operand's tiles."""
-    s = nn.sigmoid(gate)
-    act = gate * s
-    d_act = s * (1.0 + gate * (1.0 - s))
+    act, d_act = act_and_slope(gate)
     return jax.lax.optimization_barrier(tuple(
         _cast(a, dtype) for a in (dh * up * d_act, dh * act, act * up)))
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(4,))
-def gated_ff(x, wg, wu, wd, operand_dtype=None):
-    """(silu(x wg) * (x wu)) wd for x [N, d], float32. The backward's five
-    products read `_gated_operands` in `operand_dtype` (None: as they are);
-    nothing else is rounded, and the cotangents are float32."""
-    return _gated_ff_fwd(x, wg, wu, wd, operand_dtype)[0]
+@partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def gated_ff(x, wg, wu, wd, operand_dtype=None, act='silu'):
+    """(act(x wg) * (x wu)) wd for x [N, d], float32, `act` a key of
+    `GATE_ACTS`. The backward's five products read `_gated_operands` in
+    `operand_dtype` (None: as they are); nothing else is rounded, and the
+    cotangents are float32."""
+    return _gated_ff_fwd(x, wg, wu, wd, operand_dtype, act)[0]
 
 
 def _contract(a, i, b, j):
@@ -338,15 +360,17 @@ def _contract(a, i, b, j):
                                preferred_element_type=jnp.float32)
 
 
-def _gated_ff_fwd(x, wg, wu, wd, operand_dtype):
+def _gated_ff_fwd(x, wg, wu, wd, operand_dtype, act):
     gate, up = _contract(x, 1, wg, 0), _contract(x, 1, wu, 0)
-    return _contract(nn.silu(gate) * up, 1, wd, 0), (x, wg, wu, wd, gate, up)
+    return (_contract(GATE_ACTS[act][0](gate) * up, 1, wd, 0),
+            (x, wg, wu, wd, gate, up))
 
 
-def _gated_ff_bwd(operand_dtype, res, dy):
+def _gated_ff_bwd(operand_dtype, act, res, dy):
     x, wg, wu, wd, gate, up = res
     dh = _contract(dy, 1, wd, 1)
-    d_gate, d_up, hidden = _gated_operands(gate, up, dh, operand_dtype)
+    d_gate, d_up, hidden = _gated_operands(gate, up, dh, operand_dtype,
+                                           GATE_ACTS[act][1])
     dx = _contract(d_gate, 1, wg, 1) + _contract(d_up, 1, wu, 1)
     return (dx, _contract(x, 0, d_gate, 0), _contract(x, 0, d_up, 0),
             _contract(hidden, 0, dy, 0))
@@ -366,9 +390,11 @@ class _Kernel(nn.Module):
 
 
 class SwiGLU(nn.Module):
-    """(silu(x Wg) * (x Wu)) Wd, differentiated by `gated_ff`."""
+    """(silu(x Wg) * (x Wu)) Wd, differentiated by `gated_ff`; with `act`
+    'relu' the ReGLU form, (relu(x Wg) * (x Wu)) Wd."""
     width: int
     bf16_operands: bool = True   # of the backward's three built operands
+    act: str = 'silu'            # the gate's: a key of GATE_ACTS
 
     @nn.compact
     def __call__(self, x):
@@ -377,7 +403,7 @@ class SwiGLU(nn.Module):
             ('gate', (d, self.width)), ('up', (d, self.width)),
             ('down', (self.width, d))))
         y = gated_ff(x.reshape(-1, d), wg, wu, wd,
-                     jnp.bfloat16 if self.bf16_operands else None)
+                     jnp.bfloat16 if self.bf16_operands else None, self.act)
         return y.reshape(x.shape)
 
 
@@ -392,8 +418,10 @@ class SquaredReLU(nn.Module):
         return dense(x.shape[-1], name='down')(jnp.square(nn.relu(up)))
 
 
-# hidden_act -> (the shared expert's module, whether an expert has a gate)
-EXPERT_FORMS = {'silu': (SwiGLU, True), 'relu2': (SquaredReLU, False)}
+# hidden_act -> (the shared expert's module, the activation of an expert's
+# gate or None where it has none)
+EXPERT_FORMS = {'silu': (SwiGLU, nn.silu), 'relu2': (SquaredReLU, None),
+                'relu': (partial(SwiGLU, act='relu'), nn.relu)}
 
 # scoring_func -> the router's scores from its logits [N, n_experts]: each
 # expert alone, or over all the router's outputs, held here or not
@@ -457,17 +485,20 @@ class ExpertLayer(nn.Module):
     #                              product is rounded by the TPU's default)
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, routing_input=None):
         """x [N, d] -> (out [N, d], stats): `load` [experts_held] pairs
         computed per held expert, `chosen` [N, top_k], `scores` [N,
-        n_experts], `dropped` (0)."""
+        n_experts], `dropped` (0). `routing_input` [N, d]: the rows the
+        router reads where they are not the experts' (None: x)."""
         n, d = x.shape
         k, held = self.top_k, self.experts_held
         assert (self.expert_rank + 1) * held <= self.n_experts
         x = x.astype(jnp.float32)
+        routed_by = x if routing_input is None \
+            else routing_input.astype(jnp.float32)
         with named_scope('moe_router'):
             logits = nn.Dense(self.n_experts, use_bias=False, name='router',
-                              precision=jax.lax.Precision.HIGHEST)(x)
+                              precision=jax.lax.Precision.HIGHEST)(routed_by)
             bias = self.param('correction_bias', nn.initializers.zeros,
                               (self.n_experts,))
             scores = SCORING_FUNCS[self.scoring_func](logits)
@@ -481,9 +512,9 @@ class ExpertLayer(nn.Module):
             order = jnp.argsort(key, stable=True).astype(jnp.int32)
             inverse = jnp.argsort(order).astype(jnp.int32)
             load = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
-        shared, gated = EXPERT_FORMS[self.hidden_act]
+        shared, act = EXPERT_FORMS[self.hidden_act]
         up, down = (held, d, self.width), (held, self.width, d)
-        shapes = dict(gate=up, up=up, down=down) if gated \
+        shapes = dict(gate=up, up=up, down=down) if act \
             else dict(up=up, down=down)
         mats = {name: self.param(f'experts_{name}', _expert_init, shape)
                 for name, shape in shapes.items()}
@@ -492,12 +523,12 @@ class ExpertLayer(nn.Module):
         fits = jnp.sum(load) <= rows
         if rows == n * k:
             out = _held_experts(_stages(rows, order, inverse, load, n, k,
-                                        gated, dtype), x, weights, mats)
+                                        act, dtype), x, weights, mats)
         else:
-            out = _bounded_or_full(rows, gated, dtype, fits, x, weights, mats,
+            out = _bounded_or_full(rows, act, dtype, fits, x, weights, mats,
                                    order, inverse, load)
         if self.shared_width:
-            fields = dict(bf16_operands=self.bf16_operands) if gated else {}
+            fields = dict(bf16_operands=self.bf16_operands) if act else {}
             with named_scope('shared_expert'):
                 out = out + shared(self.shared_width, **fields,
                                    name='shared')(x)

@@ -1,5 +1,5 @@
-"""Grouped-query causal attention; per-head q/k norms and rotation are
-optional:
+"""Grouped-query causal attention; per-head q/k norms, rotation and a
+sliding window are optional:
 
     q = u Wq -> `heads` heads of `head_dim`;  k, v = u Wk, u Wv ->
     `kv_heads` heads, each shared by heads / kv_heads query heads;
@@ -10,6 +10,8 @@ optional:
                    None: no rotation (a decoder whose state-space layers
                    carry position), and with `qk_norm` off the parameter
                    tree and the arithmetic are what they were without both
+    `window`:      key j is visible to query i iff 0 <= i - j < window
+                   (the token itself counts); 0: every key at or before i
     softmax(q k^T / sqrt(head_dim)), causal, in float32;  out = o Wo
 
 The core is the latent attention's (`ops/latent_attention.py`): JAX's
@@ -31,6 +33,14 @@ under `mha_qkv`, and `qk_pass_bwd` on the way back), the core's launches
 read and write that layout, and the output projection reads o [B, T, H D]
 as the core wrote it. Elsewhere the composition below and the blocked core.
 Projections, norms and rotation are the same arithmetic either way.
+
+A layer with a `window` has its core under a leaf of its own, `swa_core`
+(`ops/sliding_window.py`), so one table tells a decoder's sliding layers
+from its global ones (`mha_core`): on a TPU the same kernels under the rule
+('swa', window), the same one pass before them (with no norms, a group of
+any size: 7 query heads a key-value head run as 8 do), no tile wholly
+outside the window launched; elsewhere the composition and the blocked
+causal core with the window's key extents.
 """
 from __future__ import annotations
 
@@ -43,8 +53,11 @@ import jax.numpy as jnp
 from ..kernels import pallas_block_attention as kernels
 from ..kernels.pallas_qk_pass import rotary_tables
 from ..observability import named_scope
+from . import sliding_window
 from .block_diffusion import block_diffusion_attention_blocked, kernels_run
-from .latent_attention import RMSNorm, causal_attention
+from .latent_attention import (
+    RMSNorm, causal_attention, causal_attention_blocked,
+)
 from .rotary import apply_rotary_halves, rotary_angles
 
 
@@ -66,6 +79,7 @@ class GroupedQueryAttention(nn.Module):
     qk_norm: bool = False
     rope_theta: Optional[float] = None
     eps: float = 1e-5     # of the q/k norms
+    window: int = 0       # keys a query sees, itself counted; 0: all before
 
     @nn.compact
     def __call__(self, x, positions=None, block_length: int = 0):
@@ -74,8 +88,14 @@ class GroupedQueryAttention(nn.Module):
         h, kv, dh = self.heads, self.kv_heads, self.head_dim
         assert h % kv == 0, (h, kv)
         dense = partial(nn.Dense, use_bias=False)
-        one_pass = block_length and kernels_run(t // 2, block_length,
-                                                self.block, h, kv, dh)
+        assert not (block_length and self.window), (block_length, self.window)
+        if self.window:
+            rule, tile = ('swa', self.window), min(self.block, t)
+            one_pass = sliding_window.kernels_run(t, self.block, h, kv, dh)
+        else:
+            rule, tile = ('bd', block_length), min(self.block, t // 2)
+            one_pass = block_length and kernels_run(
+                t // 2, block_length, self.block, h, kv, dh)
 
         def heads_of(a, n):     # the kernels take the products' own layout
             return a if one_pass else a.reshape(b, t, n, dh)
@@ -105,10 +125,14 @@ class GroupedQueryAttention(nn.Module):
                 if not block_length:
                     k, v = (jnp.repeat(a, h // kv, axis=2) for a in (k, v))
                 q, k, v = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
-        if one_pass:    # its scopes are the rule's own: `mha_qkv`, `bd_core`
+        if one_pass:    # its scopes are the rule's own: `mha_qkv` and the
+            #             core's, `bd_core` or `swa_core`
             o = kernels.block_attention(
-                q, k, v, norms, rotary, dh, dh ** -0.5, self.eps,
-                block_length, min(self.block, t // 2))
+                q, k, v, norms, rotary, dh, dh ** -0.5, self.eps, rule, tile)
+        elif self.window:
+            with named_scope('swa_core'):
+                o = causal_attention_blocked(q, k, v, dh ** -0.5, self.block,
+                                             self.window)
         elif block_length:
             with named_scope('bd_core'):
                 o = block_diffusion_attention_blocked(

@@ -44,27 +44,41 @@ class RMSNorm(nn.Module):
         return x * jax.lax.rsqrt(var + self.eps) * scale
 
 
-def causal_attention_blocked(q, k, v, scale: float, block_q: int = 512):
+def causal_attention_blocked(q, k, v, scale: float, block_q: int = 512,
+                             window: int = 0):
     """q, k [B, H, T, Dk], v [B, H, T, Dv] -> [B, H, T, Dv]. Query block i
     meets keys 0 .. (i + 1) block_q only (static extents, so the masked half
-    is never computed) and is recomputed in the backward pass."""
+    is never computed) and is recomputed in the backward pass.
+
+    With a `window` under T, query i sees key j exactly when 0 <= i - j <
+    window (the token itself counts): a block's keys start at its first
+    row's first visible key, whatever the window divides, and the keys
+    behind the window are never computed either. A window of T or more (or
+    0: none) is the causal core, to the bit."""
     t = q.shape[2]
     bq = min(block_q, t)
     assert t % bq == 0, (t, bq)
+    windowed = 0 < window < t
 
     @jax.checkpoint
-    def block(qi, kj, vj, q0):
+    def block(qi, kj, vj, q0, k0):
         s = jnp.einsum('bhqd,bhkd->bhqk', qi, kj,
                        preferred_element_type=jnp.float32) * scale
         qpos = q0 + jnp.arange(qi.shape[2])[:, None]
-        kpos = jnp.arange(kj.shape[2])[None, :]
-        s = jnp.where(kpos <= qpos, s, jnp.finfo(jnp.float32).min)
+        kpos = k0 + jnp.arange(kj.shape[2])[None, :]
+        seen = kpos <= qpos
+        if windowed:
+            seen = seen & (qpos - kpos < window)
+        s = jnp.where(seen, s, jnp.finfo(jnp.float32).min)
         p = jax.nn.softmax(s, axis=-1)
         return jnp.einsum('bhqk,bhkd->bhqd', p, vj,
                           preferred_element_type=jnp.float32)
 
-    outs = [block(q[:, :, i:i + bq], k[:, :, :i + bq], v[:, :, :i + bq], i)
-            for i in range(0, t, bq)]
+    outs = []
+    for i in range(0, t, bq):
+        lo = max(0, i - window + 1) if windowed else 0
+        outs.append(block(q[:, :, i:i + bq], k[:, :, lo:i + bq],
+                          v[:, :, lo:i + bq], i, lo))
     return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=2)
 
 

@@ -33,6 +33,10 @@ configuration passes the published widths as overrides):
                        rotation) and softmax-routed three-matrix experts,
                        untied head; trained by diffusion over blocks
                        (`training.lm_loss.make_block_diffusion_loss`)
+  * smallthinker_decoder — the same module, a global attention layer
+                       without rotation (`*`) beside sliding-window layers
+                       with it (`W`), ReGLU experts ('relu') routed by the
+                       attention step's input; untied head
 """
 from __future__ import annotations
 
@@ -193,6 +197,26 @@ def sdar_decoder(**overrides) -> HybridDecoder:
     return HybridDecoder(**sizes)
 
 
+def smallthinker_decoder(**overrides) -> HybridDecoder:
+    """Tiny widths by default (CPU tests): one period of a decoder whose
+    global attention layers carry no rotation and whose other three slide a
+    window with one (`*EWEWEWE`; 6 query heads over 2 key-value heads, a
+    window of 5 tokens), each followed by ReGLU experts (2 of 8 a token by
+    softmax scores, 4 held here, no shared one) routed by the attention
+    step's normed input, untied head. Train it with
+    `training.lm_loss.make_lm_loss(module)`."""
+    sizes = dict(
+        vocab_rows=48, hidden_size=32, hybrid_override_pattern='*EWEWEWE',
+        moe_intermediate_size=16, n_routed_experts=8, num_experts_per_tok=2,
+        experts_held=4, mlp_hidden_act='relu', scoring_func='softmax',
+        moe_enable_early_router=True, num_attention_heads=6,
+        num_key_value_heads=2, head_dim=8, rope_theta=None,
+        sliding_window_size=5, sliding_rope_theta=1.5e6,
+        layer_norm_epsilon=1e-6)
+    sizes.update(overrides)
+    return HybridDecoder(**sizes)
+
+
 RECIPES = {
     'toy_denoise': toy_denoise,
     'flagship': flagship,
@@ -204,4 +228,5 @@ RECIPES = {
     'hybrid_decoder': hybrid_decoder,
     'lfm2_decoder': lfm2_decoder,
     'sdar_decoder': sdar_decoder,
+    'smallthinker_decoder': smallthinker_decoder,
 }

@@ -263,7 +263,7 @@ def test_the_streaming_kernel_interpreted_is_the_blocked_core(norm, rotate):
         return kernels.block_attention(
             q, k, v, norms if norm else None,
             rotary_tables(angles) if rotate else None, dh, dh ** -0.5, 1e-6,
-            BK, 128, True)
+            ('bd', BK), 128, True)
 
     def blocked(q, k, v, norms):
         q, k, v = _composition(q, k, v, norms if norm else None,
@@ -428,14 +428,14 @@ def test_on_a_tpu_the_kernels_run_where_can_run_holds(
     assert taken == ([(
         'kernels', (1, 2 * length, 4 * head_dim),
         (1, 2 * length, 2 * head_dim), 2, 2, head_dim, head_dim ** -0.5,
-        1e-6, block_length, block)] if runs else ['blocked']), case
+        1e-6, ('bd', block_length), block)] if runs else ['blocked']), case
 
 
 def _core(q, k, v):
     """The rule that ships with no norm and no rotation, interpreted, in and
     out in the projections' layout."""
-    return kernels.block_attention(q, k, v, None, None, 128, 0.1, 1e-6, BK,
-                                   128, True)
+    return kernels.block_attention(q, k, v, None, None, 128, 0.1, 1e-6,
+                                   ('bd', BK), 128, True)
 
 
 @pytest.mark.parametrize('policy,forwards', [('SAVE_ATTN_CORE', 1),

@@ -677,3 +677,93 @@ def test_the_same_module_trained_next_token_keeps_the_causal_leaf():
     leaves = {profiling.scope_leaf(p)
               for p in _tiny_block_diffusion_step_paths(batch_of)}
     assert 'mha_core' in leaves and not leaves & set(BD_LEAVES)
+
+
+# --------------------------------------------------------------------- #
+# the sliding-window layers' leaf, beside the global layers' `mha_core`
+# --------------------------------------------------------------------- #
+def test_the_sliding_window_leaf_is_on_the_closed_list_and_new(labelled):
+    assert 'swa_core' in MODEL_SCOPES
+    assert 'swa_core' not in (HYBRID_LEAVES + DECODER_LEAVES + SCONV_LEAVES
+                              + BD_LEAVES)
+    comps = {c for _, _, p in labelled for c in p.split(';')[0].split('/')}
+    assert 'swa_core' not in comps
+
+
+def test_a_tiny_sliding_window_step_tells_its_two_cores_apart():
+    """One program, two cores: the global layer's under `mha_core`, the three
+    sliding layers' under `swa_core`, each in forward, replay and backward
+    (off the TPU the blocked forms are recomputed); the router's product
+    under `moe_router` in the expert step, though its input is the attention
+    step's; every other leaf as the other decoders write it."""
+    import optax
+
+    from se3_transformer_tpu.parallel.sharding import make_sharded_train_step
+    from se3_transformer_tpu.training.lm_loss import make_lm_loss
+    from se3_transformer_tpu.training.recipes import RECIPES
+    module = RECIPES['smallthinker_decoder'](attention_block=8)
+    tokens = jax.ShapeDtypeStruct((2, 16), jnp.int32)
+    params = jax.eval_shape(module.init, jax.random.PRNGKey(0),
+                            tokens)['params']
+    optimizer = optax.adam(1e-4)
+    text = make_sharded_train_step(
+        make_lm_loss(module, chunk=8), optimizer).lower(
+        params, jax.eval_shape(optimizer.init, params), dict(tokens=tokens),
+        jax.ShapeDtypeStruct((2,), jnp.uint32)).as_text(debug_info=True)
+    paths = {p.rsplit('/', 1)[0] for p in re.findall(
+        r'"(jit\(train_step\)/[^"]*)"', text)}
+    cells = {(profiling.scope_leaf(p), profiling.scope_phase(p))
+             for p in paths}
+    assert {leaf for leaf, _ in cells} == {
+        'swa_core', 'mha_core', 'mha_qkv', 'mha_out', 'embed', 'moe_router',
+        'moe_dispatch', 'moe_experts', 'moe_combine', 'lm_head', 'norm',
+        'loss', 'optimizer'}
+    for leaf in ('swa_core', 'mha_core', 'mha_qkv', 'moe_experts',
+                 'moe_router'):
+        assert {ph for lf, ph in cells if lf == leaf} == set(
+            profiling.PHASES), leaf
+    by_block = {leaf: {re.search(r'blocks_(\d)', p).group(1) for p in paths
+                       if f'/attn/{leaf}' in p}
+                for leaf in ('mha_core', 'swa_core')}
+    assert by_block == {'mha_core': {'0'}, 'swa_core': {'2', '4', '6'}}
+    routers = {re.search(r'blocks_(\d)', p).group(1) for p in paths
+               if '/moe_router' in p}
+    assert routers == {'1', '3', '5', '7'}
+
+
+@pytest.mark.parametrize('rematted', [False, True])
+def test_on_a_tpu_the_window_cores_launches_are_filed_under_swa_core(
+        monkeypatch, rematted):
+    """What a layer with a window takes on a TPU at shapes `launches_run`
+    admits: the same two kernels under the window's rule, named
+    `swa_core_fwd` and `swa_core_bwd` and filed under the leaf `swa_core`,
+    the one pass before and after them under `mha_qkv`; a rematted block
+    replays the pass and no launch of the core."""
+    from se3_transformer_tpu.ops import latent_attention, sliding_window
+    from se3_transformer_tpu.ops.grouped_attention import (
+        GroupedQueryAttention,
+    )
+    monkeypatch.setattr(sliding_window, 'is_tpu_backend', lambda: True)
+    attn = GroupedQueryAttention(dim=32, heads=7, kv_heads=1, head_dim=128,
+                                 block=128, rope_theta=1.5e6, window=200)
+    x = jnp.ones((1, 512, 32))
+    params = jax.eval_shape(attn.init, jax.random.PRNGKey(0), x)['params']
+
+    def layer(p, x):
+        return attn.apply({'params': p}, x)
+
+    if rematted:
+        layer = jax.checkpoint(layer, policy=latent_attention.SAVE_ATTN_CORE)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p: layer(p, x).sum()))(params)
+    filed = {(name, profiling.scope_leaf(path), profiling.scope_phase(path))
+             for name, path in _launch_paths(jaxpr.jaxpr)}
+    assert filed == {('swa_core_fwd', 'swa_core', 'forward'),
+                     ('swa_core_bwd', 'swa_core', 'backward'),
+                     ('qk_pass_fwd', 'mha_qkv', 'forward'),
+                     ('qk_pass_bwd', 'mha_qkv', 'backward')} | (
+        {('qk_pass_fwd', 'mha_qkv', 'replay')} if rematted else set())
+    assert not {'swa_core_fwd', 'swa_core_bwd'} & set(MODEL_SCOPES)
+    under_core = {str(eqn.primitive) for eqn, path in _eqn_paths(jaxpr.jaxpr)
+                  if profiling.scope_leaf(path) == 'swa_core'}
+    assert under_core <= {'pallas_call', 'jit', 'name',
+                          'reduce_precision'}, under_core

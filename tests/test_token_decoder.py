@@ -327,7 +327,7 @@ def test_grouped_dot_leaves_rows_past_the_groups_zero_and_differentiates():
 
 @pytest.mark.parametrize('dtype', [None, jnp.bfloat16],
                          ids=['float32', 'bfloat16'])
-@pytest.mark.parametrize('form', ['product', 'relu2', 'silu'])
+@pytest.mark.parametrize('form', ['product', 'relu2', 'silu', 'relu'])
 def test_padded_widths_give_the_unpadded_products_and_cotangents(
         monkeypatch, form, dtype):
     """The form a TPU takes at widths that are multiples of nothing (40 and
@@ -365,8 +365,9 @@ def test_padded_widths_give_the_unpadded_products_and_cotangents(
             assert expert_layer.padded_width(width) == (32 if padded
                                                         else width)
             assert expert_layer.padded_width(48) == 48
-            return expert_layer._stages(rows, None, None, sizes, rows, 1,
-                                        form == 'silu', dtype)[1], (xs, mats)
+            return expert_layer._stages(
+                rows, None, None, sizes, rows, 1,
+                expert_layer.EXPERT_FORMS[form][1], dtype)[1], (xs, mats)
 
     results = []
     for padded in (False, True):

@@ -632,6 +632,12 @@ LOWERED_BEFORE_THE_ONE_PASS = {
     'hybrid': ('83999f68a01618b1', '163f4d791ee9e1c7'),
     'lfm2': ('0690edcb2e6f7ca7', '390b269ab0cf6096'),
 }
+# the same of the block-diffusion cell's step, lowered from the tree before
+# its kernels took a second rule (a sliding window) and `ExpertLayer` a third
+# form and a routing input: that step is this one, launch for launch
+LOWERED_BEFORE_THE_WINDOW = {
+    'sdar': ('22b4f6dde5632b50', '46d73dc846680a68'),
+}
 
 
 def _assert_one_forward_core_a_layer(text, layers, leaf):
@@ -1004,7 +1010,8 @@ def test_the_block_diffusion_core_compiles_and_visits_288_tiles_a_head(v5e):
 
     def loss(q, k, v, norms, rotary):
         return kernels.block_attention(
-            q, k, v, norms, rotary, 128, 128 ** -0.5, 1e-6, 4, 512).astype(
+            q, k, v, norms, rotary, 128, 128 ** -0.5, 1e-6, ('bd', 4),
+            512).astype(
             f32).sum()
 
     def on_chip(*shape):
@@ -1099,10 +1106,12 @@ def test_sdar_decoder_step_compiles_and_fits(v5e, monkeypatch, capsys):
     params = jax.eval_shape(module.init, jax.random.PRNGKey(0),
                             tokens)['params']
     optimizer = optax.adam(1e-6)
-    compiled = make_sharded_train_step(
+    lowered = make_sharded_train_step(
         make_block_diffusion_loss(module, **cfg['loss']), optimizer).lower(
         on_chip(params), on_chip(jax.eval_shape(optimizer.init, params)),
-        on_chip(batch), on_chip(jax.random.PRNGKey(1))).compile()
+        on_chip(batch), on_chip(jax.random.PRNGKey(1)))
+    assert _program_digests(lowered) == LOWERED_BEFORE_THE_WINDOW['sdar']
+    compiled = lowered.compile()
     text = compiled.as_text()
     assert 'ragged-dot' in text and 'flash_attention' not in text
     assert 'splash' not in text
@@ -1132,6 +1141,131 @@ def test_sdar_decoder_step_compiles_and_fits(v5e, monkeypatch, capsys):
               f'{mem.temp_size_in_bytes / 2**30:.2f} GiB, in all '
               f'{total / 2**30:.2f} GiB of 15.75')
     assert 11.5 * 2 ** 30 < total < 13.5 * 2 ** 30, mem
+
+
+# ------------------------------------------------------------------ #
+# the sliding-window core (ops/sliding_window.py) and its decoder
+# ------------------------------------------------------------------ #
+def _window_launches(text):
+    """(role, op_name) of every launch of the sliding-window core and of the
+    pass before and after it in a compiled program."""
+    return re.findall(
+        r'%((?:swa_core|qk_pass)_(?:fwd|bwd))[.\d]* = .*?'
+        r'metadata=\{op_name="([^"]*)"', text, flags=re.S)
+
+
+def test_the_sliding_window_core_compiles_and_visits_252_tiles_a_head(v5e):
+    """The repo's kernels under the window's rule at the new cell's size (28
+    query heads over 4 key-value heads of 128, groups of 7, 16,384
+    positions, a window of 4,096, tiles of 512), in the projections' own
+    layout with rotation and no norms: the pass and the core lower for the
+    chip forward and backward, one launch each, and the table the core's
+    grid is taken from holds 252 of a head's 1,024 tiles where the causal
+    triangle has 528: a query tile meets itself, the seven whole tiles
+    before it and the far edge's (fewer at the sequence's start), 56 of
+    them on a boundary (32 diagonals, 24 far edges). No tile wholly outside
+    the window is launched, and nothing is laid out again around the
+    launches."""
+    from se3_transformer_tpu.kernels import pallas_block_attention as kernels
+    from se3_transformer_tpu.ops import sliding_window as sw
+
+    assert kernels.launches_run(16384, 512, 28, 4, 128)
+    assert sw.visited_tiles(16384, 4096, 512) == 252 \
+        == sum(min(i, 8) + 1 for i in range(32))
+    assert sw.boundary_tiles(16384, 4096, 512) == 56 == 32 + 24
+    assert sw.visited_tiles(16384, 16384, 512) == 528
+    assert sw.visible_pairs(16384, 4096) == 58_722_304
+    assert sw.visible_pairs(16384, 16384) == 134_225_920
+
+    def loss(q, k, v, rotary):
+        return kernels.block_attention(
+            q, k, v, None, rotary, 128, 128 ** -0.5, 1e-6, ('swa', 4096),
+            512).astype(f32).sum()
+
+    def on_chip(*shape):
+        return jax.ShapeDtypeStruct(shape, f32, sharding=v5e)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        on_chip(1, 16384, 28 * 128), on_chip(1, 16384, 4 * 128),
+        on_chip(1, 16384, 4 * 128),
+        (on_chip(16384, 128), on_chip(16384, 128))).compile()
+    text = compiled.as_text()
+    roles = [role for role, _ in _window_launches(text)]
+    assert sorted(roles) == ['qk_pass_bwd', 'qk_pass_fwd', 'swa_core_bwd',
+                             'swa_core_fwd'], roles
+    # the grid is the table: sequences x key-value heads x 252 entries
+    assert text.count('s32[7,252]') >= 2 and 'bd_core' not in text
+    assert 'f32[1,28,1,16384]' in text and 'f32[1,16384,3584]{' in text
+    assert not re.search(r'\[1,28,16384,128\]|\[1,16384,28,128\]', text)
+    assert not re.search(r' (copy|transpose)\(', text[text.index('ENTRY'):])
+
+
+@pytest.mark.slow
+def test_smallthinker_decoder_step_compiles_and_fits(v5e, monkeypatch,
+                                                     capsys):
+    """The benchmark's sliding-window cell: the published widths of its
+    configuration file on the one step factory at one sequence of 16,384
+    tokens, compiled for the chip: the global layer's core is the library's
+    causal kernel under `mha_core` (one forward, none in a replay), each of
+    the three sliding layers one forward and one backward launch of the
+    repo's own under `swa_core` and none in a replay, the one pass before
+    them forward, replayed and backward under `mha_qkv`; the grouped
+    products are in it; state plus temporaries fit; its memory is
+    printed."""
+    import optax
+    from se3_transformer_tpu.ops import (
+        expert_layer, latent_attention, sliding_window,
+    )
+    from se3_transformer_tpu.parallel.sharding import make_sharded_train_step
+    from se3_transformer_tpu.training.lm_loss import make_lm_loss
+    from se3_transformer_tpu.training.recipes import RECIPES
+
+    for mod in (sliding_window, latent_attention, expert_layer):
+        monkeypatch.setattr(mod, 'is_tpu_backend', lambda: True)
+    cfg = json.load(open(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        'benchmark', 'configs', 'smallthinker-21b-a3b-swa-train.json')))
+    module = RECIPES[cfg['recipe']](**cfg['model'], **cfg['overrides'])
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+            tree)
+
+    tokens = jax.ShapeDtypeStruct((1, 16384), jnp.int32)
+    params = jax.eval_shape(module.init, jax.random.PRNGKey(0),
+                            tokens)['params']
+    optimizer = optax.adam(1e-6)
+    compiled = make_sharded_train_step(
+        make_lm_loss(module, **cfg['loss']), optimizer).lower(
+        on_chip(params), on_chip(jax.eval_shape(optimizer.init, params)),
+        on_chip(dict(tokens=tokens)), on_chip(jax.random.PRNGKey(1))).compile()
+    text = compiled.as_text()
+    assert 'ragged-dot' in text and 'splash' not in text
+    _assert_one_forward_core_a_layer(text, 1, 'mha_core')
+    from se3_transformer_tpu.observability import profiling
+    by_role = {}
+    for role, path in _window_launches(text):
+        leaf = '/attn/swa_core/' if role.startswith('swa_core') \
+            else '/attn/mha_qkv/'
+        assert leaf in path, path
+        key = role, profiling.scope_phase(path)
+        by_role[key] = by_role.get(key, 0) + 1
+    assert by_role == {('swa_core_fwd', 'forward'): 3,
+                       ('swa_core_bwd', 'backward'): 3,
+                       ('qk_pass_fwd', 'forward'): 3,
+                       ('qk_pass_fwd', 'replay'): 3,
+                       ('qk_pass_bwd', 'backward'): 3}, by_role
+    _assert_product_front_ends_agree(compiled)
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    with capsys.disabled():
+        print(f'\nsmallthinker step for a v5e: arguments '
+              f'{mem.argument_size_in_bytes / 2**30:.2f} GiB, temporaries '
+              f'{mem.temp_size_in_bytes / 2**30:.2f} GiB, in all '
+              f'{total / 2**30:.2f} GiB of 15.75')
+    assert 12 * 2 ** 30 < total < 14.75 * 2 ** 30, mem
 
 
 def test_the_causal_path_lowers_as_it_did_before_the_two_streams(v5e):

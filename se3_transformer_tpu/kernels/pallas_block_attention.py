@@ -1,5 +1,5 @@
 """An attention core whose mask is a static rule, as two Pallas kernels over
-a table of the tiles that hold a visible pair. Two rules, each a pair
+a table of the tiles that hold a visible pair. Three rules, each a pair
 (kind, n) that names its leaf and its launches (`<kind>_core`,
 `<kind>_core_fwd`, `<kind>_core_bwd`):
 
@@ -18,6 +18,15 @@ a table of the tiles that hold a visible pair. Two rules, each a pair
                            the table where the causal triangle has 528, 56
                            of them on a boundary; a window of T or more is
                            the causal triangle.
+    ('mha', 0)             the causal triangle over T positions, a decoder's
+                           global layers: the window's table at a window of
+                           T (n says nothing: 0, as a layer with no window
+                           has it), 528 tiles a head at T = 16,384 and
+                           tiles of 512 and 136 at 8,192, the diagonal's 32
+                           and 16 alone on a boundary (`0 <= r - c`). A
+                           rule of its own for the leaf: what reads
+                           `mha_core` reads a model's global layers, what
+                           reads `swa_core` its sliding ones.
 
 The block-diffusion rule:
 
@@ -119,7 +128,7 @@ BOUNDS = {FULL: (-FAR, FAR), NOISED_NOISED: (0, 0), NOISED_CLEAN: (1, FAR),
 
 def launches_run(positions: int, tile: int, heads: int, kv_heads: int,
                  head_dim: int) -> bool:
-    """What Mosaic's tiles ask of either rule: whole tiles, tiles and heads
+    """What Mosaic's tiles ask of any rule: whole tiles, tiles and heads
     of whole lane rows, whole groups of query heads (of any size: the
     programs loop over a group's heads); and what the backward asks: a
     key-value head's dk and dv [positions, head_dim] float32, in the
@@ -192,17 +201,19 @@ def window_table(positions: int, window: int, tile: int) -> np.ndarray:
 
 def rule_table(rule, positions: int, tile: int) -> np.ndarray:
     """The table of `rule` = (kind, n) over `positions` (both streams of a
-    block-diffusion sequence, or a window's T)."""
+    block-diffusion sequence, a window's T, or the causal triangle's: the
+    window's table at a window of T)."""
     kind, n = rule
     return tile_table(positions // 2, n, tile) if kind == 'bd' \
-        else window_table(positions, n, tile)
+        else window_table(positions, n if kind == 'swa' else positions, tile)
 
 
 def _core_scope(rule):
     """The leaf a rule's two launches run under (each a literal, as the
     closed list of leaves is checked)."""
     return named_scope('bd_core') if rule[0] == 'bd' \
-        else named_scope('swa_core')
+        else named_scope('swa_core') if rule[0] == 'swa' \
+        else named_scope('mha_core')
 
 
 def _granule(rule) -> int:
@@ -434,7 +445,7 @@ def _bwd(q, k, v, o, do, lse, head_dim, rule, tile, interpret):
 
 
 # --------------------------------------------------------------------- #
-# from the projections' outputs to the core's, under either rule
+# from the projections' outputs to the core's, under any rule
 # --------------------------------------------------------------------- #
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
 def block_attention(q, k, v, norms, rotary, head_dim, scale, eps, rule, tile,
@@ -443,9 +454,10 @@ def block_attention(q, k, v, norms, rotary, head_dim, scale, eps, rule, tile,
     them (the noised stream, then the clean one; under a window's rule one
     stream of T positions); `norms` the scales [D] of the queries' and the
     keys' RMSNorm, or None; `rotary` the rotation's tables
-    (`pallas_qk_pass.rotary_tables`), or None; `rule` = ('bd', block_length)
-    or ('swa', window) -> o [B, 2L, H D] in the operands' width, as the
-    output projection reads it; shapes as `can_run` / `launches_run` ask.
+    (`pallas_qk_pass.rotary_tables`), or None; `rule` = ('bd', block_length),
+    ('swa', window) or ('mha', 0) -> o [B, 2L, H D] in the operands' width,
+    as the output projection reads it; shapes as `can_run` / `launches_run`
+    ask.
     One rule of differentiation for the pass and the core, so that dq, dk
     and dv go from the core's backward launch to `qk_pass_bwd` in float32;
     the core's launches under the leaf `<kind>_core`."""

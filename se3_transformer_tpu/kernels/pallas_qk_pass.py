@@ -1,23 +1,27 @@
-"""The road between an attention layer's three projections and the
-block-diffusion core's launches (`kernels/pallas_block_attention.py`), one
-pass over HBM each way, in the layout both ends share: token-major,
+"""The road between an attention layer's three projections and the core's
+launches (`kernels/pallas_block_attention.py`, under any of its rules:
+`<kind>_core_fwd` and `<kind>_core_bwd` for a kind of `bd`, `swa` or `mha`),
+one pass over HBM each way, in the layout both ends share: token-major,
 q [B, T, H D], k and v [B, T, KV D], a head a run of D lanes (whole lane
-rows: D a multiple of 128, as the core's `can_run` asks).
+rows: D a multiple of 128, as the core's `launches_run` asks).
 
-    qk_pass_fwd  reads the projections' float32 outputs and writes what
-                 `bd_core_fwd` reads, once: q normed (float32 mean of
+    qk_pass_fwd  reads the projections' float32 outputs and writes what the
+                 core's forward launch reads, once: q normed (float32 mean of
                  squares over the head's channels, eps, the learned scale),
                  rotated (half-split pairs), multiplied by the softmax's
                  scale and rounded; k normed, rotated, rounded; v rounded
-    qk_pass_bwd  reads dq, dk and dv in float32 as `bd_core_bwd` sums them
-                 and the projections' outputs again: the scale, the
+    qk_pass_bwd  reads dq, dk and dv in float32 as the core's backward
+                 launch sums them and the projections' outputs again (where
+                 there is a norm: nothing else reads them): the scale, the
                  rotation's transpose and the norm's backward in float32,
                  the two norm scales' gradients summed in float32 (one
                  partial row a program, added up outside), and the
                  projections' cotangents written once in the width their
                  products' operands are rounded to
 
-Norm and rotation are each there or not (static), as the layer has them.
+Norm and rotation are each there or not (static), as the layer has them: a
+global layer with neither (the hybrid's, the sliding-window decoder's) gets
+q scaled and rounded, k and v rounded, and nothing else.
 The rotation's tables are [T, D] float32, built once from the positions
 (`rotary_tables`): cos twice over, and sin with the first half negated, so
 that a head is rotated in its lanes, x C + swap(x) S with swap the halves
@@ -39,7 +43,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-ROWS = 256      # of a program: 1 MiB of a group's float32 q at 8 heads of 128
+# of a program: 1 MiB of a group's float32 q at 8 heads of 128, 2 MiB at 16
+# (the hybrid's global layer: compiled for the chip within the default,
+# tests/test_tpu_compile.py)
+ROWS = 256
 
 
 def rotary_tables(angles):

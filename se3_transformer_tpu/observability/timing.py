@@ -110,13 +110,21 @@ MODEL_SCOPES = (
     'ssm_out',            # ops/state_space.py: output projection
     'mha_qkv',            # ops/grouped_attention.py: q, k, v projections,
     #                       q/k norms and rotation where the model has
-    #                       them, the key-value heads repeated; before the
-    #                       block-diffusion core's kernels norm, rotation,
-    #                       scale and rounding are the launches
-    #                       `qk_pass_fwd` and `qk_pass_bwd` of
-    #                       kernels/pallas_qk_pass.py
+    #                       them; before the repo's own core launches
+    #                       (any leaf below) norm, rotation, scale and
+    #                       rounding are the launches `qk_pass_fwd` and
+    #                       `qk_pass_bwd` of kernels/pallas_qk_pass.py,
+    #                       before the library's kernel XLA's passes and
+    #                       the key-value heads repeated
     'mha_core',           # ops/grouped_attention.py: scores, softmax,
-    #                       weighted sum (the streaming kernel on a TPU)
+    #                       weighted sum of a global layer (every key at or
+    #                       before the query): on a TPU at heads of whole
+    #                       lane rows the two launches of
+    #                       kernels/pallas_block_attention.py over the
+    #                       causal triangle's table, `mha_core_fwd` and
+    #                       `mha_core_bwd`; at heads of 64 the library's
+    #                       streaming kernel's three; blocks of queries
+    #                       off the TPU
     'mha_out',            # ops/grouped_attention.py: output projection
     # a pattern's `F` layers (models/hybrid_decoder.py) are filed under
     # `dense_ff`
@@ -135,7 +143,7 @@ MODEL_SCOPES = (
     #                       on a TPU the same two launches under the
     #                       window's table, `swa_core_fwd` and
     #                       `swa_core_bwd`), apart from `mha_core`, which a
-    #                       decoder's global layers keep
+    #                       decoder's global layers keep whoever runs them
     'bd_streams',         # models/hybrid_decoder.py, training/lm_loss.py:
     #                       building the two streams and their positions,
     #                       cutting the noised one out, the weights

@@ -14,33 +14,37 @@ sliding window are optional:
                    (the token itself counts); 0: every key at or before i
     softmax(q k^T / sqrt(head_dim)), causal, in float32;  out = o Wo
 
-The core is the latent attention's (`ops/latent_attention.py`): JAX's
-streaming Pallas kernel on a TPU, blocks of queries elsewhere. Both take
-[B, H, T, D] and as many key-value heads as query heads, so the heads are
-laid out so and the key-value heads repeated here, and autodiff sums their
-gradients over each group.
+Three cores, each under a leaf of its own, so one table tells them apart:
+`mha_core` a global layer's (every key at or before the query), `swa_core`
+that of a layer with a `window` (`ops/sliding_window.py`), `bd_core` the
+block-diffusion core's (`ops/block_diffusion.py`): called with a
+`block_length` (static) the input is the two streams of a decoder trained by
+diffusion over blocks, [noised ; clean] along T, and `positions` [T] the
+rotation's (the two copies of a token share one).
 
-Called with a `block_length` (static) the input is the two streams of a
-decoder trained by diffusion over blocks, [noised ; clean] along T, and
-`positions` [T] the rotation's (the two copies of a token share one): the
-core is then `ops/block_diffusion.py`'s, under its own leaf `bd_core`, and
-takes the key-value heads as they are. Where its kernels run
-(`block_diffusion.kernels_run`: on a TPU, heads of whole lane rows, whole
-tiles) nothing is laid out again on either side of them: the projections'
-outputs [B, T, H D] and [B, T, KV D] go through one launch that norms,
-rotates, scales and rounds them (`kernels/pallas_qk_pass.py`: `qk_pass_fwd`
-under `mha_qkv`, and `qk_pass_bwd` on the way back), the core's launches
-read and write that layout, and the output projection reads o [B, T, H D]
-as the core wrote it. Elsewhere the composition below and the blocked core.
-Projections, norms and rotation are the same arithmetic either way.
+On a TPU, at the shapes the kernels' predicate admits
+(`sliding_window.kernels_run`, `block_diffusion.kernels_run`: heads of whole
+lane rows, whole tiles, whole groups of any size, 7 query heads a key-value
+head or 16 as 8), all three are `kernels/pallas_block_attention.py`'s two
+launches under the layer's rule (('mha', 0), ('swa', window), ('bd',
+block_length): the table of the tiles that hold a visible pair, no other
+launched, and the names `<leaf>_fwd` / `<leaf>_bwd`), and nothing is laid
+out again on either side of them: the projections' outputs [B, T, H D] and
+[B, T, KV D] go through one launch that norms, rotates, scales and rounds
+them (`kernels/pallas_qk_pass.py`: `qk_pass_fwd` under `mha_qkv`, and
+`qk_pass_bwd` on the way back; norm and rotation each there or not, as the
+layer has them), the core's launches read and write that layout with the
+key-value heads as they are, and the output projection reads o [B, T, H D]
+as the core wrote it.
 
-A layer with a `window` has its core under a leaf of its own, `swa_core`
-(`ops/sliding_window.py`), so one table tells a decoder's sliding layers
-from its global ones (`mha_core`): on a TPU the same kernels under the rule
-('swa', window), the same one pass before them (with no norms, a group of
-any size: 7 query heads a key-value head run as 8 do), no tile wholly
-outside the window launched; elsewhere the composition and the blocked
-causal core with the window's key extents.
+At any other shape and off the TPU the composition below: the heads laid out
+[B, H, T, D], and a blocked core with the layer's key extents (a window's,
+the two streams'); a global layer's core is then the latent attention's
+(`ops/latent_attention.py::causal_attention`: JAX's streaming Pallas kernel
+on a TPU, as heads of 64 take it, blocks of queries elsewhere), which takes
+as many key-value heads as query heads, so they are repeated here and
+autodiff sums their gradients over each group. Projections, norms and
+rotation are the same arithmetic either way, and so is the parameter tree.
 """
 from __future__ import annotations
 
@@ -89,13 +93,14 @@ class GroupedQueryAttention(nn.Module):
         assert h % kv == 0, (h, kv)
         dense = partial(nn.Dense, use_bias=False)
         assert not (block_length and self.window), (block_length, self.window)
-        if self.window:
-            rule, tile = ('swa', self.window), min(self.block, t)
-            one_pass = sliding_window.kernels_run(t, self.block, h, kv, dh)
-        else:
+        if block_length:
             rule, tile = ('bd', block_length), min(self.block, t // 2)
-            one_pass = block_length and kernels_run(
-                t // 2, block_length, self.block, h, kv, dh)
+            one_pass = kernels_run(t // 2, block_length, self.block, h, kv,
+                                   dh)
+        else:           # one stream of T positions, a window of them or all
+            rule = ('swa', self.window) if self.window else ('mha', 0)
+            tile = min(self.block, t)
+            one_pass = sliding_window.kernels_run(t, self.block, h, kv, dh)
 
         def heads_of(a, n):     # the kernels take the products' own layout
             return a if one_pass else a.reshape(b, t, n, dh)
@@ -126,7 +131,7 @@ class GroupedQueryAttention(nn.Module):
                     k, v = (jnp.repeat(a, h // kv, axis=2) for a in (k, v))
                 q, k, v = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
         if one_pass:    # its scopes are the rule's own: `mha_qkv` and the
-            #             core's, `bd_core` or `swa_core`
+            #             core's, `bd_core`, `swa_core` or `mha_core`
             o = kernels.block_attention(
                 q, k, v, norms, rotary, dh, dh ** -0.5, self.eps, rule, tile)
         elif self.window:

@@ -4,8 +4,8 @@ min(i + 1, window) keys and a head T w - w (w - 1) / 2 pairs for w =
 min(window, T) (`visible_pairs`): at T = 16,384 and a window of 4,096,
 58.7 M of the causal triangle's 134.2 M.
 
-Two forms, as the causal and the block-diffusion cores have; `kernels_run`
-says which a layer takes:
+Two forms, as the block-diffusion core has; `kernels_run` says which a layer
+takes:
 
   * off the TPU `ops/latent_attention.py::causal_attention_blocked` with a
     `window`: blocks of queries against static key extents that start at
@@ -18,6 +18,14 @@ says which a layer takes:
     (`boundary_tiles`), every operand in the projections' own layout and
     the rotation in `kernels/pallas_qk_pass.py`'s one pass, exactly as the
     block-diffusion core runs them.
+
+A window of T or more is the causal triangle, and a decoder's global layers
+(no window at all) ask the same predicate and take the same launches over
+that table, `window_table(T, T, tile)`, under a rule and a leaf of their
+own (('mha', 0): `mha_core_fwd`, `mha_core_bwd` under `mha_core`), so that
+`swa_core` holds a model's sliding layers and nothing else. Where the
+predicate fails (heads of 64, a length no tile divides, off the TPU) they
+keep `ops/latent_attention.py::causal_attention`.
 """
 from __future__ import annotations
 
@@ -54,8 +62,9 @@ def boundary_tiles(positions: int, window: int, tile: int) -> int:
 
 def kernels_run(positions: int, block: int, heads: int, kv_heads: int,
                 head_dim: int) -> bool:
-    """Whether a layer of these shapes takes the kernels (on a TPU, at the
-    shapes of `kernels.launches_run`, tiles of `block` or the sequence) or
-    the blocked core, from the platform and the shapes alone."""
+    """Whether a layer over one stream of `positions`, with a window or
+    without, takes the kernels (on a TPU, at the shapes of
+    `kernels.launches_run`, tiles of `block` or the sequence) or the
+    composition and its core, from the platform and the shapes alone."""
     return is_tpu_backend() and kernels.launches_run(
         positions, min(block, positions), heads, kv_heads, head_dim)

@@ -767,3 +767,51 @@ def test_on_a_tpu_the_window_cores_launches_are_filed_under_swa_core(
                   if profiling.scope_leaf(path) == 'swa_core'}
     assert under_core <= {'pallas_call', 'jit', 'name',
                           'reduce_precision'}, under_core
+
+
+@pytest.mark.parametrize('rematted', [False, True])
+def test_on_a_tpu_a_global_layers_launches_are_filed_under_mha_core(
+        monkeypatch, rematted):
+    """What a layer with neither window nor block length takes on a TPU at
+    shapes `launches_run` admits (groups of 16 here, as the hybrid's): the
+    same two kernels under the rule ('mha', 0), named `mha_core_fwd` and
+    `mha_core_bwd` and filed under the leaf the global layers always had,
+    `mha_core`, so what reads that leaf reads the same work whoever runs
+    it; no new leaf on the closed list, the launches' names are none; the
+    one pass before and after them under `mha_qkv`; a rematted block
+    replays the pass and no launch of the core, and nothing else is issued
+    under the leaf; no array anywhere has the heads laid out or the
+    key-value head repeated ([T, H, D], [H, T, D])."""
+    from se3_transformer_tpu.ops import latent_attention, sliding_window
+    from se3_transformer_tpu.ops.grouped_attention import (
+        GroupedQueryAttention,
+    )
+    before = set(MODEL_SCOPES)
+    monkeypatch.setattr(sliding_window, 'is_tpu_backend', lambda: True)
+    attn = GroupedQueryAttention(dim=32, heads=16, kv_heads=1, head_dim=128,
+                                 block=128)
+    x = jnp.ones((1, 256, 32))
+    params = jax.eval_shape(attn.init, jax.random.PRNGKey(0), x)['params']
+
+    def layer(p, x):
+        return attn.apply({'params': p}, x)
+
+    if rematted:
+        layer = jax.checkpoint(layer, policy=latent_attention.SAVE_ATTN_CORE)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p: layer(p, x).sum()))(params)
+    filed = {(name, profiling.scope_leaf(path), profiling.scope_phase(path))
+             for name, path in _launch_paths(jaxpr.jaxpr)}
+    assert filed == {('mha_core_fwd', 'mha_core', 'forward'),
+                     ('mha_core_bwd', 'mha_core', 'backward'),
+                     ('qk_pass_fwd', 'mha_qkv', 'forward'),
+                     ('qk_pass_bwd', 'mha_qkv', 'backward')} | (
+        {('qk_pass_fwd', 'mha_qkv', 'replay')} if rematted else set())
+    assert not {'mha_core_fwd', 'mha_core_bwd'} & set(MODEL_SCOPES)
+    assert set(MODEL_SCOPES) == before and 'mha_core' in before
+    under_core = {str(eqn.primitive) for eqn, path in _eqn_paths(jaxpr.jaxpr)
+                  if profiling.scope_leaf(path) == 'mha_core'}
+    assert under_core <= {'pallas_call', 'jit', 'name',
+                          'reduce_precision'}, under_core
+    shapes = {v.aval.shape for eqn, _ in _eqn_paths(jaxpr.jaxpr)
+              for v in eqn.outvars}
+    assert not shapes & {(1, 256, 16, 128), (1, 16, 256, 128)}, shapes

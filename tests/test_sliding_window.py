@@ -248,14 +248,20 @@ def test_the_launches_interpreted_are_the_blocked_core(window, rotate):
     t, heads, kv, d = 512, 14, 2, 128
     q, k, v, do = _projected(t, heads, kv, d)
     angles = rotary_angles(jnp.arange(t), d, 1.5e6) if rotate else None
-    with jax.default_matmul_precision('highest'):
+
+    @jax.jit        # one program: op by op the composition takes a minute
+    def both(q, k, v, do):
         got, vjp = jax.vjp(lambda *a: kernels.block_attention(
             *a, None, rotary_tables(angles) if rotate else None, d,
             d ** -0.5, 1e-6, ('swa', window), 128, True), q, k, v)
         want, want_vjp = jax.vjp(lambda *a: _composed(
             *a, angles, heads, kv, d, window, 64), q, k, v)
+        return got, vjp(do), want, want_vjp(do)
+
+    with jax.default_matmul_precision('highest'):
+        got, grads, want, want_grads = both(q, k, v, do)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
-    for a, b in zip(vjp(do), want_vjp(do)):
+    for a, b in zip(grads, want_grads):
         assert float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b)) < 1e-5
 
 
@@ -360,10 +366,10 @@ def test_a_window_layer_is_the_dense_masked_softmax_at_groups_of_7():
         return o.transpose(0, 2, 1, 3).reshape(b, t, 112) @ p['out']['kernel']
 
     with jax.default_matmul_precision('highest'):
-        got, g = jax.value_and_grad(lambda p: jnp.sum(jnp.sin(
-            attn.apply({'params': p}, x))))(params)
-        want, w = jax.value_and_grad(lambda p: jnp.sum(jnp.sin(
-            dense(p, x))))(params)
+        got, g = jax.jit(jax.value_and_grad(lambda p: jnp.sum(jnp.sin(
+            attn.apply({'params': p}, x)))))(params)
+        want, w = jax.jit(jax.value_and_grad(lambda p: jnp.sum(jnp.sin(
+            dense(p, x)))))(params)
     np.testing.assert_allclose(got, want, rtol=1e-5)
     for a, b in zip(jax.tree_util.tree_leaves(g),
                     jax.tree_util.tree_leaves(w)):

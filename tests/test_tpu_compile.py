@@ -629,7 +629,12 @@ def _program_digests(lowered):
 # programs says so and pins what it made.
 LOWERED_BEFORE_THE_ONE_PASS = {
     'token_decoder': ('fbc6acb3f2404bb1', 'cbb0f31268823c36'),
-    'hybrid': ('83999f68a01618b1', '163f4d791ee9e1c7'),
+    # PR 45 meant to change this one and pins what it made: the hybrid's
+    # global layer (32 heads over 2 of 128) takes the repo's two launches
+    # under ('mha', 0) and the one pass, where the library's kernel, the
+    # repeat of its key-value heads and four transposes were
+    # ('83999f68a01618b1', '163f4d791ee9e1c7' before it)
+    'hybrid': ('1d7574a62a328266', '7db51920a835d127'),
     'lfm2': ('0690edcb2e6f7ca7', '390b269ab0cf6096'),
 }
 # the same of the block-diffusion cell's step, lowered from the tree before
@@ -854,17 +859,21 @@ def _assert_the_scan_is_the_repos_kernels(text, layers, big):
 def test_hybrid_decoder_step_compiles_and_fits(v5e, monkeypatch, capsys):
     """The benchmark's hybrid cell: the published widths of its configuration
     file on the one step factory, compiled for the chip (under a minute):
-    the attention kernel, the grouped products and the scan's two kernels
-    are in it, and state plus temporaries fit; its memory is printed."""
+    the global layer's core is the repo's two launches under `mha_core`
+    (32 heads over 2 of 128: one forward, none in a replay, one backward),
+    the one pass on either side of them under `mha_qkv`, no launch of the
+    library's kernel, no repeated key-value head and nothing laid out again
+    around them; the grouped products and the scan's two kernels are in
+    it, and state plus temporaries fit; its memory is printed."""
     import optax
     from se3_transformer_tpu.ops import (
-        expert_layer, latent_attention, state_space,
+        expert_layer, latent_attention, sliding_window, state_space,
     )
     from se3_transformer_tpu.parallel.sharding import make_sharded_train_step
     from se3_transformer_tpu.training.lm_loss import make_lm_loss
     from se3_transformer_tpu.training.recipes import RECIPES
 
-    for ops in (latent_attention, state_space, expert_layer):
+    for ops in (latent_attention, sliding_window, state_space, expert_layer):
         monkeypatch.setattr(ops, 'is_tpu_backend', lambda: True)
     cfg = json.load(open(os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
@@ -888,8 +897,10 @@ def test_hybrid_decoder_step_compiles_and_fits(v5e, monkeypatch, capsys):
     assert _program_digests(lowered) == LOWERED_BEFORE_THE_ONE_PASS['hybrid']
     compiled = lowered.compile()
     text = compiled.as_text()
-    assert 'flash_mha_bwd_dkv' in text and 'ragged-dot' in text
-    _assert_one_forward_core_a_layer(text, 1, 'mha_core')
+    assert 'ragged-dot' in text
+    _assert_the_cores_are_the_repos_launches(text, 1, 0)
+    _assert_no_relayout_around_the_core(text, 8192 * 32 * 128,
+                                        ('mha_core',), 1)
     # 64 chunks x 64 heads x 128 x 128: what the einsum form wrote a layer
     _assert_the_scan_is_the_repos_kernels(text, 4, 64 * 64 * 128 * 128)
     _assert_product_front_ends_agree(compiled)
@@ -1033,14 +1044,16 @@ def test_the_block_diffusion_core_compiles_and_visits_288_tiles_a_head(v5e):
     assert not re.search(r' (copy|transpose)\(', text[text.index('ENTRY'):])
 
 
-def _assert_no_relayout_around_the_core(text, big):
-    """In a compiled step no instruction under `mha_qkv`, `bd_core` or
-    `mha_out` (forward, replay or backward) that computes nothing passes
-    over a tensor of `big` elements or more (q, o, do or dq: [16384, 32,
-    128] in the cell): no `copy`, no `transpose`, no fusion of converts and
-    layout changes alone. The products and the launches are all that read
-    and write them; what is left of XLA's own is named here (remat rounds
-    the saved o to its own width, `reduce-precision`, one pass a layer)."""
+def _assert_no_relayout_around_the_core(text, big, cores=('bd_core',),
+                                        layers=5):
+    """In a compiled step no instruction under `mha_qkv`, a core's leaf
+    (`cores`) or `mha_out` (forward, replay or backward) that computes
+    nothing passes over a tensor of `big` elements or more (q, o, do or dq:
+    [16384, 32, 128] in the block-diffusion cell): no `copy`, no
+    `transpose`, no fusion of converts and layout changes alone. The
+    products and the launches are all that read and write them; what is
+    left of XLA's own is named here (remat rounds the saved o to its own
+    width, `reduce-precision`, one pass a layer of `layers`)."""
     comps = _computations(text)
     moves = {'copy', 'transpose', 'convert', 'bitcast', 'bitcast-convert',
              'reshape', 'parameter', 'broadcast', 'slice', 'concatenate',
@@ -1051,7 +1064,8 @@ def _assert_no_relayout_around_the_core(text, big):
                      line)
         path = re.search(r'op_name="([^"]*)"', line)
         if not m or not path or not re.search(
-                r'/attn/(mha_qkv|bd_core|mha_out)(/|$)', path.group(1)):
+                rf'/attn/(mha_qkv|{"|".join(cores)}|mha_out)(/|$)',
+                path.group(1)):
             continue
         shape, opcode = m.groups()
         sizes = [math.prod(int(d) for d in dims.split(',') if d)
@@ -1065,7 +1079,7 @@ def _assert_no_relayout_around_the_core(text, big):
         assert not opcodes <= moves, (opcodes, line[:300])
         if 'convolution' not in opcodes:
             left[opcode] = left.get(opcode, 0) + 1
-    assert left == {'reduce-precision': 5}, left
+    assert left == {'reduce-precision': layers}, left
 
 
 @pytest.mark.slow
@@ -1147,10 +1161,11 @@ def test_sdar_decoder_step_compiles_and_fits(v5e, monkeypatch, capsys):
 # the sliding-window core (ops/sliding_window.py) and its decoder
 # ------------------------------------------------------------------ #
 def _window_launches(text):
-    """(role, op_name) of every launch of the sliding-window core and of the
-    pass before and after it in a compiled program."""
+    """(role, op_name) of every launch of the sliding-window core, of the
+    causal core under its own rule and of the pass before and after them in
+    a compiled program."""
     return re.findall(
-        r'%((?:swa_core|qk_pass)_(?:fwd|bwd))[.\d]* = .*?'
+        r'%((?:swa_core|mha_core|qk_pass)_(?:fwd|bwd))[.\d]* = .*?'
         r'metadata=\{op_name="([^"]*)"', text, flags=re.S)
 
 
@@ -1200,18 +1215,99 @@ def test_the_sliding_window_core_compiles_and_visits_252_tiles_a_head(v5e):
     assert not re.search(r' (copy|transpose)\(', text[text.index('ENTRY'):])
 
 
+def _assert_the_cores_are_the_repos_launches(text, global_layers,
+                                             sliding_layers):
+    """In a compiled step: one `mha_core_fwd` and one `mha_core_bwd` a
+    global layer under `mha_core`, one `swa_core_*` pair a sliding layer
+    under `swa_core`, the forward in the forward pass alone (a block's
+    replay launches none: it saves o and the log-sum-exp), the backward
+    under `transpose(`; the one pass before each forward, replayed, and
+    after each backward under `mha_qkv`; and nothing of the library's
+    kernel."""
+    from se3_transformer_tpu.observability import profiling
+    assert 'flash' not in text and 'splash' not in text
+    by_role = {}
+    for role, path in _window_launches(text):
+        leaf = 'mha_qkv' if role.startswith('qk_pass') \
+            else role.rsplit('_', 1)[0]         # `<leaf>_fwd`, `<leaf>_bwd`
+        assert f'/attn/{leaf}/' in path, path
+        if role.endswith('_core_fwd'):
+            assert 'rematted_computation' not in path, path
+        if role.endswith('_bwd'):
+            assert 'transpose(' in path, path
+        key = role, profiling.scope_phase(path)
+        by_role[key] = by_role.get(key, 0) + 1
+    layers = global_layers + sliding_layers
+    want = {('mha_core_fwd', 'forward'): global_layers,
+            ('mha_core_bwd', 'backward'): global_layers,
+            ('swa_core_fwd', 'forward'): sliding_layers,
+            ('swa_core_bwd', 'backward'): sliding_layers,
+            ('qk_pass_fwd', 'forward'): layers,
+            ('qk_pass_fwd', 'replay'): layers,
+            ('qk_pass_bwd', 'backward'): layers}
+    assert by_role == {k: n for k, n in want.items() if n}, by_role
+
+
+@pytest.mark.parametrize('t,heads,kv,tiles,diagonal', [
+    (16384, 28, 4, 528, 32), (8192, 32, 2, 136, 16)],
+    ids=['groups of 7 at 16k', 'groups of 16 at 8k'])
+def test_the_causal_core_compiles_at_both_cells_global_layers(
+        v5e, t, heads, kv, tiles, diagonal):
+    """The same kernels under the third rule, ('mha', 0), at the global
+    layers' shapes of the two cells that have heads of 128: the
+    sliding-window cell's 28 query heads over 4 (groups of 7) at 16,384
+    positions and the hybrid cell's 32 over 2 (groups of 16: a program's q,
+    o and do blocks are 512 rows of 2,048 lanes, the pass's 256 rows of
+    them in float32) at 8,192, tiles of 512, no norms and no rotation as
+    both layers have it. The pass and the core lower for the chip forward
+    and backward within the VMEM each asks for (the pass the default), one
+    launch each named `mha_core_*`, over the causal triangle's table, the
+    diagonal alone on a boundary; the key-value heads are never repeated
+    and nothing is laid out again around the launches."""
+    from se3_transformer_tpu.kernels import pallas_block_attention as kernels
+    from se3_transformer_tpu.ops import sliding_window as sw
+
+    assert kernels.launches_run(t, 512, heads, kv, 128)
+    n = t // 512
+    assert sw.visited_tiles(t, t, 512) == tiles == n * (n + 1) // 2
+    assert sw.boundary_tiles(t, t, 512) == diagonal == n
+
+    def loss(q, k, v):
+        return kernels.block_attention(
+            q, k, v, None, None, 128, 128 ** -0.5, 1e-6, ('mha', 0),
+            512).astype(f32).sum()
+
+    def on_chip(*shape):
+        return jax.ShapeDtypeStruct(shape, f32, sharding=v5e)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        on_chip(1, t, heads * 128), on_chip(1, t, kv * 128),
+        on_chip(1, t, kv * 128)).compile()
+    text = compiled.as_text()
+    roles = [role for role, _ in _window_launches(text)]
+    assert sorted(roles) == ['mha_core_bwd', 'mha_core_fwd', 'qk_pass_bwd',
+                             'qk_pass_fwd'], roles
+    assert text.count(f's32[7,{tiles}]') >= 2
+    assert 'swa_core' not in text and 'flash' not in text
+    assert f'f32[1,{heads},1,{t}]' in text \
+        and f'f32[1,{t},{heads * 128}]{{' in text
+    assert not re.search(rf'\[1,{heads},{t},128\]|\[1,{t},{heads},128\]',
+                         text)
+    assert not re.search(r' (copy|transpose)\(', text[text.index('ENTRY'):])
+
+
 @pytest.mark.slow
 def test_smallthinker_decoder_step_compiles_and_fits(v5e, monkeypatch,
                                                      capsys):
     """The benchmark's sliding-window cell: the published widths of its
     configuration file on the one step factory at one sequence of 16,384
-    tokens, compiled for the chip: the global layer's core is the library's
-    causal kernel under `mha_core` (one forward, none in a replay), each of
-    the three sliding layers one forward and one backward launch of the
-    repo's own under `swa_core` and none in a replay, the one pass before
-    them forward, replayed and backward under `mha_qkv`; the grouped
-    products are in it; state plus temporaries fit; its memory is
-    printed."""
+    tokens, compiled for the chip: the global layer and each of the three
+    sliding layers one forward and one backward launch of the repo's own
+    and none in a replay, under `mha_core` and `swa_core` by the layer's
+    rule, the one pass before them forward, replayed and backward under
+    `mha_qkv`, no launch of the library's kernel, and nothing laid out
+    again on either side of any of the four cores; the grouped products
+    are in it; state plus temporaries fit; its memory is printed."""
     import optax
     from se3_transformer_tpu.ops import (
         expert_layer, latent_attention, sliding_window,
@@ -1241,21 +1337,10 @@ def test_smallthinker_decoder_step_compiles_and_fits(v5e, monkeypatch,
         on_chip(params), on_chip(jax.eval_shape(optimizer.init, params)),
         on_chip(dict(tokens=tokens)), on_chip(jax.random.PRNGKey(1))).compile()
     text = compiled.as_text()
-    assert 'ragged-dot' in text and 'splash' not in text
-    _assert_one_forward_core_a_layer(text, 1, 'mha_core')
-    from se3_transformer_tpu.observability import profiling
-    by_role = {}
-    for role, path in _window_launches(text):
-        leaf = '/attn/swa_core/' if role.startswith('swa_core') \
-            else '/attn/mha_qkv/'
-        assert leaf in path, path
-        key = role, profiling.scope_phase(path)
-        by_role[key] = by_role.get(key, 0) + 1
-    assert by_role == {('swa_core_fwd', 'forward'): 3,
-                       ('swa_core_bwd', 'backward'): 3,
-                       ('qk_pass_fwd', 'forward'): 3,
-                       ('qk_pass_fwd', 'replay'): 3,
-                       ('qk_pass_bwd', 'backward'): 3}, by_role
+    assert 'ragged-dot' in text
+    _assert_the_cores_are_the_repos_launches(text, 1, 3)
+    _assert_no_relayout_around_the_core(text, 16384 * 28 * 128,
+                                        ('mha_core', 'swa_core'), 4)
     _assert_product_front_ends_agree(compiled)
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes

@@ -1,5 +1,5 @@
 """An attention core whose mask is a static rule, as two Pallas kernels over
-a table of the tiles that hold a visible pair. Three rules, each a pair
+a table of the tiles that hold a visible pair. Four rules, each a pair
 (kind, n) that names its leaf and its launches (`<kind>_core`,
 `<kind>_core_fwd`, `<kind>_core_bwd`):
 
@@ -27,6 +27,15 @@ a table of the tiles that hold a visible pair. Three rules, each a pair
                            rule of its own for the leaf: what reads
                            `mha_core` reads a model's global layers, what
                            reads `swa_core` its sliding ones.
+    ('latent', 0)          the same triangle and table for latent attention
+                           (`ops/latent_attention.py`), under its own leaf,
+                           `latent_core`, at heads of two lane rows: 20
+                           heads of 256 channels in groups of one, so a
+                           program is one head, k and v are read once a
+                           head, and a tile's two and five products are
+                           twice as deep for the same softmax. The layer
+                           has no pass before the launches
+                           (`rounded_attention`, below).
 
 The block-diffusion rule:
 
@@ -83,6 +92,11 @@ rule dq, dk and dv go from the core to the pass in float32, and the
 projections get their cotangents rounded once. The forward's output and
 log-sum-exp carry `ATTN_CORE_OUT` / `ATTN_CORE_STATS`, so a block rematted
 under `SAVE_ATTN_CORE` replays the pass and no forward launch of the core.
+`rounded_attention` is the core alone under the same names, for a layer
+whose q, k and v XLA's own products write (the latent layer's: there is
+nothing to norm, and the rotation is in the weights): the scale and the one
+rounding are `jax.numpy`'s there and fuse into those products, dq, dk and dv
+leave in float32, and no pass is launched.
 
 Arithmetic: q (carrying the scale), k, v, p, do and ds rounded to bfloat16
 once, float32 accumulation in every product (float32 operands under
@@ -104,9 +118,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..observability import named_scope
-from ..ops.latent_attention import ATTN_CORE_OUT, ATTN_CORE_STATS
 from . import pallas_qk_pass as qk_pass
 
+# What a block's replay must not rebuild: an attention core's output and its
+# softmax statistics, named in the forward rules below (and in the library
+# kernel's, `ops/latent_attention.py`, whose `SAVE_ATTN_CORE` keeps them).
+ATTN_CORE_OUT, ATTN_CORE_STATS = 'attn_core_out', 'attn_core_stats'
 LANES = 128
 # the backward holds a key-value head's dk and dv whole (2 x 8 MiB at 16,384
 # positions of 128, twice for the pipeline's buffers) beside a query tile's
@@ -201,8 +218,8 @@ def window_table(positions: int, window: int, tile: int) -> np.ndarray:
 
 def rule_table(rule, positions: int, tile: int) -> np.ndarray:
     """The table of `rule` = (kind, n) over `positions` (both streams of a
-    block-diffusion sequence, a window's T, or the causal triangle's: the
-    window's table at a window of T)."""
+    block-diffusion sequence, a window's T, or the causal triangle's, under
+    'mha' and 'latent' alike: the window's table at a window of T)."""
     kind, n = rule
     return tile_table(positions // 2, n, tile) if kind == 'bd' \
         else window_table(positions, n if kind == 'swa' else positions, tile)
@@ -213,7 +230,15 @@ def _core_scope(rule):
     closed list of leaves is checked)."""
     return named_scope('bd_core') if rule[0] == 'bd' \
         else named_scope('swa_core') if rule[0] == 'swa' \
+        else named_scope('latent_core') if rule[0] == 'latent' \
         else named_scope('mha_core')
+
+
+def _qkv_scope(rule):
+    """The leaf of what stands between a layer's projections and its core
+    (literals again): the latent layer's own, or `GroupedQueryAttention`'s."""
+    return named_scope('latent_qkv') if rule[0] == 'latent' \
+        else named_scope('mha_qkv')
 
 
 def _granule(rule) -> int:
@@ -465,16 +490,22 @@ def block_attention(q, k, v, norms, rotary, head_dim, scale, eps, rule, tile,
                                 rule, tile, interpret)[0]
 
 
+def _named_fwd(qr, kr, vr, head_dim, rule, tile, interpret):
+    """The forward launch under the rule's leaf, its two outputs carrying
+    the names `SAVE_ATTN_CORE` keeps."""
+    with _core_scope(rule):
+        o, lse = _fwd(qr, kr, vr, head_dim, rule, tile, interpret)
+        return (checkpoint_name(o, ATTN_CORE_OUT),
+                checkpoint_name(lse, ATTN_CORE_STATS))
+
+
 def _block_attention_fwd(q, k, v, norms, rotary, head_dim, scale, eps, rule,
                          tile, interpret):
     od = jnp.float32 if interpret else jnp.bfloat16
-    with named_scope('mha_qkv'):
+    with _qkv_scope(rule):
         qr, kr, vr = qk_pass.forward(q, k, v, norms, rotary, head_dim, scale,
                                      eps, od, interpret)
-    with _core_scope(rule):
-        o, lse = _fwd(qr, kr, vr, head_dim, rule, tile, interpret)
-        o = checkpoint_name(o, ATTN_CORE_OUT)
-        lse = checkpoint_name(lse, ATTN_CORE_STATS)
+    o, lse = _named_fwd(qr, kr, vr, head_dim, rule, tile, interpret)
     return o, (q, k, norms, rotary, qr, kr, vr, o, lse)
 
 
@@ -487,7 +518,7 @@ def _block_attention_bwd(head_dim, scale, eps, rule, tile, interpret,
         # dx writes it: the products and the row sums see one do
         dq, dk, dv = _bwd(qr, kr, vr, o, do, lse, head_dim, rule, tile,
                           interpret)
-    with named_scope('mha_qkv'):
+    with _qkv_scope(rule):
         dq, dk, dv, dw = qk_pass.backward(dq, dk, dv, q, k, norms, rotary,
                                           head_dim, scale, eps, qr.dtype,
                                           interpret)
@@ -495,3 +526,41 @@ def _block_attention_bwd(head_dim, scale, eps, rule, tile, interpret,
 
 
 block_attention.defvjp(_block_attention_fwd, _block_attention_bwd)
+
+
+# --------------------------------------------------------------------- #
+# the core alone, where XLA assembles the operands itself
+# --------------------------------------------------------------------- #
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def rounded_attention(q, k, v, head_dim, scale, rule, tile, interpret=False):
+    """`block_attention` with neither norm nor rotation and no pass: q
+    [B, T, H D], k, v [B, T, KV D] float32 as XLA's own fusions assemble
+    them (the latent layer's: rotation and concatenations) -> o [B, T, H D]
+    in the operands' width. The scale and the one rounding are written here
+    in `jax.numpy`, under the rule's `*_qkv` leaf, so that XLA puts them
+    into whatever writes q, k and v and no float32 copy of them reaches
+    HBM; dq (times the scale), dk and dv leave in float32 as the backward
+    launch sums them."""
+    return _rounded_attention_fwd(q, k, v, head_dim, scale, rule, tile,
+                                  interpret)[0]
+
+
+def _rounded_attention_fwd(q, k, v, head_dim, scale, rule, tile, interpret):
+    od = jnp.float32 if interpret else jnp.bfloat16
+    with _qkv_scope(rule):
+        qr, kr, vr = (q * scale).astype(od), k.astype(od), v.astype(od)
+    o, lse = _named_fwd(qr, kr, vr, head_dim, rule, tile, interpret)
+    return o, (qr, kr, vr, o, lse)
+
+
+def _rounded_attention_bwd(head_dim, scale, rule, tile, interpret, residuals,
+                           do):
+    qr, kr, vr, o, lse = residuals
+    with _core_scope(rule):
+        dq, dk, dv = _bwd(qr, kr, vr, o, do, lse, head_dim, rule, tile,
+                          interpret)
+    with _qkv_scope(rule):
+        return dq * scale, dk, dv
+
+
+rounded_attention.defvjp(_rounded_attention_fwd, _rounded_attention_bwd)

@@ -78,9 +78,12 @@ MODEL_SCOPES = (
     # norms are filed under `norm`
     'embed',              # models/token_decoder.py: token embedding rows
     'latent_qkv',         # ops/latent_attention.py: down- and
-    #                       up-projections, their norms, the rotation
+    #                       up-projections, their norms, the rotation;
+    #                       the scale and the rounding of q, k and v
     'latent_core',        # ops/latent_attention.py: scores, softmax,
-    #                       weighted sum (the streaming kernel on a TPU)
+    #                       weighted sum (on a TPU the launches
+    #                       `latent_core_fwd` / `latent_core_bwd` of
+    #                       kernels/pallas_block_attention.py)
     'latent_out',         # ops/latent_attention.py: output projection
     'moe_router',         # ops/expert_layer.py: logits, sigmoid, top-k,
     #                       weights

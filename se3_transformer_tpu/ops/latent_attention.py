@@ -11,12 +11,29 @@ head; value heads may be wider than the keys' un-rotated part:
     softmax in float32; out = (softmax . v) Wo
 
 Training materializes kn and v (no absorbed form). The core never holds the
-[heads, T, T] scores: on a TPU it is JAX's streaming Pallas kernel
-(`jax.experimental.pallas.ops.tpu.flash_attention`: bfloat16 operands,
-float32 softmax and accumulation; its output and softmax statistics are
-named, and a rematted block saves them instead of launching the forward
-again), elsewhere blocks of queries against the keys at or before them, each
-block recomputed in the backward pass.
+[heads, T, T] scores, and `kernels_run` says which of three it is:
+
+  * on a TPU, at heads of whole lane rows, keys and values of one width
+    and a length the tile divides (the GLM cell: 20 heads of 256 over 8,192,
+    under 32,768), the repo's own two launches
+    (`kernels/pallas_block_attention.py` under the rule ('latent', 0):
+    `latent_core_fwd`, `latent_core_bwd` over the causal triangle's table,
+    bfloat16 operands, float32 softmax and sums). Every operand is
+    token-major, [B, T, heads d] with a head a run of d lanes, and written
+    in that layout by products alone (`token_major_qkv`: the rotation's
+    lane exchange and the shared key's place are in the weights), so
+    nothing is transposed or viewed by heads on either side of the core;
+  * on a TPU elsewhere, `causal_attention_flash`: JAX's streaming Pallas
+    kernel (`jax.experimental.pallas.ops.tpu.flash_attention`) in the
+    head-major layout it wants. This layer no longer reaches it at the GLM
+    cell's shapes; `GroupedQueryAttention` still does at heads of 64 (the
+    short-convolution cell);
+  * off the TPU blocks of queries against the keys at or before them, each
+    block recomputed in the backward pass.
+
+In the first two the core's output and softmax statistics are named, and a
+rematted block saves them (`SAVE_ATTN_CORE`) instead of launching the
+forward again.
 """
 from __future__ import annotations
 
@@ -27,6 +44,8 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from ..kernels import pallas_block_attention as kernels
+from ..kernels.pallas_block_attention import ATTN_CORE_OUT, ATTN_CORE_STATS
 from ..observability import named_scope
 from ..utils.helpers import is_tpu_backend
 from .rotary import apply_rotary_halves, rotary_angles
@@ -82,10 +101,10 @@ def causal_attention_blocked(q, k, v, scale: float, block_q: int = 512,
     return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=2)
 
 
-# What a block's replay must not rebuild: the streaming core's output and its
-# softmax statistics, named in the forward rule below. The decoders' blocks
-# are rematted under `SAVE_ATTN_CORE`, which keeps these and nothing else.
-ATTN_CORE_OUT, ATTN_CORE_STATS = 'attn_core_out', 'attn_core_stats'
+# What a block's replay must not rebuild: a core's output and its softmax
+# statistics, named in the forward rule below and in the repo's launches'.
+# The decoders' blocks are rematted under `SAVE_ATTN_CORE`, which keeps these
+# and nothing else.
 SAVE_ATTN_CORE = jax.checkpoint_policies.save_only_these_names(
     ATTN_CORE_OUT, ATTN_CORE_STATS)
 
@@ -163,6 +182,70 @@ def causal_attention(q, k, v, scale: float, block: int = 512):
     return core(q, k, v, scale, block)
 
 
+def kernels_run(positions: int, block: int, heads: int, qk_dim: int,
+                v_dim: int) -> bool:
+    """Whether the layer's core is the repo's two launches
+    (`kernels/pallas_block_attention.py` under ('latent', 0)): on a TPU, at
+    one width for keys and values (the launches are written over one), at
+    the shapes its `launches_run` admits (tiles of `block` or the sequence,
+    heads of whole lane rows, a head's dk and dv resident in VMEM), from the
+    platform and the shapes alone."""
+    return is_tpu_backend() and qk_dim == v_dim and kernels.launches_run(
+        positions, min(block, positions), heads, heads, qk_dim)
+
+
+def token_major_qkv(cq, ckv, kr, wq, wkv, angles, heads: int, dn: int):
+    """The launches' operands in the launches' layout, [B, T, heads d]
+    float32 with a head a run of d = dn + dr lanes, written by products
+    alone: on a TPU [T, heads d] and [T, heads, d] are different memory, and
+    a view by heads to rotate a head's last dr lanes or to put the shared
+    key there costs passes over the whole tensor. So the lanes are moved
+    where the kernels are small, in the weights:
+
+        q = (cq wq) C + (cq wq') S      wq' holds, in the column of each
+                                        rotary lane, wq's column of the
+                                        lane's partner and zeros elsewhere;
+                                        C is 1 and S 0 on the first dn lanes
+                                        of a head, cos and -sin | sin after
+        k = [ckv ; kr] [wk ; E]         wk kn's columns of `wkv` with zero
+                                        columns where the rotated key goes,
+                                        E the 0 / 1 rows that put kr there,
+                                        the same for every head
+        v = ckv wv                      v's columns of `wkv`
+
+    cq [B, T, q_lora_rank] and ckv [B, T, kv_lora_rank] normed, kr
+    [B, T, dr] rotated, wq [q_lora_rank, heads d] and wkv [kv_lora_rank,
+    heads (dn + dv)] the kernels of `q_b` and `kv_b`, angles [T, dr / 2]."""
+    r = angles.shape[-1]
+    d = dn + 2 * r
+    wq3, wkv3 = (w.reshape(w.shape[0], heads, -1) for w in (wq, wkv))
+    nothing = jnp.zeros(wq3.shape[:2] + (dn,), wq.dtype)
+    partners = jnp.concatenate(
+        (nothing, wq3[..., dn + r:], wq3[..., dn:dn + r]), axis=-1)
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    one = jnp.ones((angles.shape[0], dn), cos.dtype)
+    by_head = lambda *parts: jnp.concatenate(
+        [jnp.concatenate(parts, axis=-1)] * heads, axis=-1)
+    q = (cq @ wq) * by_head(one, cos, cos) \
+        + (cq @ partners.reshape(wq.shape)) * by_head(0 * one, -sin, sin)
+    wk = jnp.pad(wkv3[..., :dn], ((0, 0), (0, 0), (0, 2 * r)))
+    place = jnp.pad(jnp.eye(2 * r, dtype=wkv.dtype), ((0, 0), (dn, 0)))
+    k = jnp.concatenate((ckv, kr), axis=-1) @ jnp.concatenate(
+        (wk.reshape(-1, heads * d), jnp.tile(place, (1, heads))), axis=0)
+    v = ckv @ wkv3[..., dn:].reshape(wkv.shape[0], -1)
+    return q, k, v
+
+
+class _Kernel(nn.Module):
+    """An `nn.Dense`'s parameter (`<name>/kernel`) without its product."""
+    shape: tuple
+
+    @nn.compact
+    def __call__(self):
+        return self.param('kernel', nn.linear.default_kernel_init,
+                          self.shape)
+
+
 class LatentAttention(nn.Module):
     dim: int
     heads: int
@@ -181,28 +264,44 @@ class LatentAttention(nn.Module):
         b, t, _ = x.shape
         h, dn, dr, dv = (self.heads, self.qk_nope_head_dim,
                          self.qk_rope_head_dim, self.v_head_dim)
+        d = dn + dr
         dense = partial(nn.Dense, use_bias=False)
+        launched = kernels_run(t, self.block, h, d, dv)
         with named_scope('latent_qkv'):
             cq = RMSNorm(self.eps, name='q_a_norm')(
                 dense(self.q_lora_rank, name='q_a')(x))
-            q = dense(h * (dn + dr), name='q_b')(cq).reshape(b, t, h, dn + dr)
             ckv_kr = dense(self.kv_lora_rank + dr, name='kv_a')(x)
             ckv = RMSNorm(self.eps, name='kv_a_norm')(
                 ckv_kr[..., :self.kv_lora_rank])
-            kr = ckv_kr[..., self.kv_lora_rank:]               # [B, T, dr]
-            kv = dense(h * (dn + dv), name='kv_b')(ckv).reshape(
-                b, t, h, dn + dv)
             angles = rotary_angles(jnp.arange(t), dr, self.rope_theta)
-            qr = apply_rotary_halves(q[..., dn:], angles[None, :, None, :])
-            kr = apply_rotary_halves(kr, angles[None])
-            q = jnp.concatenate((q[..., :dn], qr), axis=-1)
-            k = jnp.concatenate(
-                (kv[..., :dn],
-                 jnp.broadcast_to(kr[:, :, None, :], (b, t, h, dr))), axis=-1)
-            q, k, v = (a.transpose(0, 2, 1, 3) for a in (q, k, kv[..., dn:]))
-        scale = (dn + dr) ** -0.5
-        with named_scope('latent_core'):
-            o = causal_attention(q, k, v, scale, self.block)
+            kr = apply_rotary_halves(ckv_kr[..., self.kv_lora_rank:],
+                                     angles[None])             # [B, T, dr]
+            if launched:
+                wq = _Kernel((self.q_lora_rank, h * d), name='q_b')()
+                wkv = _Kernel((self.kv_lora_rank, h * (dn + dv)),
+                              name='kv_b')()
+                q, k, v = token_major_qkv(cq, ckv, kr, wq, wkv, angles, h,
+                                          dn)
+            else:
+                q = dense(h * d, name='q_b')(cq).reshape(b, t, h, d)
+                kv = dense(h * (dn + dv), name='kv_b')(ckv).reshape(
+                    b, t, h, dn + dv)
+                qr = apply_rotary_halves(q[..., dn:],
+                                         angles[None, :, None, :])
+                q = jnp.concatenate((q[..., :dn], qr), axis=-1)
+                k = jnp.concatenate(
+                    (kv[..., :dn], jnp.broadcast_to(
+                        kr[:, :, None, :], (b, t, h, dr))), axis=-1)
+                q, k, v = (a.transpose(0, 2, 1, 3)
+                           for a in (q, k, kv[..., dn:]))
+        scale = d ** -0.5
+        if launched:    # under `latent_qkv` and `latent_core` by its rule
+            o = kernels.rounded_attention(q, k, v, d, scale, ('latent', 0),
+                                          min(self.block, t))
+        else:
+            with named_scope('latent_core'):
+                o = causal_attention(q, k, v, scale, self.block)
         with named_scope('latent_out'):
-            o = o.transpose(0, 2, 1, 3).reshape(b, t, h * dv)
+            if not launched:
+                o = o.transpose(0, 2, 1, 3).reshape(b, t, h * dv)
             return dense(self.dim, name='out')(o)

@@ -427,6 +427,48 @@ def test_streaming_attention_compiles_at_the_decoder_cells_size(v5e):
                        *[((1, 20, 8192, 256), jnp.float32)] * 3) == 3
 
 
+def test_the_latent_core_compiles_at_the_decoder_cells_size(v5e, capsys):
+    """The repo's two launches under the fourth rule, ('latent', 0), at the
+    GLM cell's size: 20 heads of 256 (two lane rows a head, groups of one)
+    over 8,192 positions, tiles of 512, token-major with the scale and the
+    rounding `rounded_attention` leaves to XLA. Both lower for the chip
+    within the VMEM they ask for, one launch each named `latent_core_*`,
+    over the causal triangle's 136 tiles a head; no pass is launched, no
+    head is laid out; the windows' VMEM is printed (the backward holds a
+    head's dk and dv [8192, 256] float32 in two buffers each)."""
+    from se3_transformer_tpu.kernels import pallas_block_attention as kernels
+
+    t, heads, d, tile = 8192, 20, 256, 512
+    assert kernels.launches_run(t, tile, heads, heads, d)
+    assert not kernels.launches_run(4 * t, tile, heads, heads, d)
+
+    def loss(q, k, v):
+        return kernels.rounded_attention(
+            q, k, v, d, d ** -0.5, ('latent', 0), tile).astype(f32).sum()
+
+    x = jax.ShapeDtypeStruct((1, t, heads * d), f32, sharding=v5e)
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile().as_text()
+    roles = re.findall(r'%(\w+_core_\w+|qk_pass_\w+)[.\d]* = [^\n]*'
+                       r'tpu_custom_call', text)
+    assert sorted(roles) == ['latent_core_bwd', 'latent_core_fwd'], roles
+    assert text.count('s32[7,136]') >= 2 and 'flash' not in text
+    assert f'f32[1,{heads},1,{t}]' in text
+    assert not re.search(rf'\[1,{heads},{t},{d}\]|\[1,{t},{heads},{d}\]',
+                         text)
+    assert not re.search(r' (copy|transpose)\(', text[text.index('ENTRY'):])
+    # two buffers a window: operand tiles in bfloat16, dq, dk, dv float32
+    fwd = 2 * (4 * tile * d * 2 + tile * 4) \
+        + (2 * tile * kernels.LANES + tile * d) * 4
+    bwd = 2 * (5 * tile * d * 2 + tile * 4 + tile * d * 4
+               + 2 * t * d * 4) + tile * 4
+    with capsys.disabled():
+        print(f'\nlatent core at 20 heads of 256 over 8,192: windows and '
+              f'scratch {fwd / 2**20:.1f} MiB forward, {bwd / 2**20:.1f} MiB '
+              f'backward, of {kernels.VMEM_LIMIT / 2**20:.0f} MiB')
+    assert bwd < 40 * 2 ** 20 < kernels.VMEM_LIMIT
+
+
 def test_grouped_products_are_native_on_the_chip(v5e):
     """`jax.lax.ragged_dot` and its two cotangents lower to the TPU's own
     grouped matrix product (one custom call each and one for the tile
@@ -628,7 +670,12 @@ def _program_digests(lowered):
 # all three to what they were. A PR that means to change one of these
 # programs says so and pins what it made.
 LOWERED_BEFORE_THE_ONE_PASS = {
-    'token_decoder': ('fbc6acb3f2404bb1', 'cbb0f31268823c36'),
+    # PR 46 meant to change this one and pins what it made: the latent
+    # layer's core is the repo's two launches under ('latent', 0), q, k and
+    # v written token-major by products, where the library's kernel, its
+    # statistics and the head-major layout were
+    # ('fbc6acb3f2404bb1', 'cbb0f31268823c36' before it)
+    'token_decoder': ('1f972078eb188eba', 'f888f352ae0041fc'),
     # PR 45 meant to change this one and pins what it made: the hybrid's
     # global layer (32 heads over 2 of 128) takes the repo's two launches
     # under ('mha', 0) and the one pass, where the library's kernel, the
@@ -662,6 +709,26 @@ def _assert_one_forward_core_a_layer(text, layers, leaf):
     assert {role: len(paths) for role, paths in by_role.items()} == {
         'flash_attention': layers, 'flash_mha_bwd_dkv': layers,
         'flash_mha_bwd_dq': layers}, by_role
+
+
+def _assert_the_latent_cores_are_the_repos_launches(text, layers):
+    """In a compiled step: one `latent_core_fwd` and one `latent_core_bwd`
+    a layer of `layers`, each under the leaf `latent_core`, which the
+    per-layer metrics read, the forward in the forward pass alone (a
+    block's replay launches none: it saves o and the log-sum-exp), the
+    backward under `transpose(`; no launch of the library's kernel and no
+    pass: XLA's products write the operands."""
+    from se3_transformer_tpu.observability import profiling
+    assert 'flash' not in text and 'qk_pass' not in text
+    by_role = {}
+    for role, path in re.findall(
+            r'%(latent_core_(?:fwd|bwd))[.\d]* = .*?'
+            r'metadata=\{op_name="([^"]*)"', text, flags=re.S):
+        assert '/attn/latent_core/' in path, path
+        key = role, profiling.scope_phase(path)
+        by_role[key] = by_role.get(key, 0) + 1
+    assert by_role == {('latent_core_fwd', 'forward'): layers,
+                       ('latent_core_bwd', 'backward'): layers}, by_role
 
 
 def _assert_product_front_ends_agree(compiled):
@@ -743,9 +810,11 @@ def _assert_gated_backward_products_are_plain(text, tokens, widths):
 def test_token_decoder_step_compiles_and_fits(v5e, monkeypatch, capsys):
     """The benchmark's decoder cell: the published widths of its
     configuration file on the one step factory, compiled for the chip (under
-    a minute): both kernels are in it, the attention kernel's forward once a
-    block, and state plus temporaries fit with the six blocks' saved
-    attention outputs (11.67 GiB; 11.26 with nothing saved)."""
+    a minute): the attention core is the repo's two launches, one forward
+    and one backward a block under `latent_core` and none in a replay, no
+    launch of the library's kernel is left, the grouped products are in it,
+    and state plus temporaries fit with the six blocks' saved attention
+    outputs and log-sum-exps."""
     import optax
     from se3_transformer_tpu.ops import expert_layer, latent_attention
     from se3_transformer_tpu.parallel.sharding import make_sharded_train_step
@@ -772,12 +841,12 @@ def test_token_decoder_step_compiles_and_fits(v5e, monkeypatch, capsys):
         on_chip(params), on_chip(jax.eval_shape(optimizer.init, params)),
         on_chip(dict(tokens=tokens)),
         on_chip(jax.random.PRNGKey(1)))
-    assert _program_digests(lowered) \
-        == LOWERED_BEFORE_THE_ONE_PASS['token_decoder']
+    digests = _program_digests(lowered)
+    assert digests == LOWERED_BEFORE_THE_ONE_PASS['token_decoder'], digests
     compiled = lowered.compile()
     text = compiled.as_text()
-    assert 'flash_mha_bwd_dkv' in text and 'ragged-dot' in text
-    _assert_one_forward_core_a_layer(text, 6, 'latent_core')
+    assert 'ragged-dot' in text
+    _assert_the_latent_cores_are_the_repos_launches(text, 6)
     _assert_product_front_ends_agree(compiled)
     _assert_gated_backward_products_are_plain(
         text, 8192, [cfg['model']['intermediate_size']]

@@ -6,8 +6,9 @@
 prints, per leaf of MODEL_SCOPES and forward | replay | backward, the ms, TFLOP
 and share of the bf16 peak of the products XLA compiled, the leaf's other ms
 by its two heaviest categories, and its launches' ms
-(`observability.profiling.format_products`, docs/OBSERVABILITY.md). Reads the
-trace alone; needs no device."""
+(`observability.profiling.format_products`, docs/OBSERVABILITY.md), and, for
+a looped stack, its passes apart (`format_passes`: ms under each `ut_<t>`).
+Reads the trace alone; needs no device."""
 import os
 import sys
 

@@ -31,6 +31,14 @@ layer of one mixer: `MEMEM*EME`) or two (an operator, then a feed-forward:
 layer and three convolution layers with experts); there is no prediction
 block. Only the fields of the mixers the pattern uses need be given.
 
+With `sandwich_norm` a second norm sits on every mixer's output, h <- h +
+RMSNorm(Mixer(RMSNorm(h))). With `total_ut_steps` above 1 the stack is
+looped: the pattern's blocks run that many times on ONE set of weights, the
+final norm closes every pass and its output is what the next pass reads,
+every pass's normed state is an exit of its own (the one head reads each) and
+an `exit_gate` reads it too (`training/lm_loss.py::make_looped_lm_loss` is
+the objective over them).
+
 The fields carry the names a published `config.json` gives them;
 `experts_held`, `expert_rank` and `vocab_rows` say what this chip holds of an
 expert-parallel deployment, as in `models/token_decoder.py`, whose
@@ -68,6 +76,7 @@ class MixerBlock(nn.Module):
     eps: float
     early_router: bool = False     # an attention step hands its normed
     #                                input on, for the next step's router
+    sandwich_norm: bool = False    # a norm on the mixer's output too
 
     @nn.compact
     def __call__(self, h, positions=None, block_length: int = 0,
@@ -83,19 +92,26 @@ class MixerBlock(nn.Module):
             u = RMSNorm(self.eps, name='pre_norm')(h)
         module, name = MIXERS[self.kind]
         mixer = module(**self.mixer, name=name)
+
+        def residual(out):
+            if self.sandwich_norm:
+                with named_scope('norm'):
+                    out = RMSNorm(self.eps, name='post_norm')(out)
+            return h + out
+
         if self.kind == 'E':
             b, t, d = u.shape
             out, stats = mixer(
                 u.reshape(b * t, d), None if routing_input is None
                 else routing_input.reshape(b * t, d))
-            return h + out.reshape(b, t, d), stats
+            return residual(out.reshape(b, t, d)), stats
         if self.kind == 'F':       # SwiGLU writes no scope of its own
             with named_scope('dense_ff'):
-                return h + mixer(u), None
+                return residual(mixer(u)), None
         if self.kind in ATTENTION:
-            return h + mixer(u, positions, block_length), \
+            return residual(mixer(u, positions, block_length)), \
                 u if self.early_router else None
-        return h + mixer(u), None
+        return residual(mixer(u)), None
 
 
 class HybridDecoder(nn.Module):
@@ -141,6 +157,10 @@ class HybridDecoder(nn.Module):
     sliding_rope_theta: Optional[float] = None
     layer_norm_epsilon: float = 1e-5
     tie_word_embeddings: bool = False
+    # a looped stack: passes over the one set of blocks, and a second norm
+    # on every mixer's output (a published model has both or neither)
+    total_ut_steps: int = 1
+    sandwich_norm: bool = False
     # execution, not architecture (every block is recomputed in the
     # backward pass: its input is saved, and the streaming attention core's
     # output and softmax statistics, so the replay launches no forward)
@@ -188,11 +208,14 @@ class HybridDecoder(nn.Module):
         self.embedding = nn.Embed(self.vocab_rows, self.hidden_size)
         self.blocks = [
             block(kind, fields[kind], eps,
-                  self.moe_enable_early_router and kind in ATTENTION)
+                  self.moe_enable_early_router and kind in ATTENTION,
+                  self.sandwich_norm)
             for kind in self.hybrid_override_pattern]
         self.final_norm = RMSNorm(eps)
         if not self.tie_word_embeddings:
             self.head = nn.Dense(self.vocab_rows, use_bias=False)
+        if self.total_ut_steps > 1:
+            self.exit_gate = nn.Dense(1)
 
     def expert_layer_names(self):
         """The parameter subtrees with an expert layer, in `stats` order."""
@@ -218,36 +241,72 @@ class HybridDecoder(nn.Module):
         here: a pattern with `M` or `C` has no such pass). `main` is the
         noised stream's T positions alone (`main[t]` predicts token t, in
         place); the clean stream's last layer feeds nothing. The expert
-        layers' stats are over all 2 T positions."""
+        layers' stats are over all 2 T positions.
+
+        A looped stack (`total_ut_steps` P above 1) returns `main` [P, B, T,
+        d], every pass's normed state, and an entry of `stats` per expert
+        layer and pass, pass by pass. A pass is no module (flax gives the P
+        calls of `blocks_0` one name), so each runs under a path component
+        `ut_<t>` of its own, t = 0 .. P - 1: the trace reducer's `pass_s`
+        reads it. Unrolled in Python: inside a scanned, checkpointed body the
+        operations lose their scopes."""
         positions = None
         if noised is not None:
-            assert block_length and not set(
+            assert block_length and self.total_ut_steps == 1 and not set(
                 self.hybrid_override_pattern) & {'M', 'C'}, \
-                (block_length, self.hybrid_override_pattern)
+                (block_length, self.total_ut_steps,
+                 self.hybrid_override_pattern)
             with named_scope('bd_streams'):
                 t = tokens.shape[1]
                 tokens = jnp.concatenate((noised, tokens), axis=1)
                 positions = jnp.tile(jnp.arange(t), 2)
         with named_scope('embed'):
             h = self.embedding(tokens)
-        stats, handed = [], None
-        for kind, block in zip(self.hybrid_override_pattern, self.blocks):
-            h, s = block(h, positions, block_length,
-                         handed if kind == 'E' else None)
-            if kind == 'E':
-                stats.append(s)
-            else:       # an attention step's normed input, or None
-                handed = s
-        if noised is not None:
-            with named_scope('bd_streams'):
-                h = h[:, :h.shape[1] // 2]
-        with named_scope('norm'):
-            return self.final_norm(h), None, stats
+        stats = []
+
+        def blocks(h):      # the pattern's blocks, once
+            handed = None
+            for kind, block in zip(self.hybrid_override_pattern,
+                                   self.blocks):
+                h, s = block(h, positions, block_length,
+                             handed if kind == 'E' else None)
+                if kind == 'E':
+                    stats.append(s)
+                else:       # an attention step's normed input, or None
+                    handed = s
+            return h
+
+        if self.total_ut_steps == 1:
+            h = blocks(h)
+            if noised is not None:
+                with named_scope('bd_streams'):
+                    h = h[:, :h.shape[1] // 2]
+            with named_scope('norm'):
+                return self.final_norm(h), None, stats
+        passes = []
+        for t in range(self.total_ut_steps):
+            with named_scope(f'ut_{t}'):
+                h = blocks(h)
+                with named_scope('norm'):
+                    h = self.final_norm(h)
+            passes.append(h)
+        return jnp.stack(passes), None, stats
+
+    def exit_logits(self, main):
+        """A looped stack's `main` [P, B, T, d] -> the gate's logits
+        [P - 1, B, T] on the normed states of every pass but the last, which
+        takes the mass the others leave."""
+        with named_scope('exit_gate'):
+            return self.exit_gate(main[:-1])[..., 0]
 
     def __call__(self, tokens):
-        """The logits [B, T, vocab_rows] (float32) and the stats: for small
-        sizes and `init`; training goes through `hidden_states`."""
+        """The logits [B, T, vocab_rows] (float32; a looped stack's last
+        pass's) and the stats: for small sizes and `init`; training goes
+        through `hidden_states`."""
         main, _, stats = self.hidden_states(tokens)
+        if self.total_ut_steps > 1:
+            self.exit_logits(main)      # so that `init` makes the gate
+            main = main[-1]
         with named_scope('lm_head'):
             if self.tie_word_embeddings:
                 return self.embedding.attend(main), stats

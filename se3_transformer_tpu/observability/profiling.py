@@ -71,7 +71,9 @@ From the path: the LEAF is the innermost component on the closed list
 `MODEL_SCOPES` (`pair_<d_in>_<d_out>` reads as `pair`); the PHASE is `replay`
 under a `rematted_computation` component, `backward` under a `transpose(...)`
 one, else `forward`; a kernel launch's ROLE is its instruction's family name
-(`fused_pairwise_conv_bwd_a`) and its PAIR the `pair_*` component. Seconds
+(`fused_pairwise_conv_bwd_a`) and its PAIR the `pair_*` component; the PASS
+of a looped stack is the component `ut_<t>` (`pass_s`, by phase, whatever
+the leaf). Seconds
 are exclusive (an event's time less the events nested in it) and summed over
 chips; busy time is the union of intervals, averaged over chips. With the side
 table the same seconds are split three ways, by leaf: instructions that hold
@@ -93,15 +95,15 @@ import os
 import re
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
-from .timing import MODEL_SCOPES, PAIR_SCOPE, profile_trace
+from .timing import MODEL_SCOPES, PAIR_SCOPE, PASS_SCOPE, profile_trace
 
 __all__ = [
     'exclusive_durations', 'union_length', 'scope_leaf', 'scope_phase',
-    'scope_pair',
+    'scope_pair', 'scope_pass',
     'kernel_role', 'compiler_launch_leaf', 'hlo_op_names', 'newest_xplane',
     'xspace_class', 'read_xplane',
     'hlo_proto_computations', 'product_counts',
-    'reduce_events', 'reduce_xplane', 'format_products',
+    'reduce_events', 'reduce_xplane', 'format_products', 'format_passes',
     'capture_step_profile', 'profile_payload',
 ]
 
@@ -171,6 +173,15 @@ def scope_pair(op_name: Optional[str]) -> Optional[str]:
         m = PAIR_SCOPE.match(comp)
         if m:
             return f'{m.group(1)},{m.group(2)}'
+    return None
+
+
+def scope_pass(op_name: Optional[str]) -> Optional[str]:
+    """`'ut_2'` for a path through the component `ut_2`, the third pass of a
+    looped stack (`timing.PASS_SCOPE`); None for a path without one."""
+    for comp in _components(op_name or ''):
+        if PASS_SCOPE.match(comp):
+            return comp
     return None
 
 
@@ -684,7 +695,9 @@ def reduce_events(events: dict, op_names: Optional[Dict[str, str]] = None,
     Returns busy_s (union of intervals, averaged over chips; over worker
     threads in a CPU trace they overlap and the union is of all of them),
     device_s (exclusive seconds, summed), leaf_s {leaf: s}, phase_s,
-    leaf_phase_s {leaf: {phase: s}}, kernel_s {role: s}, kernel_pair_s
+    leaf_phase_s {leaf: {phase: s}}, pass_s {`ut_<t>`: {phase: s}} (over
+    the events whose path has such a component, labelled or not; empty for a
+    program without a looped stack), kernel_s {role: s}, kernel_pair_s
     {role: {pair: s}}, labelled_s, unlabelled_s, unlabelled_top
     [[instruction family, s], ...] and coverage = labelled_s / device_s.
 
@@ -706,6 +719,7 @@ def reduce_events(events: dict, op_names: Optional[Dict[str, str]] = None,
     leaf_s: Dict[str, float] = {}
     phase_s: Dict[str, float] = {}
     leaf_phase_s: Dict[str, Dict[str, float]] = {}
+    pass_s: Dict[str, Dict[str, float]] = {}
     kernel_s: Dict[str, float] = {}
     kernel_pair_s: Dict[str, Dict[str, float]] = {}
     unlabelled: Dict[str, float] = {}
@@ -744,6 +758,9 @@ def reduce_events(events: dict, op_names: Optional[Dict[str, str]] = None,
                      scope_pair(op) or 'none', secs)
             leaf = scope_leaf(op, scopes) or compiler_launch_leaf(name)
             phase = scope_phase(op)
+            ut = scope_pass(op)
+            if ut is not None:
+                _add2(pass_s, ut, phase, secs)
             if table:
                 info = table.get(program, {}).get(name) or {}
                 filed = leaf or UNLABELLED
@@ -776,7 +793,7 @@ def reduce_events(events: dict, op_names: Optional[Dict[str, str]] = None,
         unlabelled_s=device_s - labelled_s,
         coverage=labelled_s / device_s if device_s else 0.0,
         leaf_s=leaf_s, phase_s=phase_s, leaf_phase_s=leaf_phase_s,
-        kernel_s=kernel_s, kernel_pair_s=kernel_pair_s,
+        pass_s=pass_s, kernel_s=kernel_s, kernel_pair_s=kernel_pair_s,
         product_s=product_s, product_flops=product_flops,
         product_bytes=product_bytes, launch_s=launch_s,
         launch_roles=launch_roles, glue_s=glue_s,
@@ -904,6 +921,21 @@ def format_launches(red: dict, steps: int = 1) -> str:
     return '\n'.join(lines)
 
 
+def format_passes(red: dict, steps: int = 1) -> str:
+    """A looped stack's passes apart (`pass_s`): a row a pass, ms a step
+    forward | replay | backward and in all. The last pass's backward runs
+    first and the first pass's replay last."""
+    order = ('forward', 'replay', 'backward')
+    lines = [f'{"pass":<8}' + ''.join(f'| {phase + " ms":<12}'
+                                      for phase in order) + '| all ms']
+    for ut, phases in sorted(red['pass_s'].items()):
+        lines.append(f'{ut:<8}' + ''.join(
+            f'| {1e3 * phases.get(phase, 0.0) / steps:<12.2f}'
+            for phase in order)
+            + f'| {1e3 * sum(phases.values()) / steps:.2f}')
+    return '\n'.join(lines)
+
+
 def profile_payload(trace_dir: str, *, label: str,
                     hlo_text: Optional[str] = None,
                     scopes: Sequence[str] = MODEL_SCOPES,
@@ -968,6 +1000,8 @@ def main(argv=None):
     print(format_products(red, peaks['bf16_flops'],
                           peaks['hbm_bytes_per_sec'], args.steps))
     print(format_launches(red, args.steps))
+    if red['pass_s']:
+        print(format_passes(red, args.steps))
     parts = [sum(sum(v.values()) for v in red[k].values())
              for k in ('product_s', 'glue_s')]
     parts.append(sum(red['launch_s'].values()))

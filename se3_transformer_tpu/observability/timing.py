@@ -150,6 +150,14 @@ MODEL_SCOPES = (
     'bd_streams',         # models/hybrid_decoder.py, training/lm_loss.py:
     #                       building the two streams and their positions,
     #                       cutting the noised one out, the weights
+    # a looped stack (models/hybrid_decoder.py `total_ut_steps`): its passes
+    # are no leaves but path components `ut_<t>` (`PASS_SCOPE`), read into
+    # the reducer's `pass_s` beside whatever leaf the operation is under
+    'exit_gate',          # models/hybrid_decoder.py: the gate's logits on
+    #                       each pass's normed state
+    'exit_mix',           # training/lm_loss.py: the gate's log-sigmoids,
+    #                       the passes' probabilities, their entropy and
+    #                       the sums over tokens
     'loss',               # parallel/sharding.py train_step: what the
     #                       model's scopes do not claim inside the
     #                       differentiated loss
@@ -158,6 +166,9 @@ MODEL_SCOPES = (
 
 # `pair_<d_in>_<d_out>` / `pair_all_<d_out>` -> the leaf `pair`
 PAIR_SCOPE = re.compile(r'^pair_(\d+|all)_(\d+)$')
+# `ut_<t>`: pass t of a looped stack. A component of the path, as a flax
+# module's name is, and no leaf
+PASS_SCOPE = re.compile(r'^ut_(\d+)$')
 
 
 def named_scope(name: str):

@@ -23,6 +23,20 @@ block_length)`) and predicts the masked tokens in place, each weighted by
 
 over the noised stream's positions, in the same chunked head. The step draws
 nothing: the batch carries the noise (`noise_tokens` makes one).
+
+A third, for a looped stack (`make_looped_lm_loss`; `HybridDecoder` with
+`total_ut_steps` P above 1): every pass's normed state is an exit, read by
+the one head, and a learned gate says where a token leaves. With l_t[n] the
+cross-entropy of pass t at token n and lam_t[n] = sigmoid(gate(g_t[n])),
+
+    p_t = lam_t prod_{s < t} (1 - lam_s)   (t < P),
+    p_P = prod_{s < P} (1 - lam_s)         (the last pass takes what is left)
+    loss = mean_n [ sum_t p_t[n] l_t[n] - beta H(p[n]) ],
+    H(p) = - sum_t p_t log p_t
+
+with log p from log-sigmoids, in float32. Each pass goes through the chunked
+head once, its rows weighted by p_t, and the weight is differentiated: the
+gate's gradient comes through it.
 """
 from __future__ import annotations
 
@@ -46,10 +60,12 @@ def chunked_cross_entropy(h, kernel, targets, valid, chunk: int = 1024):
 def chunked_weighted_nll(h, kernel, targets, weight, chunk: int = 1024):
     """The same head with a float32 weight [N] a row: the SUM over rows of
     weight * (logsumexp(h kernel) - (h kernel)[target]); the caller
-    divides."""
+    divides. A weight [N, k] gives the k sums of one pass through the
+    head."""
     with named_scope('lm_head'):
-        return _chunked_nll(h, kernel, targets, weight, chunk,
-                            lambda wc, nll: wc * nll)
+        return _chunked_nll(
+            h, kernel, targets, weight, chunk,
+            lambda wc, nll: wc * (nll if wc.ndim == 1 else nll[:, None]))
 
 
 def _chunked_nll(h, kernel, targets, rows, chunk, weigh):
@@ -63,7 +79,7 @@ def _chunked_nll(h, kernel, targets, rows, chunk, weigh):
         logits = jnp.dot(hc, kernel, preferred_element_type=jnp.float32)
         picked = jnp.take_along_axis(logits, tc[:, None], axis=-1)[:, 0]
         nll = jax.nn.logsumexp(logits, axis=-1) - picked
-        return jnp.sum(weigh(rc, nll))
+        return jnp.sum(weigh(rc, nll), axis=0)
 
     # a Python loop, not a scan: inside a scanned, checkpointed body the
     # operations lose their scopes, and these are the head's products
@@ -161,6 +177,67 @@ def make_block_diffusion_loss(module, block_length: int, chunk: int = 1024):
             aux = dict(loss_main=loss,
                        bd_masked=jnp.sum(weight > 0, dtype=jnp.int32),
                        bd_weight=jnp.sum(weight))
+        if stats:
+            aux.update(expert_counters(stats))
+        return loss, aux
+
+    return loss_fn
+
+
+def make_looped_lm_loss(module, beta: float = 0.1, chunk: int = 1024):
+    """loss_fn(params, batch, rng) -> (loss, aux) for
+    `make_sharded_train_step`, the objective over a looped stack's exits
+    (this file's head); batch = {'tokens': [B, T] int32}. `aux`: `loss_main`
+    (the last pass's plain cross-entropy), `loss_ut` [P] (each pass's),
+    `exit_share` [P] (the mean of p_t over the valid tokens),
+    `exit_entropy` (the mean of H), `exit_mass_last` and `exit_tokens` (the
+    sum of p_P and the count of valid tokens: their ratio is
+    `exit_share[-1]`, for a reader that divides), and the expert layers'
+    counters where the pattern has any."""
+
+    def loss_fn(params, batch, rng):
+        del rng
+        with named_scope('loss'):      # as `make_lm_loss` opens it
+            return _loss(params, batch['tokens'])
+
+    def states(mdl, tokens):
+        main, _, stats = mdl.hidden_states(tokens)
+        return main, mdl.exit_logits(main), stats
+
+    def _loss(params, tokens):
+        b, t = tokens.shape
+        main, gate, stats = module.apply({'params': params}, tokens,
+                                         method=states)
+        kernel = module.head_kernel(params)
+        passes, d = main.shape[0], main.shape[-1]
+        targets = jnp.roll(tokens, -1, axis=1).reshape(-1)
+        valid = (jnp.broadcast_to(jnp.arange(t), (b, t)).reshape(-1)
+                 < t - 1).astype(jnp.float32)
+        n_tokens = jnp.sum(valid)
+        n_valid = jnp.maximum(n_tokens, 1)
+        with named_scope('exit_mix'):
+            gate = gate.astype(jnp.float32).reshape(passes - 1, -1)
+            stay = jnp.cumsum(jax.nn.log_sigmoid(-gate), axis=0)
+            zero = jnp.zeros_like(gate[:1])
+            # log p_t = log lam_t + sum_{s < t} log (1 - lam_s); lam_P = 1
+            log_p = jnp.concatenate((jax.nn.log_sigmoid(gate), zero)) \
+                + jnp.concatenate((zero, stay))
+            p = jnp.exp(log_p)
+            entropy = -jnp.sum(p * log_p, axis=0)
+        # a pass through the head gives two sums: p_t l_t, which is
+        # differentiated, and the plain l_t for `aux`
+        sums = jnp.stack([chunked_weighted_nll(
+            main[i].reshape(-1, d), kernel, targets,
+            jnp.stack((p[i] * valid, valid), axis=-1), chunk)
+            for i in range(passes)])
+        with named_scope('exit_mix'):
+            entropy = jnp.sum(entropy * valid) / n_valid
+            mass = jnp.sum(p * valid, axis=1)
+            loss = jnp.sum(sums[:, 0]) / n_valid - beta * entropy
+            aux = dict(loss_main=sums[-1, 1] / n_valid,
+                       loss_ut=sums[:, 1] / n_valid,
+                       exit_share=mass / n_valid, exit_entropy=entropy,
+                       exit_mass_last=mass[-1], exit_tokens=n_tokens)
         if stats:
             aux.update(expert_counters(stats))
         return loss, aux

@@ -37,6 +37,12 @@ configuration passes the published widths as overrides):
                        without rotation (`*`) beside sliding-window layers
                        with it (`W`), ReGLU experts ('relu') routed by the
                        attention step's input; untied head
+  * ouro_decoder     — the same module looped: layers of attention (rotation,
+                       no q/k norms) and a dense SwiGLU, a norm on each
+                       mixer's input and output (`sandwich_norm`), the whole
+                       stack run `total_ut_steps` times on one set of
+                       weights, an exit gate; untied head; trained over its
+                       exits (`training.lm_loss.make_looped_lm_loss`)
 """
 from __future__ import annotations
 
@@ -217,6 +223,22 @@ def smallthinker_decoder(**overrides) -> HybridDecoder:
     return HybridDecoder(**sizes)
 
 
+def ouro_decoder(**overrides) -> HybridDecoder:
+    """Tiny widths by default (CPU tests): two layers, each attention (4
+    heads, as many key-value heads, rotation, no q/k norms) and then a dense
+    SwiGLU, every mixer between two norms, the stack run four times on the
+    one set of weights with the final norm closing each pass, an exit gate
+    on each pass's normed state, untied head. Train it with
+    `training.lm_loss.make_looped_lm_loss(module, beta)`."""
+    sizes = dict(
+        vocab_rows=48, hidden_size=32, hybrid_override_pattern='*F*F',
+        intermediate_size=48, num_attention_heads=4, num_key_value_heads=4,
+        head_dim=8, rope_theta=1e6, layer_norm_epsilon=1e-6,
+        sandwich_norm=True, total_ut_steps=4)
+    sizes.update(overrides)
+    return HybridDecoder(**sizes)
+
+
 RECIPES = {
     'toy_denoise': toy_denoise,
     'flagship': flagship,
@@ -229,4 +251,5 @@ RECIPES = {
     'lfm2_decoder': lfm2_decoder,
     'sdar_decoder': sdar_decoder,
     'smallthinker_decoder': smallthinker_decoder,
+    'ouro_decoder': ouro_decoder,
 }

@@ -30,10 +30,12 @@ def _inputs(module, n=12, b=1, seed=0):
 # the SE(3) recipes (features + coordinates in, fibers out); the token
 # decoders' recipes take tokens: tests/test_token_decoder.py,
 # tests/test_hybrid_decoder.py, tests/test_lfm2_decoder.py,
-# tests/test_block_diffusion.py, tests/test_sliding_window.py
+# tests/test_block_diffusion.py, tests/test_sliding_window.py,
+# tests/test_looped_decoder.py
 @pytest.mark.parametrize('name', sorted(
     set(RECIPES) - {'token_decoder', 'hybrid_decoder', 'lfm2_decoder',
-                    'sdar_decoder', 'smallthinker_decoder'}))
+                    'sdar_decoder', 'smallthinker_decoder',
+                    'ouro_decoder'}))
 def test_recipe_forward_and_grad(name):
     builder = RECIPES[name]
     module = builder(dim=16) if name != 'toy_denoise' else builder()

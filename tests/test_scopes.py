@@ -14,7 +14,9 @@ import numpy as np
 import pytest
 
 from se3_transformer_tpu.observability import profiling
-from se3_transformer_tpu.observability.timing import MODEL_SCOPES, PAIR_SCOPE
+from se3_transformer_tpu.observability.timing import (
+    MODEL_SCOPES, PAIR_SCOPE, PASS_SCOPE,
+)
 
 PACKAGE = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), 'se3_transformer_tpu')
@@ -60,8 +62,13 @@ def test_every_named_scope_in_the_package_is_a_leaf():
         if text is None:
             continue
         if '{}' in text:
-            # `pair_<d_in>_<d_out>` / `pair_all_<d_out>`, the one pattern
-            assert PAIR_SCOPE.match(text.replace('{}', '1')), (path, line)
+            # `pair_<d_in>_<d_out>` / `pair_all_<d_out>`, the one pattern of
+            # a leaf; `ut_<t>`, a looped stack's pass, a component and no
+            # leaf (models/hybrid_decoder.py alone writes it)
+            filled = text.replace('{}', '1')
+            assert PAIR_SCOPE.match(filled) or (
+                PASS_SCOPE.match(filled)
+                and path == 'models/hybrid_decoder.py'), (path, line)
             continue
         assert text in MODEL_SCOPES, \
             f'{path}:{line}: named_scope({text!r}) is not in MODEL_SCOPES'
@@ -815,3 +822,74 @@ def test_on_a_tpu_a_global_layers_launches_are_filed_under_mha_core(
     shapes = {v.aval.shape for eqn, _ in _eqn_paths(jaxpr.jaxpr)
               for v in eqn.outvars}
     assert not shapes & {(1, 256, 16, 128), (1, 16, 256, 128)}, shapes
+
+
+LOOP_LEAVES = ('exit_gate', 'exit_mix')
+
+
+def test_the_looped_stacks_leaves_are_on_the_closed_list_and_new(labelled):
+    for leaf in LOOP_LEAVES:
+        assert leaf in MODEL_SCOPES
+    assert not set(LOOP_LEAVES) & set(
+        HYBRID_LEAVES + DECODER_LEAVES + SCONV_LEAVES + BD_LEAVES)
+    comps = {c for _, _, p in labelled for c in p.split(';')[0].split('/')}
+    assert not set(LOOP_LEAVES) & comps
+    # a pass is a component of the path and no leaf
+    assert not any(PASS_SCOPE.match(leaf) for leaf in MODEL_SCOPES)
+    assert not any(PASS_SCOPE.match(c) for c in comps)
+    assert profiling.scope_pass(
+        'jit(train_step)/loss/transpose(jvp(loss))/ut_2/blocks_0/norm/mul') \
+        == 'ut_2'
+    assert profiling.scope_pass('jit(train_step)/loss/blocks_0/mul') is None
+    assert profiling.scope_pass('jit(f)/loss/output_2/mul') is None
+
+
+def test_a_tiny_looped_step_carries_its_passes_in_all_three_phases():
+    """One set of blocks, four passes: flax gives the four calls of
+    `blocks_0` one name, so every path through a block (and through the
+    final norm that closes a pass) carries the component `ut_<t>` of its
+    pass, forward, replayed and backward alike; the exits' leaves are
+    outside any pass; every leaf reads as the other decoders write it."""
+    import optax
+
+    from se3_transformer_tpu.parallel.sharding import make_sharded_train_step
+    from se3_transformer_tpu.training.lm_loss import make_looped_lm_loss
+    from se3_transformer_tpu.training.recipes import RECIPES
+    module = RECIPES['ouro_decoder'](attention_block=8)
+    tokens = jax.ShapeDtypeStruct((2, 16), jnp.int32)
+    params = jax.eval_shape(module.init, jax.random.PRNGKey(0),
+                            tokens)['params']
+    optimizer = optax.adam(1e-4)
+    text = make_sharded_train_step(
+        make_looped_lm_loss(module, 0.1, chunk=8), optimizer).lower(
+        params, jax.eval_shape(optimizer.init, params), dict(tokens=tokens),
+        jax.ShapeDtypeStruct((2,), jnp.uint32)).as_text(debug_info=True)
+    paths = {p.rsplit('/', 1)[0] for p in re.findall(
+        r'"(jit\(train_step\)/[^"]*)"', text)}
+    cells = {(profiling.scope_leaf(p), profiling.scope_phase(p))
+             for p in paths}
+    assert {leaf for leaf, _ in cells} == {
+        'mha_core', 'mha_qkv', 'mha_out', 'dense_ff', 'embed', 'norm',
+        'lm_head', 'exit_gate', 'exit_mix', 'loss', 'optimizer'}
+    by_pass = {}
+    for p in paths:
+        by_pass.setdefault(profiling.scope_pass(p), set()).add(
+            (profiling.scope_leaf(p), profiling.scope_phase(p)))
+    assert set(by_pass) == {None, 'ut_0', 'ut_1', 'ut_2', 'ut_3'}
+    inside = {(leaf, phase) for leaf in ('mha_core', 'mha_qkv', 'mha_out',
+                                         'dense_ff', 'norm')
+              for phase in profiling.PHASES}
+    for t in range(4):      # a block's residual add is under no leaf of
+        #                     its own and reads as `loss`, as in every decoder
+        assert inside <= by_pass[f'ut_{t}'], t
+        assert {leaf for leaf, _ in by_pass[f'ut_{t}'] - inside} == {'loss'}
+    # every path through a block is in a pass; the exits are in none
+    assert all(profiling.scope_pass(p) for p in paths if '/blocks_' in p)
+    outside = {leaf for leaf, _ in by_pass[None]}
+    assert outside == {'embed', 'lm_head', 'exit_gate', 'exit_mix', 'loss',
+                       'optimizer'}
+    for leaf in ('lm_head', 'exit_gate', 'exit_mix'):
+        assert {ph for lf, ph in by_pass[None] if lf == leaf} >= {
+            'forward', 'backward'}, leaf
+    assert {ph for lf, ph in by_pass[None] if lf == 'lm_head'} == set(
+        profiling.PHASES)

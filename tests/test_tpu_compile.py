@@ -1422,6 +1422,79 @@ def test_smallthinker_decoder_step_compiles_and_fits(v5e, monkeypatch,
     assert 12 * 2 ** 30 < total < 14.75 * 2 ** 30, mem
 
 
+@pytest.mark.slow
+def test_ouro_decoder_step_compiles_and_fits(v5e, monkeypatch, capsys):
+    """The benchmark's looped cell: the published widths of its
+    configuration file on the one step factory at one sequence of 8,192
+    tokens, the objective over the four exits, compiled for the chip. The
+    four layers run four times: 16 forward and 16 backward launches of the
+    repo's own causal core under `mha_core` (16 heads of 128 in groups of
+    one) and none in a replay, the one pass before them (rotation, no norms)
+    forward, replayed and backward under `mha_qkv`, no launch of the
+    library's kernel and nothing laid out again around a core; every pass's
+    launches carry its `ut_<t>`, four of each role a pass; the gate and the
+    mix are in it; state plus temporaries fit; its memory is printed."""
+    import optax
+    from se3_transformer_tpu.observability import profiling
+    from se3_transformer_tpu.ops import (
+        expert_layer, latent_attention, sliding_window,
+    )
+    from se3_transformer_tpu.parallel.sharding import make_sharded_train_step
+    from se3_transformer_tpu.training.lm_loss import make_looped_lm_loss
+    from se3_transformer_tpu.training.recipes import RECIPES
+
+    for mod in (sliding_window, latent_attention, expert_layer):
+        monkeypatch.setattr(mod, 'is_tpu_backend', lambda: True)
+    cfg = json.load(open(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        'benchmark', 'configs', 'ouro-2.6b-loop4-train.json')))
+    module = RECIPES[cfg['recipe']](**cfg['model'], **cfg['overrides'])
+    assert sliding_window.kernels_run(8192, 512, 16, 16, 128)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+            tree)
+
+    tokens = jax.ShapeDtypeStruct((1, 8192), jnp.int32)
+    params = jax.eval_shape(module.init, jax.random.PRNGKey(0),
+                            tokens)['params']
+    assert sum(math.prod(a.shape) for a in
+               jax.tree_util.tree_leaves(params)) == 406_884_353
+    optimizer = optax.adam(1e-6)
+    compiled = make_sharded_train_step(
+        make_looped_lm_loss(module, **cfg['loss']), optimizer).lower(
+        on_chip(params), on_chip(jax.eval_shape(optimizer.init, params)),
+        on_chip(dict(tokens=tokens)), on_chip(jax.random.PRNGKey(1))).compile()
+    text = compiled.as_text()
+    passes, layers = 4, 4
+    _assert_the_cores_are_the_repos_launches(text, passes * layers, 0)
+    by_pass = {}
+    for role, path in _window_launches(text):
+        key = profiling.scope_pass(path), role, profiling.scope_phase(path)
+        by_pass[key] = by_pass.get(key, 0) + 1
+    assert by_pass == {
+        (f'ut_{t}', role, phase): layers for t in range(passes)
+        for role, phase in (
+            ('mha_core_fwd', 'forward'), ('mha_core_bwd', 'backward'),
+            ('qk_pass_fwd', 'forward'), ('qk_pass_fwd', 'replay'),
+            ('qk_pass_bwd', 'backward'))}, by_pass
+    _assert_no_relayout_around_the_core(text, 8192 * 16 * 128,
+                                        ('mha_core',), passes * layers)
+    for leaf in ('exit_gate', 'exit_mix'):
+        assert f'/{leaf}/' in text, leaf
+    _assert_product_front_ends_agree(compiled)
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    with capsys.disabled():
+        print(f'\nouro step for a v5e: arguments '
+              f'{mem.argument_size_in_bytes / 2**30:.2f} GiB, temporaries '
+              f'{mem.temp_size_in_bytes / 2**30:.2f} GiB, in all '
+              f'{total / 2**30:.2f} GiB of 15.75')
+    assert 8 * 2 ** 30 < total < 14.75 * 2 ** 30, mem
+
+
 def test_the_causal_path_lowers_as_it_did_before_the_two_streams(v5e):
     """`GroupedQueryAttention` at the short-convolution cell's widths, for
     the chip: called as the causal decoders call it and called with the new
